@@ -4,10 +4,12 @@ profiles them — see PERF.md and ops/pallas_kernels.py's adoption gate).
 """
 
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 
 from tigerbeetle_tpu.ops import hash_table as HT
+from tigerbeetle_tpu.ops import pallas_kernels as pk
 from tigerbeetle_tpu.ops.pallas_kernels import (
     ht_lookup_fused,
     probe_fusable,
@@ -58,3 +60,16 @@ def test_vmem_gate():
     assert probe_fusable(small)
     huge = HT.ht_init(1 << 21)  # (2^18+1, 24) u64 ≈ 50 MB
     assert not probe_fusable(huge)
+
+
+def test_tb_pallas_raises_instead_of_silent_xla(monkeypatch):
+    """The TPU compiler refuses the prototype, so asking for it must not
+    be answered by the XLA lookup under its name."""
+    table = HT.ht_init(1 << 6)
+    k = jnp.arange(1, 9, dtype=jnp.uint64)
+    monkeypatch.delenv("TB_PALLAS", raising=False)
+    found, _ = pk.ht_lookup_auto(table, k, k)
+    assert not bool(found.any())
+    monkeypatch.setenv("TB_PALLAS", "1")
+    with pytest.raises(NotImplementedError, match="TB_PALLAS"):
+        pk.ht_lookup_auto(table, k, k)

@@ -54,6 +54,35 @@ class _WallTime:
         return time.time_ns()
 
 
+class _CompileLog:
+    """Counts XLA backend compiles and their seconds (jax.monitoring):
+    `start` prints what warm-up compiled and, on shutdown, what was
+    still compiled on demand after `listening`."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.count = 0
+        self.seconds = 0.0
+        self._at_listening = (0, 0.0)
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event: str, seconds: float, **_kw) -> None:
+        if event == self.EVENT:
+            self.count += 1
+            self.seconds += seconds
+
+    def mark_listening(self) -> None:
+        self._at_listening = (self.count, self.seconds)
+
+    def after_listening(self) -> dict:
+        n0, s0 = self._at_listening
+        return {"count": self.count - n0,
+                "seconds": round(self.seconds - s0, 3)}
+
+
 def cmd_start(args) -> int:
     # Shutdown rides a signal FLAG from the very top: a SIGINT landing
     # during storage open / warmup / journal recovery must still reach
@@ -69,6 +98,24 @@ def cmd_start(args) -> int:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    compile_log = None
+    if args.engine == "device":
+        # The device engine is the only one that starts a JAX backend:
+        # say which device serves (the chip smoke reads this line) and
+        # keep compiled programs across boots.
+        import jax
+
+        from . import compile_cache
+        from . import native as _native
+
+        print(f"compile cache: {compile_cache.enable()}", flush=True)
+        compile_log = _CompileLog()
+        dev = jax.devices()
+        print(f"device: platform={dev[0].platform} "
+              f"kind={dev[0].device_kind!r} count={len(dev)}", flush=True)
+        print(f"storage engine: "
+              f"{'native' if _native.available() else 'python'}",
+              flush=True)
     from .state_machine import StateMachine
     from .vsr.message_bus import MessageBus
     from .vsr.replica import Replica
@@ -125,10 +172,12 @@ def cmd_start(args) -> int:
         # Compile the serving kernels BEFORE accepting connections: the
         # first create_transfers compile (~10s+ cold) must not land on a
         # client request's timeout budget.
-        from .ops.ledger import warmup_kernels
+        from .ops.warmup import warmup_kernels
 
         warm_s = warmup_kernels(a_cap=a_cap, t_cap=t_cap)
-        print(f"kernels warm in {warm_s:.1f}s", flush=True)
+        print(f"kernels warm in {warm_s:.1f}s "
+              f"({compile_log.count} compiles, "
+              f"{compile_log.seconds:.1f}s compiling)", flush=True)
     metrics_server = None
     if args.metrics_port is not None:
         from .metrics import MetricsServer, render_prometheus
@@ -154,6 +203,8 @@ def cmd_start(args) -> int:
         print(f"metrics on http://127.0.0.1:{metrics_server.port}/metrics",
               flush=True)
     replica.open()
+    if compile_log is not None:
+        compile_log.mark_listening()
     print(f"replica {args.replica} listening on "
           f"{addresses[args.replica][0]}:{addresses[args.replica][1]} "
           f"(cluster={args.cluster}, engine={args.engine})", flush=True)
@@ -188,6 +239,17 @@ def cmd_start(args) -> int:
         tracer.flush_statsd()
         if args.trace:
             tracer.dump_chrome_trace(args.trace)
+    if args.engine == "device":
+        # One JSON line on orderly shutdown: what the device engine did
+        # (the chip smoke's zero-host-fallback check reads it).
+        led = replica.state_machine.led
+        print(json.dumps({"shutdown": {
+            "commit": replica.commit_min,
+            "commit_windows": replica._windows_committed,
+            "mirror_regime": bool(led._hard_regime),
+            "compiles_after_listening": compile_log.after_listening(),
+            "fallback_stats": led.fallback_stats(),
+        }}), flush=True)
     return 0
 
 

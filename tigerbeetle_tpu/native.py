@@ -9,6 +9,8 @@ verified by tests/test_native.py against hashlib.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -26,22 +28,47 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _source_hash(*sources: str) -> str:
+    h = hashlib.sha256()
+    for s in sources:
+        with open(s, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _build(src: str, lib: str, *extra: str) -> bool:
+    """Compile `src` into `lib` and record the hash of the sources it
+    was built from. A failed build is reported, never silent: the
+    pure-Python engine is correct but it is not what was asked for."""
+    tmp = f"{lib}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-fPIC", "-shared", *extra, "-o", lib, src],
+            ["g++", "-O2", "-fPIC", "-shared", *extra, "-o", tmp, src],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib)
+        with open(tmp, "w") as f:
+            f.write(_source_hash(src, _HDR))
+        os.replace(tmp, lib + ".sha256")
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        logging.getLogger("tigerbeetle_tpu.native").warning(
+            "native build of %s failed (%r) %s — serving from the "
+            "pure-Python engine", os.path.basename(src), e,
+            detail.decode(errors="replace")[-400:])
         return False
 
 
 def _stale(lib: str, *sources: str) -> bool:
-    if not os.path.exists(lib):
+    """True unless `lib` was built from exactly these sources (keyed on
+    their content hash: mtimes say nothing in a fresh checkout or a
+    copied tree)."""
+    try:
+        with open(lib + ".sha256") as f:
+            built_from = f.read().strip()
+    except OSError:
         return True
-    mtime = os.path.getmtime(lib)
-    return any(os.path.getmtime(s) > mtime
-               for s in sources if os.path.exists(s))
+    return not os.path.exists(lib) or built_from != _source_hash(*sources)
 
 
 def load() -> Optional[ctypes.CDLL]:

@@ -3365,45 +3365,3 @@ def _transfers_from_arrays(ev: dict) -> list[Transfer]:
         )
         for i in range(n)
     ]
-
-
-def warmup_kernels(a_cap: int = 1 << 17, t_cap: int = 1 << 21) -> float:
-    """Pre-compile the serving-path kernels on a THROWAWAY ledger so the
-    first client request doesn't eat the jit compile (the jitted callables
-    are module-level, so the executable cache is shared; shapes are keyed
-    by (a_cap, t_cap, batch bucket), and every serving batch <=1024 events
-    lands in the 1024 bucket). Returns elapsed seconds. Reference analog:
-    no compile step exists (src/tigerbeetle/main.zig:251 serves cold)."""
-    import time as _time
-
-    from ..types import Account as _Account
-    from ..types import Transfer as _Transfer
-    from ..types import TransferFlags as _TF
-
-    from ..types import AccountFlags as _AF
-
-    t0 = _time.time()
-    led = DeviceLedger(a_cap=a_cap, t_cap=t_cap)
-    led.create_accounts(
-        [_Account(id=1, ledger=1, code=1), _Account(id=2, ledger=1, code=1),
-         _Account(id=3, ledger=1, code=1,
-                  flags=int(_AF.debits_must_not_exceed_credits))],
-        1_000)
-    # Warm the limit-fixpoint kernel first (a breach batch): its first
-    # compile must never land on a live request — and it must run BEFORE
-    # any fallback batch puts the throwaway ledger into the mirror regime
-    # (mirror-routed batches never reach the kernels).
-    led.create_transfers(
-        [_Transfer(id=4, debit_account_id=3, credit_account_id=2, amount=1,
-                   ledger=1, code=1)],
-        2_000)
-    assert led.fixpoint_batches == 1, "breach batch must warm the fixpoint"
-    led.create_transfers(
-        [_Transfer(id=1, debit_account_id=1, credit_account_id=2, amount=1,
-                   ledger=1, code=1),
-         _Transfer(id=2, debit_account_id=1, credit_account_id=2, amount=1,
-                   ledger=1, code=1, flags=int(_TF.pending), timeout=3600),
-         _Transfer(id=3, pending_id=2, amount=1, ledger=1, code=1,
-                   flags=int(_TF.post_pending_transfer))],
-        3_000)
-    return _time.time() - t0

@@ -11,14 +11,11 @@ the adoption gate. The XLA path stays the default everywhere:
 - VMEM residency bounds applicability: the packed table must fit the
   ~16 MiB v5e budget (capacity gate below).
 
-Enable with TB_PALLAS=1 to dispatch the fused probe where the gate
-admits it; tests run the kernel in interpreter mode on CPU, so the
-semantics are pinned before the first on-chip window profiles it.
-
-TB_PALLAS is read at TRACE time: it must be set before the process's
-first kernel dispatch (jit caches bake the chosen branch in). An on-chip
-A/B profile must therefore run each arm in a FRESH process — flipping
-the env var mid-process silently measures the cached arm twice.
+Tests run the kernel in interpreter mode on CPU, which pins its
+semantics and nothing else: put to the TPU compiler (a described v5e,
+PR 23) the kernel is refused at Mosaic lowering, so TB_PALLAS=1 makes
+`ht_lookup_auto` raise rather than serve from the XLA lookup in
+silence. The prototype is off the serving path until it compiles.
 """
 
 from __future__ import annotations
@@ -101,14 +98,18 @@ def ht_lookup_fused(table: dict, k_hi, k_lo, *, interpret: bool = False):
 
 
 def ht_lookup_auto(table: dict, k_hi, k_lo):
-    """Adoption gate: fused probe when enabled + on a TPU backend +
-    VMEM-admissible, else the XLA path (identical results either way —
-    differential-tested). The backend check matters: pallas_call has no
-    CPU/GPU lowering, and TB_PALLAS=1 on a CPU host must degrade to the
-    XLA path, not crash the serving kernel."""
+    """The serving kernels' lookup: the XLA path. TB_PALLAS=1 asks for
+    the fused probe, which the TPU compiler REFUSES (compiled for a
+    described v5e, PR 23: the in-kernel `jnp.take` row gather fails
+    Mosaic lowering with "Shape mismatch in input, indices and output",
+    before the 64-bit operands are even reached) — so the request
+    raises instead of being served by the XLA lookup under the
+    prototype's name."""
     from .hash_table import ht_lookup
 
-    if (pallas_enabled() and jax.default_backend() == "tpu"
-            and probe_fusable(table, int(k_hi.shape[0]))):
-        return ht_lookup_fused(table, k_hi, k_lo)
+    if pallas_enabled():
+        raise NotImplementedError(
+            "TB_PALLAS=1: ht_lookup_fused does not compile for TPU "
+            "(Mosaic refuses its row gather); it runs in interpreter "
+            "mode only — unset TB_PALLAS to serve from the XLA lookup")
     return ht_lookup(table, k_hi, k_lo)

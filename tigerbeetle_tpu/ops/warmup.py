@@ -1,0 +1,199 @@
+"""Serving warm-up: what `start --engine=device` compiles before it
+prints `listening`, so no client request pays a compile out of its
+timeout budget (vsr/client.py: 10 s by default).
+
+At production caps (a_cap 2^17, t_cap 2^21) one create_transfers tier
+takes the TPU compiler 100-150 s of ONE core (PERF.md "Bring-up on the
+local v5e"), and the dispatch surface is tiers x four batch buckets x
+window depths 2..8 — warming all of it cold would take the better part
+of an hour. So the warm set is chosen, not exhaustive:
+
+- the smallest and the widest batch bucket (1024: the everyday request;
+  8192: the wire maximum), each on the plain tier and the limit
+  fixpoint tier the plain tier escalates to;
+- create_accounts (always padded to the widest bucket);
+- the write-through delta gathers those dispatch.
+
+Still compiled on demand, inside some request's budget: the 2048 and
+4096 buckets, the deep fixpoint tiers, and the balancing / imported
+tiers. Commit windows (depths 2..8 x bucket, one program each) are not
+warmed either: a primary commits prepare by prepare, so only a backup
+catching up or a WAL replay at `open` dispatches them — before
+`listening`, or off the client's clock.
+
+Cold, the expensive entries compile AHEAD OF TIME and IN PARALLEL into
+the persistent compile cache (compile_cache.py; one thread each — the
+compiler is single-threaded per program); the warm-up proper then
+drives a throwaway serving-mode ledger through the real entry points,
+which finds them there. With the cache already populated (every boot
+after the first) the first phase is a handful of cache reads.
+"""
+
+from __future__ import annotations
+
+import os
+import time as _time
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+
+from .ledger import (
+    N_PAD,
+    DeviceLedger,
+    init_state,
+    pad_account_events,
+    pad_transfer_events,
+    stack_chain_window,
+    stack_superbatch,
+)
+
+WARM_BUCKETS = (1024, N_PAD)
+PRECOMPILE_WORKERS = 6  # one core and ~3-5 GB of compiler memory each
+
+
+def abstract(tree, sharding=None):
+    """The shapes of `tree` (optionally placed on `sharding`: the chip
+    compile tests hand in a described, unattached device)."""
+    import jax
+
+    def one(x):
+        x = x if hasattr(x, "shape") else np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    return jax.tree.map(one, tree)
+
+
+def abstract_state(a_cap: int, t_cap: int, sharding=None):
+    import jax
+
+    return abstract(jax.eval_shape(lambda: init_state(a_cap, t_cap)),
+                    sharding)
+
+
+def _no_transfers():
+    from .batch import transfers_to_arrays
+
+    return transfers_to_arrays([])
+
+
+def batch_args(a_cap, t_cap, n_pad, sharding=None):
+    """(state, events, timestamp, n) of the per-batch transfer tiers."""
+    ev = pad_transfer_events(_no_transfers(), n_pad)
+    return (abstract_state(a_cap, t_cap, sharding),
+            *abstract((ev, np.uint64(1), np.int32(0)), sharding))
+
+
+def accounts_args(a_cap, t_cap, sharding=None):
+    from .batch import accounts_to_arrays
+
+    ev = pad_account_events(accounts_to_arrays([]))
+    return (abstract_state(a_cap, t_cap, sharding),
+            *abstract((ev, np.uint64(1), np.int32(0)), sharding))
+
+
+def super_args(a_cap, t_cap, depth, n_pad, sharding=None):
+    """The replica's all-or-nothing commit window: `depth` prepares
+    flattened into one superbatch."""
+    packed = stack_superbatch([_no_transfers()] * depth,
+                              [10 ** 12] * depth, n_pad)
+    return (abstract_state(a_cap, t_cap, sharding),
+            *abstract(packed, sharding))
+
+
+def chain_args(a_cap, t_cap, depth, n_pad, sharding=None):
+    """The scan-form chain window (the pipelined serving route)."""
+    packed = stack_chain_window([_no_transfers()] * depth,
+                                [10 ** 12] * depth, n_pad)
+    return (abstract_state(a_cap, t_cap, sharding),
+            *abstract(packed, sharding))
+
+
+def warm_set(a_cap: int, t_cap: int, sharding=None) -> dict:
+    """name -> (jit entry, abstract args): the expensive programs of the
+    warm set (module docstring)."""
+    from . import fast_kernels as fk
+
+    out = {"create_accounts_fast@8192": (
+        fk.create_accounts_fast_jit, accounts_args(a_cap, t_cap, sharding))}
+    for n_pad in WARM_BUCKETS:
+        args = batch_args(a_cap, t_cap, n_pad, sharding)
+        out[f"create_transfers_fast@{n_pad}"] = (
+            fk.create_transfers_fast_jit, args)
+        out[f"create_transfers_fixpoint@{n_pad}"] = (
+            fk.create_transfers_fixpoint_jit, args)
+    return out
+
+
+def precompile(entries: dict, workers: int = PRECOMPILE_WORKERS) -> dict:
+    """Compile `entries` concurrently. Nothing is kept in memory: the
+    point is the persistent cache entry each compile leaves behind.
+    Returns name -> seconds."""
+
+    def one(item):
+        name, (jitfn, args) = item
+        t0 = _time.monotonic()
+        jitfn.lower(*args).compile()
+        return name, round(_time.monotonic() - t0, 1)
+
+    workers = max(1, min(workers, len(entries), os.cpu_count() or 1))
+    with ThreadPoolExecutor(workers) as pool:
+        return dict(pool.map(one, entries.items()))
+
+
+def _transfers(n, debit, credit, first_id):
+    from ..types import Transfer
+    from .batch import transfers_to_arrays
+
+    return transfers_to_arrays([
+        Transfer(id=first_id + i, debit_account_id=debit,
+                 credit_account_id=credit, amount=1, ledger=1, code=1)
+        for i in range(n)])
+
+
+def warmup_kernels(a_cap: int = 1 << 17, t_cap: int = 1 << 21) -> float:
+    """Compile the warm set (module docstring); returns elapsed seconds.
+    Reference analog: none — the reference serves cold
+    (src/tigerbeetle/main.zig:251), it has no compile step."""
+    import jax
+
+    from ..oracle.state_machine import StateMachineOracle
+    from ..types import Account, AccountFlags
+
+    t0 = _time.monotonic()
+    if jax.config.jax_compilation_cache_dir:
+        precompile(warm_set(a_cap, t_cap))
+    # The throwaway ledger serves as the replica's does: write-through
+    # to a host mirror, event ring recycled, flush columns retained
+    # (StateMachine.attach_durable) — those select the delta gathers.
+    led = DeviceLedger(a_cap=a_cap, t_cap=t_cap,
+                       write_through=StateMachineOracle())
+    led.recycle_events = True
+    led.retain_flush_columns = True
+    led.create_accounts(
+        [Account(id=1, ledger=1, code=1), Account(id=2, ledger=1, code=1),
+         Account(id=3, ledger=1, code=1,
+                 flags=int(AccountFlags.debits_must_not_exceed_credits))],
+        1_000)
+    ts = 10_000
+    nid = 1
+    # Plain tier first: a breach leaves the ledger dispatching
+    # fixpoint-first until a breach-free batch cools it down.
+    sizes = [b // 2 + 1 for b in WARM_BUCKETS]  # lands in bucket b
+    for n in sizes:
+        ts += n
+        led.create_transfers_soa(_transfers(n, 1, 2, nid), ts)
+        nid += n
+    for n in sizes:
+        # Account 3 has no credits: the plain tier proves nothing and
+        # hands the batch to the limit fixpoint.
+        ts += n
+        led.create_transfers_soa(_transfers(n, 3, 2, nid), ts)
+        nid += n
+    assert led.fixpoint_batches == len(sizes), \
+        "breach batches must warm the fixpoint tier"
+    assert led.fallbacks == 0, "warm-up must stay on the device"
+    # What a commit does next: the durable flusher takes the captured
+    # delta columns and the mirror drains (device -> host fetches).
+    led.drain_mirror()
+    led.take_flush_columns()
+    return _time.monotonic() - t0
