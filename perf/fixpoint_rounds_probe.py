@@ -11,7 +11,8 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 import jax
 import numpy as np

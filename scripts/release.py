@@ -43,7 +43,6 @@ AREAS = (
     ("tests/", "Testing"),
     ("tigerbeetle_tpu/testing/", "Testing"),
     ("bench", "Benchmarks"),
-    ("onchip/", "Benchmarks"),
     ("scripts/", "Tooling"),
     ("tigerbeetle_tpu/", "State machine & framework"),
 )

@@ -6,6 +6,7 @@ confirms). The broker here implements the server side of the same subset,
 so both directions of the codec are exercised honestly over a real socket.
 """
 
+import os
 import json
 import socket
 import struct
@@ -47,6 +48,8 @@ from tigerbeetle_tpu.amqp import (
     method_frame,
     shortstr,
 )
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class MiniBroker:
@@ -344,13 +347,13 @@ class TestAmqpCommand:
         subprocess.run(
             [sys.executable, "-m", "tigerbeetle_tpu", "format", "--cluster=4",
              "--replica=0", "--replica-count=1", "--small", str(path)],
-            check=True, cwd="/root/repo", timeout=60,
+            check=True, cwd=REPO, timeout=60,
             stdout=subprocess.DEVNULL)
         proc = subprocess.Popen(
             [sys.executable, "-m", "tigerbeetle_tpu", "start",
              f"--addresses={address}", "--replica=0", "--cluster=4",
              "--engine=oracle", "--small", str(path)],
-            cwd="/root/repo", env=dict(os.environ),
+            cwd=REPO, env=dict(os.environ),
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
         broker = MiniBroker()
         try:

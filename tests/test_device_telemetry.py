@@ -67,7 +67,7 @@ def test_telemetry_pack_preserves_word_order():
     assert out.dtype == np.uint32
 
 
-def test_tel_causes_cover_fallback_taxonomy():
+def test_tel_causes_cover_fallback_causes():
     # Every kernel fb_cause (plus the two exchange breaches and the
     # scan's transitive poison) must be encodable — a new cause key
     # must be added to TEL_CAUSES or the decode reads code_<n>.

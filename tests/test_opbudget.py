@@ -117,9 +117,8 @@ def test_heavy_census_counts_collectives_inside_shard_map():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from tigerbeetle_tpu.parallel.shard_utils import get_shard_map
 
-    shard_map = get_shard_map()
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
     def body(a):
@@ -148,9 +147,8 @@ def test_scan_body_census_counts_collectives_and_bytes():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from tigerbeetle_tpu.parallel.shard_utils import get_shard_map
 
-    shard_map = get_shard_map()
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
     def body(a, xs):

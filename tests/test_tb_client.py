@@ -17,6 +17,8 @@ import pytest
 from tigerbeetle_tpu.clients import CClient, c_client_available
 from tigerbeetle_tpu.types import Account, Operation, Transfer
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 pytestmark = pytest.mark.skipif(
     not c_client_available(), reason="native toolchain unavailable")
 
@@ -65,13 +67,13 @@ def single_replica(tmp_path):
     subprocess.run(
         [sys.executable, "-m", "tigerbeetle_tpu", "format", "--cluster=9",
          "--replica=0", "--replica-count=1", "--small", str(path)],
-        check=True, cwd="/root/repo", env=env, timeout=60,
+        check=True, cwd=REPO, env=env, timeout=60,
         stdout=subprocess.DEVNULL)
     proc = subprocess.Popen(
         [sys.executable, "-m", "tigerbeetle_tpu", "start",
          f"--addresses={address}", "--replica=0", "--cluster=9",
          "--engine=oracle", "--small", str(path)],
-        cwd="/root/repo", env=env,
+        cwd=REPO, env=env,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
     try:
         yield address

@@ -10,19 +10,23 @@ a donated state leaf whose sharding silently degrades to replicated
 out_shardings default) so every device suddenly holds, copies, and
 donates the WHOLE ledger again.
 
-It parses the lowered StableHLO of each partitioned entry and asserts:
+It parses the lowered StableHLO of each partitioned entry — as JAX 0.9
+lowers it, with Shardy: shardings are `sdy.sharding =
+#sdy.sharding<@mesh, [{"batch"}, {}, {}]>` attrs on the @main
+arguments (a dimension is sharded when its axis list names an axis;
+`[{}, {}]` or `@empty_mesh` is replicated), and shard_map is one
+`sdy.manual_computation` whose `out_shardings=[...]` say how each
+result leaves it — and asserts:
 
   - every `jax.buffer_donor` input (the donated state leaves) carries
-    an `mhlo.sharding = "{devices=...}"` attr — present, and not
-    `"{replicated}"` / `"{maximal...}"`;
+    an `sdy.sharding` that shards at least one dimension;
   - the donated-and-sharded input count >= the state leaf count (no
     leaf slipped out of the donated set into replicated-land);
-  - the output side round-trips through at least as many
-    `@SPMDShardToFullShape` device-sharded custom calls (shard_map's
-    exit markers) as there are state leaves — the state comes BACK
-    sharded, not gathered;
+  - shard_map returns at least as many axis-sharded results
+    (`out_shardings` entries) as there are state leaves — the state
+    comes BACK sharded, not gathered;
   - no state-sized operand is silently replicated: any @main input
-    without a devices-sharding whose byte size reaches the largest
+    without an axis sharding whose byte size reaches the largest
     sharded state leaf is flagged (a whole-state table passed
     replicated defeats the layout even if the named state is fine).
 
@@ -43,7 +47,37 @@ _ELEM_BYTES = {
 _MAIN_RE = re.compile(
     r"func\.func\s+public\s+@main\((.*?)\)\s*->", re.S)
 _TENSOR_RE = re.compile(r"tensor<([^>]*)>")
-_DEVICES_RE = re.compile(r'mhlo\.sharding\s*=\s*"\{devices=')
+_SDY_ARG_RE = re.compile(
+    r"sdy\.sharding\s*=\s*#sdy\.sharding<@\w+,\s*\[(.*?)\]>")
+_SDY_ENTRY_RE = re.compile(r"<@\w+,\s*\[(.*?)\]>")
+
+
+def _axis_sharded(dims: str) -> bool:
+    """True when a Shardy dimension list like `{"batch"}, {}, {}` names
+    a mesh axis on some dimension (`{}` everywhere = replicated)."""
+    return '{"' in dims
+
+
+def arg_axis_sharded(arg_decl: str) -> bool:
+    m = _SDY_ARG_RE.search(arg_decl)
+    return bool(m) and _axis_sharded(m.group(1))
+
+
+def manual_out_shardings(text: str) -> list[str]:
+    """The dimension lists of every `out_shardings=[...]` entry of the
+    lowered module's sdy.manual_computation ops (shard_map's exits)."""
+    out = []
+    key = "out_shardings=["
+    pos = text.find(key)
+    while pos != -1:
+        i = pos + len(key)
+        depth, j = 1, i
+        while depth and j < len(text):
+            depth += {"[": 1, "]": -1}.get(text[j], 0)
+            j += 1
+        out.extend(_SDY_ENTRY_RE.findall(text[i:j - 1]))
+        pos = text.find(key, j)
+    return out
 
 
 def tensor_nbytes(tensor_body: str) -> int:
@@ -104,34 +138,32 @@ def verify_lowered(lowered, n_state_leaves: int,
         # donor) or `tf.aliasing_output = N` (donor aliased to an
         # output) depending on whether XLA established the alias.
         donated = "jax.buffer_donor" in a or "tf.aliasing_output" in a
-        devices = bool(_DEVICES_RE.search(a))
-        replicated = "{replicated}" in a or "{maximal" in a
+        devices = arg_axis_sharded(a)
         arg_meta.append((i, nbytes, donated, devices))
         if donated:
-            if devices and not replicated:
+            if devices:
                 donated_sharded += 1
                 sharded_sizes.append(nbytes)
             else:
                 fails.append(
                     f"{name}: donated input #{i} "
                     f"({tm.group(1) if tm else '?'}) carries no "
-                    "devices sharding (replicated donated state — the "
+                    "axis sharding (replicated donated state — the "
                     "partitioned layout regressed)")
     if donated_sharded < n_state_leaves:
         fails.append(
             f"{name}: {donated_sharded} donated+sharded inputs < "
             f"{n_state_leaves} state leaves (a state leaf left the "
             "donated sharded set)")
-    # Output side: shard_map exits through @SPMDShardToFullShape; the
-    # state must come back device-sharded, leaf for leaf.
-    out_sharded = len(re.findall(
-        r'@SPMDShardToFullShape.*?mhlo\.sharding\s*=\s*"\{devices=',
-        text))
+    # Output side: shard_map exits through sdy.manual_computation; the
+    # state must come back axis-sharded, leaf for leaf.
+    out_sharded = sum(1 for dims in manual_out_shardings(text)
+                      if _axis_sharded(dims))
     if out_sharded < n_state_leaves:
         fails.append(
-            f"{name}: {out_sharded} device-sharded "
-            f"@SPMDShardToFullShape outputs < {n_state_leaves} state "
-            "leaves (state is gathered, not returned sharded)")
+            f"{name}: {out_sharded} axis-sharded shard_map results < "
+            f"{n_state_leaves} state leaves (state is gathered, not "
+            "returned sharded)")
     # Silent replication: any input as large as the biggest sharded
     # state leaf but carrying no devices sharding is whole-state mass
     # being re-shipped to every device.
@@ -141,7 +173,7 @@ def verify_lowered(lowered, n_state_leaves: int,
             if not devices and nbytes >= threshold:
                 fails.append(
                     f"{name}: input #{i} ({nbytes} B) is state-sized "
-                    "but replicated (no devices sharding) — a "
+                    "but replicated (no axis sharding) — a "
                     "whole-state operand is shipped to every device")
     return fails
 

@@ -55,7 +55,6 @@ from ..ops.fast_kernels import (
     per_event_status,
 )
 from ..trace import Event, NullTracer
-from .shard_utils import get_shard_map
 
 __all__ = ["make_sharded_create_transfers", "shard_batch", "ShardedRouter",
            "MODES"]
@@ -84,7 +83,7 @@ def make_sharded_create_transfers(mesh: Mesh, axis: str = "batch",
     contract as the matching single-chip jit entry. `ev` arrays must be
     divisible by the mesh axis size (pad_transfer_events' N_PAD=8192
     divides any power-of-two mesh)."""
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     assert mode in MODES, mode
     n_dev = mesh.shape[axis]

@@ -103,7 +103,7 @@ from ..ops.ledger import (
 from ..trace import Event, FlightRecorder, Histogram, NullTracer
 from .full_sharded import MODES, _MODE_KWARGS, ShardedRouter
 from .shard_utils import (
-    OwnershipTable, get_shard_map, owner_read, owner_read_int,
+    OwnershipTable, owner_read, owner_read_int,
     shard_of_id, shard_of_int, writes_here,
 )
 
@@ -592,7 +592,7 @@ def make_partitioned_create_transfers(mesh: Mesh, axis: str = "batch",
     the overhead-probe baseline. `overlay` (elastic shards) is the
     static ownership-override tuple baked into the lowering; () — the
     default — lowers byte-identically to the pre-overlay artifact."""
-    shard_map = get_shard_map()
+    from jax import shard_map
     assert mode in MODES, mode
     n_dev = mesh.shape[axis]
 
@@ -662,7 +662,7 @@ def make_partitioned_chain_create_transfers(mesh: Mesh,
     is ONE dispatch whose whole-program op count is flat in W (the
     scan body is censused once — partitioned_chain tiers in
     perf/opbudget_r09.json)."""
-    shard_map = get_shard_map()
+    from jax import shard_map
     assert mode in MODES, mode
     n_dev = mesh.shape[axis]
 
@@ -1034,7 +1034,7 @@ class PartitionedRouter:
     def resync(self, oracle):
         """Bounded oracle-replay resync of the lost range(s): rebuild
         the sharded state from the last verified oracle through the
-        supervisor recovery path's event taxonomy (`shard_resync`
+        supervisor recovery path's event classes (`shard_resync`
         cause). Returns the fresh stacked state.
 
         Staging is torn down FIRST: a pack staged under the

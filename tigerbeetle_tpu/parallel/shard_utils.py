@@ -1,11 +1,9 @@
 """Shared SPMD plumbing for the sharded modules.
 
-Two things live here because BOTH parallel/full_sharded.py (replicated
+This lives here because BOTH parallel/full_sharded.py (replicated
 state, sharded per-event stage) and parallel/partitioned.py (sharded
-state, exchange-assembled per-event stage) need them and must agree:
+state, exchange-assembled per-event stage) need it and must agree:
 
-  - `get_shard_map()`: the jax.shard_map / jax.experimental.shard_map
-    import fallback, previously duplicated per module;
   - `shard_of_id()`: the ownership function — which mesh shard owns a
     128-bit object id. The device kernels, the host packers
     (partitioned_from_oracle), and the oracle-side digest pack
@@ -42,16 +40,6 @@ _C1 = 0x9E3779B97F4A7C15
 _C2 = 0xBF58476D1CE4E5B9
 _C3 = 0x94D049BB133111EB
 _M64 = (1 << 64) - 1
-
-
-def get_shard_map():
-    """Resolve shard_map across jax versions (>=0.5 exports it from the
-    top-level namespace; older jax keeps it under experimental)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-0.5 jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map
 
 
 def mix_id(k_hi, k_lo):

@@ -57,7 +57,7 @@ from .trace import Event, FlightRecorder, NullTracer, fmt_trace_id
 class TransientDispatchError(RuntimeError):
     """A device dispatch failed in a way worth retrying (the chaos
     harness's injected dispatch failures subclass this; a real backend
-    wrapper would translate transient PJRT/tunnel errors into it)."""
+    wrapper would translate transient PJRT errors into it)."""
 
 
 class DispatchTimeout(TransientDispatchError):

@@ -50,15 +50,9 @@ SLACK_MS = 5.0
 # The committed BENCH trajectory's pinned p99 series: latest vs best
 # prior. Cross-run machines differ more than same-gate runs do.
 TRAJECTORY_TOLERANCE = 2.0
-# Trajectory RESTART marker: round 9 moved the bench serving loop onto
-# the fused partitioned-chain route, so p99 values before this record
-# measure a DIFFERENT workload shape — comparing across the cut is
-# apples-to-oranges (the r01-r05 series' best-prior would red every
-# honest post-restart record). The audit walks records from this
-# basename forward only; restarting again means bumping this marker in
-# the same commit that restarts the series (see
-# docs/operating/monitoring.md "Trajectory restarts").
-TRAJECTORY_RESTART = "BENCH_r06.json"
+# The series starts EMPTY: PR 23 deleted every committed BENCH record
+# (all were CPU runs or re-reports of one file taken over a retired
+# remote link); the next benchmark PR commits the first of the new one.
 
 WARMUP_WINDOWS = 2
 MEASURE_WINDOWS = 12
@@ -116,9 +110,7 @@ def measure(windows: int = MEASURE_WINDOWS,
 
 
 def check_trajectory(bench_glob: str | None = None) -> int:
-    """Audit the committed BENCH_r*.json pinned p99 series, from the
-    TRAJECTORY_RESTART record forward (earlier records measured a
-    different workload shape — see the marker's comment). Returns
+    """Audit the committed BENCH_r*.json pinned p99 series. Returns
     failure count; records without the series are reported, never
     silently skipped. Schema-stable across record generations: the
     audit keys ONLY on `parsed.serving_batch_latency.p99_ms`, so
@@ -126,13 +118,6 @@ def check_trajectory(bench_glob: str | None = None) -> int:
     before ISSUE 20) audit identically to new ones (`bench_glob` lets
     tests prove that on synthetic old records)."""
     paths = sorted(glob.glob(bench_glob or BENCH_GLOB))
-    names = [os.path.basename(p) for p in paths]
-    if TRAJECTORY_RESTART in names:
-        paths = paths[names.index(TRAJECTORY_RESTART):]
-    else:
-        print(f"[bench-reg] trajectory: restart marker "
-              f"{TRAJECTORY_RESTART} not found; auditing the full "
-              f"series", flush=True)
     series = []
     for path in paths:
         with open(path) as f:

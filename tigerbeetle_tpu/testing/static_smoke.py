@@ -53,7 +53,7 @@ def _negative_proofs(entries) -> dict[str, bool]:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from ..jaxhound import determinism, hostdet, retrace, shardspec

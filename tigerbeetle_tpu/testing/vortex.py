@@ -24,6 +24,11 @@ import threading
 import time
 from typing import Optional
 
+# The checkout this package was imported from: child processes run
+# `python -m tigerbeetle_tpu` there, wherever the copy lives.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
 
 def free_ports(n: int) -> list[int]:
     socks = [socket.socket() for _ in range(n)]
@@ -165,7 +170,7 @@ class VortexSupervisor:
              f"--cluster={self.cluster}", f"--replica={i}",
              f"--replica-count={self.replica_count}", "--small",
              self._data_path(i)],
-            check=True, cwd="/root/repo", timeout=60,
+            check=True, cwd=_CHECKOUT, timeout=60,
             stdout=subprocess.DEVNULL)
 
     def trace_path(self, i: int) -> str:
@@ -195,7 +200,7 @@ class VortexSupervisor:
         log = open(self._log_path(i), "wb")
         self.procs[i] = subprocess.Popen(
             cmd + [self._data_path(i)],
-            cwd="/root/repo", env=dict(os.environ),
+            cwd=_CHECKOUT, env=dict(os.environ),
             stdout=log, stderr=log)
         log.close()
 
@@ -231,7 +236,7 @@ class VortexSupervisor:
              f"--replica-count={self.replica_count}", "--small",
              f"--listen-port={self.real_ports[i]}",
              f"--timeout-s={timeout_s}", self._data_path(i)],
-            cwd="/root/repo", env=dict(os.environ),
+            cwd=_CHECKOUT, env=dict(os.environ),
             stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
         if crash_after_s is not None:
             time.sleep(crash_after_s)
@@ -245,7 +250,7 @@ class VortexSupervisor:
         out = subprocess.run(
             [sys.executable, "-m", "tigerbeetle_tpu", "inspect",
              "--small", "--digest", self._data_path(i)],
-            capture_output=True, text=True, cwd="/root/repo", timeout=120)
+            capture_output=True, text=True, cwd=_CHECKOUT, timeout=120)
         assert out.returncode == 0, f"r{i} digest: {out.stdout}"
         ckpt = digest = None
         for line in out.stdout.splitlines():
@@ -432,6 +437,6 @@ class VortexSupervisor:
             out = subprocess.run(
                 [sys.executable, "-m", "tigerbeetle_tpu", "inspect",
                  "--small", "--integrity", self._data_path(i)],
-                capture_output=True, text=True, cwd="/root/repo",
+                capture_output=True, text=True, cwd=_CHECKOUT,
                 timeout=120)
             assert out.returncode == 0, f"r{i}: {out.stdout}"

@@ -31,8 +31,8 @@ Design, matching the reference's shape (grid_scrubber.zig:101-138,
   grid_scrubber.zig:65-72).
 
 The free set and client sessions live in the superblock-referenced A/B
-snapshot zone here, not in grid blocks (documented substitution —
-ROUND3.md), so the checkpoint-trailer legs of the reference's tour have
+snapshot zone here, not in grid blocks (a documented substitution),
+so the checkpoint-trailer legs of the reference's tour have
 no grid analog; the snapshot zone is checksummed and quorum-protected on
 its own read path.
 """
