@@ -374,18 +374,22 @@ def shard_balance_probe(quick: bool) -> dict:
     # `unsplittable` (naming the hash) and must NOT thrash (cooldown:
     # the immediate re-propose returns None). The remedy documented in
     # ARCHITECTURE.md is AT2 lane parallelism, not placement.
-    from tigerbeetle_tpu.parallel.resharding import HotRangeDetector
-    det = HotRangeDetector(n_shards=router.n_shards)
-    hot = [Transfer(id=10 ** 7 + i, debit_account_id=7,
-                    credit_account_id=7, amount=1, ledger=1, code=1)
-           for i in range(256)]
-    for _ in range(2):
-        det.observe_window([transfers_to_arrays(hot)])
-    verdict = det.propose()
-    assert verdict and verdict["verdict"] == "unsplittable", verdict
-    assert det.propose() is None, "detector thrashed past cooldown"
-    hot_range = {k: verdict[k] for k in
-                 ("verdict", "shard", "fraction", "note")}
+    # One shard has no placement to judge: the detector proposes
+    # nothing there, and the record says so.
+    hot_range = None
+    if router.n_shards >= 2:
+        from tigerbeetle_tpu.parallel.resharding import HotRangeDetector
+        det = HotRangeDetector(n_shards=router.n_shards)
+        hot = [Transfer(id=10 ** 7 + i, debit_account_id=7,
+                        credit_account_id=7, amount=1, ledger=1, code=1)
+               for i in range(256)]
+        for _ in range(2):
+            det.observe_window([transfers_to_arrays(hot)])
+        verdict = det.propose()
+        assert verdict and verdict["verdict"] == "unsplittable", verdict
+        assert det.propose() is None, "detector thrashed past cooldown"
+        hot_range = {k: verdict[k] for k in
+                     ("verdict", "shard", "fraction", "note")}
 
     s = router.stats()
     lat_ms.sort()

@@ -552,7 +552,8 @@ def run_served(args) -> dict:
     n_wire_max = len(secs["create_transfers"])
     for name, v in secs.items():
         say(f"{name}: {len(v)} requests, seconds "
-            f"min {min(v)} median {sorted(v)[len(v) // 2]} max {max(v)}")
+            f"min {min(v)} median {sorted(v)[len(v) // 2]} max {max(v)}; "
+            f"in order sent: {v}")
     say(f"transfers requests at the wire maximum ({n_max}): {n_wire_max}; "
         f"events {st['events']}; mismatches {st['mismatches']}")
     say(f"statuses: {json.dumps(st['statuses'], sort_keys=True)}")
@@ -599,7 +600,7 @@ def main(argv=None) -> int:
                 f"{device['platform']!r}, not a TPU")
         require(device["count"] == want,
                 f"expected {want} device(s), JAX reports {device['count']}")
-    except SmokeFailure as e:
+    except (SmokeFailure, AssertionError) as e:
         print(f"[chip_smoke] FAILED after {time.monotonic() - t0:.0f}s: {e}",
               file=sys.stderr, flush=True)
         return 1

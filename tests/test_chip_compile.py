@@ -61,125 +61,55 @@ def _no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def abstract(tree, sharding):
-    """Shapes of `tree` placed on the described device."""
+def _cases():
+    """name -> (jit entry, abstract args builder(sharding)). Built
+    inside a test: nothing of the package's device code is imported
+    while this file is collected. The shapes are the ones `start` warms
+    and serves (ops/warmup.py), at production caps."""
     import jax
 
-    def one(x):
-        x = np.asarray(x) if not hasattr(x, "shape") else x
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+    from tigerbeetle_tpu.ops import fast_kernels as fk
+    from tigerbeetle_tpu.ops import ledger, warmup
 
-    return jax.tree.map(one, tree)
+    def batch(n_pad):
+        return lambda s: warmup.batch_args(A_CAP, T_CAP, n_pad, s)
 
+    gather = jax.jit(ledger._xfer_delta_gather, static_argnums=(3, 4))
 
-def production_state(sharding):
-    import jax
+    def gather_args(size):
+        return lambda s: (warmup.abstract_state(A_CAP, T_CAP, s),
+                          *warmup.abstract((np.int32(0), np.int32(0)), s),
+                          size, size)
 
-    from tigerbeetle_tpu.ops.ledger import init_state
+    return {
+        "create_transfers_fast@8192": (
+            fk.create_transfers_fast_jit, batch(N_PAD)),
+        "create_transfers_chain@W8x8192": (
+            fk.create_transfers_chain_jit,
+            lambda s: warmup.chain_args(A_CAP, T_CAP, WINDOW_DEPTH,
+                                        N_PAD, s)),
+        "create_accounts_fast@8192": (
+            fk.create_accounts_fast_jit,
+            lambda s: warmup.accounts_args(A_CAP, T_CAP, s)),
+        "create_transfers_fast@1024": (
+            fk.create_transfers_fast_jit, batch(1024)),
+        "create_transfers_fixpoint@1024": (
+            fk.create_transfers_fixpoint_jit, batch(1024)),
+        "create_transfers_fixpoint@8192": (
+            fk.create_transfers_fixpoint_jit, batch(N_PAD)),
+        "create_transfers_fixpoint_deep@8192": (
+            fk.create_transfers_fixpoint_deep_jit, batch(N_PAD)),
+        "create_transfers_super@K2x8192": (
+            fk.create_transfers_super_jit,
+            lambda s: warmup.super_args(A_CAP, T_CAP, 2, N_PAD, s)),
+        "create_transfers_super@K8x8192": (
+            fk.create_transfers_super_jit,
+            lambda s: warmup.super_args(A_CAP, T_CAP, WINDOW_DEPTH,
+                                        N_PAD, s)),
+        "xfer_delta_gather@8192": (gather, gather_args(N_PAD)),
+        "xfer_delta_gather@65536": (gather, gather_args(8 * N_PAD)),
+    }
 
-    return abstract(jax.eval_shape(lambda: init_state(A_CAP, T_CAP)),
-                    sharding)
-
-
-def _empty_transfers():
-    from tigerbeetle_tpu.ops.batch import transfers_to_arrays
-
-    return transfers_to_arrays([])
-
-
-def batch_args(sharding, n_pad=N_PAD):
-    """(state, padded events, timestamp, n) for the per-batch tiers."""
-    from tigerbeetle_tpu.ops.ledger import pad_transfer_events
-
-    ev = pad_transfer_events(_empty_transfers(), n_pad)
-    return (production_state(sharding), abstract(ev, sharding),
-            abstract(np.uint64(1), sharding),
-            abstract(np.int32(0), sharding))
-
-
-def super_args(sharding, depth, n_pad=N_PAD):
-    """The replica's all-or-nothing commit window: `depth` prepares
-    flattened into one superbatch."""
-    from tigerbeetle_tpu.ops.ledger import stack_superbatch
-
-    ev_s, seg = stack_superbatch([_empty_transfers()] * depth,
-                                 [10 ** 12] * depth, n_pad)
-    return (production_state(sharding), abstract(ev_s, sharding),
-            abstract(seg, sharding))
-
-
-def chain_args(sharding, depth, n_pad=N_PAD):
-    from tigerbeetle_tpu.ops.ledger import stack_chain_window
-
-    ev_c, seg_c = stack_chain_window([_empty_transfers()] * depth,
-                                     [10 ** 12] * depth, n_pad)
-    return (production_state(sharding), abstract(ev_c, sharding),
-            abstract(seg_c, sharding))
-
-
-def accounts_args(sharding):
-    from tigerbeetle_tpu.ops.batch import accounts_to_arrays
-    from tigerbeetle_tpu.ops.ledger import pad_account_events
-
-    ev = pad_account_events(accounts_to_arrays([]))
-    return (production_state(sharding), abstract(ev, sharding),
-            abstract(np.uint64(1), sharding),
-            abstract(np.int32(0), sharding))
-
-
-def _fk():
-    from tigerbeetle_tpu.ops import fast_kernels
-
-    return fast_kernels
-
-
-def _ledger():
-    from tigerbeetle_tpu.ops import ledger
-
-    return ledger
-
-
-def _delta_gather_jit():
-    import jax
-
-    return jax.jit(_ledger()._xfer_delta_gather, static_argnums=(3, 4))
-
-
-# name -> (jit entry thunk, args builder). Thunks: nothing of the
-# package's device code is imported while this file is collected.
-CASES = {
-    "create_transfers_fast@8192": (
-        lambda: _fk().create_transfers_fast_jit, batch_args),
-    "create_transfers_chain@W8x8192": (
-        lambda: _fk().create_transfers_chain_jit,
-        lambda s: chain_args(s, WINDOW_DEPTH)),
-    "create_accounts_fast@8192": (
-        lambda: _fk().create_accounts_fast_jit, accounts_args),
-    "create_transfers_fast@1024": (
-        lambda: _fk().create_transfers_fast_jit,
-        lambda s: batch_args(s, 1024)),
-    "create_transfers_fixpoint@1024": (
-        lambda: _fk().create_transfers_fixpoint_jit,
-        lambda s: batch_args(s, 1024)),
-    "create_transfers_fixpoint@8192": (
-        lambda: _fk().create_transfers_fixpoint_jit, batch_args),
-    "create_transfers_fixpoint_deep@8192": (
-        lambda: _fk().create_transfers_fixpoint_deep_jit, batch_args),
-    "create_transfers_super@K2x8192": (
-        lambda: _fk().create_transfers_super_jit,
-        lambda s: super_args(s, 2)),
-    "create_transfers_super@K8x8192": (
-        lambda: _fk().create_transfers_super_jit,
-        lambda s: super_args(s, WINDOW_DEPTH)),
-    "xfer_delta_gather@8192": (
-        _delta_gather_jit,
-        lambda s: (production_state(s), abstract(np.int32(0), s),
-                   abstract(np.int32(0), s), N_PAD, N_PAD)),
-    "xfer_delta_gather@65536": (
-        _delta_gather_jit,
-        lambda s: (production_state(s), abstract(np.int32(0), s),
-                   abstract(np.int32(0), s), 8 * N_PAD, 8 * N_PAD)),
-}
 
 TIER1 = ("create_transfers_fast@8192", "create_transfers_chain@W8x8192",
          "create_accounts_fast@8192")
@@ -188,9 +118,9 @@ TIER1 = ("create_transfers_fast@8192", "create_transfers_chain@W8x8192",
 def compile_case(name, sharding):
     """Lower + compile one case for the described chip. Returns
     (seconds, memory_analysis)."""
-    entry, make_args = CASES[name]
+    entry, make_args = _cases()[name]
     t0 = time.monotonic()
-    compiled = entry().lower(*make_args(sharding)).compile()
+    compiled = entry.lower(*make_args(sharding)).compile()
     return time.monotonic() - t0, compiled.memory_analysis()
 
 
@@ -210,7 +140,42 @@ def test_served_entry_compiles_for_v5e(name, one_chip):
     _check(name, one_chip)
 
 
+SLOW = ("create_transfers_fast@1024", "create_transfers_fixpoint@1024",
+        "create_transfers_fixpoint@8192",
+        "create_transfers_fixpoint_deep@8192",
+        "create_transfers_super@K2x8192", "create_transfers_super@K8x8192",
+        "xfer_delta_gather@8192", "xfer_delta_gather@65536")
+
+
 @pytest.mark.slow
-@pytest.mark.parametrize("name", [n for n in CASES if n not in TIER1])
+@pytest.mark.parametrize("name", SLOW)
 def test_served_entry_compiles_for_v5e_slow(name, one_chip):
     _check(name, one_chip)
+
+
+def test_case_lists_cover_every_case():
+    assert sorted(TIER1 + SLOW) == sorted(_cases())
+
+
+def test_warm_set_is_among_the_compiled_cases():
+    """What `start` warms is what these tests put to the chip's
+    compiler (the warm set's names are keys of the case table)."""
+    from tigerbeetle_tpu.ops import warmup
+
+    assert set(warmup.warm_set(1 << 10, 1 << 12)) <= set(_cases())
+
+
+@pytest.mark.slow
+def test_partitioned_window_compiles_for_v5e_2x2(topo):
+    """chip_smoke.py --four-chips' fused window step, for a mesh of the
+    four described chips: collectives over u64 lanes, state sharded."""
+    from tigerbeetle_tpu.testing import partitioned_smoke as ps
+
+    mesh, router = ps.build_router(topo.devices)
+    with mesh:
+        compiled = router._chain_step("plain").lower(
+            *ps.abstract_chain_args(mesh)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15 * (1 << 30)
+    assert "all-reduce" in compiled.as_text()

@@ -164,3 +164,44 @@ def test_operation_codes():
     assert Operation.create_transfers.is_multi_batch()
     assert not Operation.get_change_events.is_multi_batch()
     assert not Operation.pulse.is_batchable()
+
+
+@pytest.mark.parametrize("message_size_max", [1024 * 1024, 64 * 1024])
+@pytest.mark.parametrize("op_name", ["create_accounts", "create_transfers",
+                                     "lookup_accounts", "lookup_transfers"])
+def test_client_refuses_what_the_replica_would_drop(op_name,
+                                                    message_size_max):
+    """clients/common.py admits exactly what the replica's on_request
+    admits: the largest count passes input_valid and both size bounds,
+    one more raises (it used to be sent, dropped in silence, and resent
+    until the client's timeout)."""
+    from tigerbeetle_tpu.clients.common import encode_batch, events_max
+    from tigerbeetle_tpu.constants import HEADER_SIZE
+    from tigerbeetle_tpu.state_machine import OPERATION_SPECS, StateMachine
+    from tigerbeetle_tpu.types import Operation
+    from tigerbeetle_tpu.vsr.replica import _reply_fits
+
+    op = Operation[op_name]
+    body_max = message_size_max - HEADER_SIZE
+    n = events_max(op, body_max)
+    event = b"\x01" * OPERATION_SPECS[op].event_size
+    body = encode_batch(op, [event] * n, body_max)
+    assert HEADER_SIZE + len(body) <= message_size_max
+    assert _reply_fits(op, len(body), message_size_max)
+    assert StateMachine(engine="oracle").input_valid(op, body)
+    with pytest.raises(ValueError, match=f"carries {n}"):
+        encode_batch(op, [event] * (n + 1), body_max)
+    # One more would break a replica bound, so n is the maximum.
+    over = encode_batch(op, [event] * (n + 1), 10 ** 9)
+    assert (HEADER_SIZE + len(over) > message_size_max
+            or not _reply_fits(op, len(over), message_size_max))
+
+
+def test_served_maximum_is_one_below_batch_max():
+    from tigerbeetle_tpu.clients.common import events_max
+    from tigerbeetle_tpu.constants import BATCH_MAX
+    from tigerbeetle_tpu.types import Operation
+
+    assert BATCH_MAX == 8190
+    assert events_max(Operation.create_transfers) == 8189
+    assert events_max(Operation.create_accounts) == 8189

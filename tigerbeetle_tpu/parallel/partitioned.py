@@ -120,6 +120,18 @@ _EV_PROW_COL = EV_P32_POS["p_row"][0]     # ("pstat","p_row") word
 _EV_TFLAGS_COL = EV_P32_POS["tflags"][0]  # ("tflags","dr_flags") word
 
 
+def _psum_u64(x, axis):
+    """psum over u64 lanes, as a signed sum. XLA:TPU lowers a 64-bit
+    all-reduce for s64 but not for u64 ("UNIMPLEMENTED: Supported
+    lowering only of Sum all reduce" — the fused window step compiled
+    for a described v5e 2x2, PR 23). Two's-complement addition is the
+    same bits either way, and the exchange's sums are selects (one
+    shard contributes, the rest add zero)."""
+    return jax.lax.bitcast_convert_type(
+        jax.lax.psum(jax.lax.bitcast_convert_type(x, jnp.int64), axis),
+        jnp.uint64)
+
+
 def _uniq_rows(k_hi, k_lo, active):
     """First-occurrence dedupe of 128-bit keys over the exchange lanes.
 
@@ -281,7 +293,7 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
     xrow_l = jnp.where(x_live_l, xv_l, t_dump_l)
     xdata_l = jnp.where(x_live_l[:, None],
                         xfr["u64"][xrow_l], jnp.uint64(0))
-    g = jax.lax.psum(
+    g = _psum_u64(
         jnp.concatenate([enc_l[:, None], xdata_l], axis=1), axis)
     g_enc, g_rows = g[:, 0], g[:, 1:]
     x_active = g_enc > 0
@@ -314,7 +326,7 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
                      acc["u64"][arow_g_l], jnp.uint64(0))
     ab_l = jnp.where(af_l[:, None],
                      acc["bal"][arow_g_l], jnp.uint64(0))
-    ga = jax.lax.psum(
+    ga = _psum_u64(
         jnp.concatenate([aenc_l[:, None], au_l, ab_l], axis=1),
         axis)
     g_aenc = ga[:, 0]

@@ -189,13 +189,15 @@ def device_memory_stats() -> list:
         stats = None
         try:
             s = d.memory_stats()
-            if s:
-                stats = {k: int(v) for k, v in s.items()
-                         if isinstance(v, (int, float))
-                         and k in ("bytes_in_use", "peak_bytes_in_use",
-                                   "bytes_limit", "largest_alloc_size")}
         except Exception:
-            stats = None
+            if d.platform == "tpu":
+                raise  # a chip that cannot report its memory is a fault
+            s = None
+        if s:
+            stats = {k: int(v) for k, v in s.items()
+                     if isinstance(v, (int, float))
+                     and k in ("bytes_in_use", "peak_bytes_in_use",
+                               "bytes_limit", "largest_alloc_size")}
         out.append({"device": str(d), "platform": d.platform,
                     "stats": stats})
     return out

@@ -37,17 +37,33 @@ from typing import Callable, Optional
 
 from .event import Event
 
-# Nominal peak envelopes per platform: (FLOP/s, HBM bytes/s). These are
-# headline device numbers, not measured ceilings — the roofline fraction
-# is an attribution signal (which tier is furthest from achievable),
-# not a benchmark claim. v5e: 197 TFLOP/s bf16, 819 GB/s HBM. The cpu
-# row is a deliberately round envelope so fractions stay comparable
-# across dev runs; on-chip campaigns read the tpu row.
+# Published peaks, keyed by `device_kind` as JAX reports it:
+# (FLOP/s, HBM bytes/s). These are headline device numbers, not
+# measured ceilings — the roofline fraction is an attribution signal
+# (which tier is furthest from achievable), not a benchmark claim.
+# "TPU v5 lite": 197 TFLOP/s bf16, 819 GB/s HBM (Google Cloud
+# documentation, "TPU v5e"). A TPU kind that is not in the table is an
+# error, never a default. The "cpu" row is a deliberately round
+# envelope for the gate's CPU smoke (testing/observatory_smoke.py);
+# nothing a chip run prints reads it.
 NOMINAL_PEAKS = {
-    "tpu": (197e12, 819e9),
-    "gpu": (60e12, 1000e9),
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (100e9, 50e9),
 }
+
+
+def peaks_key(device) -> str:
+    """The NOMINAL_PEAKS key for a JAX device: its `device_kind` on a
+    TPU (unknown kind -> KeyError), its platform name otherwise."""
+    if device.platform == "tpu":
+        if device.device_kind not in NOMINAL_PEAKS:
+            raise KeyError(
+                f"no published peaks for TPU kind {device.device_kind!r}: "
+                "add a row to trace/profiler.py NOMINAL_PEAKS, with its "
+                "source")
+        return device.device_kind
+    return device.platform
+
 
 # Representative registry entry per dispatch tier (jaxhound.registry
 # names): the cost model lowers these, not all 19 entries — one per
@@ -194,7 +210,7 @@ def static_cost_model(include_partitioned: Optional[bool] = None,
     from ..jaxhound import analyze_lowered
     from ..jaxhound.registry import entries
 
-    platform = jax.devices()[0].platform
+    platform = peaks_key(jax.devices()[0])
     reg = entries(include_partitioned=include_partitioned)
     model: dict = {"platform": platform, "depth": depth, "tiers": {}}
     for tier, entry_name in TIER_ENTRIES.items():
