@@ -167,8 +167,7 @@ class Traffic:
         self.tight = self.limited[:8]
         tight = set(self.tight)
         self.pool = [a for a in self.acct_ids if a not in tight]
-        self.stats = {"requests": 0, "events": 0, "mismatches": 0,
-                      "statuses": {}}
+        self.stats = {"events": 0, "mismatches": 0, "statuses": {}}
 
     def tid(self) -> int:
         self.next_tid += 1
@@ -260,7 +259,6 @@ class Checker:
         want = fn(events, ts)
         bad = [(i, w, g) for i, (w, g) in enumerate(zip(want, results))
                if (w.status, w.timestamp) != (g.status, g.timestamp)]
-        st["requests"] += 1
         st["events"] += len(events)
         for g in results:
             st["statuses"][g.status.name] = \
@@ -275,7 +273,6 @@ class Checker:
                 f"got {g.status.name}@{g.timestamp}")
 
     def same(self, what: str, got: list, want: list) -> None:
-        self.t.stats["requests"] += 1
         if got != want:
             self.t.stats["mismatches"] += 1
             n = next((i for i, (g, w) in enumerate(zip(got, want))
@@ -299,25 +296,10 @@ def decode_results(op, body: bytes) -> list:
             for i in range(0, len(payload), 16)]
 
 
-def timed_request(client, op, body: bytes, seconds: list) -> bytes:
-    """One request at the client's DEFAULT timeout: a TimeoutError here
-    is the finding (a server still compiling what it serves)."""
-    t0 = time.monotonic()
-    try:
-        out = client.request(op, body)
-    except TimeoutError as e:
-        raise SmokeFailure(
-            f"{op.name}: no reply within the client's default timeout "
-            f"({e}) — the server was not ready for what it serves")
-    seconds.append(round(time.monotonic() - t0, 3))
-    return out
-
-
 # --------------------------------------------------------------- one chip
 
 
 def run_served(args) -> dict:
-    import jax  # noqa: F401 — imported by the package; no backend starts
     from jax._src import xla_bridge
 
     from tigerbeetle_tpu.clients.common import encode_batch, events_max
@@ -374,10 +356,25 @@ def run_served(args) -> dict:
             "create_accounts", "create_transfers", "lookup_accounts",
             "lookup_transfers", "get_account_transfers")}
 
+        def timed(name, fn, *a):
+            """One request at the client's DEFAULT timeout: a
+            TimeoutError here is the finding (a server still compiling
+            what it serves)."""
+            t0 = time.monotonic()
+            try:
+                out = fn(*a)
+            except TimeoutError as e:
+                raise SmokeFailure(
+                    f"{name}: no reply within the client's default "
+                    f"timeout ({e}) — the server was not ready for what "
+                    "it serves")
+            secs[name].append(round(time.monotonic() - t0, 3))
+            return out
+
         def create(client, op, events):
             body = encode_batch(op, [e.pack() for e in events], body_max)
-            out = timed_request(client, op, body, secs[op.name])
-            return decode_results(op, out)
+            return decode_results(
+                op, timed(op.name, client.request, op, body))
 
         # Phase 1: accounts, wire-max requests.
         accounts = traffic.accounts()
@@ -497,18 +494,13 @@ def run_served(args) -> dict:
         unknown = (7 << 64) | 12345
         for i in range(0, len(traffic.acct_ids), n_lookup - 1):
             ids = traffic.acct_ids[i:i + n_lookup - 1] + [unknown]
-            t0 = time.monotonic()
-            got = c0.lookup_accounts(ids)
-            secs["lookup_accounts"].append(
-                round(time.monotonic() - t0, 3))
+            got = timed("lookup_accounts", c0.lookup_accounts, ids)
             check.same("lookup_accounts", got, oracle.lookup_accounts(ids))
         all_ids = [t.id for b in (funding, limit_batch, mixed, settle)
                    for t in b[:16]]
         all_ids += [t.id for events, _ in done for t in events[:400]]
         ids = all_ids[:n_lookup]
-        t0 = time.monotonic()
-        got = c0.lookup_transfers(ids)
-        secs["lookup_transfers"].append(round(time.monotonic() - t0, 3))
+        got = timed("lookup_transfers", c0.lookup_transfers, ids)
         check.same("lookup_transfers", got, oracle.lookup_transfers(ids))
         by_ts = sorted((t for t in oracle.transfers.values()),
                        key=lambda t: t.timestamp)
@@ -517,10 +509,8 @@ def run_served(args) -> dict:
                 account_id=acct, limit=n_lookup,
                 flags=int(AccountFilterFlags.debits
                           | AccountFilterFlags.credits))
-            t0 = time.monotonic()
-            raw = c0.query(O.get_account_transfers, f)
-            secs["get_account_transfers"].append(
-                round(time.monotonic() - t0, 3))
+            raw = timed("get_account_transfers", c0.query,
+                        O.get_account_transfers, f)
             got = [Transfer.unpack(raw[i:i + 128])
                    for i in range(0, len(raw), 128)]
             want = [t for t in by_ts if acct in (t.debit_account_id,
@@ -569,16 +559,6 @@ def run_served(args) -> dict:
     return device
 
 
-# -------------------------------------------------------------- four chips
-
-
-def run_four_chips(args) -> dict:
-    """The partitioned route, one process on a 4-device mesh."""
-    from tigerbeetle_tpu.testing.partitioned_smoke import run
-
-    return run(seed=args.seed, n_accounts=args.accounts, say=say)
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--seed", type=int, default=20260926)
@@ -592,8 +572,14 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     t0 = time.monotonic()
     try:
-        device = run_four_chips(args) if args.four_chips \
-            else run_served(args)
+        if args.four_chips:
+            # The partitioned route, one process on a 4-device mesh:
+            # that phase and its comparison, and no other.
+            from tigerbeetle_tpu.testing.partitioned_smoke import run
+
+            device = run(seed=args.seed, n_accounts=args.accounts, say=say)
+        else:
+            device = run_served(args)
         want = 4 if args.four_chips else 1
         require(device["platform"] == "tpu",
                 f"every comparison passed, but the device is "

@@ -86,11 +86,13 @@ def _cases():
             fk.create_transfers_fast_jit, batch(N_PAD)),
         "create_transfers_chain@W8x8192": (
             fk.create_transfers_chain_jit,
-            lambda s: warmup.chain_args(A_CAP, T_CAP, WINDOW_DEPTH,
-                                        N_PAD, s)),
+            lambda s: warmup.window_args(
+                A_CAP, T_CAP, WINDOW_DEPTH, N_PAD, s,
+                stack=ledger.stack_chain_window)),
         "create_accounts_fast@8192": (
             fk.create_accounts_fast_jit,
-            lambda s: warmup.accounts_args(A_CAP, T_CAP, s)),
+            lambda s: warmup.batch_args(A_CAP, T_CAP, sharding=s,
+                                        accounts=True)),
         "create_transfers_fast@1024": (
             fk.create_transfers_fast_jit, batch(1024)),
         "create_transfers_fixpoint@1024": (
@@ -101,11 +103,11 @@ def _cases():
             fk.create_transfers_fixpoint_deep_jit, batch(N_PAD)),
         "create_transfers_super@K2x8192": (
             fk.create_transfers_super_jit,
-            lambda s: warmup.super_args(A_CAP, T_CAP, 2, N_PAD, s)),
+            lambda s: warmup.window_args(A_CAP, T_CAP, 2, N_PAD, s)),
         "create_transfers_super@K8x8192": (
             fk.create_transfers_super_jit,
-            lambda s: warmup.super_args(A_CAP, T_CAP, WINDOW_DEPTH,
-                                        N_PAD, s)),
+            lambda s: warmup.window_args(A_CAP, T_CAP, WINDOW_DEPTH,
+                                         N_PAD, s)),
         "xfer_delta_gather@8192": (gather, gather_args(N_PAD)),
         "xfer_delta_gather@65536": (gather, gather_args(8 * N_PAD)),
     }

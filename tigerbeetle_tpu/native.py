@@ -28,10 +28,11 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _source_hash(*sources: str) -> str:
+def _source_hash(src: str) -> str:
+    """Content hash of what `src` compiles from (itself + blake2b.h)."""
     h = hashlib.sha256()
-    for s in sources:
-        with open(s, "rb") as f:
+    for path in (src, _HDR):
+        with open(path, "rb") as f:
             h.update(f.read())
     return h.hexdigest()
 
@@ -47,7 +48,7 @@ def _build(src: str, lib: str, *extra: str) -> bool:
             check=True, capture_output=True, timeout=120)
         os.replace(tmp, lib)
         with open(tmp, "w") as f:
-            f.write(_source_hash(src, _HDR))
+            f.write(_source_hash(src))
         os.replace(tmp, lib + ".sha256")
         return True
     except (OSError, subprocess.SubprocessError) as e:
@@ -59,16 +60,16 @@ def _build(src: str, lib: str, *extra: str) -> bool:
         return False
 
 
-def _stale(lib: str, *sources: str) -> bool:
-    """True unless `lib` was built from exactly these sources (keyed on
-    their content hash: mtimes say nothing in a fresh checkout or a
-    copied tree)."""
+def _stale(lib: str, src: str) -> bool:
+    """True unless `lib` was built from exactly the present sources
+    (keyed on their content hash: mtimes say nothing in a fresh
+    checkout or a copied tree)."""
     try:
         with open(lib + ".sha256") as f:
             built_from = f.read().strip()
     except OSError:
         return True
-    return not os.path.exists(lib) or built_from != _source_hash(*sources)
+    return not os.path.exists(lib) or built_from != _source_hash(src)
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -80,7 +81,7 @@ def load() -> Optional[ctypes.CDLL]:
         _tried = True
         if not os.path.exists(_SRC):
             return None
-        if _stale(_LIB, _SRC, _HDR):
+        if _stale(_LIB, _SRC):
             if not _build(_SRC, _LIB, "-pthread"):
                 return None
         try:
@@ -212,7 +213,7 @@ def load_client() -> Optional[ctypes.CDLL]:
         _client_tried = True
         if not os.path.exists(_CLIENT_SRC):
             return None
-        if _stale(_CLIENT_LIB, _CLIENT_SRC, _HDR):
+        if _stale(_CLIENT_LIB, _CLIENT_SRC):
             if not _build(_CLIENT_SRC, _CLIENT_LIB, "-pthread"):
                 return None
         try:

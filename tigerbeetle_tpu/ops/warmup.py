@@ -31,6 +31,7 @@ after the first) the first phase is a handful of cache reads.
 
 from __future__ import annotations
 
+import functools
 import os
 import time as _time
 from concurrent.futures import ThreadPoolExecutor
@@ -43,7 +44,6 @@ from .ledger import (
     init_state,
     pad_account_events,
     pad_transfer_events,
-    stack_chain_window,
     stack_superbatch,
 )
 
@@ -63,47 +63,37 @@ def abstract(tree, sharding=None):
     return jax.tree.map(one, tree)
 
 
+@functools.lru_cache(maxsize=4)
 def abstract_state(a_cap: int, t_cap: int, sharding=None):
+    """Shapes of the ledger state (cached: tracing init_state fills a
+    host array the size of the event ring)."""
     import jax
 
     return abstract(jax.eval_shape(lambda: init_state(a_cap, t_cap)),
                     sharding)
 
 
-def _no_transfers():
+def batch_args(a_cap, t_cap, n_pad=N_PAD, sharding=None, accounts=False):
+    """(state, events, timestamp, n) of the per-batch tiers:
+    create_transfers at bucket `n_pad`, or create_accounts (always
+    padded to the widest bucket)."""
+    from .batch import accounts_to_arrays, transfers_to_arrays
+
+    ev = (pad_account_events(accounts_to_arrays([])) if accounts
+          else pad_transfer_events(transfers_to_arrays([]), n_pad))
+    return (abstract_state(a_cap, t_cap, sharding),
+            *abstract((ev, np.uint64(1), np.int32(0)), sharding))
+
+
+def window_args(a_cap, t_cap, depth, n_pad=N_PAD, sharding=None,
+                stack=stack_superbatch):
+    """(state, events, seg) of a `depth`-prepare window: flattened into
+    one superbatch (the replica's all-or-nothing commit window), or with
+    stack=stack_chain_window the scan-form chain's stacked inputs."""
     from .batch import transfers_to_arrays
 
-    return transfers_to_arrays([])
-
-
-def batch_args(a_cap, t_cap, n_pad, sharding=None):
-    """(state, events, timestamp, n) of the per-batch transfer tiers."""
-    ev = pad_transfer_events(_no_transfers(), n_pad)
-    return (abstract_state(a_cap, t_cap, sharding),
-            *abstract((ev, np.uint64(1), np.int32(0)), sharding))
-
-
-def accounts_args(a_cap, t_cap, sharding=None):
-    from .batch import accounts_to_arrays
-
-    ev = pad_account_events(accounts_to_arrays([]))
-    return (abstract_state(a_cap, t_cap, sharding),
-            *abstract((ev, np.uint64(1), np.int32(0)), sharding))
-
-
-def super_args(a_cap, t_cap, depth, n_pad, sharding=None):
-    """The replica's all-or-nothing commit window: `depth` prepares
-    flattened into one superbatch."""
-    packed = stack_superbatch([_no_transfers()] * depth,
-                              [10 ** 12] * depth, n_pad)
-    return (abstract_state(a_cap, t_cap, sharding),
-            *abstract(packed, sharding))
-
-
-def chain_args(a_cap, t_cap, depth, n_pad, sharding=None):
-    """The scan-form chain window (the pipelined serving route)."""
-    packed = stack_chain_window([_no_transfers()] * depth,
-                                [10 ** 12] * depth, n_pad)
+    packed = stack([transfers_to_arrays([])] * depth,
+                   [10 ** 12] * depth, n_pad)
     return (abstract_state(a_cap, t_cap, sharding),
             *abstract(packed, sharding))
 
@@ -114,7 +104,8 @@ def warm_set(a_cap: int, t_cap: int, sharding=None) -> dict:
     from . import fast_kernels as fk
 
     out = {"create_accounts_fast@8192": (
-        fk.create_accounts_fast_jit, accounts_args(a_cap, t_cap, sharding))}
+        fk.create_accounts_fast_jit,
+        batch_args(a_cap, t_cap, sharding=sharding, accounts=True))}
     for n_pad in WARM_BUCKETS:
         args = batch_args(a_cap, t_cap, n_pad, sharding)
         out[f"create_transfers_fast@{n_pad}"] = (

@@ -51,27 +51,27 @@ def abstract_chain_args(mesh, a_cap: int = A_CAP, t_cap: int = T_CAP,
     window step at this smoke's shapes, placed on `mesh` — what the
     chip compile test hands to a mesh of described devices."""
     import jax
+    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ..ops.batch import transfers_to_arrays
     from ..ops.ledger import init_state
+    from ..ops.warmup import abstract
     from ..parallel.partitioned import stack_partitioned_window
 
     n = mesh.shape["batch"]
     t_cap_s = t_cap // n
-    sub = jax.eval_shape(lambda: init_state(
-        a_cap // n, t_cap_s, orphan_cap=max((1 << 16) // n, t_cap_s),
-        e_cap=t_cap_s))
-
-    def place(tree, spec, lead=()):
-        sh = NamedSharding(mesh, spec)
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            lead + tuple(np.shape(x)), np.asarray(x).dtype
-            if not hasattr(x, "dtype") else x.dtype, sharding=sh), tree)
-
+    # Per-shard sub-states stacked on a leading shard axis, exactly as
+    # partitioned_from_oracle lays them out.
+    stacked = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: jnp.broadcast_to(x, (n, *jnp.shape(x))),
+        init_state(a_cap // n, t_cap_s,
+                   orphan_cap=max((1 << 16) // n, t_cap_s),
+                   e_cap=t_cap_s)))
     packed = stack_partitioned_window(
         [transfers_to_arrays([])] * depth, [10 ** 12] * depth, n_pad)
-    return (place(sub, P("batch"), lead=(n,)), *place(packed, P()), None)
+    return (abstract(stacked, NamedSharding(mesh, P("batch"))),
+            *abstract(packed, NamedSharding(mesh, P())), None)
 
 
 def check_sharded(state, mesh) -> int:
@@ -124,12 +124,8 @@ def run(seed: int, n_accounts: int, say=print) -> dict:
     oracle = StateMachineOracle()
     limited = int(AccountFlags.debits_must_not_exceed_credits)
     ids = [int(x) for x in (1 << 40) + np.arange(n_accounts) * 3]
-    oracle.create_accounts(
-        [Account(id=a, ledger=1, code=1,
-                 flags=limited if i % 4 == 0 else 0)
-         for i, a in enumerate(ids)][:n_max], 10 ** 9)
     ts = 10 ** 9
-    for lo in range(n_max, n_accounts, n_max):
+    for lo in range(0, n_accounts, n_max):
         chunk = ids[lo:lo + n_max]
         ts += len(chunk)
         oracle.create_accounts(
