@@ -41,6 +41,8 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 WORKDIR = os.path.join(HERE, "scratch", "chip_smoke")
 BOOT_TIMEOUT_S = 1000  # cold warm-up compiles are minutes, not seconds
+N_ACCOUNTS = 1 << 15
+ROUNDS = 8  # concurrent wire-max requests per session
 
 
 class SmokeFailure(Exception):
@@ -346,7 +348,7 @@ def run_served(args) -> dict:
         boot_s = time.monotonic() - t_boot
         say(f"boot to listening {boot_s:.1f}s (warm-up {warm_s:.1f}s)")
 
-        traffic = Traffic(args.seed, args.accounts, n_max)
+        traffic = Traffic(args.seed, N_ACCOUNTS, n_max)
         check = Checker(traffic)
         addr = [("127.0.0.1", port)]
         clients = [Client(cluster=0, client_id=0xC0FFEE + i,
@@ -411,7 +413,7 @@ def run_served(args) -> dict:
                 errors.append(e)
                 barrier.abort()
 
-        per_session = [[traffic.fill([]) for _ in range(args.rounds)]
+        per_session = [[traffic.fill([]) for _ in range(ROUNDS)]
                        for _ in clients]
         threads = [threading.Thread(target=session, args=(c, b))
                    for c, b in zip(clients, per_session)]
@@ -526,9 +528,8 @@ def run_served(args) -> dict:
         for c in clients:
             c.close()
         server.kill()
-        if not args.keep:
-            # 1.6 GB sparse data file; the compile cache stays.
-            shutil.rmtree(WORKDIR, ignore_errors=True)
+        # 1.6 GB sparse data file; the compile cache stays.
+        shutil.rmtree(WORKDIR, ignore_errors=True)
 
     # The parent stayed off the device: the chip had one owner.
     require(not xla_bridge.backends_are_initialized(),
@@ -562,13 +563,8 @@ def run_served(args) -> dict:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--seed", type=int, default=20260926)
-    p.add_argument("--accounts", type=int, default=1 << 15)
-    p.add_argument("--rounds", type=int, default=8,
-                   help="concurrent wire-max requests per session")
     p.add_argument("--four-chips", action="store_true",
                    help="run ONLY the partitioned 4-device phase")
-    p.add_argument("--keep", action="store_true",
-                   help="keep scratch/chip_smoke (data file, server log)")
     args = p.parse_args(argv)
     t0 = time.monotonic()
     try:
@@ -577,7 +573,7 @@ def main(argv=None) -> int:
             # that phase and its comparison, and no other.
             from tigerbeetle_tpu.testing.partitioned_smoke import run
 
-            device = run(seed=args.seed, n_accounts=args.accounts, say=say)
+            device = run(seed=args.seed, say=say)
         else:
             device = run_served(args)
         want = 4 if args.four_chips else 1

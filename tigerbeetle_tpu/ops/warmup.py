@@ -115,7 +115,7 @@ def warm_set(a_cap: int, t_cap: int, sharding=None) -> dict:
     return out
 
 
-def precompile(entries: dict, workers: int = PRECOMPILE_WORKERS) -> dict:
+def precompile(entries: dict) -> dict:
     """Compile `entries` concurrently. Nothing is kept in memory: the
     point is the persistent cache entry each compile leaves behind.
     Returns name -> seconds."""
@@ -126,7 +126,8 @@ def precompile(entries: dict, workers: int = PRECOMPILE_WORKERS) -> dict:
         jitfn.lower(*args).compile()
         return name, round(_time.monotonic() - t0, 1)
 
-    workers = max(1, min(workers, len(entries), os.cpu_count() or 1))
+    workers = max(1, min(PRECOMPILE_WORKERS, len(entries),
+                         os.cpu_count() or 1))
     with ThreadPoolExecutor(workers) as pool:
         return dict(pool.map(one, entries.items()))
 

@@ -31,22 +31,23 @@ import numpy as np
 N_DEVICES = 4
 A_CAP = 1 << 17
 T_CAP = 1 << 21
+N_PAD = 8192
+N_ACCOUNTS = 1 << 15
 WINDOW_DEPTH = 4
 N_WINDOWS = 3
 
 
-def build_router(devices, a_cap: int = A_CAP, t_cap: int = T_CAP):
+def build_router(devices):
     """(mesh, router) over `devices` (real, virtual or described)."""
     from jax.sharding import Mesh
 
     from ..parallel.partitioned import PartitionedRouter
 
     mesh = Mesh(np.array(list(devices)[:N_DEVICES]), ("batch",))
-    return mesh, PartitionedRouter(mesh, a_cap=a_cap, t_cap=t_cap)
+    return mesh, PartitionedRouter(mesh, a_cap=A_CAP, t_cap=T_CAP)
 
 
-def abstract_chain_args(mesh, a_cap: int = A_CAP, t_cap: int = T_CAP,
-                        depth: int = WINDOW_DEPTH, n_pad: int = 8192):
+def abstract_chain_args(mesh):
     """Abstract (state, ev_stack, ts_stack, n_stack, None) of the fused
     window step at this smoke's shapes, placed on `mesh` — what the
     chip compile test hands to a mesh of described devices."""
@@ -60,16 +61,17 @@ def abstract_chain_args(mesh, a_cap: int = A_CAP, t_cap: int = T_CAP,
     from ..parallel.partitioned import stack_partitioned_window
 
     n = mesh.shape["batch"]
-    t_cap_s = t_cap // n
+    t_cap_s = T_CAP // n
     # Per-shard sub-states stacked on a leading shard axis, exactly as
     # partitioned_from_oracle lays them out.
     stacked = jax.eval_shape(lambda: jax.tree.map(
         lambda x: jnp.broadcast_to(x, (n, *jnp.shape(x))),
-        init_state(a_cap // n, t_cap_s,
+        init_state(A_CAP // n, t_cap_s,
                    orphan_cap=max((1 << 16) // n, t_cap_s),
                    e_cap=t_cap_s)))
     packed = stack_partitioned_window(
-        [transfers_to_arrays([])] * depth, [10 ** 12] * depth, n_pad)
+        [transfers_to_arrays([])] * WINDOW_DEPTH,
+        [10 ** 12] * WINDOW_DEPTH, N_PAD)
     return (abstract(stacked, NamedSharding(mesh, P("batch"))),
             *abstract(packed, NamedSharding(mesh, P())), None)
 
@@ -93,7 +95,7 @@ def check_sharded(state, mesh) -> int:
     return len(leaves)
 
 
-def run(seed: int, n_accounts: int, say=print) -> dict:
+def run(seed: int, say=print) -> dict:
     import jax
 
     from ..clients.common import events_max
@@ -115,6 +117,7 @@ def run(seed: int, n_accounts: int, say=print) -> dict:
     n_max = events_max(Operation.create_transfers,
                        StorageLayout().message_size_max - HEADER_SIZE)
     rng = np.random.default_rng(seed)
+    n_accounts = N_ACCOUNTS
     t0 = time.monotonic()
     mesh, router = build_router(dev)
     say(f"mesh {dict(mesh.shape)}; global caps a_cap={A_CAP} t_cap={T_CAP} "
