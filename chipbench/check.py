@@ -1,0 +1,137 @@
+"""The comparison that decides `correct`.
+
+Every answer the client received (set-up and window) is replayed
+through the plain reference (chipbench/reference/ledger.py) in the
+order the server committed, and the state the server holds after the
+window is read back through lookup_accounts / lookup_transfers and
+compared row for row. All comparisons are exact: each number's limit
+is 0.
+
+    result_mismatches     events whose (status, timestamp) differs
+    account_mismatches    accounts read back that differ, or are missing
+    transfer_mismatches   sampled transfers read back that differ
+    order_violations      prepares whose order contradicts real time,
+                          or that share a timestamp (strict
+                          serializability as far as a client can see)
+    unanswered            requests with no reply, or one that does not decode
+
+The commit order is the server's own claim (each reply's timestamps
+name its prepare); the claim is checked against the client's clock, and
+the reference then has to reproduce every answer in that order.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+from .reference.ledger import StateMachineOracle
+from .reference.ledger_types import Account, Transfer
+
+LIMITS = {"result_mismatches": 0, "account_mismatches": 0,
+          "transfer_mismatches": 0, "order_violations": 0, "unanswered": 0}
+
+
+def prepare_timestamp(results: np.ndarray) -> int:
+    """Event i of n carries ts - n + i + 1: the median candidate names
+    the prepare even if some event's timestamp is wrong (which the
+    replay then counts)."""
+    n = len(results)
+    cand = results["timestamp"].astype(np.int64) + (n - 1 - np.arange(n))
+    return int(np.sort(cand)[n // 2])  # exact: a float median rounds 1e18
+
+
+def commit_order(answered: list) -> tuple[list, int]:
+    """Requests sorted by their prepare's timestamp, and how many
+    contradict real time: b replied before a was sent, yet a is ordered
+    first; or two prepares with one timestamp."""
+    order = sorted(answered, key=lambda s: s.ts)
+    violations = 0
+    latest_send = float("-inf")
+    last_ts = None
+    for s in order:
+        if s.t_reply < latest_send or s.ts == last_ts:
+            violations += 1
+        latest_send = max(latest_send, s.t_send)
+        last_ts = s.ts
+    return order, violations
+
+
+def ordered(sent: list) -> tuple[list, int]:
+    """The answered requests in commit order, each with its prepare's
+    timestamp as `.ts`, and the count of order violations."""
+    answered = [s for s in sent if s.error is None]
+    for s in answered:
+        s.ts = prepare_timestamp(s.results)
+    return commit_order(answered)
+
+
+def apply(reference: StateMachineOracle, s) -> list:
+    """One request through the reference at its prepare's timestamp."""
+    payload = s.request.payload
+    accounts = s.request.operation == "create_accounts"
+    cls = Account if accounts else Transfer
+    events = [cls.unpack(payload[i:i + 128])
+              for i in range(0, len(payload), 128)]
+    return (reference.create_accounts(events, s.ts) if accounts
+            else reference.create_transfers(events, s.ts))
+
+
+def replay(reference: StateMachineOracle, order: list) -> int:
+    """Feed the reference in commit order; the number of events whose
+    (status, timestamp) differs from what the client received."""
+    mismatches = 0
+    for s in order:
+        want = apply(reference, s)
+        if len(s.results) != len(want):
+            mismatches += max(len(want), len(s.results))
+            continue
+        want_ts = np.fromiter((w.timestamp for w in want), np.uint64,
+                              len(want))
+        want_st = np.fromiter((int(w.status) for w in want), np.uint32,
+                              len(want))
+        mismatches += int(((want_ts != s.results["timestamp"])
+                           | (want_st != s.results["status"])).sum())
+    return mismatches
+
+
+def rows_differ(got: bytes | None, want_rows: list[bytes]) -> int:
+    """Rows of a lookup reply against the reference's, position by
+    position; a missing or surplus row counts, and so does every row of
+    a lookup that was never answered."""
+    got = got or b""
+    got_rows = [got[i:i + 128] for i in range(0, len(got), 128)]
+    n = max(len(got_rows), len(want_rows))
+    same = sum(1 for g, w in zip(got_rows, want_rows) if g == w)
+    return n - same
+
+
+def judge(sent: list, readback: dict) -> dict:
+    """The compared numbers, by name, from every request of the run and
+    the read-back replies ({"accounts": [(ids, reply)], "transfers":
+    [(ids, reply)]})."""
+    order, violations = ordered(sent)
+    reference = StateMachineOracle()
+    return {
+        "result_mismatches": replay(reference, order),
+        "account_mismatches": sum(
+            rows_differ(reply, [a.pack() for a in
+                                reference.lookup_accounts(ids)])
+            for ids, reply in readback["accounts"]),
+        "transfer_mismatches": sum(
+            rows_differ(reply, [t.pack() for t in
+                                reference.lookup_transfers(ids)])
+            for ids, reply in readback["transfers"]),
+        "order_violations": violations,
+        "unanswered": len(sent) - len(order)
+        + sum(1 for k in readback for _, reply in readback[k]
+              if reply is None),
+    }
+
+
+def verdict(numbers: dict) -> bool:
+    return all(numbers[k] <= LIMITS[k] for k in LIMITS)
+
+
+def compared(numbers: dict) -> dict:
+    """Each number beside its limit, for the result line and stderr."""
+    return {k: {"value": numbers[k], "limit": LIMITS[k]} for k in LIMITS}

@@ -1,0 +1,9 @@
+"""Milliseconds of the program's `commit_execute` span inside the window:
+state-machine execution (the device dispatch and its host work), mean per prepare."""
+
+from chipbench.trace_reduce import window_durations
+
+
+def read(context: dict):
+    dur = window_durations(context, "commit_execute")
+    return None if dur is None else 1e3 * float(dur.mean())
