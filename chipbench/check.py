@@ -27,6 +27,19 @@ import numpy as np
 from .reference.ledger import StateMachineOracle
 from .reference.ledger_types import Account, Transfer
 
+# The guarantees a configuration file may state (its `guarantees`
+# block, key by key), each with the compared numbers that hold the
+# program to it. A configuration that states another has no cell until
+# a comparison for it exists: the harness refuses to run it.
+GUARANTEES = {
+    "replicas": ("unanswered",),
+    "acknowledged_means": ("unanswered", "account_mismatches",
+                           "transfer_mismatches"),
+    "consistency": ("order_violations",),
+    "limits": ("result_mismatches", "account_mismatches"),
+    "results": ("result_mismatches", "account_mismatches",
+                "transfer_mismatches"),
+}
 LIMITS = {"result_mismatches": 0, "account_mismatches": 0,
           "transfer_mismatches": 0, "order_violations": 0, "unanswered": 0}
 

@@ -1,4 +1,4 @@
-"""Percent of the traced span in which no operation ran on the device:
+"""Percent of the traced window in which no operation ran on the device:
 100 x (1 - union of device-op intervals / span)."""
 
 

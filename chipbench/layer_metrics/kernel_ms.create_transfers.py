@@ -1,7 +1,7 @@
 """Device milliseconds per create_transfers dispatch: the union of
-the op intervals under each executed XLA module whose name contains
-`create_transfers`, from the profiler's trace, averaged over the
-dispatches in the traced span."""
+the op intervals under each executed XLA module whose name starts with
+`jit_create_transfers` (trace_reduce.KERNEL_MODULES), from the
+profiler's trace, averaged over the dispatches of the traced window."""
 
 
 def read(context: dict):

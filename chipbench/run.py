@@ -3,8 +3,9 @@
 
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Formats a data file, boots `start --engine=device` (production layout,
-one replica) as the only process on the chip, loads the configuration's
+Formats a data file, boots `start --engine=<the configuration's>`
+(production layout, one replica) as the only process on the chip, loads
+the configuration's
 accounts over TCP, sends a few un-timed requests of the cell's own
 traffic, measures a closed-loop window of about `--seconds` (a fixed
 number of requests: `--seconds` times the mix's stated rate), reads the
@@ -14,9 +15,12 @@ stdout. This process never starts a JAX backend.
 
 A cell is an entry of BENCHMARK.json's `workloads`; its configuration
 is chipbench/configs/<config>.json, its traffic mix
-chipbench/traffic/<traffic>.json, and each per-layer metric
+chipbench/traffic/<traffic>.json, each end-to-end metric
+chipbench/e2e_metrics/<name>.py and each per-layer metric
 chipbench/layer_metrics/<name>.py: adding one is adding files and
-entries, editing none.
+entries, editing none. What a file asks for and the harness cannot
+serve (an open loop, three replicas, a guarantee no comparison holds
+the program to) fails the run; no key is read by nothing.
 """
 
 from __future__ import annotations
@@ -31,7 +35,6 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
-import threading  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -48,9 +51,7 @@ from chipbench.window import Sent, StoreBudget, run_window, send  # noqa: E402
 HERE = os.path.join(ROOT, "chipbench")
 BOOT_TIMEOUT_S = 1000      # a cold warm-up compiles for minutes
 SETUP_REPLY_TIMEOUT_S = 900.0  # and so may a cell's first un-timed request or lookup
-PROFILE_AT = 0.35          # the traced span starts this far into the window
-PROFILE_SHARE = 1 / 3      # and lasts this share of it,
-PROFILE_MAX_S = 12.0       # at most (a checkpoint cycle of wire-max requests)
+PROFILE_TIMEOUT_S = 120.0  # for the profiler to start, and to write its trace
 # The rehearsal's `--small` server: TEST_LAYOUT and start's small caps.
 REHEARSAL = {"accounts": 2000, "transfers": 1 << 14}
 
@@ -75,18 +76,60 @@ def load_cell(workload: str) -> tuple[dict, dict, dict, dict]:
     return bench, cell, config, mix
 
 
-def load_reader(name: str):
-    path = os.path.join(HERE, "layer_metrics", name + ".py")
+def load_reader(kind: str, name: str):
+    """The reader of one metric: chipbench/<kind>/<name>.py's `read`."""
+    path = os.path.join(HERE, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(
-        "chipbench_layer_metric_" + name.replace(".", "_").replace("-", "_"),
-        path)
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
 
 
-def percentile(values: list[float], q: float) -> float:
-    return float(np.percentile(np.asarray(values), q))
+def read_metrics(kind: str, entries: list, workload: str, context: dict) -> dict:
+    """Each metric of `entries` that this cell reports, through its
+    reader; a reader that finds nothing to read leaves its metric out."""
+    out = {}
+    for entry in entries:
+        if workload not in entry.get("workloads", [workload]):
+            continue
+        value = load_reader(kind, entry["name"])(context)
+        if value is not None:
+            out[entry["name"]] = {"value": value, "unit": entry["unit"]}
+    return out
+
+
+def servable(config: dict, mix: dict) -> None:
+    """Fail on what a configuration or a mix asks for and this harness
+    cannot serve: it drives closed loops against one server process."""
+    if mix["loop"] != "closed":
+        raise BenchFailure(f"traffic {mix['name']!r} asks for a "
+                           f"{mix['loop']!r} loop; the harness drives closed "
+                           "loops only")
+    server = config["server"]
+    if server["replica_count"] != 1:
+        raise BenchFailure(f"configuration {config['name']!r} asks for "
+                           f"{server['replica_count']} replicas; the harness "
+                           "starts one server process")
+    if server["small_layout"]:
+        raise BenchFailure(f"configuration {config['name']!r} asks for the "
+                           "small layout: that is the rehearsal's, no cell's")
+    if config["guarantees"]["replicas"] != server["replica_count"]:
+        raise BenchFailure("the guarantees name another number of replicas "
+                           "than the server block")
+    unheld = sorted(set(config["guarantees"]) - set(check.GUARANTEES))
+    if unheld:
+        raise BenchFailure(f"configuration {config['name']!r} states "
+                           f"guarantees {unheld} that no compared number "
+                           "holds the program to")
+
+
+def wait_for(path: str, what: str) -> None:
+    deadline = time.monotonic() + PROFILE_TIMEOUT_S
+    while not os.path.exists(path):
+        if time.monotonic() > deadline:
+            raise BenchFailure(f"{what} within {PROFILE_TIMEOUT_S:.0f}s")
+        time.sleep(0.02)
 
 
 def lookup(client, operation, ids: list[int]) -> bytes | None:
@@ -140,6 +183,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     in the program's place before the comparison; `launcher` lets the
     fault tests start the server with its timed path broken."""
     bench, cell, config, mix = load_cell(workload)
+    servable(config, mix)
     # The program's client library and admission rules: the system
     # under test, imported here; none of them starts a JAX backend.
     from tigerbeetle_tpu.clients.common import events_max
@@ -172,12 +216,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     os.makedirs(workdir)
     data_path = os.path.join(workdir, "0_0.tigerbeetle")
     span_path = os.path.join(workdir, "spans.json") if trace else None
-    profile_s = (min(PROFILE_MAX_S, PROFILE_SHARE * seconds) if trace else 0.0)
     format_data_file(data_path, small=rehearse)
     port = free_port()
     server = Server(port, data_path, workdir, small=rehearse,
-                    span_trace=span_path, profile_seconds=profile_s,
-                    launcher=launcher)
+                    span_trace=span_path, profile=trace,
+                    engine=config["server"]["engine"], launcher=launcher)
     clients: list = []
     sent: list[Sent] = []
     try:
@@ -229,25 +272,26 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"set-up: {dep.n} accounts, {len(sent)} requests, "
             f"{budget.created} transfers created")
 
+        if trace:
+            # The profiler brackets the whole window: every checkpoint
+            # of it is in the trace, whatever the wall clock says.
+            open(os.path.join(workdir, "profile.go"), "w").close()
+            wait_for(os.path.join(workdir, "profile.started"),
+                     "the launcher did not start the profiler")
         open(os.path.join(workdir, "mark.window_begin"), "w").close()
         time.sleep(0.05)  # the launcher polls for the mark every 20 ms
-        profile_timer = None
-        if trace:
-            profile_timer = threading.Timer(
-                PROFILE_AT * seconds,
-                lambda: open(os.path.join(workdir, "profile.go"), "w").close())
-            profile_timer.daemon = True
-            profile_timer.start()
         setup_s = time.monotonic() - T_PROCESS_START
         wall_t0 = time.time()
         window, t0, t1, cut = run_window(
-            clients, Operation.create_transfers,
+            clients, Operation,
             lambda s, k: dep.transfer_request(s, k, n_req), quota, seconds,
             budget)
         wall_t1 = wall_t0 + (t1 - t0)
         open(os.path.join(workdir, "mark.window_end"), "w").close()
-        if profile_timer is not None:
-            profile_timer.cancel()
+        if trace:
+            open(os.path.join(workdir, "profile.stop"), "w").close()
+            wait_for(os.path.join(workdir, "profile.json"),
+                     "the launcher did not write the profiler's trace")
         sent += window
         if cut:
             say(f"WINDOW CUT at {t1 - t0:.1f}s: the sessions had not sent "
@@ -306,7 +350,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             str(n): [round(s.seconds, 4) for s in window if s.session == n]
             for n in range(mix["sessions"])}))
     say(f"latency sample: {len(secs)} requests (p95 has "
-        f"{int(len(secs) * 0.05)} beyond it)")
+        f"{int(len(secs) * 0.05)} beyond it, p98 {int(len(secs) * 0.02)}; "
+        f"the longest {round(max(secs), 4)}s)")
     say(f"compiles inside the window: {compiles_in_window} "
         f"(after listening, whole run: {shutdown['compiles_after_listening']})")
     say(f"server shutdown record: {json.dumps(shutdown, sort_keys=True)}")
@@ -315,25 +360,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     context = {
         "server_lines": server.lines, "shutdown": shutdown, "marks": marks,
-        "compiles_in_window": compiles_in_window,
+        "compiles_in_window": compiles_in_window, "setup_s": setup_s,
         "window": {"wall_t0": wall_t0, "wall_t1": wall_t1,
                    "seconds": window_s, "requests": len(answered),
+                   "request_seconds": secs, "created": created,
                    "events_per_request": n_req,
                    "create_requests_answered":
-                       sum(1 for s in sent if s.error is None)},
+                       sum(1 for s in sent if s.error is None and
+                           s.request.operation.startswith("create_"))},
         "device_kind": device["kind"], "spans": None, "device": None,
         "profile": None,
     }
-    measured = {
-        "accepted_tps": created / window_s,
-        "request_p50_ms": 1e3 * percentile(secs, 50),
-        "request_p95_ms": 1e3 * percentile(secs, 95),
-        "setup_s": setup_s,
-    }
-    # An end-to-end metric that lists `workloads` exists only there.
-    metrics = {e["name"]: {"value": measured[e["name"]], "unit": e["unit"]}
-               for e in bench["end_to_end"]
-               if workload in e.get("workloads", [workload])}
+    metrics = read_metrics("e2e_metrics", bench["end_to_end"], workload,
+                           context)
     result = {
         "correct": correct, "attempted": len(window),
         "failed": len(window) - len(answered), "metrics": metrics,
@@ -346,15 +385,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if context["device"] is not None:
             result["device"]["busy_s"] = context["device"]["busy_s"]
             result["device"]["window_s"] = context["profile"]["seconds"]
-        per_layer = {}
-        for entry in bench["per_layer"]:
-            if "workloads" in entry and workload not in entry["workloads"]:
-                continue
-            value = load_reader(entry["name"])(context)
-            if value is not None:
-                per_layer[entry["name"]] = {"value": value,
-                                            "unit": entry["unit"]}
-        result["metrics"] = per_layer
+        result["metrics"] = read_metrics("layer_metrics", bench["per_layer"],
+                                         workload, context)
         say("end-to-end metrics of this traced run (not the ones judged): "
             + json.dumps(metrics))
     result["window"] = {
@@ -398,7 +430,7 @@ def read_traces(workdir: str, span_path: str, context: dict,
         raise BenchFailure("the profiler's trace has no chipbench_anchor")
     summary = trace_reduce.device_summary(xp, trace_reduce.KERNEL_MODULES)
     if summary["busy_s"] <= 0:
-        raise BenchFailure("no operation ran on the device in the traced span")
+        raise BenchFailure("no operation ran on the device in the traced window")
     anchor_wall_s = prof["anchor_wall_ns"] / 1e9
     t0_ns = xp["anchor_ns"]
     t1_ns = t0_ns + (prof["stop_call_wall_ns"] - prof["anchor_wall_ns"])
@@ -409,7 +441,7 @@ def read_traces(workdir: str, span_path: str, context: dict,
     gaps = trace_reduce.idle_gaps(summary["busy_intervals_ns"], t0_ns, t1_ns,
                                   xp["anchor_ns"], anchor_wall_s,
                                   spans["spans"])
-    say(f"traced span {context['profile']['seconds']:.2f}s, device busy "
+    say(f"traced window {context['profile']['seconds']:.2f}s, device busy "
         f"{summary['busy_s']:.4f}s, {len(summary['dispatch_seconds'])} "
         f"create_transfers dispatches; modules run: "
         f"{json.dumps(summary['module_counts'])}; xplane "

@@ -2,7 +2,7 @@
 
 `Server` is copied from chip_smoke.py (PR 23) and started through
 chipbench/server_launcher.py: `python -m tigerbeetle_tpu format`, then
-`start --engine=device`, one replica, the only process that starts a
+`start --engine=<the configuration's engine>`, one replica, the only process that starts a
 JAX backend. The harness reads the lines `start` prints.
 """
 
@@ -44,20 +44,20 @@ class Server:
     """`start --engine=device` as a child: the one process on the chip."""
 
     def __init__(self, port: int, path: str, workdir: str, *, small: bool,
-                 span_trace: str | None, profile_seconds: float,
-                 launcher: str | None = None):
+                 span_trace: str | None, profile: bool,
+                 engine: str = "device", launcher: str | None = None):
         env = dict(os.environ, PYTHONUNBUFFERED="1")
         self.log_path = os.path.join(workdir, "server.log")
         self.lines: list[str] = []
         start = ["start", f"--addresses=127.0.0.1:{port}", "--replica=0",
-                 "--engine=device"]
+                 f"--engine={engine}"]
         if small:
             start.append("--small")
         if span_trace:
             start += ["--trace", span_trace]
         self.proc = subprocess.Popen(
             [sys.executable, launcher or LAUNCHER, "--workdir", workdir,
-             "--profile-seconds", str(profile_seconds), "--", *start, path],
+             *(["--profile"] if profile else []), "--", *start, path],
             cwd=ROOT, env=env, stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT, text=True)
         self._cond = threading.Condition()

@@ -2,7 +2,7 @@
 """The benchmark's launcher of the system under test: the one process
 on the chip.
 
-    python3 chipbench/server_launcher.py --workdir DIR [--profile-seconds S] -- start ...
+    python3 chipbench/server_launcher.py --workdir DIR [--profile] -- start ...
 
 Runs `tigerbeetle_tpu.main.main([...])` in this process, with nothing
 changed, and adds the two things only the process that holds the chip
@@ -14,12 +14,15 @@ can give the benchmark:
 - a count of XLA compiles at the instants the harness marks by
   creating DIR/mark.<name> (window begin and end), so that compiles
   inside the measured window are a number of their own;
-- with --profile-seconds S, a jax.profiler trace of S seconds, started
-  when the harness creates DIR/profile.go (a file, not a new option of
-  the program) and stopped before the server stops. DIR/profile.json
-  says when it ran; a `chipbench_anchor` annotation inside the trace,
-  entered at a recorded wall-clock instant, puts the trace's clock and
-  the program's span clock (wall-anchored) on one axis.
+- with --profile, a jax.profiler trace that starts when the harness
+  creates DIR/profile.go and stops when it creates DIR/profile.stop
+  (files, not new options of the program): the harness brackets the
+  whole measured window with them, so the trace holds every checkpoint
+  of the window and no luck decides which. DIR/profile.started appears
+  once the trace runs; DIR/profile.json says when it ran; a
+  `chipbench_anchor` annotation inside the trace, entered at a recorded
+  wall-clock instant, puts the trace's clock and the program's span
+  clock (wall-anchored) on one axis.
 """
 
 from __future__ import annotations
@@ -34,9 +37,10 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def profile_when_asked(workdir: str, seconds: float, stop: threading.Event,
+def profile_when_asked(workdir: str, stop: threading.Event,
                        out: dict) -> None:
     go = os.path.join(workdir, "profile.go")
+    halt = os.path.join(workdir, "profile.stop")
     while not os.path.exists(go):
         if stop.wait(0.02):
             return
@@ -53,7 +57,9 @@ def profile_when_asked(workdir: str, seconds: float, stop: threading.Event,
     out["anchor_wall_ns"] = time.time_ns()
     with jax.profiler.TraceAnnotation("chipbench_anchor"):
         time.sleep(0.001)
-    stop.wait(seconds)
+    open(os.path.join(workdir, "profile.started"), "w").close()
+    while not os.path.exists(halt) and not stop.wait(0.02):
+        pass
     out["stop_call_wall_ns"] = time.time_ns()
     jax.profiler.stop_trace()
     out["stopped_wall_ns"] = time.time_ns()
@@ -98,7 +104,7 @@ class Marks:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--workdir", required=True)
-    p.add_argument("--profile-seconds", type=float, default=0.0)
+    p.add_argument("--profile", action="store_true")
     p.add_argument("program_args", nargs=argparse.REMAINDER)
     args = p.parse_args(argv)
     program_args = [a for a in args.program_args if a != "--"]
@@ -111,10 +117,9 @@ def main(argv=None) -> int:
     marks = Marks(args.workdir)
     marker = threading.Thread(target=marks.watch, args=(stop,), daemon=True)
     marker.start()
-    if args.profile_seconds > 0:
+    if args.profile:
         watcher = threading.Thread(
-            target=profile_when_asked,
-            args=(args.workdir, args.profile_seconds, stop, profile),
+            target=profile_when_asked, args=(args.workdir, stop, profile),
             daemon=True)
         watcher.start()
     try:

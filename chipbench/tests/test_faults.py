@@ -20,7 +20,7 @@ from chipbench.run import run_cell  # noqa: E402
 
 FAULTY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "faulty_launcher.py")
-CELL = "twophase_limits.full_batch_1s"
+CELL = "default.b1024_4s"
 
 
 def test_sound_run_is_correct_and_every_control_is_not():

@@ -12,6 +12,7 @@ microseconds).
 
 from __future__ import annotations
 
+import functools
 import glob
 import json
 import os
@@ -22,12 +23,18 @@ import numpy as np
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 ANCHOR = "chipbench_anchor"
-# The XLA modules that are create_transfers dispatches. Only the plain
-# tier carries its name: every other tier (limit fixpoint, deep, ...) is
+# The XLA modules that are create_transfers dispatches: those whose name
+# starts with this. Only the plain tier carries the name; every other
+# tier (limit fixpoint, deep, ...) is
 # `jax.jit(functools.partial(create_transfers_fast, ...))`, which XLA
-# names `jit__unknown` (ops/fast_kernels.py:2364-2410 at PR 26). No cell
-# sends what the other unnamed entries serve (imported accounts, chains).
-KERNEL_MODULES = ("jit_create_transfers", "jit__unknown")
+# names `jit__unknown` (ops/fast_kernels.py:2364-2410 at PR 26), as it
+# does any other unnamed entry, so those are not counted: no cell sends
+# what they serve, and a cell that does needs the program to name them.
+KERNEL_MODULES = ("jit_create_transfers",)
+# A device gap shorter than this lies between two ops of one dispatch;
+# such gaps are summed under one name and not looked up span by span.
+SHORT_GAP_NS = 100_000.0
+BETWEEN_OPS = "between ops of a dispatch"
 # Host spans a device gap is attributed to, most specific first.
 GAP_SPANS = ("commit_checkpoint", "commit_compact", "journal_write",
              "commit_execute", "commit_prefetch", "bus_recv", "bus_send")
@@ -68,6 +75,7 @@ def union_seconds(start: np.ndarray, dur: np.ndarray) -> tuple[float, list]:
 _OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
 
 
+@functools.lru_cache(maxsize=None)
 def short_op(hlo: str) -> str:
     """`%while.6 while` from the HLO text the trace names an op by."""
     name, _, rest = hlo.partition(" = ")
@@ -186,13 +194,19 @@ def window_durations(context: dict, name: str):
 
 def idle_gaps(merged_ns: list, trace_t0_ns: float, trace_t1_ns: float,
               anchor_ns: float, anchor_wall_s: float, spans: dict) -> dict:
-    """Device-idle seconds inside the traced span, by the host span that
-    covers most of each gap ("none" where none does)."""
+    """Device-idle seconds inside the traced window, by the host span that
+    covers most of each gap ("none" where none does); the gaps under
+    SHORT_GAP_NS together under BETWEEN_OPS."""
     edges = [trace_t0_ns] + [x for iv in merged_ns for x in iv] + [trace_t1_ns]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
             if edges[i + 1] > edges[i]]
     totals: dict[str, float] = {}
+    short = sum(hi - lo for lo, hi in gaps if hi - lo < SHORT_GAP_NS)
+    if short:
+        totals[BETWEEN_OPS] = short / 1e9
     for lo, hi in gaps:
+        if hi - lo < SHORT_GAP_NS:
+            continue
         w0 = anchor_wall_s + (lo - anchor_ns) / 1e9
         w1 = anchor_wall_s + (hi - anchor_ns) / 1e9
         best, best_cover = "none", 0.0

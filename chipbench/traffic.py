@@ -50,21 +50,15 @@ class Deployment:
         self.seed = seed
         self.n = accounts_cut or acc["count"]  # the population traffic draws from
         self.ledger = acc["ledger"]
-        # One more limited account of its own, outside the draw, where the
-        # configuration asks for a cascade in set-up (see _cascade).
-        self.cascade = acc.get("cascade")
-        n_all = self.n + (1 if self.cascade else 0)
         rng = _rng(seed, 0xACC)
         base = int(rng.integers(1, 1 << 47)) | 1
-        i = np.arange(n_all, dtype=np.uint64)
+        i = np.arange(self.n, dtype=np.uint64)
         # 128-bit ids with both limbs in play.
         self.id_lo = np.uint64(base) + np.uint64(7) * i
         self.id_hi = i % np.uint64(5)
         every = acc.get("limited_every", 0)
         self.limited = ((i % np.uint64(every)) == 0) if every else \
-            np.zeros(n_all, dtype=bool)
-        if self.cascade:
-            self.limited[self.n] = True
+            np.zeros(self.n, dtype=bool)
         self.limit_flag = ACCOUNT_FLAGS[acc["limit_flag"]] if every else 0
         self.funding_amount = acc.get("funding_amount", 0)
         tr = config["transfers"]
@@ -82,15 +76,14 @@ class Deployment:
                 for l, h in zip(self.id_lo, self.id_hi)]
 
     def account_requests(self, n_max: int) -> list[Request]:
-        n_all = len(self.id_lo)
-        rec = np.zeros(n_all, dtype=wire.ACCOUNT)
+        rec = np.zeros(self.n, dtype=wire.ACCOUNT)
         rec["id_lo"], rec["id_hi"] = self.id_lo, self.id_hi
-        rec["ud64"] = np.arange(n_all)
+        rec["ud64"] = np.arange(self.n)
         rec["ledger"] = self.ledger
         rec["code"] = self.config["accounts"]["code"]
         rec["flags"] = np.where(self.limited, self.limit_flag, 0)
         return [self._request("create_accounts", rec[i:i + n_max])
-                for i in range(0, n_all, n_max)]
+                for i in range(0, self.n, n_max)]
 
     def funding_requests(self, n_max: int) -> list[Request]:
         """One credit of `funding_amount` to every limited account, from
@@ -104,8 +97,6 @@ class Deployment:
         rec["debit_lo"], rec["debit_hi"] = self.id_lo[src], self.id_hi[src]
         rec["credit_lo"], rec["credit_hi"] = self.id_lo[lim], self.id_hi[lim]
         rec["amount_lo"] = self.funding_amount
-        if self.cascade:
-            rec["amount_lo"][lim == self.n] = self.cascade["funding"]
         step = min(n_max, self.config["accounts"]["funding_events_per_request"])
         return [self._request("create_transfers", rec[i:i + step])
                 for i in range(0, len(rec), step)]
@@ -153,24 +144,7 @@ class Deployment:
         rec["debit_hi"][unknown] = 9
         rec["debit_lo"][unknown] = rng.integers(1, 1 << 40, int(unknown.sum()))
         rec["ledger"][ledger] = self.ledger + 1
-        if self.cascade and stream == STREAM_WARM and k == 0:
-            self._cascade(rec)
         return self._request("create_transfers", rec)
-
-    def _cascade(self, rec: np.ndarray) -> None:
-        """The first un-timed request leads with debits of the cascade
-        account whose verdicts alternate, each depending on the one
-        before: a limit cascade deeper than the program's 8-round
-        fixpoint tier resolves. That loads the deep tier, which `start`
-        does not warm, during set-up in every run; left to the traffic,
-        some seeds first need it in the window (a 21 s stall) and some
-        never (PERF.md, PR 26)."""
-        amounts = self.cascade["amounts"]
-        head = rec[:len(amounts)]
-        head["debit_lo"], head["debit_hi"] = self.id_lo[self.n], self.id_hi[self.n]
-        head["credit_lo"], head["credit_hi"] = self.id_lo[1], self.id_hi[1]
-        head["amount_lo"] = amounts
-        head["ledger"] = self.ledger
 
     def _resolve(self, stream: int, k: int, n: int) -> Request:
         prev = np.frombuffer(

@@ -75,6 +75,12 @@ class StoreBudget:
             self.created += created
 
 
+def percentile_ms(seconds: list[float], q: float) -> float:
+    """The one percentile request latencies are read by: linear
+    interpolation between the order statistics (numpy's default)."""
+    return 1e3 * float(np.percentile(np.asarray(seconds), q))
+
+
 def send(client, operation, sent: Sent,
          timeout_s: float = REPLY_TIMEOUT_S) -> Sent:
     """One request through the program's client; fills in the reply."""
@@ -91,10 +97,12 @@ def send(client, operation, sent: Sent,
     return sent
 
 
-def run_window(clients: list, operation, make_request, quota: int,
+def run_window(clients: list, operations, make_request, quota: int,
                seconds: float, budget: StoreBudget,
                ) -> tuple[list[Sent], float, float, bool]:
-    """Drive one session per client through `quota` requests each;
+    """Drive one session per client through `quota` requests each, each
+    under the member of `operations` (the program's Operation enum) that
+    the request names;
     returns every request in send order per session, the window's start
     and its end (the last reply), both on time.monotonic(), and whether
     the hard stop cut it."""
@@ -113,15 +121,17 @@ def run_window(clients: list, operation, make_request, quota: int,
                 cut.set()
                 break
             request = make_request(s, k)
-            if not budget.reserve(request.n_events):
+            stores = request.operation == "create_transfers"
+            if stores and not budget.reserve(request.n_events):
                 halt.set()
                 break
-            one = send(clients[s], operation,
+            one = send(clients[s], getattr(operations, request.operation),
                        Sent("window", s, request, 0.0))
             sent[s].append(one)
-            budget.settle(request.n_events,
-                          one.created if one.error is None else
-                          request.n_events)
+            if stores:
+                budget.settle(request.n_events,
+                              one.created if one.error is None else
+                              request.n_events)
             if one.error is not None:
                 halt.set()  # an unanswered request ends the window
                 break
