@@ -1,0 +1,10 @@
+"""Milliseconds of the program's `checkpoint_forest` spans per checkpoint:
+forest.checkpoint(): memtable freeze, manifests, free set. Summed over
+the spans that start inside a `commit_checkpoint` span of the window,
+over the number of those parents."""
+
+from chipbench.span_children import child_ms_per_parent
+
+
+def read(context: dict):
+    return child_ms_per_parent(context, "checkpoint_forest", "commit_checkpoint")

@@ -585,23 +585,47 @@ class TestClusterConfigEnforcement:
 
 
 class TestCommitMetrics:
-    def test_per_op_timing_table(self):
+    def test_per_op_timing_from_commit_execute_spans(self):
         """reference: per-op timings recorded at commit
-        (src/state_machine.zig:729-780, :2637-2667)."""
+        (src/state_machine.zig:729-780, :2637-2667). Here the replica's
+        `commit_execute` span is the timer: it carries `operation`, so
+        count and duration per operation read off the recorded spans."""
         from tigerbeetle_tpu import multi_batch
         from tigerbeetle_tpu.state_machine import StateMachine
+        from tigerbeetle_tpu.testing.cluster import Cluster
+        from tigerbeetle_tpu.trace import Tracer
         from tigerbeetle_tpu.types import Account, Operation
 
-        sm = StateMachine(engine="oracle")
-        body = multi_batch.encode(
+        tracer = Tracer()
+        cluster = Cluster(
+            seed=11, replica_count=1, tracer_factory=lambda i: tracer,
+            state_machine_factory=lambda: StateMachine(engine="oracle"))
+        client = cluster.client(5)
+
+        def drive(op, body):
+            client.request(op, body)
+            assert cluster.run(4000, until=lambda: client.idle), \
+                cluster.debug_status()
+
+        drive(Operation.create_accounts, multi_batch.encode(
             [b"".join(Account(id=i, ledger=1, code=1).pack()
-                      for i in (1, 2))], 128)
-        sm.commit(Operation.create_accounts, body, 100)
+                      for i in (1, 2))], 128))
         lookup = multi_batch.encode([(1).to_bytes(16, "little")], 16)
-        sm.commit(Operation.lookup_accounts, lookup, 200)
-        sm.commit(Operation.lookup_accounts, lookup, 300)
-        m = sm.metrics
+        drive(Operation.lookup_accounts, lookup)
+        drive(Operation.lookup_accounts, lookup)
+        m: dict = {}
+        for e in tracer.events:
+            if e["name"] == "commit_execute":
+                name = Operation(e["args"]["operation"]).name
+                row = m.setdefault(name, {"count": 0, "total_us": 0.0,
+                                          "max_us": 0.0})
+                row["count"] += 1
+                row["total_us"] += e["dur"]
+                row["max_us"] = max(row["max_us"], e["dur"])
         assert m["create_accounts"]["count"] == 1
         assert m["lookup_accounts"]["count"] == 2
-        assert m["lookup_accounts"]["total_ns"] >= \
-            m["lookup_accounts"]["max_ns"] > 0
+        assert m["lookup_accounts"]["total_us"] >= \
+            m["lookup_accounts"]["max_us"] > 0
+        assert not hasattr(cluster.replicas[0].state_machine, "metrics")
+
+

@@ -83,6 +83,39 @@ class _CompileLog:
                 "seconds": round(self.seconds - s0, 3)}
 
 
+# A turn of the serving loop shorter than this did no work worth a
+# span (an empty poll and a tick take tens of microseconds).
+LOOP_BUSY_MIN_NS = 1_000_000
+
+
+def serve(bus, replica, tracer, stop: list) -> None:
+    """The reference main loop: tick + io.run_for_ns
+    (src/tigerbeetle/main.zig:522-525), until `stop` holds something.
+
+    The one serving thread's utilisation is recorded as `loop_busy`
+    spans: a busy turn runs from the moment the bus's wait ended
+    (`bus.woke_ns`: messages are delivered inside `poll`, after its
+    select) to the next `poll` call. Explicit timing through the
+    tracer's own clock, so the null tracer reads none."""
+    from .trace import Event
+
+    last_commit = -1
+    while not stop:
+        bus.poll(0.01)
+        replica.tick()
+        if replica.commit_min != last_commit:
+            # Progress marker: the vortex supervisor's shutdown
+            # reads these from the replica log to wait for every
+            # replica to catch up to the cluster commit level
+            # before delivering SIGINT (a lagging backup stopped
+            # mid-catch-up would dump a commit-free trace).
+            last_commit = replica.commit_min
+            print(f"commit={last_commit}", flush=True)
+        busy_ns = tracer.now_ns() - bus.woke_ns
+        if busy_ns >= LOOP_BUSY_MIN_NS:
+            tracer.record_span(Event.loop_busy, bus.woke_ns, busy_ns)
+
+
 def cmd_start(args) -> int:
     # Shutdown rides a signal FLAG from the very top: a SIGINT landing
     # during storage open / warmup / journal recovery must still reach
@@ -132,7 +165,7 @@ def cmd_start(args) -> int:
 
     tracer = None
     if args.trace or args.statsd or args.metrics_port is not None:
-        from .trace import StatsD, Tracer
+        from .trace import StatsD, Tracer, install_gc_spans
 
         statsd = None
         if args.statsd:
@@ -146,6 +179,9 @@ def cmd_start(args) -> int:
         # recording tracer: the endpoint exposes its registry.
         tracer = Tracer(statsd=statsd, pid=args.replica,
                         emit_interval_s=args.trace_emit_interval)
+        # The collector's pauses fall under no commit stage: one
+        # host_gc span each, only while a tracer records them.
+        install_gc_spans(tracer)
     bus = MessageBus(cluster=args.cluster, on_message=on_message,
                      replica_addresses=addresses, replica_id=args.replica,
                      listen=True, listen_port=args.listen_port,
@@ -208,26 +244,13 @@ def cmd_start(args) -> int:
     print(f"replica {args.replica} listening on "
           f"{addresses[args.replica][0]}:{addresses[args.replica][1]} "
           f"(cluster={args.cluster}, engine={args.engine})", flush=True)
-    # The reference main loop: tick + io.run_for_ns
-    # (src/tigerbeetle/main.zig:522-525). Shutdown rides the signal
-    # FLAG installed at the top of cmd_start, not KeyboardInterrupt: a
-    # SIGINT delivered while the interpreter is inside a C callback
-    # (e.g. JAX's gc hook) raises there and is swallowed as "exception
-    # ignored in callback" — the loop would never see it and the
-    # server would ignore the shutdown.
+    # Shutdown rides the signal FLAG installed at the top of cmd_start,
+    # not KeyboardInterrupt: a SIGINT delivered while the interpreter is
+    # inside a C callback (e.g. JAX's gc hook) raises there and is
+    # swallowed as "exception ignored in callback" — the loop would
+    # never see it and the server would ignore the shutdown.
     try:
-        last_commit = -1
-        while not stop:
-            bus.poll(0.01)
-            replica.tick()
-            if replica.commit_min != last_commit:
-                # Progress marker: the vortex supervisor's shutdown
-                # reads these from the replica log to wait for every
-                # replica to catch up to the cluster commit level
-                # before delivering SIGINT (a lagging backup stopped
-                # mid-catch-up would dump a commit-free trace).
-                last_commit = replica.commit_min
-                print(f"commit={last_commit}", flush=True)
+        serve(bus, replica, replica.tracer, stop)
     except KeyboardInterrupt:
         pass  # belt and braces: a late-registered handler race
     finally:
@@ -249,6 +272,10 @@ def cmd_start(args) -> int:
             "mirror_regime": bool(led._hard_regime),
             "compiles_after_listening": compile_log.after_listening(),
             "fallback_stats": led.fallback_stats(),
+            # Transfer rows the durable flush put, by path, and the
+            # checkpoints taken: whether a checkpoint re-puts rows the
+            # column path had already written.
+            "durable_rows": dict(replica.durable.rows_put),
         }}), flush=True)
     return 0
 

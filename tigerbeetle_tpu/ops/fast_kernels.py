@@ -2249,12 +2249,23 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
 
 create_transfers_fast_jit = jax.jit(create_transfers_fast, donate_argnums=0)
 
+
+def _tier_jit(name: str, fn=create_transfers_fast, **static):
+    """jit `fn` with `static` bound, under a name of its own: XLA names
+    the program `jit_<name>`, and a bare functools.partial has none, so
+    every tier would show in a device trace as `jit__unknown`. The
+    per-batch tiers are `create_transfers_<tier>`; the window programs
+    keep the leading underscore of their named siblings."""
+    tier = functools.partial(fn, **static)
+    tier.__name__ = name
+    return jax.jit(tier, donate_argnums=0)
+
+
 # Imported tier (plain eligibility + native imported rules + the
 # left-to-right maxima chain for in-batch regress). Selected by the
 # ledger's host pre-route when a batch/window carries imported flags.
-create_transfers_imported_jit = jax.jit(
-    functools.partial(create_transfers_fast, imported_mode=True),
-    donate_argnums=0)
+create_transfers_imported_jit = _tier_jit(
+    "create_transfers_imported", imported_mode=True)
 
 
 def _create_transfers_super_imported(state, ev, seg, force_fallback=None):
@@ -2361,20 +2372,17 @@ create_transfers_super_balancing_ring_jit = jax.jit(
 # K=8 empirically covers even the adversarial config4 workload with ~16
 # breach-boundary events per limited account per batch).
 LIMIT_FIXPOINT_ROUNDS = 8
-create_transfers_fixpoint_jit = jax.jit(
-    functools.partial(create_transfers_fast,
-                      limit_rounds=LIMIT_FIXPOINT_ROUNDS),
-    donate_argnums=0)
+create_transfers_fixpoint_jit = _tier_jit(
+    "create_transfers_fixpoint", limit_rounds=LIMIT_FIXPOINT_ROUNDS)
 
 # Escalation tier: full protocol-max batches over few limited accounts
 # can cascade deeper than 8 waves (config4 at 8190 events / 64 accounts
 # measured 9-32); the deep variant costs ~4x the rounds but still beats
 # the host path by an order of magnitude on chip.
 LIMIT_FIXPOINT_ROUNDS_DEEP = 32
-create_transfers_fixpoint_deep_jit = jax.jit(
-    functools.partial(create_transfers_fast,
-                      limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP),
-    donate_argnums=0)
+create_transfers_fixpoint_deep_jit = _tier_jit(
+    "create_transfers_fixpoint_deep",
+    limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP)
 
 # Imported fixpoint tier: the plain imported tier's escalation target
 # (closing flags, voids of closing pendings, potential limit breaches).
@@ -2383,14 +2391,12 @@ create_transfers_fixpoint_deep_jit = jax.jit(
 # applied set it runs over evolves with the closed/limit decisions).
 # Uniform closing eligibility across tiers is what lets the SPMD driver
 # run mixed imported+closing windows with zero host fallbacks.
-create_transfers_imported_fixpoint_jit = jax.jit(
-    functools.partial(create_transfers_fast, imported_mode=True,
-                      limit_rounds=LIMIT_FIXPOINT_ROUNDS),
-    donate_argnums=0)
-create_transfers_imported_fixpoint_deep_jit = jax.jit(
-    functools.partial(create_transfers_fast, imported_mode=True,
-                      limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP),
-    donate_argnums=0)
+create_transfers_imported_fixpoint_jit = _tier_jit(
+    "create_transfers_imported_fixpoint", imported_mode=True,
+    limit_rounds=LIMIT_FIXPOINT_ROUNDS)
+create_transfers_imported_fixpoint_deep_jit = _tier_jit(
+    "create_transfers_imported_fixpoint_deep", imported_mode=True,
+    limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP)
 
 # Balancing tier (reference :3840-3853): balancing_debit/credit clamps
 # ride the limit fixpoint — per-round clamped amounts from the exact
@@ -2398,16 +2404,12 @@ create_transfers_imported_fixpoint_deep_jit = jax.jit(
 # ledger's host pre-route when a batch carries balancing flags; its
 # fallbacks (closing flags, deep cascades, balancing in-window defs) go
 # to the exact host path via the same shallow->deep ladder as limits.
-create_transfers_balancing_jit = jax.jit(
-    functools.partial(create_transfers_fast,
-                      limit_rounds=LIMIT_FIXPOINT_ROUNDS,
-                      balancing_mode=True),
-    donate_argnums=0)
-create_transfers_balancing_deep_jit = jax.jit(
-    functools.partial(create_transfers_fast,
-                      limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP,
-                      balancing_mode=True),
-    donate_argnums=0)
+create_transfers_balancing_jit = _tier_jit(
+    "create_transfers_balancing", limit_rounds=LIMIT_FIXPOINT_ROUNDS,
+    balancing_mode=True)
+create_transfers_balancing_deep_jit = _tier_jit(
+    "create_transfers_balancing_deep",
+    limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP, balancing_mode=True)
 
 # Tiny on-device accumulator for back-to-back batch drivers: summing
 # created_counts on device keeps the dispatch loop free of per-batch host
@@ -2481,9 +2483,9 @@ create_transfers_chain_jit = jax.jit(
     _create_transfers_chain, donate_argnums=0)
 # Pipelined-serving variant: the event ring resets once per chain
 # dispatch (see ring_reset above).
-create_transfers_chain_ring_jit = jax.jit(
-    functools.partial(_create_transfers_chain, ring_reset=True),
-    donate_argnums=0)
+create_transfers_chain_ring_jit = _tier_jit(
+    "_create_transfers_chain_ring", _create_transfers_chain,
+    ring_reset=True)
 
 
 def _create_transfers_chain_unrolled(state, ev_stack, seg_stack,
@@ -2752,6 +2754,5 @@ def create_accounts_fast(state, ev, timestamp, n, imported_mode=False):
 
 
 create_accounts_fast_jit = jax.jit(create_accounts_fast, donate_argnums=0)
-create_accounts_imported_jit = jax.jit(
-    functools.partial(create_accounts_fast, imported_mode=True),
-    donate_argnums=0)
+create_accounts_imported_jit = _tier_jit(
+    "create_accounts_imported", create_accounts_fast, imported_mode=True)

@@ -1033,10 +1033,13 @@ class DeviceLedger:
         # behind device execution.
         self.staging_stats = {"windows": 0, "staged": 0, "misses": 0,
                               "stall_ms": 0.0, "work_ms": 0.0}
-        # Observability hook: the ServingSupervisor installs its tracer
-        # here (window_stage spans + the host-stall gauge); standalone
-        # ledgers keep the null tracer.
+        # Observability hook: the replica's state machine (and the
+        # ServingSupervisor) install their tracer here — the spans under
+        # commit_execute, window_stage and the host-stall gauge;
+        # standalone ledgers keep the null tracer. trace_op is the op
+        # the state machine is executing (the spans' `op` tag).
         self.tracer = NullTracer()
+        self.trace_op = 0
         # Partitioned-mesh attach (attach_partitioned): when set, commit
         # windows dispatch through the PartitionedRouter's fused
         # shard_map+scan route against the sharded state instead of the
@@ -1086,23 +1089,20 @@ class DeviceLedger:
             results = self.mirror.create_accounts(accounts, timestamp)
             self._push_dirty()
             return results
-        ev = pad_account_events(accounts_to_arrays(accounts))
+        with self.tracer.span(Event.execute_stage, op=self.trace_op):
+            ev = pad_account_events(accounts_to_arrays(accounts))
         n = len(accounts)
+        tier = create_accounts_fast_jit
         if (np.asarray(ev["flags"])
                 & np.uint32(_F_A_IMPORTED_HOST())).any():
             from .fast_kernels import create_accounts_imported_jit
 
-            new_state, out = create_accounts_imported_jit(
-                self.state, ev, np.uint64(timestamp), np.int32(n))
-        else:
-            new_state, out = create_accounts_fast_jit(
-                self.state, ev, np.uint64(timestamp), np.int32(n))
-        if bool(out["fallback"]):
-            # new_state is the old state (all selects masked); it was donated,
-            # so adopt it before syncing down.
-            self.state = new_state
+            tier = create_accounts_imported_jit
+        # On fallback the adopted state is the old one (all selects
+        # masked); it was donated, so adopt it before syncing down.
+        out, fallback = self._dispatch(tier, ev, timestamp, n, "fallback")
+        if fallback:
             return self._fallback_accounts(accounts, timestamp)
-        self.state = new_state
         self.fast_batches += 1
         self._probe_succeeded()
         st = np.asarray(out["r_status"][:n])
@@ -1925,16 +1925,30 @@ class DeviceLedger:
         deep = (create_transfers_balancing_deep_jit if balancing
                 else create_transfers_imported_fixpoint_deep_jit
                 if imported else create_transfers_fixpoint_deep_jit)
-        new_state, deep_out = deep(
-            self.state, evp, np.uint64(timestamp), np.int32(n))
-        self.state = new_state
+        deep_out, fallback = self._dispatch(deep, evp, timestamp, n,
+                                            "fallback")
         self.deep_fixpoint_batches += 1
         self.escalations += 1
         if balancing:
             self._bal_deep_first = self.DEEP_PROBE_INTERVAL
         elif not imported:
             self._deep_first = self.DEEP_PROBE_INTERVAL
-        return bool(deep_out["fallback"]), deep_out
+        return fallback, deep_out
+
+    def _dispatch(self, tier_jit, evp, timestamp, n, *flags):
+        """One create kernel dispatch of `tier_jit`: launch it, adopt
+        the state it returns (the old one was donated), and block on the
+        scalar `flags` of its output. Returns (out, *flag bools). One
+        execute_dispatch span per call — launch, device time and the
+        sync — so an escalation shows as a second span."""
+        import jax
+
+        with self.tracer.span(Event.execute_dispatch, op=self.trace_op,
+                              tier=tier_jit.__name__):
+            self.state, out = tier_jit(
+                self.state, evp, np.uint64(timestamp), np.int32(n))
+            got = jax.device_get(tuple(out[f] for f in flags))
+        return (out, *(bool(x) for x in got))
 
     def warm_kernels(self, n_pad: int = N_PAD,
                      balancing: bool = True) -> None:
@@ -1975,8 +1989,6 @@ class DeviceLedger:
                                 transfers=None, raw=False):
         """ev: unpadded SoA dict (the zero-host-cost entry point)."""
         self.resolve_windows()  # pipeline ordering
-        import jax
-
         from .fast_kernels import (
             create_transfers_fast_jit,
             create_transfers_fixpoint_jit,
@@ -1991,10 +2003,12 @@ class DeviceLedger:
             self._push_dirty()
             return results
         n = len(ev["id_lo"])
+        span, at = self.tracer.span, self.trace_op
         # Small batches compile + run at the smallest padded shape that
         # fits (jit caches one executable per bucket): a 1k-event batch
         # costs 1k-row kernel work, not BATCH_MAX-row work.
-        evp = pad_transfer_events(ev, n_pad=_pad_bucket(n))
+        with span(Event.execute_stage, op=at):
+            evp = pad_transfer_events(ev, n_pad=_pad_bucket(n))
         if _has_imported([ev]):
             # Imported batches run their own tier (native imported rules
             # + the in-batch maxima chain). Closing flags, voids of
@@ -2006,19 +2020,16 @@ class DeviceLedger:
                 create_transfers_imported_jit,
             )
 
-            new_state, out = create_transfers_imported_jit(
-                self.state, evp, np.uint64(timestamp), np.int32(n))
-            self.state = new_state
-            fallback, limit_only = (bool(x) for x in jax.device_get(
-                (out["fallback"], out["limit_only"])))
+            out, fallback, limit_only = self._dispatch(
+                create_transfers_imported_jit, evp, timestamp, n,
+                "fallback", "limit_only")
             if fallback and limit_only:
                 # Resolvable on device (state was donated but unchanged
                 # on fallback — evp is intact).
                 self.escalations += 1
-                new_state, out = create_transfers_imported_fixpoint_jit(
-                    self.state, evp, np.uint64(timestamp), np.int32(n))
-                self.state = new_state
-                fallback = bool(jax.device_get(out["fallback"]))
+                out, fallback = self._dispatch(
+                    create_transfers_imported_fixpoint_jit, evp,
+                    timestamp, n, "fallback")
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
                         evp, timestamp, n, imported=True)
@@ -2037,16 +2048,14 @@ class DeviceLedger:
 
             if self._bal_deep_first > 0:
                 self._bal_deep_first -= 1
-                new_state, out = create_transfers_balancing_deep_jit(
-                    self.state, evp, np.uint64(timestamp), np.int32(n))
-                self.state = new_state
+                out, fallback = self._dispatch(
+                    create_transfers_balancing_deep_jit, evp, timestamp,
+                    n, "fallback")
                 self.deep_fixpoint_batches += 1
-                fallback = bool(jax.device_get(out["fallback"]))
             else:
-                new_state, out = create_transfers_balancing_jit(
-                    self.state, evp, np.uint64(timestamp), np.int32(n))
-                self.state = new_state
-                fallback = bool(jax.device_get(out["fallback"]))
+                out, fallback = self._dispatch(
+                    create_transfers_balancing_jit, evp, timestamp, n,
+                    "fallback")
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
                         evp, timestamp, n, balancing=True)
@@ -2064,18 +2073,14 @@ class DeviceLedger:
 
             if self._deep_first > 0:
                 self._deep_first -= 1
-                new_state, out = create_transfers_fixpoint_deep_jit(
-                    self.state, evp, np.uint64(timestamp), np.int32(n))
-                self.state = new_state
+                out, fallback, limit_hit = self._dispatch(
+                    create_transfers_fixpoint_deep_jit, evp, timestamp,
+                    n, "fallback", "limit_hit")
                 self.deep_fixpoint_batches += 1
-                fallback, limit_hit = (bool(x) for x in jax.device_get(
-                    (out["fallback"], out["limit_hit"])))
             else:
-                new_state, out = create_transfers_fixpoint_jit(
-                    self.state, evp, np.uint64(timestamp), np.int32(n))
-                self.state = new_state
-                fallback, limit_hit = (bool(x) for x in jax.device_get(
-                    (out["fallback"], out["limit_hit"])))
+                out, fallback, limit_hit = self._dispatch(
+                    create_transfers_fixpoint_jit, evp, timestamp, n,
+                    "fallback", "limit_hit")
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
                         evp, timestamp, n)
@@ -2084,21 +2089,18 @@ class DeviceLedger:
                 if not limit_hit:
                     self._fixpoint_first = False
         else:
-            new_state, out = create_transfers_fast_jit(
-                self.state, evp, np.uint64(timestamp), np.int32(n))
-            self.state = new_state
-            fallback, limit_only = (bool(x) for x in jax.device_get(
-                (out["fallback"], out["limit_only"])))
+            out, fallback, limit_only = self._dispatch(
+                create_transfers_fast_jit, evp, timestamp, n,
+                "fallback", "limit_only")
             if fallback and limit_only:
                 # The only obstacle was the balance-limit headroom proof,
                 # a collision, a closing flag or a void of a closing
                 # pending: all resolve natively on the fixpoint variant
                 # (only the state was donated — evp is intact).
                 self.escalations += 1
-                new_state, out = create_transfers_fixpoint_jit(
-                    self.state, evp, np.uint64(timestamp), np.int32(n))
-                self.state = new_state
-                fallback = bool(out["fallback"])
+                out, fallback = self._dispatch(
+                    create_transfers_fixpoint_jit, evp, timestamp, n,
+                    "fallback")
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
                         evp, timestamp, n)
@@ -2112,10 +2114,12 @@ class DeviceLedger:
             return self._fallback_transfers(transfers, timestamp)
         self.fast_batches += 1
         self._probe_succeeded()
-        st = np.asarray(out["r_status"][:n])
-        ts = np.asarray(out["r_ts"][:n])
+        with span(Event.execute_encode, op=at):
+            st = np.asarray(out["r_status"][:n])
+            ts = np.asarray(out["r_ts"][:n])
         if self._wt:
-            self._capture_fast_delta_transfers(ev, st)
+            with span(Event.execute_delta_fetch, op=at):
+                self._capture_fast_delta_transfers(ev, st)
         if raw:
             return st, ts
         ts_l = ts.tolist()
@@ -3041,7 +3045,20 @@ class DeviceLedger:
 
     def fallback_stats(self) -> dict:
         """Host-visible routing/fallback counters (bench diagnostics +
-        devhub): 'zero host fallbacks' is a measured invariant."""
+        devhub): 'zero host fallbacks' is a measured invariant.
+
+        Which counter counts what (they overlap, so their sum counts
+        nothing): `fast_batches` is the count of create REQUESTS
+        (accounts and transfers) the device judged, whatever tier
+        answered — one per batch that did not fall back, one per prepare
+        of a window; `fixpoint_batches` is how many of the per-batch
+        ones a fixpoint tier answered, shallow or deep;
+        `deep_fixpoint_batches` counts dispatches of a deep tier (per
+        prepare for a deep window) and `escalations` the extra
+        dispatches a batch cost beyond its first. A batch that climbs
+        plain -> fixpoint -> deep reads fast 1, fixpoint 1, deep 1,
+        escalations 2: one request, three dispatches. `host_fallbacks`
+        is the count of requests the host answered instead."""
         return {
             "host_fallbacks": self.fallbacks,
             "window_fallbacks": self.window_fallbacks,

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import dataclasses
 import struct
-import time as _time
 from typing import Optional
 
 from . import multi_batch
@@ -28,6 +27,7 @@ from .constants import (
     U128_MAX,
 )
 from .oracle.state_machine import AccountEventRecord, StateMachineOracle
+from .trace import Event, NullTracer
 from .types import (
     Account,
     AccountBalance,
@@ -125,6 +125,11 @@ class StateMachine:
         self._a_cap = a_cap
         self._t_cap = t_cap
         self._state = StateMachineOracle()
+        # The replica installs its tracer here (`tracer` setter); the
+        # op number it is executing rides beside it, for the `op` tag
+        # of the spans under commit_execute.
+        self._tracer = NullTracer()
+        self.trace_op = 0
         self.led = None
         if engine == "device":
             from .ops.ledger import DeviceLedger
@@ -152,14 +157,24 @@ class StateMachine:
         self._fq = None
         self._acct_cache = None
         self._xfer_cache = None
-        # Per-operation commit timing table (op name -> count/total/max).
-        self.metrics: dict[str, dict] = {}
         # Pipelined commit windows awaiting resolution (submit_commit_window).
         self._pending_windows: list = []
         # stage_commit_window's decode cache: the staged window's exact
         # SoA dicts, reused by the matching submit_commit_window so the
         # ledger's staged pack can be consumed by identity.
         self._staged_window = None
+
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        """Install a tracer here and in the device ledger (the `state`
+        setter hands it to each ledger it rebuilds)."""
+        self._tracer = tracer
+        if self.led is not None:
+            self.led.tracer = tracer
 
     def fallback_stats(self) -> dict:
         """Device-engine routing/fallback counters (per-cause host
@@ -251,6 +266,7 @@ class StateMachine:
 
             self.led = DeviceLedger(a_cap=self._a_cap, t_cap=self._t_cap,
                                     write_through=new_state)
+            self.led.tracer = self._tracer
         # Derived query indexes must be rebuilt from scratch.
         self._xfer_ts = []
         for idx in self._xfer_by.values():
@@ -614,21 +630,44 @@ class StateMachine:
         """Execute one operation body (reference StateMachine.commit,
         src/state_machine.zig:2564-2669): decode (multi-batch aware),
         dispatch, encode results. Raises ProtocolError on malformed input
-        (callers validate first via input_valid). Per-op timings aggregate
-        into `metrics` (reference: the commit Metrics table,
+        (callers validate first via input_valid). Per-operation timing is
+        the replica's `commit_execute` span, tagged `operation`
+        (reference: the commit Metrics table,
         src/state_machine.zig:729-780, :2637-2667)."""
-        # Metrics-only timing, never committed state.
-        t0 = _time.perf_counter_ns()  # jaxhound: allow(wall_clock)
-        try:
-            return self._commit_timed(op, body, timestamp)
-        finally:
-            m = self.metrics.setdefault(
-                op.name, {"count": 0, "total_ns": 0, "max_ns": 0})
-            dt = _time.perf_counter_ns() - t0  # jaxhound: allow(wall_clock)
-            m["count"] += 1
-            m["total_ns"] += dt
-            if dt > m["max_ns"]:
-                m["max_ns"] = dt
+        span, at = self._tracer.span, self.trace_op
+        if self.led is not None:
+            self.led.trace_op = at
+        with span(Event.execute_decode, op=at):
+            if not self.input_valid(op, body):
+                raise ProtocolError(f"malformed body for {op!r}")
+            spec = OPERATION_SPECS[op]
+            if op.is_multi_batch():
+                batches = multi_batch.decode(body, spec.event_size)
+        if op == Operation.pulse:
+            if self.engine == "device":
+                self.led.expire_pending_transfers(timestamp)
+            else:
+                self.state.expire_pending_transfers(timestamp)
+            return b""
+        if op.is_multi_batch():
+            results = []
+            if _base_operation(op) in (Operation.create_accounts,
+                                       Operation.create_transfers):
+                # Each inner batch consumes one timestamp per event; the
+                # prepare timestamp is the LAST event's
+                # (reference: execute_multi_batch advances the execute
+                # timestamp per batch, src/state_machine.zig:2720-2756).
+                counts = [len(b) // spec.event_size for b in batches]
+                running = timestamp - sum(counts)
+                for b, n in zip(batches, counts):
+                    running += n
+                    results.append(self._commit_one(op, spec, b, running))
+            else:
+                results = [self._commit_one(op, spec, b, timestamp)
+                           for b in batches]
+            with span(Event.execute_encode, op=at):
+                return multi_batch.encode(results, spec.result_size)
+        return self._commit_one(op, spec, body, timestamp)
 
     def commit_window(self, op: Operation, bodies: list[bytes],
                       timestamps: list[int],
@@ -662,22 +701,16 @@ class StateMachine:
                     for b, ts in zip(bodies, timestamps)]
 
         spec = OPERATION_SPECS[op]
-        # Metrics-only timing, never committed state.
-        t0 = _time.perf_counter_ns()  # jaxhound: allow(wall_clock)
-        evs, tss, shape = self._flatten_window(op, bodies, timestamps)
+        span, at = self._tracer.span, self.trace_op
+        with span(Event.execute_decode, op=at):
+            evs, tss, shape = self._flatten_window(op, bodies, timestamps)
         outs = self.led.create_transfers_window(
             evs, tss, all_or_nothing=all_or_nothing)
         if outs is None:
             assert all_or_nothing
             return None
-        replies = self._encode_window_replies(spec, outs, shape)
-        m = self.metrics.setdefault(
-            op.name, {"count": 0, "total_ns": 0, "max_ns": 0})
-        dt = _time.perf_counter_ns() - t0  # jaxhound: allow(wall_clock)
-        m["count"] += len(bodies)
-        m["total_ns"] += dt
-        if dt > m["max_ns"]:
-            m["max_ns"] = dt
+        with span(Event.execute_encode, op=at):
+            replies = self._encode_window_replies(spec, outs, shape)
         if all_or_nothing:
             return replies, shape
         return replies
@@ -789,37 +822,6 @@ class StateMachine:
             done.append(rec)
         return done
 
-    def _commit_timed(self, op: Operation, body: bytes,
-                      timestamp: int) -> bytes:
-        if not self.input_valid(op, body):
-            raise ProtocolError(f"malformed body for {op!r}")
-        spec = OPERATION_SPECS[op]
-        if op == Operation.pulse:
-            if self.engine == "device":
-                self.led.expire_pending_transfers(timestamp)
-            else:
-                self.state.expire_pending_transfers(timestamp)
-            return b""
-        if op.is_multi_batch():
-            batches = multi_batch.decode(body, spec.event_size)
-            results = []
-            if _base_operation(op) in (Operation.create_accounts,
-                                       Operation.create_transfers):
-                # Each inner batch consumes one timestamp per event; the
-                # prepare timestamp is the LAST event's
-                # (reference: execute_multi_batch advances the execute
-                # timestamp per batch, src/state_machine.zig:2720-2756).
-                counts = [len(b) // spec.event_size for b in batches]
-                running = timestamp - sum(counts)
-                for b, n in zip(batches, counts):
-                    running += n
-                    results.append(self._commit_one(op, spec, b, running))
-            else:
-                results = [self._commit_one(op, spec, b, timestamp)
-                           for b in batches]
-            return multi_batch.encode(results, spec.result_size)
-        return self._commit_one(op, spec, body, timestamp)
-
     def _commit_one(self, op: Operation, spec: OperationSpec, body: bytes,
                     timestamp: int) -> bytes:
         O = Operation
@@ -830,9 +832,12 @@ class StateMachine:
             # part, src/state_machine.zig:2564-2669).
             from .ops.batch import transfers_soa_from_bytes
 
-            ev = transfers_soa_from_bytes(body)
+            span, at = self._tracer.span, self.trace_op
+            with span(Event.execute_decode, op=at):
+                ev = transfers_soa_from_bytes(body)
             st, ts = self.led.create_transfers_soa(ev, timestamp)
-            return _encode_results_soa(st, ts, spec)
+            with span(Event.execute_encode, op=at):
+                return _encode_results_soa(st, ts, spec)
         events = [body[i:i + spec.event_size]
                   for i in range(0, len(body), spec.event_size)]
         if base == O.create_accounts:

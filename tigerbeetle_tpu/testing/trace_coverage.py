@@ -654,6 +654,65 @@ def _scenario_causal_trace(col: _Collector) -> None:
     assert kept == [tid], kept
 
 
+def _scenario_commit_stage_children(col: _Collector) -> None:
+    """One device-engine replica driven past a checkpoint: every child
+    span of commit_execute / commit_compact / commit_checkpoint and the
+    durable row counter; then the serving loop over a bus that wakes it
+    for one long turn (loop_busy), under the collector hook (host_gc)."""
+    import gc
+    import time
+
+    from .. import multi_batch
+    from ..main import serve
+    from ..state_machine import StateMachine
+    from ..trace import install_gc_spans
+    from ..types import Account, Operation, Transfer
+    from .cluster import Cluster
+
+    tracer = col.make(0)
+    cluster = Cluster(
+        seed=17, replica_count=1, tracer_factory=lambda i: tracer,
+        state_machine_factory=lambda: StateMachine(
+            engine="device", a_cap=1 << 9, t_cap=1 << 12))
+    client = cluster.client(7)
+
+    def drive(op, body):
+        client.request(op, body)
+        assert cluster.run(4000, until=lambda: client.idle), \
+            cluster.debug_status()
+
+    drive(Operation.create_accounts, multi_batch.encode(
+        [b"".join(Account(id=i, ledger=1, code=1).pack()
+                  for i in (1, 2))], 128))
+    replica = cluster.replicas[0]
+    for k in range(replica.options.checkpoint_interval):
+        drive(Operation.create_transfers, multi_batch.encode(
+            [Transfer(id=300 + k, debit_account_id=1, credit_account_id=2,
+                      amount=1, ledger=1, code=1).pack()], 128))
+    assert replica.durable.rows_put["checkpoints"] >= 1
+
+    class _Bus:
+        woke_ns = 0
+
+        def poll(self, timeout):
+            self.woke_ns = tracer.now_ns()
+
+    class _Busy:
+        commit_min = replica.commit_min
+
+        def tick(self):
+            time.sleep(0.002)
+            stop.append(1)
+
+    stop: list = []
+    remove = install_gc_spans(tracer)
+    try:
+        serve(_Bus(), _Busy(), tracer, stop)
+        gc.collect()
+    finally:
+        remove()
+
+
 SCENARIOS = (
     _scenario_rebuild,
     _scenario_view_change,
@@ -670,6 +729,7 @@ SCENARIOS = (
     _scenario_slo,
     _scenario_observatory,
     _scenario_causal_trace,
+    _scenario_commit_stage_children,
 )
 
 

@@ -9,7 +9,8 @@ Layout mirrors the reference:
   on catalog events the smokes never emit.
 - `tracer.py` — NullTracer (production default, zero overhead) and the
   recording Tracer (bounded ring with self-describing eviction,
-  wall-clock-anchored timestamps, per-event timing aggregates).
+  wall-clock-anchored timestamps, per-event timing aggregates), and
+  `install_gc_spans` (one `host_gc` span per collector pause).
 - `statsd.py` — DogStatsD UDP emission + interval-flushed aggregates
   (gauges reset after emit, like the reference) with histogram-derived
   p50/p95/p99/p999 `|ms` timing lines per series.
@@ -41,8 +42,11 @@ Layout mirrors the reference:
   with runbook anchors, `alert:<rule>` tail retention, and page-
   severity flight-recorder freezes.
 
-The tracer is injected at construction into the replica, journal, grid
-scrubber, message bus, serving supervisor, and sharded router; see
+The tracer is injected at construction into the replica (which hands it
+on to its durable state, its state machine and the device ledger),
+journal, grid scrubber, message bus, serving supervisor, and sharded
+router; `trace/span_tree.py` lays the commit stages' child spans over
+their parents; see
 docs/operating/monitoring.md for the operator-facing catalog.
 """
 
@@ -64,7 +68,7 @@ from .profiler import (DispatchProfiler, measured_dispatch_us,
 from .slo import (Objective, burn_rates, evaluate, evaluate_bench_record,
                   load_objectives)
 from .statsd import StatsD, TimingAggregates
-from .tracer import NullTracer, Tracer
+from .tracer import NullTracer, Tracer, install_gc_spans
 
 __all__ = [
     "CATALOG", "TID_BASE", "Event", "EventKind", "EventSpec", "lookup",
@@ -76,7 +80,7 @@ __all__ = [
     "merge_trace_files", "merge_traces", "span_quantile",
     "Objective", "burn_rates", "evaluate", "evaluate_bench_record",
     "load_objectives", "StatsD", "TimingAggregates",
-    "NullTracer", "Tracer",
+    "NullTracer", "Tracer", "install_gc_spans",
     "Alert", "AlertEngine", "AlertRule", "load_alert_rules",
     "MemWatch", "check_budget", "device_memory_stats", "load_budget",
     "measure_ledger", "pytree_bytes", "static_ledger",

@@ -281,6 +281,82 @@ class Event(enum.Enum):
         "firing freezes a flight-recorder artifact and tail-keeps the "
         "breaching traces under reason alert:<rule>", "rule", "severity")
 
+    # -------------------------------------- inside the commit stages
+    # The children of commit_execute, commit_compact and
+    # commit_checkpoint, one event per phase and each tagged with the
+    # parent's `op`: a reader that keys spans by name alone can still
+    # split a parent, and containment in time says which parent a child
+    # belongs to. Opened where the work happens (state_machine.py,
+    # ops/ledger.py, vsr/durable.py, vsr/replica.py) through the
+    # replica's own tracer; none sits inside a per-row or per-tree loop.
+    execute_decode = _span(
+        "wire validation + multi-batch decode of one prepare's body and "
+        "the bytes -> SoA column decode of each inner batch", "op")
+    execute_stage = _span(
+        "host staging of one batch: pad the SoA columns to the kernel's "
+        "bucket (the host -> device transfer rides the dispatch)", "op")
+    execute_dispatch = _span(
+        "one create kernel dispatch (a create_transfers tier, or "
+        "create_accounts): jit call until the "
+        "device_get of its fallback flags returns (launch + device + "
+        "sync); an escalation shows as a second span. `tier` is the "
+        "jitted entry's name, the device trace's module less `jit_`",
+        "op", "tier", hist_tags=("tier",))
+    execute_delta_fetch = _span(
+        "write-through capture of one batch's device delta: the gather "
+        "dispatch and the device -> host copy it starts (the wait for "
+        "the bytes falls under flush_columns)", "op")
+    execute_encode = _span(
+        "status/timestamp arrays to host and the wire encode of one "
+        "prepare's results", "op")
+    flush_columns = _span(
+        "durable flush of an op's device delta columns: transfer rows + "
+        "index keys, then events/accounts/pending (vectorized path)",
+        "op")
+    flush_objects = _span(
+        "durable flush's object loops over the mirror's dirty accounts, "
+        "transfers, pending, expiry, orphaned and unpersisted events",
+        "op")
+    flush_cache_upsert = _span(
+        "object-cache coherence after a flush (drop or refresh the "
+        "flushed ids)", "op")
+    compact_beat = _span(
+        "one compaction beat over every tree of the forest", "op")
+    checkpoint_wal_barrier = _span(
+        "checkpoint: wait for every in-flight WAL append (and, in "
+        "extra-check mode, walk the committed suffix's hash chain)",
+        "op")
+    checkpoint_mirror_drain = _span(
+        "checkpoint: session table pack + the state read that drains "
+        "the deferred device mirror into host objects", "op")
+    checkpoint_flush = _span(
+        "checkpoint: the durable flush inside DurableState.checkpoint "
+        "(holds its own flush_columns / flush_objects)", "op")
+    checkpoint_forest = _span(
+        "checkpoint: forest.checkpoint() — freeze memtables, write "
+        "manifests and the free set", "op")
+    checkpoint_superblock = _span(
+        "checkpoint: snapshot write, superblock store, and the prune of "
+        "the host event tail", "op")
+    durable_rows_put = _counter(
+        "transfer rows put into the trees by the durable flush, by path: "
+        "column (device delta columns), object (mirror objects, per-op "
+        "flush), object_at_checkpoint (mirror objects, during a "
+        "checkpoint's flush)", "path")
+
+    # ------------------------------------------------ serving thread
+    # Stamped with now_ns() in the same wall-anchored domain as every
+    # span, so an idle gap or a stalled request lays over them with no
+    # second offset.
+    loop_busy = _span(
+        "one busy turn of the serving loop: from the bus's select "
+        "returning to the next poll call (message delivery, tick, "
+        "commit); only turns of 1 ms or more are recorded")
+    host_gc = _span(
+        "one collection of Python's cyclic garbage collector "
+        "(gc.callbacks start -> stop)", "generation",
+        hist_tags=("generation",))
+
     # ------------------------------------------------------ tracer internal
     trace_dropped_events = _counter(
         "span ring evictions (the trace is truncated at its start)")

@@ -22,6 +22,7 @@ post-hoc clock guessing.
 
 from __future__ import annotations
 
+import gc
 import json
 import time as _time
 from typing import Optional
@@ -438,6 +439,27 @@ class Tracer(NullTracer):
         """Chrome/Perfetto-loadable trace (reference: --trace=file)."""
         with open(path, "w") as f:
             json.dump(self.chrome_dict(), f)
+
+
+def install_gc_spans(tracer: Tracer):
+    """Record one `host_gc` span per collection of Python's cyclic
+    collector, tagged with its generation, through `gc.callbacks`.
+    Stamped with the tracer's own now_ns(), so the pauses lie on the
+    same clock as every other span. Installed only where a recording
+    tracer is built (`start --trace`): with no hook a collection costs
+    what it always did. Returns the function that removes the hook."""
+    started = [0]
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            started[0] = tracer.now_ns()
+        else:
+            tracer.record_span(Event.host_gc, started[0],
+                               tracer.now_ns() - started[0],
+                               generation=info["generation"])
+
+    gc.callbacks.append(on_gc)
+    return lambda: gc.callbacks.remove(on_gc)
 
 
 class _Span:

@@ -28,6 +28,7 @@ from ..lsm.forest import Forest
 from ..lsm.grid import Grid
 from ..lsm.scan import composite_key
 from ..oracle.state_machine import AccountEventRecord, StateMachineOracle
+from ..trace import Event, NullTracer
 from ..types import (Account, AccountFlags, Transfer, TransferFlags,
                      TransferPendingStatus)
 from .storage import Storage
@@ -279,7 +280,14 @@ class _ZoneDevice:
 class DurableState:
     """Write-behind LSM persistence for one replica's state machine."""
 
-    def __init__(self, storage: Storage):
+    def __init__(self, storage: Storage, tracer=None):
+        self.tracer = tracer if tracer is not None else NullTracer()
+        # Transfer rows put into the trees, by path (the `path` tag of
+        # durable_rows_put), and the checkpoints taken: plain ints, kept
+        # whether or not a tracer records. `object_at_checkpoint` rows
+        # are those a checkpoint's flush puts through the object path.
+        self.rows_put = {"column": 0, "object": 0,
+                         "object_at_checkpoint": 0, "checkpoints": 0}
         layout = storage.layout
         self.grid = Grid(
             _ZoneDevice(storage, "grid"),
@@ -298,12 +306,17 @@ class DurableState:
 
     # ------------------------------------------------------------- writes
 
-    def flush(self, state: StateMachineOracle, flush_columns=None):
+    def flush(self, state: StateMachineOracle, flush_columns=None,
+              op: int = 0, at_checkpoint: bool = False):
         """Write every object mutated since the last flush into the trees
         (sorted key order: byte-deterministic across replicas). Returns
         (flushed account ids, flushed transfer ids) so the serving layer
         can write its bounded object caches through (state_machine.py
         cache_upsert).
+
+        `op` tags the two spans (flush_columns where there are chunks,
+        flush_objects always); `at_checkpoint` says the caller is
+        checkpoint(), for the row counters.
 
         flush_columns: drained device-delta transfer columns
         (DeviceLedger.take_flush_columns). Transfers covered by them are
@@ -311,19 +324,41 @@ class DurableState:
         in numpy passes instead of per-object int.to_bytes — and skipped
         by the object loop. Same puts, same bytes; memtable freeze sorts,
         so put order cannot affect the on-grid result."""
-        trees = self.forest.trees
         vector_tids: list = []
         vector_aids: list = []
         if flush_columns:
-            # Contract: the column path is only valid against a QUIESCENT
-            # mirror — interleaved mirror writes (hard-regime handoffs,
-            # account creations, expiries) carry ordering the two paths
-            # cannot merge; the caller must drain and flush the object
-            # path instead (vsr/replica.py does exactly that).
-            assert mirror_quiescent(state, self.events_persisted), \
-                "column flush with a dirty/unpersisted mirror: drain first"
+            with self.tracer.span(Event.flush_columns, op=op):
+                self._flush_columns(state, flush_columns,
+                                    vector_tids, vector_aids)
+            self._count_rows("column", len(vector_tids))
+        with self.tracer.span(Event.flush_objects, op=op):
+            flushed_accounts, flushed_transfers = self._flush_objects(
+                state, vector_tids)
+        self._count_rows(
+            "object_at_checkpoint" if at_checkpoint else "object",
+            len(flushed_transfers))
+        return (flushed_accounts + vector_aids,
+                flushed_transfers + vector_tids)
+
+    def _count_rows(self, path: str, n: int) -> None:
+        if n:
+            self.rows_put[path] += n
+            self.tracer.count(Event.durable_rows_put, n, path=path)
+
+    def _flush_columns(self, state, flush_columns, vector_tids: list,
+                       vector_aids: list) -> None:
+        """The vectorized path over an op's chunks; appends the flushed
+        transfer and account ids to the two lists."""
+        trees = self.forest.trees
+        # Contract: the column path is only valid against a QUIESCENT
+        # mirror — interleaved mirror writes (hard-regime handoffs,
+        # account creations, expiries) carry ordering the two paths
+        # cannot merge; the caller must drain and flush the object
+        # path instead (vsr/replica.py does exactly that).
+        assert mirror_quiescent(state, self.events_persisted), \
+            "column flush with a dirty/unpersisted mirror: drain first"
         for (t_cols, e_cols, der_cols, n_new, abs_start,
-             orphan_ids) in flush_columns or ():
+             orphan_ids) in flush_columns:
             # Orphan puts are idempotent: flushed even for zero-create
             # chunks (transient failures poison ids without creating).
             for oid in orphan_ids:
@@ -342,6 +377,12 @@ class DurableState:
             vector_aids.extend(self._flush_side_columns(
                 trees, t_cols, e_cols, der_cols, n_new))
             self.events_persisted = abs_start + n_new
+
+    def _flush_objects(self, state, vector_tids: list):
+        """The object loops: every dirty object of the mirror the column
+        path did not cover. Returns (flushed account ids, flushed
+        transfer ids)."""
+        trees = self.forest.trees
         # A dirty key absent from its dict was created then rolled back by a
         # linked-chain scope within one commit — it was never flushed, so
         # skip it (accounts/transfers/pending are never legitimately
@@ -467,8 +508,7 @@ class DurableState:
         self.events_persisted = max(
             self.events_persisted,
             state.events_base + len(state.account_events))
-        return (flushed_accounts + vector_aids,
-                flushed_transfers + vector_tids)
+        return flushed_accounts, flushed_transfers
 
     def _flush_transfer_columns(self, trees, t, n: int) -> list:
         """Vectorized transfer flush from drained device columns: value
@@ -747,22 +787,27 @@ class DurableState:
         return len(doomed)
 
     def compact_beat(self, op: int) -> None:
-        self.forest.compact_beat(op)
+        with self.tracer.span(Event.compact_beat, op=op):
+            self.forest.compact_beat(op)
 
     def checkpoint(self, state: StateMachineOracle,
-                   flush_columns=None) -> bytes:
+                   flush_columns=None, op: int = 0) -> bytes:
         """Flush + forest checkpoint; returns the root blob to persist.
         The 40 scalar bytes (key maxes, pulse, commit timestamp, event
         count) ride in the root blob itself — they are only ever read at
         restore, so they don't belong in a tree (reference analog: the
         superblock's VSRState vs the checkpoint trailer)."""
-        self.flush(state, flush_columns=flush_columns)
+        self.rows_put["checkpoints"] += 1
+        with self.tracer.span(Event.checkpoint_flush, op=op):
+            self.flush(state, flush_columns=flush_columns, op=op,
+                       at_checkpoint=True)
         meta = struct.pack(
             "<QQQQQ",
             state.accounts_key_max or 0, state.transfers_key_max or 0,
             state.pulse_next_timestamp, state.commit_timestamp,
             self.events_persisted)
-        return self.forest.checkpoint() + meta
+        with self.tracer.span(Event.checkpoint_forest, op=op):
+            return self.forest.checkpoint() + meta
 
     # ------------------------------------------------------------- recover
 

@@ -78,6 +78,8 @@ class MessageBus:
         self.by_peer: dict[tuple, _Connection] = {}
         # Pool accounting + drop counters (observable backpressure).
         self.pool_used = 0
+        # poll(): when its wait last ended, on the tracer's clock.
+        self.woke_ns = 0
         self.dropped_replica = 0
         self.dropped_client = 0
         # Regime flags: O(1) hot-path checks instead of per-message scans.
@@ -213,7 +215,12 @@ class MessageBus:
     # ------------------------------------------------------------ the loop
 
     def poll(self, timeout: float = 0.0) -> None:
-        for key, events in self.selector.select(timeout):
+        ready = self.selector.select(timeout)
+        # When the wait ended, on the tracer's clock (0 under the null
+        # tracer): the serving loop's busy turn starts here, since the
+        # messages are delivered below, inside this call.
+        self.woke_ns = self.tracer.now_ns()
+        for key, events in ready:
             if key.fileobj is self.listener:
                 self._accept()
                 continue

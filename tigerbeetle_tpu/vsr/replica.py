@@ -136,8 +136,8 @@ class Replica:
         # traffic is dropped until a matching ping clears them.
         self._config_mismatch: set[int] = set()
         self.journal = Journal(storage, tracer=self.tracer)
-        self.state_machine: StateMachine = state_machine_factory()
-        self.durable = DurableState(storage)
+        self.state_machine: StateMachine = self._new_state_machine()
+        self.durable = DurableState(storage, tracer=self.tracer)
         # Serve reads from the LSM with a bounded object cache
         # (state_machine.attach_durable; reference: groove object cache).
         self.state_machine.attach_durable(self.durable)
@@ -245,6 +245,14 @@ class Replica:
 
     # ------------------------------------------------------------ lifecycle
 
+    def _new_state_machine(self) -> StateMachine:
+        """A state machine from the factory, carrying this replica's
+        tracer down to where execute's work happens (it hands the tracer
+        on to its device ledger, and again whenever it rebuilds one)."""
+        sm = self.state_machine_factory()
+        sm.tracer = self.tracer
+        return sm
+
     @staticmethod
     def format(storage: Storage, *, cluster: int, replica_id: int,
                replica_count: int) -> None:
@@ -312,7 +320,7 @@ class Replica:
         # (reference: checkpoint trailer carries the client sessions too).
         forest_root, sessions_blob = _split_root(root)
         self.sessions.restore(sessions_blob)
-        self.state_machine = self.state_machine_factory()
+        self.state_machine = self._new_state_machine()
         self.state_machine.state = self.durable.open(forest_root,
                                                      load_events=False)
         self.state_machine.attach_durable(self.durable)
@@ -923,6 +931,7 @@ class Replica:
                     for c in wctxs:
                         if c is not None:
                             wsp.link(c.trace_id)
+                    self.state_machine.trace_op = window[0].header.op
                     out = self.state_machine.commit_window(
                         Operation(window[0].header.operation),
                         [m.body for m in window],
@@ -979,9 +988,9 @@ class Replica:
         # in-memory LSM/grid structure the divergent suffix built (the
         # copy-on-write grid still holds the checkpoint's blocks; blocks
         # written after it are unreferenced from this root).
-        self.durable = DurableState(self.storage)
+        self.durable = DurableState(self.storage, tracer=self.tracer)
         self.sessions.restore(sessions_blob)
-        self.state_machine = self.state_machine_factory()
+        self.state_machine = self._new_state_machine()
         self.state_machine.state = self.durable.open(forest_root,
                                                      load_events=False)
         self.state_machine.attach_durable(self.durable)
@@ -1076,6 +1085,7 @@ class Replica:
         with self.tracer.span(Event.commit_execute, ctx=h.trace_ctx,
                               op=h.op, operation=int(operation),
                               window=1):
+            self.state_machine.trace_op = h.op
             result = self.state_machine.commit(operation, prepare.body,
                                                h.timestamp)
         self._post_commit(prepare, result)
@@ -1117,8 +1127,9 @@ class Replica:
                     "window commit entered a dirty-mirror regime"
                 self.state_machine.state  # drains; chunks become stale
                 cols = None
-            flushed = self.durable.flush(raw, flush_columns=cols)
-            self.state_machine.cache_upsert(*flushed)
+            flushed = self.durable.flush(raw, flush_columns=cols, op=h.op)
+            with self.tracer.span(Event.flush_cache_upsert, op=h.op):
+                self.state_machine.cache_upsert(*flushed)
             self.durable.compact_beat(h.op)
         if h.client:
             # Reply fields derive from the PREPARE (its view and original
@@ -1157,59 +1168,68 @@ class Replica:
         Only manifests + the free set are serialized — table data is already
         durable in the copy-on-write grid, so the flip is incremental."""
         sb = self.superblock
-        # WAL durability barrier: every in-flight async append lands
-        # before state derived from those prepares is checkpointed.
-        # fire=False: a quorum callback firing here could advance
-        # commit_min mid-flip (and reenter _checkpoint); the callbacks
-        # run at the next tick's poll_io instead.
-        self.journal.wait_all(fire=False)
-        if constants.VERIFY:
-            # Extra-check mode: walk the committed WAL suffix's hash
-            # chain (parent linkage across held neighbors).
-            prev = None
-            for op in range(max(1, self.commit_min - 64),
-                            self.commit_min + 1):
-                m = self.journal.read_prepare(op)
-                if m is None:
-                    prev = None
-                    continue
-                if prev is not None:
-                    assert m.header.parent == prev, \
-                        f"verify: journal chain break at op {op}"
-                prev = m.header.checksum
-        sessions_blob = self.sessions.pack()
-        ckpt_state = self.state_machine.state  # drains the mirror first
-        led = self.state_machine.led
-        if led is not None:
-            # The drain above made any queued columns stale (the object
-            # path now covers everything) — pop them so they cannot leak
-            # or trip the column path's quiescent-mirror contract.
-            led.take_flush_columns()
-        root = (self.durable.checkpoint(ckpt_state)
+        span, at = self.tracer.span, self.commit_min
+        with span(Event.checkpoint_wal_barrier, op=at):
+            # WAL durability barrier: every in-flight async append lands
+            # before state derived from those prepares is checkpointed.
+            # fire=False: a quorum callback firing here could advance
+            # commit_min mid-flip (and reenter _checkpoint); the
+            # callbacks run at the next tick's poll_io instead.
+            self.journal.wait_all(fire=False)
+            if constants.VERIFY:
+                # Extra-check mode: walk the committed WAL suffix's hash
+                # chain (parent linkage across held neighbors).
+                prev = None
+                for op in range(max(1, self.commit_min - 64),
+                                self.commit_min + 1):
+                    m = self.journal.read_prepare(op)
+                    if m is None:
+                        prev = None
+                        continue
+                    if prev is not None:
+                        assert m.header.parent == prev, \
+                            f"verify: journal chain break at op {op}"
+                    prev = m.header.checksum
+        with span(Event.checkpoint_mirror_drain, op=at):
+            sessions_blob = self.sessions.pack()
+            ckpt_state = self.state_machine.state  # drains the mirror first
+            led = self.state_machine.led
+            if led is not None:
+                # The drain above made any queued columns stale (the
+                # object path now covers everything) — pop them so they
+                # cannot leak or trip the column path's quiescent-mirror
+                # contract.
+                led.take_flush_columns()
+        # checkpoint_flush + checkpoint_forest open inside.
+        root = (self.durable.checkpoint(ckpt_state, op=at)
                 + sessions_blob + struct.pack("<I", len(sessions_blob)))
         assert len(root) <= self.storage.layout.snapshot_size_max, \
             "checkpoint root exceeds slot (raise snapshot_size_max)"
-        slot = 1 - sb.snapshot_slot
-        self.storage.write(
-            "snapshot", slot * self.storage.layout.snapshot_size_max, root)
-        sb.snapshot_slot = slot
-        sb.snapshot_size = len(root)
-        sb.snapshot_checksum = checksum(root, domain=b"ckptroot")
-        sb.op_checkpoint = self.commit_min
-        sb.commit_min = self.commit_min
-        sb.commit_max = self.commit_max
-        sb.view = self.view
-        sb.log_view = self.log_view
-        sb.release = self.release
-        sb.checkpoint_id = checksum(
-            sb.checkpoint_id.to_bytes(16, "little") + root[:64], domain=b"ckpt")
-        sb.store(self.storage)
-        # Memory-bounds doctrine: everything below the checkpoint is
-        # durable in the forest's events tree — prune the host tail at
-        # this DETERMINISTIC point (same op on every replica, so states
-        # stay byte-identical; restart restores the same base).
-        self.state_machine.state.prune_account_events(
-            self.durable.events_persisted)
+        with span(Event.checkpoint_superblock, op=at):
+            slot = 1 - sb.snapshot_slot
+            self.storage.write(
+                "snapshot", slot * self.storage.layout.snapshot_size_max,
+                root)
+            sb.snapshot_slot = slot
+            sb.snapshot_size = len(root)
+            sb.snapshot_checksum = checksum(root, domain=b"ckptroot")
+            sb.op_checkpoint = self.commit_min
+            sb.commit_min = self.commit_min
+            sb.commit_max = self.commit_max
+            sb.view = self.view
+            sb.log_view = self.log_view
+            sb.release = self.release
+            sb.checkpoint_id = checksum(
+                sb.checkpoint_id.to_bytes(16, "little") + root[:64],
+                domain=b"ckpt")
+            sb.store(self.storage)
+            # Memory-bounds doctrine: everything below the checkpoint is
+            # durable in the forest's events tree — prune the host tail
+            # at this DETERMINISTIC point (same op on every replica, so
+            # states stay byte-identical; restart restores the same
+            # base).
+            self.state_machine.state.prune_account_events(
+                self.durable.events_persisted)
 
     # ---------------------------------------------------------- view change
 
@@ -1837,7 +1857,7 @@ class Replica:
         slot = 1 - sb.snapshot_slot
         self.storage.write(
             "snapshot", slot * self.storage.layout.snapshot_size_max, root)
-        durable = DurableState(self.storage)
+        durable = DurableState(self.storage, tracer=self.tracer)
         state = durable.open(forest_root, load_events=False)
         self.sessions.restore(sessions_blob)
         self.durable = durable
@@ -1846,7 +1866,7 @@ class Replica:
             self.durable.forest,
             origin_seed=self.replica_id * 2654435761, tracer=self.tracer)
         self.block_repair.clear()
-        self.state_machine = self.state_machine_factory()
+        self.state_machine = self._new_state_machine()
         self.state_machine.state = state
         self.state_machine.attach_durable(self.durable)
         sb.snapshot_slot = slot
