@@ -273,8 +273,8 @@ def cmd_start(args) -> int:
             "compiles_after_listening": compile_log.after_listening(),
             "fallback_stats": led.fallback_stats(),
             # Transfer rows the durable flush put, by path, and the
-            # checkpoints taken: whether a checkpoint re-puts rows the
-            # column path had already written.
+            # checkpoints taken: a checkpoint that put again what the
+            # column path had already written would show here.
             "durable_rows": dict(replica.durable.rows_put),
         }}), flush=True)
     return 0

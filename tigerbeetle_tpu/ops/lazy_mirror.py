@@ -432,14 +432,18 @@ class LazyTransferDict(DirtyDict):
 
     # --------------------------------------------------------- mutations
 
-    def register(self, ids: list, chunk: DeltaChunk) -> None:
+    def register(self, ids: list, chunk: DeltaChunk,
+                 dirty: bool = True) -> None:
         """Bulk-add one chunk's created transfers as lazy rows. Created
         ids are globally unique (the kernel's idempotency predicate), so
-        no key can already exist on either side."""
+        no key can already exist on either side. dirty=False: the rows
+        are in the trees already (the column flush put them), so the
+        flusher's channel does not hear of them."""
         from itertools import repeat
 
         self._lazy.update(zip(ids, repeat(chunk)))
-        self.dirty.update(ids)
+        if dirty:
+            self.dirty.update(ids)
 
     def __delitem__(self, key):
         if key in self._lazy:

@@ -56,6 +56,10 @@ from .hash_table import ORPHAN_VAL, ht_init
 N_PAD = 8192
 assert N_PAD >= BATCH_MAX
 
+# The column slots of a queued chunk that created nothing (orphan ids
+# only, or an empty prepare the window commit still counts).
+_NO_COLS = (None, None, None)
+
 # Padded-shape buckets for the transfer kernels: a batch compiles and runs
 # at the smallest bucket that fits instead of always paying BATCH_MAX-row
 # kernel work (jit keeps one cached executable per bucket actually used).
@@ -1005,6 +1009,11 @@ class DeviceLedger:
         # them every commit, so retention is bounded by one bar).
         self.retain_flush_columns = False
         self._flush_columns: list = []
+        # The flusher's watermark (attach_durable: a reader of
+        # DurableState.events_persisted): the event index up to which the
+        # column path has put rows into the trees. The drain registers a
+        # chunk under it clean. With no flusher nothing is durable.
+        self.events_persisted = lambda: 0
         # Unloaded lazy fetch columns (device buffers still alive); capped
         # so a long drain-free run cannot accumulate unbounded HBM.
         self._pending_cols: list = []
@@ -1706,26 +1715,12 @@ class DeviceLedger:
                     derc = _LazyCols(handle, "der", off, n_new)
                 ec = _LazyCols(handle, "e", off, n_new)
                 self._track_pending_cols(tc, ec, derc)
-                self._mirror_chunks.append(
-                    (tc, ec, derc, handle.t0 + off, n_new, orphan_ids,
-                     op_no))
-                if self.retain_flush_columns:
-                    self._flush_columns.append(
-                        (tc, ec, derc, n_new, self._events_seen_abs,
-                         orphan_ids))
-                self._xfer_rows_dev += n_new
-                self._events_pushed += n_new
-                self._events_seen_abs += n_new
+                self._queue_chunk((tc, ec, derc), handle.t0 + off, n_new,
+                                  orphan_ids, op_no)
                 off += n_new
             else:
-                if orphan_ids:
-                    self._mirror_chunks.append(
-                        (None, None, None, 0, 0, orphan_ids, op_no))
-                if self.retain_flush_columns and (
-                        orphan_ids or tk.all_or_nothing):
-                    self._flush_columns.append(
-                        (None, None, None, 0, self._events_seen_abs,
-                         orphan_ids))
+                self._queue_chunk(_NO_COLS, 0, 0, orphan_ids, op_no,
+                                  keep_empty=tk.all_or_nothing)
         self._clear_dirty_dev()
 
     def create_transfers_window(self, evs: list[dict],
@@ -2633,26 +2628,12 @@ class DeviceLedger:
                         derc = _LazyCols(handle, "der", off, n_new)
                     ec = _LazyCols(handle, "e", off, n_new)
                     self._track_pending_cols(tc, ec, derc)
-                    self._mirror_chunks.append(
-                        (tc, ec, derc, handle.t0 + off, n_new, orphan_ids,
-                         op_no))
-                    if self.retain_flush_columns:
-                        self._flush_columns.append(
-                            (tc, ec, derc, n_new, self._events_seen_abs,
-                             orphan_ids))
-                    self._xfer_rows_dev += n_new
-                    self._events_pushed += n_new
-                    self._events_seen_abs += n_new
+                    self._queue_chunk((tc, ec, derc), handle.t0 + off,
+                                      n_new, orphan_ids, op_no)
                     off += n_new
                 else:
-                    if orphan_ids:
-                        self._mirror_chunks.append(
-                            (None, None, None, 0, 0, orphan_ids, op_no))
-                    if self.retain_flush_columns and (orphan_ids
-                                                      or exact_chunks):
-                        self._flush_columns.append(
-                            (None, None, None, 0, self._events_seen_abs,
-                             orphan_ids))
+                    self._queue_chunk(_NO_COLS, 0, 0, orphan_ids, op_no,
+                                      keep_empty=exact_chunks)
 
         # One fetch per <= 8*N_PAD created rows (the fetch's largest
         # static bucket); a serving window of 8 prepares fits in one.
@@ -2710,13 +2691,7 @@ class DeviceLedger:
         op_no = self._op_seq
         self._op_seq += 1
         if n_new == 0:
-            if orphan_ids:
-                self._mirror_chunks.append((None, None, None, 0, 0,
-                                            orphan_ids, op_no))
-                if self.retain_flush_columns:
-                    self._flush_columns.append(
-                        (None, None, None, 0, self._events_seen_abs,
-                         orphan_ids))
+            self._queue_chunk(_NO_COLS, 0, 0, orphan_ids, op_no)
             self._clear_dirty_dev()
             return
         handle = self._delta_fetch_start(n_new)
@@ -2724,22 +2699,33 @@ class DeviceLedger:
         e = _LazyCols(handle, "e", 0, n_new)
         der = _LazyCols(handle, "der", 0, n_new)
         self._track_pending_cols(t, e, der)
-        self._mirror_chunks.append((t, e, der, handle.t0, n_new, orphan_ids,
-                                    op_no))
-        if self.retain_flush_columns:
-            # The durable flusher consumes these columns directly (the
-            # vectorized flush path) — retained at CAPTURE, so flushing
-            # does not require materializing the mirror first. abs_start
-            # is the chunk's absolute event index (the flusher's
-            # double-flush watermark); orphan ids ride along so the
-            # orphaned tree stays in lockstep without a drain.
+        self._queue_chunk((t, e, der), handle.t0, n_new, orphan_ids, op_no)
+        self._clear_dirty_dev()
+        self._maybe_recycle_ring()
+
+    def _queue_chunk(self, cols, t0: int, n_new: int, orphan_ids: list,
+                     op_no: int, keep_empty: bool = False) -> None:
+        """Queue one prepare's captured delta twice: for the mirror
+        drain, and (attach_durable) for the durable flusher's vectorized
+        path — retained at CAPTURE, so flushing does not require
+        materializing the mirror first. Both carry abs_start, the
+        chunk's absolute event index: the flusher's double-flush
+        watermark on one side, what the drain holds against that
+        watermark on the other. Orphan ids ride along so the orphaned
+        tree stays in lockstep without a drain. keep_empty queues a
+        flush chunk even for a prepare with nothing to flush (the
+        replica's window commit attributes chunks positionally)."""
+        abs_start = self._events_seen_abs
+        if n_new or orphan_ids:
+            self._mirror_chunks.append(
+                (*cols, t0, n_new, orphan_ids, op_no, abs_start))
+        if self.retain_flush_columns and (n_new or orphan_ids
+                                          or keep_empty):
             self._flush_columns.append(
-                (t, e, der, n_new, self._events_seen_abs, orphan_ids))
+                (*cols, n_new, abs_start, orphan_ids))
         self._xfer_rows_dev += n_new
         self._events_pushed += n_new
         self._events_seen_abs += n_new
-        self._clear_dirty_dev()
-        self._maybe_recycle_ring()
 
     def drain_mirror(self) -> None:
         """Materialize every queued fast-batch delta into the host mirror.
@@ -2761,11 +2747,25 @@ class DeviceLedger:
                         not c.loaded and c._handle is not None:
                     c._handle.start_copy()
                     break
-        for t, e, der, t0, n_new, orphan_ids, _op in chunks:
-            for oid in orphan_ids:
-                self.mirror.orphaned.add(oid)
+        persisted = self.events_persisted()
+        orphaned = self.mirror.orphaned
+        for t, e, der, t0, n_new, orphan_ids, _op, abs_start in chunks:
+            # A chunk whose events all lie under the flusher's watermark
+            # went into the trees through its flush-columns twin (rows,
+            # account finals, pending/expiry effects, orphan ids): the
+            # mirror takes it in clean, so no flush puts those bytes a
+            # second time. A chunk that created nothing has no event
+            # range to hold against the watermark and stays dirty, as
+            # does every chunk the column path has not reached.
+            durable = bool(n_new) and abs_start + n_new <= persisted
+            if durable:
+                set.update(orphaned, orphan_ids)
+            else:
+                for oid in orphan_ids:
+                    orphaned.add(oid)
             if n_new:
-                self._materialize_delta_transfers(t, e, der, t0, n_new)
+                self._materialize_delta_transfers(t, e, der, t0, n_new,
+                                                  durable)
         self._clear_dirty_dev()
         from .. import constants
 
@@ -2784,7 +2784,7 @@ class DeviceLedger:
             except ValueError:
                 rate = 0.0
             checked = 0
-            for t, e, der, t0, n_new, _, op_no in reversed(chunks):
+            for t, e, der, t0, n_new, _, op_no, _abs in reversed(chunks):
                 if not n_new:
                     continue
                 k = n_new if rate >= 1.0 else min(2, n_new)
@@ -2861,8 +2861,8 @@ class DeviceLedger:
         self._flush_columns = self._flush_columns[count:]
         return cols
 
-    def _materialize_delta_transfers(self, t, e, der, t0,
-                                     n_new: int) -> None:
+    def _materialize_delta_transfers(self, t, e, der, t0, n_new: int,
+                                     durable: bool = False) -> None:
         """Register one captured chunk with the host mirror COLUMNARLY
         (ops/lazy_mirror.py): created transfers become lazy rows in the
         LazyTransferDict (keys + (chunk, row) refs, no objects), account
@@ -2873,7 +2873,11 @@ class DeviceLedger:
         over just the flip subset. Values any reader can observe are
         identical to the old eager per-event drain (the oracle success
         path, oracle/state_machine.py _create_transfer :417) —
-        tests/test_lazy_mirror.py pins this differentially."""
+        tests/test_lazy_mirror.py pins this differentially.
+
+        durable: the column flush has already put this chunk into the
+        trees (drain_mirror), so nothing it registers enters a store's
+        durable channel (.dirty)."""
         from .lazy_mirror import (DeltaChunk, LazyTransferDict,
                                   apply_account_finals)
 
@@ -2888,7 +2892,7 @@ class DeviceLedger:
         transfers = sm.transfers
         assert isinstance(transfers, LazyTransferDict), \
             "device write-through mirror must hold a LazyTransferDict"
-        transfers.register(ids, chunk)
+        transfers.register(ids, chunk, dirty=not durable)
         sm.transfer_by_timestamp.update(zip(ts_list, ids))
         self._xfer_row.update(zip(ids, range(t0, t0 + n)))
         last_ts = ts_list[-1]
@@ -2896,7 +2900,9 @@ class DeviceLedger:
             sm.transfers_key_max = last_ts
         sm.commit_timestamp = last_ts
 
-        sm.accounts.dirty.update(apply_account_finals(sm, e, der))
+        changed_accounts = apply_account_finals(sm, e, der)
+        if not durable:
+            sm.accounts.dirty.update(changed_accounts)
 
         # Pending-status flips: adds (pending creates) and releases
         # (post/void) interleave with order-dependent pulse bookkeeping,
@@ -2912,6 +2918,10 @@ class DeviceLedger:
             timeout_l = np.asarray(t["timeout"])[flips].tolist()
             pending_raw = sm.pending_status
             pset = dict.__setitem__
+            expiry = sm.expiry
+            # dict's own methods pass the DirtyDict's channels by.
+            edict = dict if durable else type(expiry)
+            expiry_set, expiry_pop = edict.__setitem__, edict.pop
             touched_pending: list = []
             for j in range(len(pstat_l)):
                 pstat = pstat_l[j]
@@ -2922,7 +2932,7 @@ class DeviceLedger:
                     timeout = timeout_l[j]
                     if timeout:
                         expires_at = ts + timeout * NS_PER_S
-                        sm.expiry[ts] = expires_at
+                        expiry_set(expiry, ts, expires_at)
                         if expires_at < sm.pulse_next_timestamp:
                             sm.pulse_next_timestamp = expires_at
                 else:  # posted / voided release
@@ -2934,10 +2944,11 @@ class DeviceLedger:
                     # timeout and has not been released/expired — so the
                     # pop replaces reading p_obj.timeout (no object
                     # materialization on the flip path).
-                    ea = sm.expiry.pop(pts, None)
+                    ea = expiry_pop(expiry, pts, None)
                     if ea is not None and sm.pulse_next_timestamp == ea:
                         sm.pulse_next_timestamp = TIMESTAMP_MIN
-            pending_raw.dirty.update(touched_pending)
+            if not durable:
+                pending_raw.dirty.update(touched_pending)
 
         sm.account_events.extend_lazy(chunk, n)
 

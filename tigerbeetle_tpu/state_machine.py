@@ -210,6 +210,9 @@ class StateMachine:
             # The durable flusher consumes drained transfer columns
             # through the vectorized path (durable._flush_transfer_columns).
             self.led.retain_flush_columns = True
+            # ... and says how far it has got: the mirror drain leaves
+            # clean what lies under this watermark.
+            self.led.events_persisted = lambda: durable.events_persisted
 
     def cache_upsert(self, acct_ids, xfer_ids) -> None:
         """Cache coherence after a durable flush. Device engine: the

@@ -228,7 +228,7 @@ def poison_delta_fetch(led, f: dict) -> bool:
     (the bad-DMA model): the mirror materializes the poisoned value and
     now disagrees with BOTH the device and the oracle — the spot audit
     or the epoch's mirror audit must catch it."""
-    for t, e, der, t0, n_new, _orph, _op in reversed(led._mirror_chunks):
+    for t, e, der, t0, n_new, *_ in reversed(led._mirror_chunks):
         if not n_new or t is None:
             continue
         cols = t.load()

@@ -1195,10 +1195,15 @@ class Replica:
             ckpt_state = self.state_machine.state  # drains the mirror first
             led = self.state_machine.led
             if led is not None:
-                # The drain above made any queued columns stale (the
-                # object path now covers everything) — pop them so they
-                # cannot leak or trip the column path's quiescent-mirror
-                # contract.
+                # Every op's columns were flushed by its own
+                # _post_commit, and the drain above took those chunks
+                # into the mirror clean (they lie under
+                # durable.events_persisted), so the checkpoint's flush
+                # puts none of their rows again. Columns still queued
+                # here were never flushed: their chunks lie over the
+                # watermark, the drain marked them dirty and the object
+                # path covers them — pop them so they cannot leak or
+                # trip the column path's quiescent-mirror contract.
                 led.take_flush_columns()
         # checkpoint_flush + checkpoint_forest open inside.
         root = (self.durable.checkpoint(ckpt_state, op=at)
