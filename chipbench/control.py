@@ -96,6 +96,7 @@ def main(argv=None) -> int:
     p.add_argument("--workload", required=True)
     p.add_argument("--seeds", required=True)
     p.add_argument("--seconds", type=float, default=15.0)
+    p.add_argument("--cells", default=None)
     p.add_argument("--rehearse", action="store_true")
     args = p.parse_args(argv)
     ok = True
@@ -107,7 +108,8 @@ def main(argv=None) -> int:
 
         try:
             result = run_cell(args.workload, seed, args.seconds, False,
-                              rehearse=args.rehearse, tamper=keep)
+                              rehearse=args.rehearse, tamper=keep,
+                              cells=args.cells)
         except BenchFailure as e:
             print(f"[control] seed {seed}: FAILED: {e}", file=sys.stderr)
             return 1
@@ -117,7 +119,8 @@ def main(argv=None) -> int:
                                        for s in kept["sent"]),
                 "program": {k: v["value"]
                             for k, v in result["compared"].items()},
-                "program_correct": result["correct"]}
+                "program_correct": result["correct"],
+                "metrics": result["metrics"], "window": result["window"]}
         ok &= result["correct"]
         limited = any(
             np.frombuffer(s.request.payload, dtype=wire.ACCOUNT)["flags"].any()

@@ -18,9 +18,14 @@ is chipbench/configs/<config>.json, its traffic mix
 chipbench/traffic/<traffic>.json, each end-to-end metric
 chipbench/e2e_metrics/<name>.py and each per-layer metric
 chipbench/layer_metrics/<name>.py: adding one is adding files and
-entries, editing none. What a file asks for and the harness cannot
+entries, editing none. A configuration states the sizes its server is
+formatted and started with (`server.format_args`, `server.start_args`:
+appended to the two command lines as they stand) and the state that
+exists before the window (`transfers.preloaded_count`, sent through
+the served path in set-up). What a file asks for and the harness cannot
 serve (an open loop, three replicas, a guarantee no comparison holds
-the program to) fails the run; no key is read by nothing.
+the program to, an argument the harness itself puts on the command
+line) fails the run; no key is read by nothing.
 """
 
 from __future__ import annotations
@@ -33,6 +38,7 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 
@@ -43,31 +49,50 @@ if ROOT not in sys.path:
 import numpy as np  # noqa: E402
 
 from chipbench import check, trace_reduce, wire  # noqa: E402
-from chipbench.server import (BenchFailure, Server, format_data_file,  # noqa: E402
-                              free_port)
-from chipbench.traffic import STREAM_WARM, Deployment  # noqa: E402
+from chipbench.server import (HARNESS_ARGS, BenchFailure, Server,  # noqa: E402
+                              format_data_file, free_port)
+from chipbench.traffic import STREAM_PRELOAD, STREAM_WARM, Deployment  # noqa: E402
 from chipbench.window import Sent, StoreBudget, run_window, send  # noqa: E402
 
 HERE = os.path.join(ROOT, "chipbench")
 BOOT_TIMEOUT_S = 1000      # a cold warm-up compiles for minutes
 SETUP_REPLY_TIMEOUT_S = 900.0  # and so may a cell's first un-timed request or lookup
 PROFILE_TIMEOUT_S = 120.0  # for the profiler to start, and to write its trace
-# The rehearsal's `--small` server: TEST_LAYOUT and start's small caps.
-REHEARSAL = {"accounts": 2000, "transfers": 1 << 14}
+# The rehearsal's `--small` server: TEST_LAYOUT and start's small caps;
+# of a preload it sends eight requests, a quarter of its store.
+REHEARSAL = {"accounts": 2000, "transfers": 1 << 14, "preload_requests": 8}
 
 
 def say(msg: str) -> None:
     print(f"[chipbench] {msg}", flush=True)
 
 
-def load_cell(workload: str) -> tuple[dict, dict, dict, dict]:
+def rss_bytes() -> int:
+    """This process's resident set now (Linux; 0 where /proc is not)."""
+    try:
+        with open("/proc/self/statm") as f:
+            return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+    except OSError:
+        return 0
+
+
+def load_cell(workload: str,
+              cells: str | None = None) -> tuple[dict, dict, dict, dict]:
+    """`cells` names a file of further `configs` and `workloads`: the
+    builder's scratch cells and the tests' fixtures, never the
+    driver's, which runs what BENCHMARK.json holds."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cells = {w["name"]: w for w in bench["workloads"]}
-    if workload not in cells:
+    if cells:
+        with open(os.path.join(ROOT, cells)) as f:
+            more = json.load(f)
+        bench = dict(bench, configs=bench["configs"] + more["configs"],
+                     workloads=bench["workloads"] + more["workloads"])
+    by_name = {w["name"]: w for w in bench["workloads"]}
+    if workload not in by_name:
         raise BenchFailure(f"no workload {workload!r} in BENCHMARK.json "
-                           f"(has: {sorted(cells)})")
-    cell = cells[workload]
+                           f"(has: {sorted(by_name)})")
+    cell = by_name[workload]
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(ROOT, cfg_entry["file"])) as f:
         config = json.load(f)
@@ -114,6 +139,17 @@ def servable(config: dict, mix: dict) -> None:
     if server["small_layout"]:
         raise BenchFailure(f"configuration {config['name']!r} asks for the "
                            "small layout: that is the rehearsal's, no cell's")
+    for key in ("format_args", "start_args"):
+        for arg in server.get(key, []):
+            name = arg.split("=", 1)[0]
+            # argparse takes any unambiguous prefix of a flag for it
+            if not arg.startswith("--") or len(name) < 3 or any(
+                    owned.startswith(name) for owned in HARNESS_ARGS):
+                raise BenchFailure(
+                    f"configuration {config['name']!r} puts {arg!r} in "
+                    f"server.{key}: each entry is one `--flag` or "
+                    f"`--flag=value`, and {', '.join(HARNESS_ARGS)} and the "
+                    "path are the harness's own")
     if config["guarantees"]["replicas"] != server["replica_count"]:
         raise BenchFailure("the guarantees name another number of replicas "
                            "than the server block")
@@ -122,6 +158,33 @@ def servable(config: dict, mix: dict) -> None:
         raise BenchFailure(f"configuration {config['name']!r} states "
                            f"guarantees {unheld} that no compared number "
                            "holds the program to")
+
+
+def plan_setup(dep: Deployment, mix: dict, n_max: int, n_req: int,
+               capacity: int, preload_cut: int | None = None,
+               ) -> tuple[list, list[int]]:
+    """The set-up's transfers before anything boots: the funding
+    requests and the width of each preload request, held as a whole
+    (the warm requests with them) against the same budget the run
+    counts by. `preload_cut` is the rehearsal's."""
+    preload = dep.config["transfers"].get("preloaded_count", 0)
+    if preload_cut is not None:
+        preload = min(preload, preload_cut)
+    try:
+        widths = dep.preload_widths(preload, n_max)
+    except ValueError as e:
+        raise BenchFailure(str(e))
+    funding = dep.funding_requests(n_max)
+    dry = StoreBudget(capacity, n_req)
+    for n in ([r.n_events for r in funding] + widths
+              + [n_req] * mix["warm_requests"]):
+        if not dry.reserve(n):
+            raise BenchFailure(
+                f"set-up alone would pass transfer_count: {dry.created} "
+                f"transfers and a request of {n} more, and a run may create "
+                f"{capacity} less one request of {n_req}")
+        dry.settle(n, n)
+    return funding, widths
 
 
 def wait_for(path: str, what: str) -> None:
@@ -175,14 +238,15 @@ def read_back(client, Operation, dep: Deployment, sent: list,
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              rehearse: bool = False, tamper=None,
-             launcher: str | None = None) -> dict:
+             launcher: str | None = None, cells: str | None = None) -> dict:
     """The whole run; returns the result line's object. `rehearse` runs
     a `--small` server on whatever backend JAX has and skips the look
     for a chip (the caller fails the run afterwards); `tamper(sent,
     readback)` lets the control and the fault tests put other answers
     in the program's place before the comparison; `launcher` lets the
-    fault tests start the server with its timed path broken."""
-    bench, cell, config, mix = load_cell(workload)
+    fault tests start the server with its timed path broken; `cells`
+    as in `load_cell`."""
+    bench, cell, config, mix = load_cell(workload, cells)
     servable(config, mix)
     # The program's client library and admission rules: the system
     # under test, imported here; none of them starts a JAX backend.
@@ -203,24 +267,47 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 else config["transfers"]["transfer_count"])
     dep = Deployment(config, seed,
                      accounts_cut=REHEARSAL["accounts"] if rehearse else None)
+    # The rehearsal's server is `--small` whatever the configuration
+    # states: its sizes are left out and its preload is cut.
+    stated = [config["server"].get(k, [])
+              for k in ("format_args", "start_args")]
+    format_args, start_args = ([], []) if rehearse else stated
+    funding, preload_widths = plan_setup(
+        dep, mix, n_max, n_req, capacity,
+        REHEARSAL["preload_requests"] * n_max if rehearse else None)
+    preload = sum(preload_widths)
     say(f"cell {workload}: config {config['name']}, traffic {mix['name']} "
         f"({mix['sessions']} sessions x {quota} requests x {n_req} events, "
         f"closed loop), "
-        f"seed {seed}, {seconds}s, trace {int(trace)}; layout message "
-        f"{layout.message_size_max} B, data file {layout.size / 1e9:.2f} GB; "
-        f"a run may create {capacity} transfers (the configuration's "
-        "transfer_count: what the device store and the data file's grid hold)")
+        f"seed {seed}, {seconds}s, trace {int(trace)}; messages of "
+        f"{layout.message_size_max} B; format_args {format_args}, "
+        f"start_args {start_args}; {preload} transfers preloaded in "
+        f"{len(preload_widths)} requests before the window; a run may create "
+        f"{capacity} transfers (the configuration's transfer_count: what it "
+        "states its stores hold, set-up and preload included)")
+    if rehearse:
+        say("rehearsal: a --small server; the configuration's format_args "
+            f"{stated[0]} and start_args {stated[1]} are left out, its "
+            f"preload cut from {config['transfers'].get('preloaded_count', 0)}"
+            f" to {preload}, its accounts to {dep.n}, its transfers to "
+            f"{capacity}")
 
     workdir = os.path.join(ROOT, "scratch", "chipbench", workload)
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir)
     data_path = os.path.join(workdir, "0_0.tigerbeetle")
     span_path = os.path.join(workdir, "spans.json") if trace else None
-    format_data_file(data_path, small=rehearse)
+    for line in format_data_file(data_path, small=rehearse,
+                                 extra=format_args):
+        say("format: " + line)
+    stat = os.stat(data_path)
+    say(f"data file as formatted: {stat.st_size} B, {stat.st_blocks * 512} B "
+        "of them allocated")
     port = free_port()
     server = Server(port, data_path, workdir, small=rehearse,
                     span_trace=span_path, profile=trace,
-                    engine=config["server"]["engine"], launcher=launcher)
+                    engine=config["server"]["engine"], launcher=launcher,
+                    start_args=start_args)
     clients: list = []
     sent: list[Sent] = []
     try:
@@ -263,8 +350,22 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
         for request in dep.account_requests(n_max):
             setup(request)
-        for request in dep.funding_requests(n_max):
+        for request in funding:
             setup(request)
+        # The state the deployment holds before the window, through the
+        # served path like everything else.
+        rss_before, t_preload = rss_bytes(), time.monotonic()
+        for k, n in enumerate(preload_widths):
+            setup(dep.transfer_request(STREAM_PRELOAD, k, n))
+        preload_s = time.monotonic() - t_preload
+        if preload_widths:
+            rss_after = rss_bytes()
+            say(f"preload: {preload} transfers in {len(preload_widths)} "
+                f"requests, {preload_s:.3f}s ({preload / preload_s:.0f} "
+                f"transfers/s), {budget.created} created so far; the "
+                f"harness's resident set {rss_before} -> {rss_after} B "
+                f"({(rss_after - rss_before) / len(preload_widths):.0f} B "
+                "a held request)")
         # Un-timed requests of the cell's own traffic: every shape the
         # window uses is compiled (or loaded) before it.
         for k in range(mix["warm_requests"]):
@@ -300,11 +401,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if budget.exhausted:
             say(f"WINDOW ENDED EARLY after {t1 - t0:.1f}s of {seconds}s: "
                 f"{budget.created} transfers created and a run may create "
-                f"{capacity} (transfer_count: start hard-codes t_cap = 2^21, "
-                "and the data file's grid fills well before it); the next "
-                "request could have passed it. The rate is over the "
-                "shortened window.")
+                f"{capacity} (the configuration's transfer_count, which "
+                "states what its stores hold, set-up and preload included); "
+                "the next request could have passed it. The rate is over "
+                "the shortened window.")
 
+        rss_window = rss_bytes()
         readback = read_back(c0, Operation, dep, sent, n_lookup, seed)
         for c in clients:
             c.close()
@@ -315,7 +417,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             c.close()
         server.kill()
         if os.path.exists(data_path):
-            os.remove(data_path)  # 1.7 GB sparse; logs and traces stay
+            os.remove(data_path)  # its size is said above; logs and traces stay
 
     # The harness stayed off the device: the chip had one owner.
     from jax._src import xla_bridge
@@ -331,6 +433,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     numbers = check.judge(sent, readback)
     correct = check.verdict(numbers)
     ref_s = time.monotonic() - t_ref
+    rss_compared = rss_bytes()
 
     answered = [s for s in window if s.error is None]
     if not answered:
@@ -355,8 +458,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     say(f"compiles inside the window: {compiles_in_window} "
         f"(after listening, whole run: {shutdown['compiles_after_listening']})")
     say(f"server shutdown record: {json.dumps(shutdown, sort_keys=True)}")
-    say(f"reference replay and comparison: {ref_s:.1f}s for "
-        f"{sum(s.request.n_events for s in sent)} events")
+    events = sum(s.request.n_events for s in sent)
+    say(f"reference replay and comparison: {ref_s:.1f}s for {events} events "
+        f"({events / ref_s:.0f} events/s); the harness's resident set "
+        f"{rss_window} B after the window, {rss_compared} B after the "
+        f"comparison, peak "
+        f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024} B")
 
     context = {
         "server_lines": server.lines, "shutdown": shutdown, "marks": marks,
@@ -368,8 +475,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                    "create_requests_answered":
                        sum(1 for s in sent if s.error is None and
                            s.request.operation.startswith("create_"))},
-        "device_kind": device["kind"], "spans": None, "device": None,
-        "profile": None,
+        "device_kind": device["kind"],
+        "memory_peak_bytes": device_record["memory_peak_bytes"],
+        "spans": None, "device": None, "profile": None,
     }
     metrics = read_metrics("e2e_metrics", bench["end_to_end"], workload,
                            context)
@@ -394,6 +502,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "cut_at_hard_stop": cut,
         "ended_early_at_store_capacity": budget.exhausted,
         "transfers_created_whole_run": budget.created,
+        "preloaded_transfers": preload, "preload_seconds": preload_s,
+        "preloaded_ids_read_back": sum(
+            1 for ids, _ in readback["transfers"] for i in ids
+            if (i >> 64) & 0xFFFF == STREAM_PRELOAD),
         "compiles_in_window": compiles_in_window,
         "host_fallbacks": fb["host_fallbacks"],
         "fallback_causes": fb["causes"],
@@ -457,13 +569,17 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--cells", default=None,
+                   help="a file of further configs and workloads (the "
+                        "builder's scratch cells); the driver gives none")
     p.add_argument("--rehearse", action="store_true",
                    help="CPU rehearsal: a --small server on any backend; "
                         "passes everything, then fails at the device check")
     args = p.parse_args(argv)
     try:
         result = run_cell(args.workload, args.seed, args.seconds,
-                          bool(args.trace), rehearse=args.rehearse)
+                          bool(args.trace), rehearse=args.rehearse,
+                          cells=args.cells)
         if args.rehearse:
             say("rehearsal line (NOT a result): " + json.dumps(result))
             if not result["correct"]:

@@ -2,8 +2,10 @@
 
 `Server` is copied from chip_smoke.py (PR 23) and started through
 chipbench/server_launcher.py: `python -m tigerbeetle_tpu format`, then
-`start --engine=<the configuration's engine>`, one replica, the only process that starts a
-JAX backend. The harness reads the lines `start` prints.
+`start --engine=<the configuration's engine>`, one replica, the only
+process that starts a JAX backend. A configuration's `format_args` and
+`start_args` ride on the two command lines as they stand. The harness
+reads the lines `format` and `start` print.
 """
 
 from __future__ import annotations
@@ -32,12 +34,38 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def format_data_file(path: str, small: bool) -> None:
-    cmd = [sys.executable, "-m", "tigerbeetle_tpu", "format", "--cluster=0",
-           "--replica=0", "--replica-count=1"]
-    subprocess.run(cmd + (["--small"] if small else []) + [path],
-                   cwd=ROOT, check=True, timeout=300,
-                   stdout=subprocess.DEVNULL)
+# What the harness itself puts on `format`'s and `start`'s command lines
+# (the path besides): a configuration's `format_args` / `start_args`
+# may name none of them.
+HARNESS_ARGS = ("--cluster", "--replica", "--replica-count", "--addresses",
+                "--engine", "--small", "--trace")
+
+
+def format_argv(path: str, small: bool, extra: list[str] = ()) -> list[str]:
+    return [sys.executable, "-m", "tigerbeetle_tpu", "format", "--cluster=0",
+            "--replica=0", "--replica-count=1",
+            *(["--small"] if small else []), *extra, path]
+
+
+def start_argv(port: int, path: str, *, engine: str, small: bool,
+               span_trace: str | None, extra: list[str] = ()) -> list[str]:
+    """The program's own arguments, as the launcher hands them on."""
+    return ["start", f"--addresses=127.0.0.1:{port}", "--replica=0",
+            f"--engine={engine}", *(["--small"] if small else []),
+            *(["--trace", span_trace] if span_trace else []), *extra, path]
+
+
+def format_data_file(path: str, small: bool,
+                     extra: list[str] = ()) -> list[str]:
+    """Runs `format`; the lines it printed. An argument it refuses
+    fails the run in the program's own words."""
+    done = subprocess.run(format_argv(path, small, extra), cwd=ROOT,
+                          timeout=300, stdout=subprocess.PIPE,
+                          stderr=subprocess.STDOUT, text=True)
+    if done.returncode != 0:
+        raise BenchFailure(f"format exited ({done.returncode}); it said:\n"
+                           + done.stdout[-4000:])
+    return done.stdout.splitlines()
 
 
 class Server:
@@ -45,19 +73,16 @@ class Server:
 
     def __init__(self, port: int, path: str, workdir: str, *, small: bool,
                  span_trace: str | None, profile: bool,
-                 engine: str = "device", launcher: str | None = None):
+                 engine: str = "device", launcher: str | None = None,
+                 start_args: list[str] = ()):
         env = dict(os.environ, PYTHONUNBUFFERED="1")
         self.log_path = os.path.join(workdir, "server.log")
         self.lines: list[str] = []
-        start = ["start", f"--addresses=127.0.0.1:{port}", "--replica=0",
-                 f"--engine={engine}"]
-        if small:
-            start.append("--small")
-        if span_trace:
-            start += ["--trace", span_trace]
         self.proc = subprocess.Popen(
             [sys.executable, launcher or LAUNCHER, "--workdir", workdir,
-             *(["--profile"] if profile else []), "--", *start, path],
+             *(["--profile"] if profile else []), "--",
+             *start_argv(port, path, engine=engine, small=small,
+                         span_trace=span_trace, extra=start_args)],
             cwd=ROOT, env=env, stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT, text=True)
         self._cond = threading.Condition()

@@ -27,11 +27,11 @@ def _sound(context: dict, *names: str):
     return by_name
 
 
-def child_ms_per_parent(context: dict, child: str, parent: str):
-    """Milliseconds of `child` spans per occurrence of `parent`: the
-    summed duration of the children that start inside a parent that
-    starts inside the measured window, over the number of those parents
-    (a parent with no such child counts as zero)."""
+def children_in_window(context: dict, child: str, parent: str):
+    """Durations (s) of the `child` spans that start inside a `parent`
+    that starts inside the measured window, and the number of those
+    parents; None where there is nothing sound to read or no such
+    child."""
     by_name = _sound(context, child, parent)
     if by_name is None:
         return None
@@ -48,7 +48,18 @@ def child_ms_per_parent(context: dict, child: str, parent: str):
     inside = (owner >= 0) & (c_start < p_hi[np.clip(owner, 0, None)])
     if not inside.any():
         return None
-    return 1e3 * float(c_dur[inside].sum()) / len(p_lo)
+    return c_dur[inside], len(p_lo)
+
+
+def child_ms_per_parent(context: dict, child: str, parent: str):
+    """Milliseconds of `child` spans per occurrence of `parent`: the
+    summed duration of the children that start inside a parent that
+    starts inside the measured window, over the number of those parents
+    (a parent with no such child counts as zero)."""
+    found = children_in_window(context, child, parent)
+    if found is None:
+        return None
+    return 1e3 * float(found[0].sum()) / found[1]
 
 
 def seconds_in_window(context: dict, name: str):

@@ -16,9 +16,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from chipbench import (check, control, kernel_bytes, peaks, run,  # noqa: E402
-                       trace_reduce, wire)
+                       server, trace_reduce, wire)
 from chipbench.server import BenchFailure  # noqa: E402
-from chipbench.traffic import Deployment  # noqa: E402
+from chipbench.traffic import (STREAM_FUNDING, STREAM_PRELOAD,  # noqa: E402
+                               STREAM_WARM, Deployment)
 from chipbench.window import Sent, StoreBudget  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -27,6 +28,11 @@ CONFIGS = os.path.join(ROOT, "chipbench", "configs")
 # (PERF.md, Open questions); a fixture keeps them and the no_limits
 # control under test.
 TWOPHASE = os.path.join(HERE, "fixtures", "twophase_limits.json")
+# tb_bench_default_1r with `start_args` and `preloaded_count` stated:
+# the builder's scratch cell on the chip (PERF.md, PR 29), in no
+# BENCHMARK.json.
+PRELOAD = os.path.join(HERE, "fixtures", "preload_args.json")
+CELLS = "chipbench/tests/fixtures/cells.json"
 
 
 def config(name):
@@ -132,6 +138,143 @@ def test_what_the_harness_cannot_serve_fails():
         change(c, m)
         with pytest.raises(BenchFailure):
             run.servable(c, m)
+
+
+def test_default_command_lines_are_the_parents():
+    """tb_bench_default_1r states neither key: `format` and `start` get
+    the literal lists the parent of PR 29 built."""
+    srv = config("tb_bench_default_1r")["server"]
+    assert "format_args" not in srv and "start_args" not in srv
+    assert server.format_argv("/d/0_0.tigerbeetle", small=False) == [
+        sys.executable, "-m", "tigerbeetle_tpu", "format", "--cluster=0",
+        "--replica=0", "--replica-count=1", "/d/0_0.tigerbeetle"]
+    assert server.start_argv(3001, "/d/0_0.tigerbeetle", engine=srv["engine"],
+                             small=False, span_trace=None) == [
+        "start", "--addresses=127.0.0.1:3001", "--replica=0",
+        "--engine=device", "/d/0_0.tigerbeetle"]
+    # the traced rehearsal's, as the parent built it
+    assert server.start_argv(7, "p", engine="device", small=True,
+                             span_trace="s.json") == [
+        "start", "--addresses=127.0.0.1:7", "--replica=0", "--engine=device",
+        "--small", "--trace", "s.json", "p"]
+    assert server.format_argv("p", small=True)[-2:] == ["--small", "p"]
+
+
+def test_a_configurations_arguments_land_after_the_harness_own():
+    extra = ["--grid-blocks=65536", "--verbose"]
+    assert server.format_argv("p", small=False, extra=extra)[-4:] == [
+        "--replica-count=1", *extra, "p"]
+    assert server.start_argv(7, "p", engine="device", small=False,
+                             span_trace="s.json", extra=extra)[-5:] == [
+        "--trace", "s.json", *extra, "p"]
+    _, _, cfg, mix = run.load_cell("preload.full_batch_1s", CELLS)
+    assert cfg["server"]["start_args"] == ["--trace-emit-interval=10.0"]
+    run.servable(cfg, mix)
+
+
+@pytest.mark.parametrize("key", ["format_args", "start_args"])
+@pytest.mark.parametrize("arg", [
+    "--cluster=1", "--replica=1", "--replica-count=3",
+    "--addresses=127.0.0.1:1", "--engine=oracle", "--small", "--trace=x.json",
+    "--eng=oracle", "--trac=x.json", "0_0.tigerbeetle", "-x", "--"])
+def test_an_argument_the_harness_owns_is_refused(key, arg):
+    _, _, cfg, mix = run.load_cell("preload.full_batch_1s", CELLS)
+    cfg["server"][key] = ["--trace-emit-interval=10.0", arg]
+    with pytest.raises(BenchFailure, match="the harness's own"):
+        run.servable(cfg, mix)
+
+
+def test_preload_same_seed_same_bytes_and_no_id_twice():
+    """The preload is a stream of its own beside funding, warm and the
+    sessions: the same bytes for the same seed, no id twice within it or
+    across them."""
+    cfg = config(PRELOAD)
+    a, b = Deployment(cfg, 4000000123), Deployment(cfg, 4000000123)
+    widths = a.preload_widths(cfg["transfers"]["preloaded_count"], 8189)
+    assert widths == [8189] * 8
+    assert a.preload_widths(20_000, 8189) == [8189, 8189, 3622]
+    assert a.preload_widths(0, 8189) == []
+    pre = [a.transfer_request(STREAM_PRELOAD, k, n)
+           for k, n in enumerate(widths)]
+    assert [r.payload for r in pre] == [
+        b.transfer_request(STREAM_PRELOAD, k, n).payload
+        for k, n in enumerate(widths)]
+    assert pre[0].payload != Deployment(cfg, 4000000124).transfer_request(
+        STREAM_PRELOAD, 0, 8189).payload
+    lim = Deployment(config(TWOPHASE), 4000000123)  # has funding requests
+    others = ([a.transfer_request(STREAM_WARM, k, 8189) for k in range(2)]
+              + [a.transfer_request(s, k, 1024)
+                 for s in range(4) for k in range(4)])
+    ids = np.concatenate([r.ids for r in pre + others])
+    assert len(np.unique(ids, axis=0)) == len(ids) == 8 * 8189 + 2 * 8189 + 16 * 1024
+    streams = {int(r.ids[0, 1]) & 0xFFFF
+               for r in pre + others + lim.funding_requests(8189)}
+    assert streams == {STREAM_PRELOAD, STREAM_WARM, STREAM_FUNDING, 0, 1, 2, 3}
+    # the event shape is the deployment's own: skew, amounts, fail shares
+    rec = np.frombuffer(pre[3].payload, wire.TRANSFER)
+    win = np.frombuffer(a.transfer_request(0, 3, 8189).payload, wire.TRANSFER)
+    assert rec["amount_lo"].min() >= 1 and rec["amount_lo"].max() < 1000
+    assert abs(int((rec["ledger"] != 1).sum())
+               - int((win["ledger"] != 1).sum())) < 30
+    hot = a.id_lo[0]
+    assert (rec["debit_lo"] == hot).sum() > 400 < (win["debit_lo"] == hot).sum()
+
+
+def test_two_phase_preload_is_whole_pairs():
+    d = Deployment(config(TWOPHASE), 5)
+    assert d.preload_widths(4 * 8189, 8189) == [8189] * 4
+    for count in (3 * 8189, 2 * 8189 + 5):
+        with pytest.raises(ValueError, match="whole pairs"):
+            d.preload_widths(count, 8189)
+
+
+def test_preload_counts_against_transfer_count():
+    cfg = config(PRELOAD)
+    with open(os.path.join(ROOT, "chipbench", "traffic",
+                           "full_batch_1s.json")) as f:
+        mix = json.load(f)
+    dep = Deployment(cfg, 9)
+    funding, widths = run.plan_setup(dep, mix, 8189, 8189, 500_000)
+    assert funding == [] and sum(widths) == 65_512
+    # the rehearsal's cut: eight requests of its own narrower wire
+    assert run.plan_setup(dep, mix, 509, 509, 1 << 14, 8 * 509)[1] == [509] * 8
+    cfg["transfers"]["preloaded_count"] = 600_000
+    with pytest.raises(BenchFailure,
+                       match="set-up alone would pass transfer_count"):
+        run.plan_setup(Deployment(cfg, 9), mix, 8189, 8189, 500_000)
+    # 58 preload + 2 warm requests fit under 500,000 less one request; 59 do not
+    cfg["transfers"]["preloaded_count"] = 58 * 8189
+    run.plan_setup(Deployment(cfg, 9), mix, 8189, 8189, 500_000)
+    cfg["transfers"]["preloaded_count"] = 59 * 8189
+    with pytest.raises(BenchFailure, match="set-up alone"):
+        run.plan_setup(Deployment(cfg, 9), mix, 8189, 8189, 500_000)
+
+
+def test_new_per_layer_readers_on_a_hand_made_context():
+    """compact_beat_max_ms is the longest beat inside a commit_compact
+    of the window; device_memory_share the launcher's peak over the
+    chip's HBM, and nothing where the backend keeps no peak."""
+    arr = np.array
+    spans = {"commit_compact": (arr([9.0, 10.0, 11.0, 12.0, 40.0]),
+                                arr([0.5, 0.5, 0.9, 0.5, 0.5])),
+             # before the window; two in op 10; the freeze in op 11; one
+             # outside any commit_compact; one after the window
+             "compact_beat": (arr([9.1, 10.1, 10.2, 11.05, 12.7, 40.1]),
+                              arr([2.0, 0.003, 0.004, 0.84, 3.0, 5.0]))}
+    context = {"spans": {"spans": spans, "dropped_events": 0},
+               "window": {"wall_t0": 9.5, "wall_t1": 30.0},
+               "memory_peak_bytes": 2_925_394_432,
+               "device_kind": "TPU v5 lite"}
+    longest = run.load_reader("layer_metrics", "compact_beat_max_ms")
+    mean = run.load_reader("layer_metrics", "compact_beat_ms")
+    share = run.load_reader("layer_metrics", "device_memory_share")
+    assert longest(context) == pytest.approx(840.0)
+    assert mean(context) == pytest.approx(1e3 * (0.003 + 0.004 + 0.84) / 3)
+    assert share(context) == pytest.approx(18.28371520)
+    assert share(dict(context, memory_peak_bytes=None)) is None
+    assert longest(dict(context, spans=None)) is None
+    context["spans"]["dropped_events"] = 1
+    assert longest(context) is None
 
 
 def test_every_metric_of_the_benchmark_has_its_reader():

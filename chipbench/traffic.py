@@ -26,6 +26,7 @@ F_PENDING, F_POST, F_VOID = 1 << 1, 1 << 2, 1 << 3
 # count from 0, set-up streams sit above any session count.
 STREAM_FUNDING = 0x7F00
 STREAM_WARM = 0x7000
+STREAM_PRELOAD = 0x7800
 SEED_MASK = (1 << 63) - 1
 
 
@@ -100,6 +101,20 @@ class Deployment:
         step = min(n_max, self.config["accounts"]["funding_events_per_request"])
         return [self._request("create_transfers", rec[i:i + step])
                 for i in range(0, len(rec), step)]
+
+    def preload_widths(self, count: int, n_max: int) -> list[int]:
+        """Events in each request of the state that exists before the
+        window: `count` transfers in wire-max requests, the last one
+        holding what is left. Request k of them is
+        `transfer_request(STREAM_PRELOAD, k, width)`: the deployment's
+        own event shape. A two-phase deployment resolves request k in
+        request k + 1, so its preload is whole pairs of equal width."""
+        widths = [n_max] * (count // n_max) + [count % n_max] * bool(count % n_max)
+        if self.two_phase and (len(widths) % 2 or count % n_max):
+            raise ValueError(
+                f"a two-phase deployment preloads whole pairs of {n_max}-"
+                f"event requests; preloaded_count {count} is not one")
+        return widths
 
     def _blank(self, n: int, stream: int, k: int) -> np.ndarray:
         rec = np.zeros(n, dtype=wire.TRANSFER)
