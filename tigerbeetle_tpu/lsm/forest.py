@@ -58,6 +58,19 @@ class Forest:
         for tree in self.trees.values():
             tree.compact_beat(op)
 
+    def depth_stats(self) -> dict:
+        """How deep the trees stand (`start`'s shutdown record), read
+        off the manifests: the deepest level (0-based, as
+        `Tree.levels`; -1 with no table anywhere) that holds a live
+        table in any tree, and the live tables of all trees."""
+        deepest, tables = -1, 0
+        for tree in self.trees.values():
+            for li, level in enumerate(tree.levels):
+                if len(level):
+                    deepest = max(deepest, li)
+                    tables += len(level)
+        return {"deepest_level": deepest, "tables": tables}
+
     def checkpoint(self) -> bytes:
         """Flush + serialize everything; returns the root blob (manifest
         chain head address + free set). Pending grid frees are applied

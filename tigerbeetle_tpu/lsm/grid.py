@@ -90,6 +90,12 @@ class Grid:
         self.on_corrupt = None
         self.freed_pending: list[int] = []  # released at next checkpoint
         self.acquire_cursor = 0
+        # Counted off the free set at each checkpoint (held_stats): the
+        # blocks not free before that checkpoint's frees landed, the
+        # most over all checkpoints; and the blocks the last
+        # checkpoint's own free set holds.
+        self.held_peak = 0
+        self.held_at_checkpoint = 0
         # Live reservations (reserve() .. forfeit()): their unwritten
         # blocks are excluded from checkpointed free sets — a crash mid-
         # job must not leak them (the restored job re-reserves afresh).
@@ -162,6 +168,7 @@ class Grid:
         (tables install and manifests pack only after a job drains), so
         a crash must not leak them: the restored job re-reserves and
         rewrites from scratch."""
+        self.held_peak = max(self.held_peak, self.held())
         for idx in self.freed_pending:
             self.free[idx] = True
         self.freed_pending.clear()
@@ -171,6 +178,7 @@ class Grid:
             for idx in res.indices:
                 assert not bits[idx]
                 bits[idx] = True
+        self.held_at_checkpoint = self.block_count - sum(bits)
         return ewah.encode_bitset(bits)
 
     def restore_free_set(self, blob: bytes) -> None:
@@ -180,6 +188,24 @@ class Grid:
         self.freed_pending.clear()
         self.acquire_cursor = 0
         self._reservations.clear()
+        self.held_at_checkpoint = self.held()
+        self.held_peak = max(self.held_peak, self.held_at_checkpoint)
+
+    def held(self) -> int:
+        """Blocks not free now: written, awaiting a checkpoint to be
+        freed, or reserved by a running job. One pass over the free
+        set; checkpoints and the shutdown record call it, no block path
+        does."""
+        return self.block_count - sum(self.free)
+
+    def held_stats(self) -> dict:
+        """How full the grid got (`start`'s shutdown record): a
+        reservation that finds fewer free blocks than it asks for kills
+        the server, so `held_peak` against `blocks` says how far a
+        `format --grid-blocks` was from that."""
+        return {"blocks": self.block_count,
+                "held_at_checkpoint": self.held_at_checkpoint,
+                "held_peak": self.held_peak}
 
     # ------------------------------------------------------------- blocks
 

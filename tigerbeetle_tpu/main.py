@@ -2,8 +2,9 @@
 
 reference: src/tigerbeetle/main.zig (commands :146-186) + cli.zig. Commands:
 
-  format     --cluster=N --replica=I --replica-count=N <path>
-  start      --addresses=a:p,b:p,... --replica=I [--engine=device|kernel|oracle] <path>
+  format     --cluster=N --replica=I --replica-count=N [--grid-blocks=N] <path>
+  start      --addresses=a:p,b:p,... --replica=I [--engine=device|kernel|oracle]
+             [--account-capacity=N] [--transfer-capacity=N] <path>
   recover    <aof> <path>  |  --from-cluster --addresses=... <path>
   repl       --addresses=... [--cluster=N]
   benchmark  [--transfer-count=N] [--account-count=N]
@@ -27,11 +28,44 @@ def _parse_addresses(text: str) -> list[tuple[str, int]]:
     return out
 
 
-def cmd_format(args) -> int:
-    from .vsr.replica import Replica
-    from .vsr.storage import FileStorage, StorageLayout, TEST_LAYOUT
+def _data_file_layout(args, formatting: bool = False):
+    """The layout of the data file at `args.path`, or None with the
+    reason printed (the caller exits 1). `format` states the grid's
+    size (`--grid-blocks`); every other command reads it off the file's
+    length, the grid being the file's last zone, so no second flag can
+    disagree with the file. Called once per command, before its storage
+    opens. A file of the default length, or one that is not there yet
+    (or is empty), gives the layout that `--small` names, as before
+    there was a choice."""
+    from .vsr.storage import (LayoutError, StorageLayout, TEST_LAYOUT,
+                              layout_of_file, with_grid_blocks)
 
     layout = TEST_LAYOUT if args.small else StorageLayout()
+    try:
+        if formatting:
+            if args.grid_blocks is not None:
+                layout = with_grid_blocks(layout, args.grid_blocks)
+        else:
+            try:
+                length = os.path.getsize(args.path)
+            except OSError:
+                length = 0  # not there yet: FileStorage says so, or makes it
+            if length:
+                layout = layout_of_file(layout, length)
+    except LayoutError as e:
+        print(f"error: {args.path}: {e}")
+        return None
+    return layout
+
+
+def cmd_format(args) -> int:
+    from .vsr.replica import Replica
+    from .vsr.storage import (SUPERBLOCK_COPIES, SUPERBLOCK_COPY_SIZE,
+                              FileStorage)
+
+    layout = _data_file_layout(args, formatting=True)
+    if layout is None:
+        return 1
     storage = FileStorage(args.path, layout=layout, create=True)
     Replica.format(storage, cluster=args.cluster, replica_id=args.replica,
                    replica_count=args.replica_count)
@@ -39,6 +73,19 @@ def cmd_format(args) -> int:
     storage.close()
     print(f"formatted {args.path}: cluster={args.cluster} "
           f"replica={args.replica}/{args.replica_count}")
+    # What `format` wrote, so that nobody has to guess from the file's
+    # length: the file is extended, not filled, and the grid (its last
+    # zone, whose size `start` reads off that length) stays unwritten
+    # but for the empty forest's manifest block.
+    stat = os.stat(args.path)
+    print(f"data file: {stat.st_size} B long, {stat.st_blocks * 512} B "
+          f"allocated; written: {SUPERBLOCK_COPIES} superblock copies "
+          f"({SUPERBLOCK_COPIES * SUPERBLOCK_COPY_SIZE} B), "
+          f"{layout.slot_count} WAL headers, one checkpoint root and one "
+          f"manifest block; left unwritten: the WAL's prepares, the reply "
+          f"slots and a grid of {layout.grid_block_count} blocks of "
+          f"{layout.grid_block_size} B "
+          f"({layout.grid_block_count * layout.grid_block_size} B)")
     return 0
 
 
@@ -116,7 +163,36 @@ def serve(bus, replica, tracer, stop: list) -> None:
             tracer.record_span(Event.loop_busy, bus.woke_ns, busy_ns)
 
 
+def _store_capacities(args) -> tuple[int, int]:
+    """(a_cap, t_cap) of `start`'s device stores. Production capacities
+    match the DeviceLedger defaults (the static-allocation bound,
+    reference: config.zig limits); --small keeps test clusters light; a
+    deployment states its own (--account-capacity,
+    --transfer-capacity). Shared by the serving factory AND the warmup
+    so the pre-compiled executables always match serving shapes."""
+    a_cap = (1 << 12) if args.small else (1 << 17)
+    t_cap = (1 << 14) if args.small else (1 << 21)
+    if args.account_capacity is not None:
+        a_cap = args.account_capacity
+    if args.transfer_capacity is not None:
+        t_cap = args.transfer_capacity
+    return a_cap, t_cap
+
+
 def cmd_start(args) -> int:
+    # What the command line itself rules out is refused here, in words,
+    # before a signal handler is replaced or a backend starts (with no
+    # capacity stated there is nothing to refuse, and no import).
+    if args.account_capacity is not None or args.transfer_capacity is not None:
+        from .ops.warmup import capacity_error
+
+        refusal = capacity_error(*_store_capacities(args))
+        if refusal:
+            print(f"error: {refusal}")
+            return 1
+    layout = _data_file_layout(args)
+    if layout is None:
+        return 1
     # Shutdown rides a signal FLAG from the very top: a SIGINT landing
     # during storage open / warmup / journal recovery must still reach
     # the main loop as an orderly stop (and dump the trace), not die as
@@ -152,10 +228,9 @@ def cmd_start(args) -> int:
     from .state_machine import StateMachine
     from .vsr.message_bus import MessageBus
     from .vsr.replica import Replica
-    from .vsr.storage import FileStorage, StorageLayout, TEST_LAYOUT
+    from .vsr.storage import FileStorage
 
     addresses = _parse_addresses(args.addresses)
-    layout = TEST_LAYOUT if args.small else StorageLayout()
     storage = FileStorage(args.path, layout=layout)
 
     replica_holder: list = []
@@ -191,12 +266,7 @@ def cmd_start(args) -> int:
         from .aof import AOF
 
         aof = AOF(args.aof)
-    # Production capacities match the DeviceLedger defaults (the
-    # static-allocation bound, reference: config.zig limits); --small
-    # keeps test clusters light. Shared by the serving factory AND the
-    # warmup so the pre-compiled executables always match serving shapes.
-    a_cap = (1 << 12) if args.small else (1 << 17)
-    t_cap = (1 << 14) if args.small else (1 << 21)
+    a_cap, t_cap = _store_capacities(args)
     replica = Replica(
         cluster=args.cluster, replica_id=args.replica,
         replica_count=len(addresses), storage=storage, bus=bus,
@@ -276,6 +346,13 @@ def cmd_start(args) -> int:
             # checkpoints taken: a checkpoint that put again what the
             # column path had already written would show here.
             "durable_rows": dict(replica.durable.rows_put),
+            # How full the deployment's sizes got, read here from state
+            # that exists anyway: the stores' row counters, the free
+            # set (and what each checkpoint counted of it), the
+            # manifests.
+            "stores": led.store_stats(),
+            "grid": replica.durable.grid.held_stats(),
+            "forest": replica.durable.forest.depth_stats(),
         }}), flush=True)
     return 0
 
@@ -332,7 +409,7 @@ def _recover_from_cluster(args) -> int:
     from .state_machine import StateMachine
     from .vsr.message_bus import MessageBus
     from .vsr.replica import Replica
-    from .vsr.storage import FileStorage, StorageLayout, TEST_LAYOUT
+    from .vsr.storage import FileStorage
 
     if not args.addresses:
         print("error: recover --from-cluster requires --addresses")
@@ -342,7 +419,9 @@ def _recover_from_cluster(args) -> int:
         print(f"error: --replica-count={args.replica_count} but "
               f"--addresses lists {len(addresses)} replicas")
         return 2
-    layout = TEST_LAYOUT if args.small else StorageLayout()
+    layout = _data_file_layout(args)
+    if layout is None:
+        return 1
     storage = FileStorage(args.path, layout=layout, create=True)
     holder: list = []
     bus = MessageBus(cluster=args.cluster,
@@ -417,12 +496,14 @@ def cmd_recover(args) -> int:
     from .vsr.checksum import checksum
     from .vsr.durable import DurableState
     from .vsr.replica import Replica
-    from .vsr.storage import FileStorage, StorageLayout, TEST_LAYOUT
+    from .vsr.storage import FileStorage
     from .vsr.superblock import SuperBlock
 
+    layout = _data_file_layout(args)
+    if layout is None:
+        return 1
     sm = StateMachine(engine="oracle")
     applied = recover(args.aof, sm)
-    layout = TEST_LAYOUT if args.small else StorageLayout()
     storage = FileStorage(args.path, layout=layout, create=True)
     Replica.format(storage, cluster=args.cluster, replica_id=args.replica,
                    replica_count=args.replica_count)
@@ -452,11 +533,14 @@ def cmd_recover(args) -> int:
 
 def _open_superblock(args):
     """(storage, superblock) for a path/--small pair, or (storage, None)
-    with the shared no-quorum error printed."""
-    from .vsr.storage import FileStorage, StorageLayout, TEST_LAYOUT
+    with the shared no-quorum error printed; (None, None) where the
+    file's length fits no layout (said by _data_file_layout)."""
+    from .vsr.storage import FileStorage
     from .vsr.superblock import SuperBlock
 
-    layout = TEST_LAYOUT if args.small else StorageLayout()
+    layout = _data_file_layout(args)
+    if layout is None:
+        return None, None
     storage = FileStorage(args.path, layout=layout)
     sb = SuperBlock.load(storage)
     if sb is None:
@@ -473,10 +557,12 @@ def cmd_inspect(args) -> int:
     from .vsr.journal import Journal
     from .vsr.checksum import checksum
     from .vsr.storage import (SUPERBLOCK_COPIES, SUPERBLOCK_COPY_SIZE,
-                              FileStorage, StorageLayout, TEST_LAYOUT)
+                              FileStorage)
     from .vsr.superblock import SuperBlock
 
-    layout = TEST_LAYOUT if args.small else StorageLayout()
+    layout = _data_file_layout(args)
+    if layout is None:
+        return 1
     storage = FileStorage(args.path, layout=layout)
     # Per-copy superblock dump (the quorum rule tolerates torn/corrupt
     # copies — show which ones).
@@ -937,7 +1023,7 @@ def cmd_version(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(prog="tigerbeetle_tpu")
     sub = parser.add_subparsers(dest="command", required=True)
 
@@ -947,6 +1033,11 @@ def main(argv=None) -> int:
     p.add_argument("--replica-count", type=int, required=True)
     p.add_argument("--small", action="store_true",
                    help="small test layout (32-slot WAL)")
+    p.add_argument("--grid-blocks", type=int, default=None,
+                   help="blocks in the grid, the data file's last zone "
+                        "(default 8192 of 64 KiB, 512 MiB; --small: 2048 "
+                        "of 8 KiB). Left unwritten; every later command "
+                        "reads the grid's size off the file's length")
     p.add_argument("path")
     p.set_defaults(fn=cmd_format)
 
@@ -959,6 +1050,14 @@ def main(argv=None) -> int:
     p.add_argument("--platform", default=None,
                    help="force a JAX platform (e.g. cpu)")
     p.add_argument("--small", action="store_true")
+    p.add_argument("--account-capacity", type=int, default=None,
+                   help="accounts the device store holds: a power of two "
+                        "(default 2^17; --small: 2^12)")
+    p.add_argument("--transfer-capacity", type=int, default=None,
+                   help="transfers the device store holds, and rows of "
+                        "its history ring: a power of two (default 2^21; "
+                        "--small: 2^14). The warm set compiles at these "
+                        "shapes, so a new pair makes a cold first boot")
     p.add_argument("--trace", default=None,
                    help="dump a Chrome trace JSON here on shutdown")
     p.add_argument("--statsd", default=None,
@@ -1112,8 +1211,11 @@ def main(argv=None) -> int:
 
     p = sub.add_parser("version")
     p.set_defaults(fn=cmd_version)
+    return parser
 
-    args = parser.parse_args(argv)
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     return args.fn(args)
 
 

@@ -3054,6 +3054,13 @@ class DeviceLedger:
                 cbf[k] = cbf.get(k, 0) + v
         return {"windows": windows, "chain_batch_fallbacks": cbf}
 
+    def store_stats(self) -> dict:
+        """The stores' capacities and the rows they hold now (`start`'s
+        shutdown record): two scalars fetched from the device state."""
+        return {"a_cap": self.a_cap, "t_cap": self.t_cap,
+                "account_rows": int(self.state["accounts"]["count"]),
+                "transfer_rows": int(self.state["transfers"]["count"])}
+
     def fallback_stats(self) -> dict:
         """Host-visible routing/fallback counters (bench diagnostics +
         devhub): 'zero host fallbacks' is a measured invariant.

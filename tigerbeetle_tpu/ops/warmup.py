@@ -38,6 +38,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from .hash_table import SLOTS
 from .ledger import (
     N_PAD,
     DeviceLedger,
@@ -49,6 +50,31 @@ from .ledger import (
 
 WARM_BUCKETS = (1024, N_PAD)
 PRECOMPILE_WORKERS = 6  # one core and ~3-5 GB of compiler memory each
+# What warmup_kernels itself puts into the throwaway ledger: a batch
+# that lands in each bucket, once on the plain tier and once on the
+# fixpoint tier, between three accounts.
+WARM_SIZES = tuple(b // 2 + 1 for b in WARM_BUCKETS)
+WARM_TRANSFERS = 2 * sum(WARM_SIZES)
+WARM_ACCOUNTS = 3
+
+
+def capacity_error(a_cap: int, t_cap: int) -> str | None:
+    """Why `start` cannot serve at these store capacities, in words, or
+    None. `init_state` sizes its hash tables by shifts (`ht_init`
+    asserts a power of two of at least two buckets), and the warm-up
+    drives a ledger of the same capacities through WARM_TRANSFERS
+    transfers before `listening`."""
+    for flag, cap, least in (
+            ("--account-capacity", a_cap, max(WARM_ACCOUNTS, SLOTS)),
+            ("--transfer-capacity", t_cap, WARM_TRANSFERS)):
+        floor = 1 << (least - 1).bit_length()
+        if cap < floor or cap & (cap - 1):
+            return (f"{flag}={cap}: the device stores take a power of two "
+                    f"of at least {floor} rows (the hash tables are sized "
+                    f"by shifts, and the warm-up alone creates "
+                    f"{WARM_ACCOUNTS} accounts and {WARM_TRANSFERS} "
+                    "transfers in stores of this size)")
+    return None
 
 
 def abstract(tree, sharding=None):
@@ -170,7 +196,7 @@ def warmup_kernels(a_cap: int = 1 << 17, t_cap: int = 1 << 21) -> float:
     nid = 1
     # Plain tier first: a breach leaves the ledger dispatching
     # fixpoint-first until a breach-free batch cools it down.
-    sizes = [b // 2 + 1 for b in WARM_BUCKETS]  # lands in bucket b
+    sizes = WARM_SIZES  # n lands in bucket b
     for n in sizes:
         ts += n
         led.create_transfers_soa(_transfers(n, 1, 2, nid), ts)

@@ -40,7 +40,9 @@ class StorageLayout:
     # manifests address + free set) — small; bulk state lives in the grid.
     snapshot_size_max: int = 4 * 1024 * 1024
     grid_block_size: int = 64 * 1024
-    grid_block_count: int = 8192  # 512 MiB grid zone
+    # 512 MiB grid zone; `format --grid-blocks` makes files of another
+    # count (with_grid_blocks), which is why this is the LAST zone.
+    grid_block_count: int = 8192
 
     @property
     def zone_offsets(self) -> dict:
@@ -70,6 +72,50 @@ TEST_LAYOUT = StorageLayout(
     slot_count=32, message_size_max=64 * 1024, clients_max=8,
     snapshot_size_max=256 * 1024, grid_block_size=8 * 1024,
     grid_block_count=2048)
+
+
+class LayoutError(ValueError):
+    """A grid size no data file can have; the message is for the
+    operator (`main.py` prints it and exits 1)."""
+
+
+def with_grid_blocks(base: StorageLayout, count: int) -> StorageLayout:
+    """`base` with a grid of `count` blocks (`format --grid-blocks`):
+    `base` itself where that is its own count. The grid is the file's
+    last zone, so nothing before it moves. The checkpoint root carries
+    the grid's free set (EWAH: at worst two words for each 64 blocks)
+    beside the manifest's address and the session table; half a
+    snapshot slot is kept for those, so a count whose free set could
+    outgrow the other half is refused."""
+    if count == base.grid_block_count:
+        return base
+    if count < 1:
+        raise LayoutError(f"a grid of {count} blocks: a grid takes at "
+                          "least one block")
+    free_set_max = 8 + 16 * -(-count // 64)
+    if free_set_max > base.snapshot_size_max // 2:
+        raise LayoutError(
+            f"a grid of {count} blocks cannot be checkpointed: its free "
+            f"set may take {free_set_max} B of a snapshot slot of "
+            f"{base.snapshot_size_max} B, half of which is kept for the "
+            f"rest of the checkpoint root; this layout holds 1 to "
+            f"{(base.snapshot_size_max // 2 - 8) // 16 * 64} blocks")
+    return dataclasses.replace(base, grid_block_count=count)
+
+
+def layout_of_file(base: StorageLayout, length: int) -> StorageLayout:
+    """The layout of a data file `length` bytes long: `base` with as
+    many grid blocks as lie behind its other zones (`format` sized the
+    grid; every later command reads it here, so no flag can disagree
+    with the file)."""
+    grid_bytes = length - base.zone_offsets["grid"]
+    if grid_bytes <= 0 or grid_bytes % base.grid_block_size:
+        raise LayoutError(
+            f"a data file of {length} B holds no whole grid: this layout's "
+            f"zones before the grid take {base.zone_offsets['grid']} B and "
+            f"a grid block {base.grid_block_size} B (was it formatted with "
+            "the other of --small and the production layout, or cut short?)")
+    return with_grid_blocks(base, grid_bytes // base.grid_block_size)
 
 
 class Storage:
