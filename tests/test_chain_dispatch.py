@@ -113,12 +113,12 @@ def test_chain_matches_sequential(form, poison_window):
         fbs = np.asarray(outs["fallback"])
         assert not fbs[0] and fbs[1] and fbs[2]  # suffix poisoned
     # Final ledger state identical (the poisoned windows left it alone).
-    for table in ("transfers", "accounts"):
-        for mat in ("u64",):
-            np.testing.assert_array_equal(
-                np.asarray(got_state[table][mat]),
-                np.asarray(want_state[table][mat]),
-                err_msg=f"{table}.{mat} diverged ({form})")
+    for table, mat in (("transfers", "u32"), ("accounts", "u32"),
+                       ("accounts", "bal")):
+        np.testing.assert_array_equal(
+            np.asarray(got_state[table][mat]),
+            np.asarray(want_state[table][mat]),
+            err_msg=f"{table}.{mat} diverged ({form})")
     np.testing.assert_array_equal(
         np.asarray(got_state["transfers"]["count"]),
         np.asarray(want_state["transfers"]["count"]))

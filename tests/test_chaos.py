@@ -74,7 +74,9 @@ class TestStateDigest:
         rng = random.Random(7)
         for _ in range(20):
             comp = rng.choice(("accounts", "transfers"))
-            mat = pack[comp]["u64"]
+            # The pack is held as the device holds it: u32 halves,
+            # whose u64 view is the digested matrix.
+            mat = pack[comp]["u32"].view(np.uint64)
             covered = [j for j in range(mat.shape[1])
                        if comp == "accounts"
                        or state_epoch.XF_COL_MASKS[j]]
@@ -93,7 +95,7 @@ class TestStateDigest:
         sm = _small_oracle()
         pack = state_epoch.pack_oracle_state(sm, A_CAP)
         base = state_epoch._digest_components(pack, np)
-        mat = pack["transfers"]["u64"]
+        mat = pack["transfers"]["u32"].view(np.uint64)
         mat[0, XF_U64_IDX["expires"]] ^= np.uint64(1 << 17)
         mat[1, XF_P32_POS["dr_row"][0]] ^= np.uint64(1 << 3)
         got = state_epoch._digest_components(pack, np)

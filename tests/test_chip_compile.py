@@ -4,7 +4,11 @@ the 8192-row batch bucket — the chip's compiler asked without the chip.
 
 Nothing here runs on a device: a compile that passes says the chip's
 compiler accepts the program and how much device memory it plans, never
-that results or times are right (chip_smoke.py shows those). Each
+that results or times are right (chip_smoke.py shows those). The plain
+create_transfers tier is also held to what PR 32 was for: the stores
+are updated IN PLACE — the compiler plans no temporary the size of a
+store and no pass over one beside the aliased scatters (`_in_place`).
+Each
 compile costs tens of seconds, so tier 1 keeps three entries (the plain
 create_transfers tier, the scan-form chain window at the replica's
 window depth, create_accounts) and the rest are marked slow:
@@ -24,6 +28,7 @@ import pytest
 
 A_CAP = 1 << 17
 T_CAP = 1 << 21
+T_CAP_FILL = 1 << 22  # chipbench's tb_hbm_fill_1r
 N_PAD = 8192
 WINDOW_DEPTH = 8  # Replica.COMMIT_WINDOW_MAX
 
@@ -71,8 +76,8 @@ def _cases():
     from tigerbeetle_tpu.ops import fast_kernels as fk
     from tigerbeetle_tpu.ops import ledger, warmup
 
-    def batch(n_pad):
-        return lambda s: warmup.batch_args(A_CAP, T_CAP, n_pad, s)
+    def batch(n_pad, t_cap=T_CAP):
+        return lambda s: warmup.batch_args(A_CAP, t_cap, n_pad, s)
 
     gather = jax.jit(ledger._xfer_delta_gather, static_argnums=(3, 4))
 
@@ -110,6 +115,8 @@ def _cases():
                                          N_PAD, s)),
         "xfer_delta_gather@8192": (gather, gather_args(N_PAD)),
         "xfer_delta_gather@65536": (gather, gather_args(8 * N_PAD)),
+        "create_transfers_fast@8192,t_cap=2^22": (
+            fk.create_transfers_fast_jit, batch(N_PAD, T_CAP_FILL)),
     }
 
 
@@ -119,15 +126,66 @@ TIER1 = ("create_transfers_fast@8192", "create_transfers_chain@W8x8192",
 
 def compile_case(name, sharding):
     """Lower + compile one case for the described chip. Returns
-    (seconds, memory_analysis)."""
+    (seconds, memory_analysis, optimised HLO text)."""
     entry, make_args = _cases()[name]
     t0 = time.monotonic()
     compiled = entry.lower(*make_args(sharding)).compile()
-    return time.monotonic() - t0, compiled.memory_analysis()
+    return (time.monotonic() - t0, compiled.memory_analysis(),
+            compiled.as_text())
+
+
+# Cases whose stores must be updated in place, and their t_cap.
+IN_PLACE = {"create_transfers_fast@8192": T_CAP,
+            "create_transfers_fast@8192,t_cap=2^22": T_CAP_FILL}
+TEMP_LIMIT = 256 << 20  # 2,065 MiB before PR 32, 102 MiB with it
+
+
+def store_sized_dims(t_cap):
+    """Every dimension an array the size of a store or a transfer
+    table can have: the row counts (t_cap+1 rows, b+1 buckets) and
+    their products with the column counts, whole and halved."""
+    from tigerbeetle_tpu.ops import ev_layout as L
+    from tigerbeetle_tpu.ops.hash_table import ROW, STRIDE, ht_buckets
+    from tigerbeetle_tpu.ops import warmup
+
+    state = warmup.abstract_state(A_CAP, t_cap)
+    b = ht_buckets(state["xfer_ht"])
+    rows = {state["transfers"]["u32"].shape[0],
+            state["events"]["u32"].shape[0], b + 1, b + 2, (b + 2) // 2}
+    widths = {1, ROW, ROW // 2, STRIDE, STRIDE // 2, L.XF_NCOLS,
+              2 * L.XF_NCOLS, L.EV_NCOLS, 2 * L.EV_NCOLS}
+    return {r * w for r in rows for w in widths}
+
+
+def passes_over_stores(hlo_text, t_cap):
+    """Instructions of the optimised HLO that copy, relayout, split,
+    combine or loop over an array the size of a store: [(op, line)].
+    The aliased scatters (and the fusions they sit in) are the program's
+    work and are not among them."""
+    import re
+
+    dims = store_sized_dims(t_cap)
+    shape = re.compile(r"\w+\[([\d,]+)\]")
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        result, op = m.groups()
+        target = re.search(r'custom_call_target="(X64\w+)"', line)
+        if target:
+            op = target.group(1)
+        if op not in ("while", "copy", "reshape") \
+                and not op.startswith("X64"):
+            continue
+        if any(int(d) in dims for s in shape.findall(result)
+               for d in s.split(",")):
+            found.append((op, line.strip()[:200]))
+    return found
 
 
 def _check(name, one_chip):
-    seconds, mem = compile_case(name, one_chip)
+    seconds, mem, hlo = compile_case(name, one_chip)
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     print(f"{name}: compiled for {one_chip} in {seconds:.1f}s, "
@@ -135,6 +193,10 @@ def _check(name, one_chip):
           f"temps {mem.temp_size_in_bytes >> 20} MiB")
     # One v5e chip holds 16 GB; the donated state is aliased in place.
     assert total < 15 * (1 << 30), (name, total)
+    if name in IN_PLACE:
+        assert mem.temp_size_in_bytes < TEMP_LIMIT, (
+            name, mem.temp_size_in_bytes >> 20)
+        assert passes_over_stores(hlo, IN_PLACE[name]) == []
 
 
 @pytest.mark.parametrize("name", TIER1)
@@ -146,7 +208,8 @@ SLOW = ("create_transfers_fast@1024", "create_transfers_fixpoint@1024",
         "create_transfers_fixpoint@8192",
         "create_transfers_fixpoint_deep@8192",
         "create_transfers_super@K2x8192", "create_transfers_super@K8x8192",
-        "xfer_delta_gather@8192", "xfer_delta_gather@65536")
+        "xfer_delta_gather@8192", "xfer_delta_gather@65536",
+        "create_transfers_fast@8192,t_cap=2^22")
 
 
 @pytest.mark.slow
@@ -157,6 +220,29 @@ def test_served_entry_compiles_for_v5e_slow(name, one_chip):
 
 def test_case_lists_cover_every_case():
     assert sorted(TIER1 + SLOW) == sorted(_cases())
+    assert set(IN_PLACE) <= set(_cases())
+
+
+def test_the_in_place_check_sees_a_pass_over_a_store():
+    """The reader is held to lines of the kind the u64 layout compiled
+    to (PR 32's parent: `X64Combine.58`, `while.6`, `copy.1982`,
+    `reshape.2`), and to what it must let through."""
+    t1 = T_CAP + 1
+    bad = f"""
+  %X64Combine.58 = u64[1048577,24]{{0,1}} custom-call(%a, %b), custom_call_target="X64Combine"
+  %while.6 = (s32[], u32[{t1 * 20}]{{0}}, u32[1,20,{t1}]{{2,1,0}}) while(%tuple.1), condition=%c, body=%b
+  %copy.1982 = u32[24,1048577]{{1,0:T(8,128)}} copy(%x)
+  %reshape.2 = u32[25165848]{{0}} reshape(%y)
+"""
+    assert [op for op, _ in passes_over_stores(bad, T_CAP)] == [
+        "X64Combine", "while", "copy", "reshape"]
+    fine = f"""
+  %fusion.38 = u32[{t1},40]{{0,1:T(8,128)}} fusion(%p, %i, %u), kind=kLoop, calls=%scatter
+  %copy.7 = u32[8192,40]{{1,0}} copy(%z)
+  %X64Combine.3 = u64[131073,8]{{0,1}} custom-call(%a, %b), custom_call_target="X64Combine"
+  %while.1 = (s32[], u32[8192,48]{{1,0}}) while(%tuple.2), condition=%c, body=%b
+"""
+    assert passes_over_stores(fine, T_CAP) == []
 
 
 def test_warm_set_is_among_the_compiled_cases():

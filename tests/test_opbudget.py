@@ -241,10 +241,13 @@ def test_closure_constant_detector():
 
 def test_packed_layout_roundtrip_transfers():
     from tigerbeetle_tpu.ops.ev_layout import (
-        XF_NCOLS, XF_P32_POS, XF_U64_IDX, pack32, xf_col, xf_named)
+        XF_NCOLS, XF_P32_POS, XF_PSTAT_COL32, XF_U64_IDX, narrow, pack32,
+        widen, xf_col, xf_named, xf_rows32)
 
+    # The host writes the packed u64 matrix; the store holds its u32
+    # view (columns 2c / 2c+1 = low / high half of u64 column c).
     m = np.zeros((3, XF_NCOLS), dtype=np.uint64)
-    m[:, XF_U64_IDX["ts"]] = [7, 8, 9]
+    m[:, XF_U64_IDX["ts"]] = [7, 8, (9 << 32) | 1]
     # ud32 above 2^31 (sign-sensitive), pstat/dr_row as i32 views.
     col, half = XF_P32_POS["ud32"]
     m[:, col] |= np.uint64(0xDEADBEEF) << np.uint64(32 * half)
@@ -252,14 +255,27 @@ def test_packed_layout_roundtrip_transfers():
     m[:, col] |= np.uint64(17) << np.uint64(32 * half)
     col, half = XF_P32_POS["pstat"]
     m[:, col] |= np.uint64(2) << np.uint64(32 * half)
-    xfr = {"u64": m}
+    xfr = {"u32": narrow(m)}
+    assert xfr["u32"].dtype == np.uint32
+    assert xfr["u32"].shape == (3, 2 * XF_NCOLS)
+    assert (widen(xfr["u32"]) == m).all()
+    assert (xfr["u32"][:, XF_PSTAT_COL32] == 2).all()
     assert list(xf_col(xfr, "ud32")) == [0xDEADBEEF] * 3
     assert xf_col(xfr, "ud32").dtype == np.uint32
     assert list(xf_col(xfr, "timeout")) == [17] * 3
     named = xf_named(xfr)
     assert named["pstat"].dtype == np.int32
     assert list(named["pstat"]) == [2, 2, 2]
-    assert list(named["ts"]) == [7, 8, 9]
+    assert list(named["ts"]) == [7, 8, (9 << 32) | 1]
+    # The device path (arithmetic, no view) agrees with the host view,
+    # both ways, and rows built from named columns are the same bytes.
+    dev = {"u32": jnp.asarray(xfr["u32"])}
+    for k, v in xf_named(dev).items():
+        assert v.dtype == named[k].dtype, k
+        assert (np.asarray(v) == named[k]).all(), k
+    assert (np.asarray(widen(dev["u32"])) == m).all()
+    assert (np.asarray(narrow(jnp.asarray(m))) == xfr["u32"]).all()
+    assert (np.asarray(xf_rows32(xf_named(dev))) == xfr["u32"]).all()
     # pack32 zero-extends signed inputs (no sign smear into the partner).
     w = pack32(np.array([-1], dtype=np.int32),
                np.array([5], dtype=np.int32))
@@ -282,18 +298,25 @@ def test_packed_layout_roundtrip_events_negative_p_row():
 
 def test_packed_layout_accounts_flags_isolated_from_code():
     from tigerbeetle_tpu.ops.ev_layout import (
-        AC_NCOLS, AC_P32_POS, ac_named, pack32)
+        AC_FLAGS_COL32, AC_NCOLS, AC_P32_POS, ac_named, ac_rows32, narrow,
+        pack32)
 
     m = np.zeros((2, AC_NCOLS), dtype=np.uint64)
     col, _ = AC_P32_POS["code"]
     assert AC_P32_POS["flags"][0] == col, \
-        "flags must share its packed column with code only (the " \
-        "closing-native RMW write-back preserves exactly that half)"
+        "flags shares its packed u64 word with code (the durable row " \
+        "format); in the store it is a u32 column of its own"
     m[:, col] = pack32(np.array([77, 78], dtype=np.uint32),
                        np.array([0x10, 0x20], dtype=np.uint32))
-    named = ac_named({"u64": m})
+    bal = np.arange(32, dtype=np.uint64).reshape(2, 16)
+    rows = {"u32": narrow(m), "bal": narrow(bal)}
+    assert list(rows["u32"][:, AC_FLAGS_COL32]) == [0x10, 0x20]
+    named = ac_named(rows)
     assert list(named["code"]) == [77, 78]
     assert list(named["flags"]) == [0x10, 0x20]
+    assert (named["bal"] == bal).all()  # widened to its u64 view
+    dev = ac_named({"u32": jnp.asarray(rows["u32"])})
+    assert (np.asarray(ac_rows32(dev)) == rows["u32"]).all()
 
 
 # ------------------------------------------------- committed budgets

@@ -58,7 +58,7 @@ def test_fused_probe_matches_xla_lookup():
 def test_vmem_gate():
     small = HT.ht_init(1 << 12)
     assert probe_fusable(small)
-    huge = HT.ht_init(1 << 21)  # (2^18+1, 24) u64 ≈ 50 MB
+    huge = HT.ht_init(1 << 21)  # (2^18+1) * 48 u32 ≈ 50 MB
     assert not probe_fusable(huge)
 
 

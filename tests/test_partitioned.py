@@ -24,7 +24,8 @@ from jax.sharding import Mesh
 
 from tigerbeetle_tpu.oracle import StateMachineOracle
 from tigerbeetle_tpu.ops.batch import transfers_to_arrays
-from tigerbeetle_tpu.ops.ev_layout import EV_P32_POS, XF_NCOLS, XF_P32_POS
+from tigerbeetle_tpu.ops.ev_layout import (
+    EV_P32_POS, XF_NCOLS, XF_P32_POS, widen)
 from tigerbeetle_tpu.ops.ledger import (
     DeviceLedger, _delta_gather_body, _pad_bucket, pad_transfer_events)
 from tigerbeetle_tpu.ops.state_epoch import (
@@ -121,6 +122,10 @@ class Harness:
         # p_ts is only defined on ring rows referencing a pending
         # (p_row >= 0); elsewhere the gather reads row 0 of whichever
         # scope — not a canonical value.
+        flush = dict(flush, t={"u64": widen(flush["t"]["u32"])},
+                     e={"u64": widen(flush["e"]["u32"])})
+        ref = dict(ref, t={"u64": widen(ref["t"]["u32"])},
+                   e={"u64": widen(ref["e"]["u32"])})
         prow_hi = (ref["e"]["u64"][:c, _EV_PROW_COL]
                    >> np.uint64(32)).astype(np.uint32)
         has_p = prow_hi != np.uint32(0xFFFFFFFF)

@@ -74,9 +74,9 @@ def _ht_content(table):
     plan time, and the superbatch plans the whole window against the
     pre-window table) — but the mapping, hence every lookup and every
     derived result, must be identical."""
-    from tigerbeetle_tpu.ops.hash_table import SLOTS
+    from tigerbeetle_tpu.ops.hash_table import SLOTS, ht_matrix
 
-    p = np.asarray(table["packed"])[:-1]
+    p = ht_matrix(table)[:-1]
     kh = p[:, :SLOTS].reshape(-1)
     kl = p[:, SLOTS:2 * SLOTS].reshape(-1)
     v = p[:, 2 * SLOTS:].reshape(-1)

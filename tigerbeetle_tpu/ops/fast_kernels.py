@@ -48,20 +48,23 @@ import numpy as np
 from ..constants import NS_PER_S, U63_MAX
 from . import u128
 from .ev_layout import (
-    AC_P32,
+    AC_FLAGS_COL32,
     AC_P32_POS,
-    AC_U64,
     AC_U64_IDX,
     BAL_IDX,
-    EV_P32,
-    EV_U64,
-    XF_P32,
-    XF_P32_POS,
-    XF_U64,
-    XF_U64_IDX,
+    XF_PSTAT_COL32,
+    ac_col,
+    ac_rows32,
+    col64,
     ev_cap,
+    ev_rows32,
+    narrow,
     pack32,
+    widen,
+    with_col32,
+    xf_col,
     xf_named,
+    xf_rows32,
 )
 from .create_kernels import (
     _A_CLOSED,
@@ -350,6 +353,42 @@ def _chain_pass(status, linked, valid, idxs, n, N, seg_start=None,
 
 # ================================================== create_transfers (fast)
 
+def _worst_case_loads(ral, dr_rowc, cr_rowc, A_rows):
+    """Per account row, the sum of the batch's amount limbs `ral`
+    ((N, 4) u64, u32-normalized) against it as debit side and as credit
+    side: ([4 x u64[A_rows]], [4 x u64[A_rows]]), limb sums not carried.
+
+    ONE segment-sum, accumulating in u32 over a FLAT segment space, as a
+    v5e wants it (PERF.md §6, PR 32): the chip has no 64-bit lanes, so a
+    u64 scatter-add is two with carries, and a (2 * A_rows, 4) result is
+    laid out with its four limbs padded to 128 lanes, 128 MB a half.
+    Each 32-bit limb goes in as pieces of w bits, w such that 2N pieces
+    cannot overflow a u32; piece q of side s of account r sums at
+    (2q + s) * A_rows + r, and the pieces recombine exactly."""
+    N = ral.shape[0]
+    w = min(16, 32 - (2 * N - 1).bit_length())
+    per_limb = -(-32 // w)
+    pieces = jnp.stack([
+        ((ral[:, j] >> jnp.uint64(k * w))
+         & jnp.uint64((1 << w) - 1)).astype(jnp.uint32)
+        for j in range(4) for k in range(per_limb)])
+    rows2 = jnp.concatenate([dr_rowc, cr_rowc + jnp.int32(A_rows)])
+    seg = (jnp.arange(4 * per_limb, dtype=jnp.int32)[:, None]
+           * jnp.int32(2 * A_rows) + rows2[None, :])
+    sums = jax.ops.segment_sum(
+        jnp.concatenate([pieces, pieces], axis=1).reshape(-1),
+        seg.reshape(-1), num_segments=8 * per_limb * A_rows)
+
+    def piece(q, side):
+        return jax.lax.dynamic_slice_in_dim(
+            sums, (2 * q + side) * A_rows, A_rows).astype(jnp.uint64)
+
+    return tuple(
+        [sum(piece(j * per_limb + k, side) << jnp.uint64(k * w)
+             for k in range(per_limb)) for j in range(4)]
+        for side in (0, 1))
+
+
 # Packed 32-bit account meta positions (ev_layout.AC_P32): ledger is
 # the high half of the (ud32|ledger) column, code/flags the halves of
 # the next one.
@@ -381,18 +420,19 @@ def _acct_unpack(g_bal, g64, found):
 
 def _acct_gather(acc, rows, found):
     """Gather the account fields the kernel needs at `rows` (clamped):
-    TWO row gathers total (balance limbs + the packed u64 matrix whose
-    tail columns carry the 32-bit meta)."""
-    return _acct_unpack(acc["bal"][rows], acc["u64"][rows], found)
+    TWO row gathers total (balance limbs + the meta matrix), widened
+    to u64 words after the gather."""
+    return _acct_unpack(widen(acc["bal"][rows]), widen(acc["u32"][rows]),
+                        found)
 
 
 def _acct_gather_multi(acc, rows_list, found_list):
     """K account-role gathers as TWO matrix gathers over the
-    concatenated row set (per-dispatch overhead dominates on TPU: 2K
-    gathers -> 2). Returns one named dict per role."""
+    concatenated row set (2K gathers -> 2). Returns one named dict per
+    role."""
     rows = jnp.concatenate(rows_list)
-    g_bal = acc["bal"][rows]
-    g64 = acc["u64"][rows]
+    g_bal = widen(acc["bal"][rows])
+    g64 = widen(acc["u32"][rows])
     outs = []
     off = 0
     for r, found in zip(rows_list, found_list):
@@ -404,21 +444,20 @@ def _acct_gather_multi(acc, rows_list, found_list):
 
 
 def _xfer_gather(xfr, rows):
-    """Row gather of the packed transfers store: ONE matrix gather (the
-    32-bit columns ride pair-packed in the u64 tail), returned as a
-    named column dict."""
-    return xf_named({"u64": xfr["u64"][rows]})
+    """Row gather of the packed transfers store: ONE matrix gather of
+    u32 rows, widened to named columns after the gather."""
+    return xf_named({"u32": xfr["u32"][rows]})
 
 
 def _xfer_gather_multi(xfr, rows_list):
     """K transfer-role gathers as ONE concatenated matrix gather."""
     rows = jnp.concatenate(rows_list)
-    g64 = xfr["u64"][rows]
+    g32 = xfr["u32"][rows]
     outs = []
     off = 0
     for r in rows_list:
         n = r.shape[0]
-        outs.append(xf_named({"u64": g64[off:off + n]}))
+        outs.append(xf_named({"u32": g32[off:off + n]}))
         off += n
     return outs
 
@@ -603,10 +642,9 @@ def imported_batch_ctx(state, ev, ts_event, valid, idxs, seg_start=None):
     # which degrades every later dispatch in the process to 5-8 ms
     # (PERF.md round-2 finding; jaxhound's serving-path lint enforces
     # while-free lowerings).
-    au = acc["u64"]
     acct_ts_sorted = jnp.where(
-        jnp.arange(au.shape[0], dtype=jnp.int32) < acc["count"],
-        au[:, AC_U64_IDX["ts"]], jnp.uint64(0xFFFFFFFFFFFFFFFF))
+        jnp.arange(acc["u32"].shape[0], dtype=jnp.int32) < acc["count"],
+        ac_col(acc, "ts"), jnp.uint64(0xFFFFFFFFFFFFFFFF))
     pos = jnp.searchsorted(acct_ts_sorted, ev["ts"], method="sort")
     pos = jnp.minimum(pos, acct_ts_sorted.shape[0] - 1)
     coll = imp_lane & (acct_ts_sorted[pos] == ev["ts"]) \
@@ -651,8 +689,8 @@ def per_event_status(state, ev, ts_event, return_gathers=False,
 
     acc = state["accounts"]
     xfr = state["transfers"]
-    A_dump = acc["u64"].shape[0] - 1
-    T_dump = xfr["u64"].shape[0] - 1
+    A_dump = acc["u32"].shape[0] - 1
+    T_dump = xfr["u32"].shape[0] - 1
     # Note: statuses returned here are NOT valid-masked — the tail in
     # create_transfers_fast applies the valid mask after chain handling.
 
@@ -958,8 +996,8 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
     acc = state["accounts"]
     xfr = state["transfers"]
     N = ev["id_lo"].shape[0]
-    A_dump = acc["u64"].shape[0] - 1
-    T_dump = xfr["u64"].shape[0] - 1
+    A_dump = acc["u32"].shape[0] - 1
+    T_dump = xfr["u32"].shape[0] - 1
     idxs = jnp.arange(N, dtype=jnp.int32)
     valid = ev["valid"]
     if seg is None:
@@ -1213,14 +1251,13 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
     # fail the limit in any prefix, so parallel == sequential. Only a
     # potential breach falls back to the exact path.
     reg = opt & ~pv
-    A_rows = acc["u64"].shape[0]
+    A_rows = acc["u32"].shape[0]
     z64 = jnp.uint64(0)
     ral0, ral1, ral2, ral3 = _to_limbs(
         jnp.where(reg, amt_res_hi, z64), jnp.where(reg, amt_res_lo, z64))
     ral = jnp.stack([ral0, ral1, ral2, ral3], axis=1)  # (N, 4)
 
-    aflags_full = (acc["u64"][:, _AC_CF_COL]
-                   >> jnp.uint64(32)).astype(jnp.uint32)
+    aflags_full = ac_col(acc, "flags")
     # The dump row (last) is scratch: failed creates scatter raw flags
     # there and masked transfers scatter-add amounts into its balances —
     # it must never latch a breach. A static iota mask, not a one-slot
@@ -1233,9 +1270,12 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         # (each limb sum < 2^46: no u64 overflow before normalize).
         # Returns the per-account breach VECTOR; both sides reduce in
         # one stacked any below.
-        balm = acc["bal"]
+        # Whole limb COLUMNS of the account store (a_cap rows).
+        def balm(col):
+            return col64(acc["bal"], col)
+
         h1, h2, ag = BAL_IDX[held1], BAL_IDX[held2], BAL_IDX[against1]
-        lft = [balm[:, h1 + j] + balm[:, h2 + j] + load[j]
+        lft = [balm(h1 + j) + balm(h2 + j) + load[j]
                for j in range(4)]
         c = lft[0] >> jnp.uint64(32); f0 = lft[0] & _M32
         lft[1] = lft[1] + c
@@ -1246,8 +1286,8 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         l4 = lft[3] >> jnp.uint64(32); f3 = lft[3] & _M32
         left_hi = f2 | (f3 << jnp.uint64(32))
         left_lo = f0 | (f1 << jnp.uint64(32))
-        right_hi = balm[:, ag + 2] | (balm[:, ag + 3] << jnp.uint64(32))
-        right_lo = balm[:, ag] | (balm[:, ag + 1] << jnp.uint64(32))
+        right_hi = balm(ag + 2) | (balm(ag + 3) << jnp.uint64(32))
+        right_lo = balm(ag) | (balm(ag + 1) << jnp.uint64(32))
         limited = _flag(aflags_full, limit_bit) & not_dump
         over = (l4 > 0) | u128.lt(right_hi, right_lo, left_hi, left_lo)
         return limited & over
@@ -1261,17 +1301,12 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         # limit_rounds > 1).
         e3 = jnp.bool_(False)
     else:
-        # ONE segment-sum covers BOTH sides' worst-case loads (credit
-        # rows offset by A_rows) and ONE stacked any reduces both
-        # breach vectors.
-        rows2l = jnp.concatenate([dr_rowc, cr_rowc + jnp.int32(A_rows)])
-        s2 = jax.ops.segment_sum(jnp.concatenate([ral, ral]), rows2l,
-                                 num_segments=2 * A_rows)
+        # ONE segment-sum covers BOTH sides' worst-case loads and ONE
+        # stacked any reduces both breach vectors.
+        load = _worst_case_loads(ral, dr_rowc, cr_rowc, A_rows)
         e3 = jnp.any(jnp.stack([
-            _breach([s2[:A_rows, j] for j in range(4)],
-                    "dp", "dpos", "cpos", _A_DR_LIMIT),
-            _breach([s2[A_rows:, j] for j in range(4)],
-                    "cp", "cpos", "dpos", _A_CR_LIMIT)]))
+            _breach(load[0], "dp", "dpos", "cpos", _A_DR_LIMIT),
+            _breach(load[1], "cp", "cpos", "dpos", _A_CR_LIMIT)]))
     # The headroom-proof outcome, preserved across the fixpoint override
     # below: the adaptive router drops back to the proof-gated kernel only
     # once the PROOF would pass (dropping back on "no actual breach" would
@@ -1373,7 +1408,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
             fstart, jnp.arange(2 * N, dtype=jnp.int32), jnp.int32(-1)))
         finv = jnp.zeros(2 * N, dtype=jnp.int32).at[fperm].set(
             jnp.arange(2 * N, dtype=jnp.int32))
-        fbase = acc["bal"][frows_sorted].T.reshape(4, 4, 2 * N)
+        fbase = widen(acc["bal"][frows_sorted]).T.reshape(4, 4, 2 * N)
         cand_dr = (valid & ~pv & _flag(dr["flags"], _A_DR_LIMIT)
                    & (status == _CREATED))
         cand_cr = (valid & ~pv & _flag(cr["flags"], _A_CR_LIMIT)
@@ -1420,11 +1455,12 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
                 (~pv & ((status == _CREATED)
                         | (status == _TS["overflows_timeout"])))
                 | (pv & is_post & (status == _CREATED)))
-            # One gather of the packed (code|flags) column serves the
-            # round-0 closed view AND the application stage's flag
-            # write-back (which must preserve the code half).
-            cf_s = acc["u64"][frows_sorted, _AC_CF_COL]
-            base_flags_s = (cf_s >> jnp.uint64(32)).astype(jnp.uint32)
+            # One gather of the meta ROWS serves the round-0 closed
+            # view AND the application stage's flag write-back, which
+            # rewrites the whole row (no element scatter into a 2-D
+            # store: ev_layout).
+            meta_s = acc["u32"][frows_sorted]
+            base_flags_s = meta_s[:, AC_FLAGS_COL32]
             init_closed_s = _flag(base_flags_s, _A_CLOSED)
             idx2 = jnp.arange(2 * N, dtype=jnp.int32)
             # Round 0: pre-batch closed flags (the per-event gathers).
@@ -1874,19 +1910,6 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
 
     # Insert created transfer rows (compacted).
     trow = jnp.where(ap, new_rows, T_dump)
-    # Pending-status flips on committed pendings (E2 guarantees unique
-    # rows; masked lanes write a uniform 0 to the dump slot so the
-    # duplicate-index scatter stays deterministic). An in-window use
-    # flips the row its definition is inserting IN THIS DISPATCH —
-    # trow[didx] — so the flip scatter must run AFTER the row insert
-    # (below), or the insert would overwrite the flip with PENDING.
-    if limit_rounds > 1 and not imported_mode:
-        flip_row = jnp.where(inwin, trow[didx], p_rowc)
-    else:
-        # inwin is statically all-False on these tiers: skip the
-        # def-side gather entirely (op budget).
-        flip_row = p_rowc
-    flip_pos = jnp.where(ap_pv, flip_row, T_dump)
     ud128z = u128.is_zero(ev["ud128_hi"], ev["ud128_lo"])
     stores = dict(
         id_hi=ev["id_hi"], id_lo=ev["id_lo"],
@@ -1913,26 +1936,39 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         dr_row=jnp.where(pv, p["dr_row"], dr_rowc),
         cr_row=jnp.where(pv, p["cr_row"], cr_rowc),
     )
-    # Packed row insert: ONE row scatter (the 32-bit columns ride
-    # pair-packed in the u64 tail — ev_layout.XF_P32). Masked lanes
-    # write uniform zero rows to the dump slot (duplicate-index scatters
-    # stay deterministic only if every duplicate writes one value). The
-    # pstat flip is a second scatter into pstat's OWN packed column
-    # (sequenced after the insert — see flip_pos above).
-    u64_rows = jnp.stack(
-        [stores[n] for n in XF_U64]
-        + [pack32(stores[pr[0]],
-                  stores[pr[1]] if len(pr) > 1 else None)
-           for pr in XF_P32],
-        axis=1)
-    apn = ap[:, None]
-    u64_inserted = xfr["u64"].at[trow].set(
-        jnp.where(apn, u64_rows, jnp.uint64(0)))
+    # Packed row insert: ONE row scatter of u32 rows (ev_layout).
+    # Masked lanes write uniform zero rows to the dump slot
+    # (duplicate-index scatters stay deterministic only if every
+    # duplicate writes one value).
+    rows32 = xf_rows32(stores)
+    # Pending-status flips on committed pendings: a SECOND ROW scatter
+    # that rewrites the whole pending row, as gathered, with its pstat
+    # flipped (E2 guarantees unique rows; masked lanes write zero rows
+    # to the dump slot). An element scatter into the one column would
+    # make XLA:TPU relayout the whole store around it (PERF.md §6,
+    # PR 32). An in-window use flips the row its definition is
+    # inserting IN THIS DISPATCH — at trow[didx] — so the flip runs
+    # AFTER the insert (below) and rewrites the inserted row.
+    flip_rows32 = xf_rows32(p)
+    if limit_rounds > 1 and not imported_mode:
+        # The definition's inserted row and where it goes: ONE gather.
+        d = jnp.concatenate(
+            [rows32, trow[:, None].astype(jnp.uint32)], axis=1)[didx]
+        flip_row = jnp.where(inwin, d[:, -1].astype(jnp.int32), p_rowc)
+        flip_rows32 = jnp.where(inwin[:, None], d[:, :-1], flip_rows32)
+    else:
+        # inwin is statically all-False on these tiers: skip the
+        # def-side gather entirely (op budget).
+        flip_row = p_rowc
+    flip_pos = jnp.where(ap_pv, flip_row, T_dump)
+    flip_rows32 = with_col32(
+        flip_rows32, XF_PSTAT_COL32,
+        jnp.where(is_post, _PS_POSTED, _PS_VOIDED))
+    inserted = xfr["u32"].at[trow].set(
+        jnp.where(ap[:, None], rows32, jnp.uint32(0)))
     new_xfr = {
-        "u64": u64_inserted.at[flip_pos, XF_P32_POS["pstat"][0]].set(
-            pack32(jnp.where(ap_pv,
-                             jnp.where(is_post, _PS_POSTED, _PS_VOIDED),
-                             jnp.int32(0)))),
+        "u32": inserted.at[flip_pos].set(
+            jnp.where(ap_pv[:, None], flip_rows32, jnp.uint32(0))),
         "count": xfr["count"] + jnp.where(ok, n_created, 0),
     }
 
@@ -2014,7 +2050,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         ]
         rows2 = jnp.concatenate(side_rows)  # 2N: dr sides then cr sides
         order2 = jnp.concatenate([idxs, idxs])
-        perm = _packed_perm(rows2, order2, acc["u64"].shape[0])
+        perm = _packed_perm(rows2, order2, acc["u32"].shape[0])
         rows_sorted = rows2[perm]
         is_start = jnp.concatenate([
             jnp.ones(1, dtype=jnp.bool_),
@@ -2029,7 +2065,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         # Packed-balance base: one row gather, reshaped to
         # [field][limb][entry] (column = field * 4 + limb, matching the
         # `fields` order).
-        base = acc["bal"][rows_sorted].T.reshape(4, 4, 2 * N)
+        base = widen(acc["bal"][rows_sorted]).T.reshape(4, 4, 2 * N)
         # Stacked (4 fields, 4 limbs, 2N): ONE sort-gather, ONE cumsum,
         # ONE segment-offset gather, ONE base add — not 16 scalar-lane
         # pipelines. The permute runs on u32 lanes (all delta limbs are
@@ -2058,7 +2094,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
     vals = jnp.stack([l0, l1, l2, l3], axis=1).reshape(16, 2 * N).T
     new_acc = dict(acc)
     new_acc["bal"] = acc["bal"].at[tgt].set(
-        jnp.where(real[:, None], vals, jnp.uint64(0)))
+        narrow(jnp.where(real[:, None], vals, jnp.uint64(0))))
     # Snapshot rows back to entry order: ONE stacked take for the hi and
     # lo halves together (op budget).
     hilo_all = jnp.take(jnp.concatenate([hi_sorted, lo_sorted]),
@@ -2094,19 +2130,19 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
                                   init_closed_s)
         # Post-batch flag word per account: last entry of each real
         # segment; only segments that carried an op write (untouched
-        # accounts keep their word byte-identical). The write-back RMWs
-        # the packed (code|flags) column gathered once in the fixpoint
-        # setup (cf_s), preserving the code half.
+        # accounts keep their word byte-identical). The write-back
+        # rewrites the meta rows gathered once in the fixpoint setup
+        # (meta_s) with the flags column replaced.
         seg_has_op = jax.ops.segment_max(
             op_pos2, seg_id, num_segments=2 * N)[seg_id] >= 0
         wrf = real & seg_has_op
         new_word = jnp.where(closed_incl_s, base_flags_s | cl_u,
                              base_flags_s & ~cl_u)
-        new_word64 = ((cf_s & _M32)
-                      | (new_word.astype(jnp.uint64) << jnp.uint64(32)))
-        new_acc["u64"] = acc["u64"].at[
-            jnp.where(wrf, rows_sorted, A_dump), _AC_CF_COL].set(
-            jnp.where(wrf, new_word64, jnp.uint64(0)))
+        new_acc["u32"] = acc["u32"].at[
+            jnp.where(wrf, rows_sorted, A_dump)].set(jnp.where(
+                wrf[:, None],
+                with_col32(meta_s, AC_FLAGS_COL32, new_word),
+                jnp.uint32(0)))
         closed_incl = closed_incl_s[inv]
         eff_dr_flags = jnp.where(closed_incl[:N], eff_dr_flags | cl_u,
                                  eff_dr_flags & ~cl_u)
@@ -2137,18 +2173,12 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
             hi_arr, lo_arr = snap[f"{sside}_{field}"]
             stores_ev[f"{sside}_{field}_hi"] = hi_arr
             stores_ev[f"{sside}_{field}_lo"] = lo_arr
-    # Packed ring append: ONE row scatter (44 logical columns -> 1; the
-    # 32-bit columns ride pair-packed in the u64 tail, ev_layout.EV_P32);
-    # masked lanes write uniform zero rows to the dump slot (determinism).
-    ev_u64_rows = jnp.stack(
-        [stores_ev[n] for n in EV_U64]
-        + [pack32(stores_ev[pr[0]],
-                  stores_ev[pr[1]] if len(pr) > 1 else None)
-           for pr in EV_P32],
-        axis=1)
+    # Packed ring append: ONE row scatter of u32 rows (44 logical
+    # columns -> 1, ev_layout); masked lanes write uniform zero rows to
+    # the dump slot (determinism).
     new_evr = {
-        "u64": evr["u64"].at[erow].set(jnp.where(
-            ap[:, None], ev_u64_rows, jnp.uint64(0))),
+        "u32": evr["u32"].at[erow].set(jnp.where(
+            ap[:, None], ev_rows32(stores_ev), jnp.uint32(0))),
         "count": jnp.where(ok, ring_base + n_created, evr["count"]),
     }
 
@@ -2531,7 +2561,7 @@ def create_accounts_fast(state, ev, timestamp, n, imported_mode=False):
     from .hash_table import ht_lookup, ht_plan, ht_write
 
     acc = state["accounts"]
-    A_dump = acc["u64"].shape[0] - 1
+    A_dump = acc["u32"].shape[0] - 1
     N = ev["id_lo"].shape[0]
     idxs = jnp.arange(N, dtype=jnp.int32)
     valid = ev["valid"]
@@ -2553,9 +2583,9 @@ def create_accounts_fast(state, ev, timestamp, n, imported_mode=False):
     e2 = _dup_keys(ev["id_hi"], ev["id_lo"], tag)
     fallback_pre = e1 | e2
 
-    # ONE meta gather: the 32-bit fields unpack from the u64 tail
-    # columns (ev_layout.AC_P32).
-    g64 = acc["u64"][e_rowc]
+    # ONE meta gather, widened to its u64 words after the gather (the
+    # 32-bit fields unpack from the tail words, ev_layout.AC_P32).
+    g64 = widen(acc["u32"][e_rowc])
     AU = AC_U64_IDX
     g_ul = g64[:, _AC_UL_COL]
     g_cf = g64[:, _AC_CF_COL]
@@ -2604,11 +2634,11 @@ def create_accounts_fast(state, ev, timestamp, n, imported_mode=False):
         # lowering) is gone.
         # method='sort', not the while-lowering default (see
         # imported_batch_ctx).
-        xu = state["transfers"]["u64"]
+        xfr = state["transfers"]
         xfer_ts_sorted = jnp.where(
-            jnp.arange(xu.shape[0], dtype=jnp.int32)
-            < state["transfers"]["count"],
-            xu[:, XF_U64_IDX["ts"]], jnp.uint64(0xFFFFFFFFFFFFFFFF))
+            jnp.arange(xfr["u32"].shape[0], dtype=jnp.int32)
+            < xfr["count"],
+            xf_col(xfr, "ts"), jnp.uint64(0xFFFFFFFFFFFFFFFF))
         pos = jnp.minimum(
             jnp.searchsorted(xfer_ts_sorted, ev["ts"], method="sort"),
             xfer_ts_sorted.shape[0] - 1)
@@ -2699,8 +2729,8 @@ def create_accounts_fast(state, ev, timestamp, n, imported_mode=False):
     arow = jnp.where(ap, new_rows, A_dump)
 
     z64 = jnp.uint64(0)
-    # Packed row insert: ONE meta scatter (32-bit fields pair-packed in
-    # the u64 tail — ev_layout.AC_P32) + the balance-zero scatter;
+    # Packed row insert: ONE meta scatter of u32 rows (ev_layout) + the
+    # balance-zero scatter;
     # masked lanes write uniform zero rows to the dump slot (scatter
     # determinism). Stored timestamp: the ACTUAL one (imported created
     # accounts keep their user timestamp; == ts_event otherwise).
@@ -2710,18 +2740,11 @@ def create_accounts_fast(state, ev, timestamp, n, imported_mode=False):
                   "ud64": ev["ud64"], "ts": ts_store,
                   "ud32": ev["ud32"], "ledger": ev["ledger"],
                   "code": ev["code"], "flags": flags}
-    u64_rows_a = jnp.stack(
-        [named_vals[n] for n in AC_U64]
-        + [pack32(named_vals[pr[0]],
-                  named_vals[pr[1]] if len(pr) > 1 else None)
-           for pr in AC_P32],
-        axis=1)
-    apn = ap[:, None]
     new_acc = dict(acc)
-    new_acc["u64"] = acc["u64"].at[arow].set(
-        jnp.where(apn, u64_rows_a, z64))
+    new_acc["u32"] = acc["u32"].at[arow].set(
+        jnp.where(ap[:, None], ac_rows32(named_vals), jnp.uint32(0)))
     new_acc["bal"] = acc["bal"].at[arow].set(
-        jnp.zeros((N, 16), dtype=jnp.uint64))
+        jnp.zeros((N, acc["bal"].shape[1]), dtype=jnp.uint32))
     new_acc["count"] = acc["count"] + jnp.where(ok, n_created, 0)
 
     new_ht = ht_write(

@@ -11,8 +11,9 @@ count depends on the data, which a TPU serializes and which the op-budget
 lints forbid on the serving path. Two-choice bucketed hashing bounds every
 lookup to exactly two bucket gathers — straight-line data flow.
 
-Layout: arrays shaped (B+1, S) with S = 8 slots per bucket; bucket B is a
-write-dump scratch row so masked-out scatter lanes never alias a live slot.
+Layout: B+1 buckets of S = 8 slots, held as ONE FLAT u32 array, STRIDE
+words a bucket (see ht_init); bucket B is a write-dump scratch row so
+masked-out scatter lanes never alias a live slot.
 Key 0 is the empty sentinel — valid object ids are never 0
 (id_must_not_be_zero precedes every insert). A key lives in one of two
 buckets chosen by independent hashes; inserts fill buckets as prefix of the
@@ -34,7 +35,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .ev_layout import narrow, widen
+
 SLOTS = 8
+# u32 words of one bucket row: (key_hi | key_lo | val) groups of SLOTS
+# u64 words, each as its (low, high) halves.
+ROW = 3 * SLOTS * 2
+# A bucket starts every STRIDE words, so that two buckets fill one
+# 128-lane row of the chip's memory exactly; the words past ROW stay 0.
+LANES = 128
+STRIDE = LANES // 2
 
 # Sentinel value for orphaned (transiently-failed) transfer ids stored
 # inline in the transfer table: the id sets are disjoint forever
@@ -52,21 +62,72 @@ def ht_init(cap: int) -> dict:
     """cap must be a power of two >= 2*SLOTS, sized >= 2x expected live
     keys; B = cap // SLOTS buckets of SLOTS slots (+ one dump bucket).
 
-    Layout: ONE u64 matrix of (key_hi | key_lo | val) column groups —
-    a bucket probe is a single row gather instead of three (per-dispatch
-    overhead dominates the serving path on TPU; see the cost model in
-    ARCHITECTURE.md)."""
+    Layout: ONE FLAT u32 array. Bucket r is words [r*STRIDE,
+    r*STRIDE + ROW): the (key_hi | key_lo | val) groups of SLOTS u64
+    words each, every word as its (low, high) u32 halves; `ht_matrix`
+    is the host's view of it, the (b+1, 3*SLOTS) u64 matrix. An even
+    number of buckets is allocated (b+1 and one never used), so the
+    array is whole 128-lane rows.
+    Flat, u32 and lane-aligned because of what a v5e does with the
+    alternatives (PR 32, PERF.md §6): a u64 table is split and
+    recombined whole around every program that takes it; an element
+    scatter into a 2-D table, or a reshape of a (b+1, ROW) table to
+    1-D, relayouts the whole table. An element scatter into a 1-D u32
+    array runs in place, and reshaping a flat array to rows of exactly
+    128 lanes moves nothing (the two are the same bytes in the chip's
+    tiled memory), so a probe stays ONE row gather of 512 contiguous
+    bytes a lane."""
     assert cap & (cap - 1) == 0 and cap >= 2 * SLOTS
     b = cap // SLOTS
     return dict(
-        packed=jnp.zeros((b + 1, 3 * SLOTS), dtype=jnp.uint64),
+        packed=jnp.zeros(((b + 2) * STRIDE,), dtype=jnp.uint32),
     )
 
 
+def ht_buckets(table: dict) -> int:
+    """b: live buckets (the dump bucket b and the spare excluded). The
+    word axis is the last one (a partitioned state stacks shards in
+    front)."""
+    return table["packed"].shape[-1] // STRIDE - 2
+
+
 def ht_cap(table: dict) -> int:
-    return (table["packed"].shape[0] - 1) * SLOTS
+    return ht_buckets(table) * SLOTS
 
 
+def ht_matrix(table: dict) -> np.ndarray:
+    """Host view of a table: the (b+1, 3*SLOTS) u64 matrix of
+    (key_hi | key_lo | val) column groups, one bucket a row."""
+    flat = np.ascontiguousarray(np.asarray(table["packed"]))
+    return flat.view(np.uint64).reshape(-1, STRIDE // 2)[:-1, :3 * SLOTS]
+
+
+def ht_unpack(packed):
+    """Device counterpart of `ht_matrix` over ONE table's flat array
+    (the spare bucket's row included): a pass over the whole table, for
+    control-plane kernels (resharding) only — a serving program probes
+    with `ht_rows`."""
+    return widen(packed.reshape(-1, STRIDE)[:, :ROW])
+
+
+def ht_pack(matrix):
+    """Inverse of `ht_unpack`."""
+    return jnp.pad(narrow(matrix), ((0, 0), (0, STRIDE - ROW))).reshape(-1)
+
+
+def bucket_rows(lanes, buckets):
+    """The buckets' rows, (N, 3*SLOTS) u64, out of the table seen as
+    128-lane rows (`packed.reshape(-1, LANES)`, two buckets a row): ONE
+    row gather, then the bucket's half of each gathered row, widened."""
+    g = lanes[buckets >> 1]
+    g = jnp.where((buckets & 1).astype(jnp.bool_)[:, None],
+                  g[:, STRIDE:], g[:, :STRIDE])
+    return widen(g[:, :ROW])
+
+
+def ht_rows(table: dict, buckets):
+    """Gather bucket rows: (N, 3*SLOTS) u64, ONE row gather."""
+    return bucket_rows(table["packed"].reshape(-1, LANES), buckets)
 
 
 def _buckets(k_hi, k_lo, b: int):
@@ -105,14 +166,14 @@ def ht_lookup(table: dict, k_hi, k_lo):
     `found & (val >= 0)` for a live row and `found & (val < 0)` for an
     orphan marker; never compare a lookup val to ORPHAN_VAL itself
     (ht_live_items returns exact stored vals when those are needed)."""
-    b = table["packed"].shape[0] - 1
+    b = ht_buckets(table)
     querying = ~((k_hi == 0) & (k_lo == 0))
     b1, b2 = _buckets(k_hi, k_lo, b)
     found = jnp.zeros_like(querying)
     val = jnp.full(k_hi.shape, -1, dtype=jnp.int32)
     for rows in (b1, b2):
         hit, lane_val = match_bucket(
-            table["packed"][rows], k_hi, k_lo, querying)
+            ht_rows(table, rows), k_hi, k_lo, querying)
         found = found | hit
         val = jnp.where(hit, lane_val, val)
     return found, val
@@ -161,13 +222,13 @@ def ht_plan(table: dict, k_hi, k_lo, mask):
     Separating plan from write lets callers compute a global commit/abort
     decision first and then apply all writes masked — no state copies for
     the abort path."""
-    b = table["packed"].shape[0] - 1
+    b = ht_buckets(table)
     n = k_hi.shape[0]
     dump = jnp.int32(b * SLOTS)
     b1, b2 = _buckets(k_hi, k_lo, b)
 
-    g1 = table["packed"][b1]
-    g2 = table["packed"][b2]
+    g1 = ht_rows(table, b1)
+    g2 = ht_rows(table, b2)
     occ1 = jnp.sum(
         (g1[:, :SLOTS] != 0) | (g1[:, SLOTS:2 * SLOTS] != 0), axis=1
     ).astype(jnp.int32)
@@ -204,23 +265,22 @@ def ht_plan(table: dict, k_hi, k_lo, mask):
 
 
 def ht_write(table: dict, pos, k_hi, k_lo, vals, mask):
-    """Apply a planned insert: ONE masked scatter into the packed matrix
-    (the dump bucket absorbs masked-out lanes). `pos` is a flat
-    bucket*SLOTS+slot index; the packed flat index per column group is
-    bucket*(3*SLOTS) + group*SLOTS + slot."""
-    b = table["packed"].shape[0] - 1
-    shape = table["packed"].shape
-    flat = shape[0] * shape[1]
+    """Apply a planned insert: ONE masked element scatter into the flat
+    table (the dump bucket absorbs masked-out lanes). `pos` is a flat
+    bucket*SLOTS+slot index; the word index of a u64 lane's low half is
+    bucket*STRIDE + (group*SLOTS + slot)*2, the high half follows it."""
+    b = ht_buckets(table)
     wpos = jnp.where(mask, pos, jnp.int32(b * SLOTS))
     bucket = wpos // SLOTS
     slot = wpos % SLOTS
-    base = bucket * jnp.int32(3 * SLOTS) + slot
-    idx = jnp.concatenate([base, base + jnp.int32(SLOTS),
-                           base + jnp.int32(2 * SLOTS)])
-    val64 = vals.astype(jnp.uint64)
-    data = jnp.concatenate([k_hi, k_lo, val64])
-    packed = table["packed"].reshape(flat).at[idx].set(data).reshape(shape)
-    return {"packed": packed}
+    base = bucket * jnp.int32(STRIDE) + slot * 2
+    offsets = np.array([g * SLOTS * 2 + h
+                        for g in range(3) for h in range(2)], np.int32)
+    # vals are int32 row indexes or negative markers: the stored u64
+    # word is their sign extension.
+    data = narrow(jnp.stack([k_hi, k_lo, vals.astype(jnp.uint64)], axis=1))
+    return {"packed": table["packed"].at[
+        (base[:, None] + offsets).reshape(-1)].set(data.reshape(-1))}
 
 
 def ht_insert(table: dict, k_hi, k_lo, vals, mask):
@@ -236,7 +296,7 @@ def ht_live_items(table: dict):
     """Host helper: (key_hi, key_lo, val) numpy arrays of all live slots
     (dump bucket excluded). val is int32 — negative values are sentinel
     markers (ORPHAN_VAL), non-negative are row indexes."""
-    p = np.asarray(table["packed"])[:-1]
+    p = ht_matrix(table)[:-1]
     kh = p[:, :SLOTS].reshape(-1)
     kl = p[:, SLOTS:2 * SLOTS].reshape(-1)
     v = p[:, 2 * SLOTS:].reshape(-1).astype(np.int64).astype(np.int32)

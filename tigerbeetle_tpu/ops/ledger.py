@@ -87,13 +87,16 @@ from .ev_layout import (  # noqa: F401 — re-exported ring layout
     EV_U64_IDX,
     XF_NCOLS,
     XF_P32_POS,
+    XF_PSTAT_COL32,
     XF_U64,
     XF_U64_IDX,
     bal_col,
     ev_cap,
     ev_col,
     ev_named,
+    narrow,
     pack32,
+    widen,
     xf_col,
     xf_named,
 )
@@ -206,7 +209,7 @@ def _pack_event_rows(records, acct_row: dict, xfer_row: dict,
                  u64[i, U[f"{side}_{f}_lo"]]) = _split(val)
     for name, vals in w32.items():
         _set32(u64, EV_P32_POS, name, vals)
-    return {"u64": u64}
+    return {"u32": narrow(u64)}
 
 
 class MirrorDivergence(AssertionError):
@@ -262,21 +265,21 @@ def init_state(a_cap: int = 1 << 17, t_cap: int = 1 << 21,
         e_cap = t_cap  # one history row per created transfer (+ expiries)
 
     def rows_accounts():
-        # One packed u64 matrix (32-bit meta pair-packed into the tail
-        # columns, see ev_layout.AC_P32): row appends/gathers are two
-        # ops (meta + balances), not three.
+        # Two u32 matrices of interleaved halves (see ev_layout): row
+        # appends/gathers are two ops (meta + balances).
         return dict(
-            u64=jnp.zeros((a_cap + 1, AC_NCOLS), jnp.uint64),
-            # Packed balances: (rows, 16) u64 — see ev_layout.BAL_FIELDS.
-            bal=jnp.zeros((a_cap + 1, 16), jnp.uint64),
+            u32=jnp.zeros((a_cap + 1, 2 * AC_NCOLS), jnp.uint32),
+            # Packed balances: the u64 view is (rows, 16) — see
+            # ev_layout.BAL_FIELDS.
+            bal=jnp.zeros((a_cap + 1, 2 * 16), jnp.uint32),
             count=jnp.int32(0),
         )
 
     def rows_transfers():
-        # One packed u64 matrix (see ev_layout.XF_P32): row appends and
-        # row-set gathers are ONE op each.
+        # One u32 matrix of interleaved halves (see ev_layout): row
+        # appends and row-set gathers are ONE op each, in place.
         return dict(
-            u64=jnp.zeros((t_cap + 1, XF_NCOLS), jnp.uint64),
+            u32=jnp.zeros((t_cap + 1, 2 * XF_NCOLS), jnp.uint32),
             count=jnp.int32(0),
         )
 
@@ -285,15 +288,15 @@ def init_state(a_cap: int = 1 << 17, t_cap: int = 1 << 21,
         # groove, src/state_machine.zig:104-220): per created transfer,
         # POST-application u128 balance snapshots of both touched accounts,
         # computed exactly in-kernel via segmented prefix sums. One
-        # packed u64 matrix (see ev_layout.EV_P32) so an append is ONE
-        # row scatter.
+        # u32 matrix of interleaved halves (see ev_layout) so an append
+        # is ONE row scatter.
         u64 = np.zeros((e_cap + 1, EV_NCOLS), dtype=np.uint64)
         _set32(u64, EV_P32_POS, "p_row",
                np.full(e_cap + 1, -1, dtype=np.int64))
         _set32(u64, EV_P32_POS, "tflags",
                np.full(e_cap + 1, 0xFFFFFFFF, dtype=np.int64))
         return dict(
-            u64=jnp.asarray(u64),
+            u32=jnp.asarray(narrow(u64)),
             count=jnp.int32(0),
         )
 
@@ -337,20 +340,22 @@ def _delta_gather_body(state, t_start, e_start, size_t, size_e):
     dr_row = ev_col(e, "dr_row")
     cr_row = ev_col(e, "cr_row")
     p_rows = jnp.maximum(ev_col(e, "p_row"), 0)
-    au = acc["u64"]
-    # Touched-account ids: ONE fused gather of the store's two leading
-    # id columns over the concatenated row set (was four scalar-lane
-    # gathers — round-7 op cut; the column positions are static layout
-    # facts, asserted so a reorder cannot silently gather the wrong
-    # pair).
+    # Touched-account ids: ONE row gather over the concatenated row
+    # set, the two leading id columns taken from the gathered rows (the
+    # column positions are static layout facts, asserted so a reorder
+    # cannot silently take the wrong pair).
     assert (AC_U64_IDX["id_hi"], AC_U64_IDX["id_lo"]) == (0, 1)
-    ids2 = au[:, :2][jnp.concatenate([dr_row, cr_row])]
+    ids2 = widen(
+        acc["u32"][jnp.concatenate([dr_row, cr_row])][:, :4])
     n_e = dr_row.shape[0]
     return dict(
         t=t, e=e,
         dr_id_hi=ids2[:n_e, 0], dr_id_lo=ids2[:n_e, 1],
         cr_id_hi=ids2[n_e:, 0], cr_id_lo=ids2[n_e:, 1],
-        p_ts=xf_col(xfr, "ts")[p_rows],
+        # The pending rows' timestamps: gather the ROWS and take the
+        # column from them (a whole-column slice first would be a pass
+        # over the store every fetch).
+        p_ts=xf_col({"u32": xfr["u32"][p_rows]}, "ts"),
     )
 
 
@@ -697,7 +702,7 @@ def _xfer_delta_gather_window(state, created, size_t, size_e):
 
     xfr = state["transfers"]
     evr = state["events"]
-    t_len = xfr["u64"].shape[0]
+    t_len = xfr["u32"].shape[0]
     e_len = ev_cap(evr) + 1
     t_start = jnp.clip(xfr["count"] - created, 0, t_len - size_t)
     e_start = jnp.clip(evr["count"] - created, 0, e_len - size_e)
@@ -1172,7 +1177,7 @@ class DeviceLedger:
             # window's created rows must fit one delta-gather bucket
             # (the sync path splits into groups instead; a pipelined
             # caller just takes that path).
-            t_len = int(self.state["transfers"]["u64"].shape[0])
+            t_len = int(self.state["transfers"]["u32"].shape[0])
             e_len = ev_cap(self.state["events"]) + 1
             if sum(ns) > min(32 * N_PAD, t_len, e_len):
                 return None
@@ -1423,7 +1428,7 @@ class DeviceLedger:
         if self._wt:
             # Delta gather with DEVICE-computed slice starts: ordered
             # after the kernel on device, resolved at drain/flush.
-            t_len = int(self.state["transfers"]["u64"].shape[0])
+            t_len = int(self.state["transfers"]["u32"].shape[0])
             e_len = ev_cap(self.state["events"]) + 1
             total_cap = sum(ns)
             for size in (N_PAD, 8 * N_PAD, 32 * N_PAD):
@@ -1690,7 +1695,7 @@ class DeviceLedger:
             t0 = self._xfer_rows_dev
             e0 = self._events_pushed
             size_t, size_e = tk.size
-            t_len = int(self.state["transfers"]["u64"].shape[0])
+            t_len = int(self.state["transfers"]["u32"].shape[0])
             e_len = ev_cap(self.state["events"]) + 1
             t_start = max(0, min(t0, t_len - size_t))
             e_start = max(0, min(e0, e_len - size_e))
@@ -2363,8 +2368,8 @@ class DeviceLedger:
                for k, v in st["accounts"].items()}
         n_a_rows = len(accounts)
         a_u64, a_bal = _pack_account_rows(accounts)
-        acc["u64"][:n_a_rows] = a_u64
-        acc["bal"][:n_a_rows] = a_bal
+        acc["u32"][:n_a_rows] = narrow(a_u64)
+        acc["bal"][:n_a_rows] = narrow(a_bal)
         acc["count"] = np.int32(len(accounts))
         st["accounts"] = {k: jnp.asarray(v) for k, v in acc.items()}
 
@@ -2386,7 +2391,7 @@ class DeviceLedger:
             lambda aid, dump: acct_row.get(aid, dump),
             self.a_cap)
         n_t = len(transfers)
-        xfr["u64"][:n_t] = u64m
+        xfr["u32"][:n_t] = narrow(u64m)
         xfr["count"] = np.int32(len(transfers))
         st["transfers"] = {k: jnp.asarray(v) for k, v in xfr.items()}
         st["xfer_ht"] = batch_insert(
@@ -2403,7 +2408,7 @@ class DeviceLedger:
                for k, v in st["events"].items()}
         cols = self._event_cols(sm.account_events)
         n_e = len(sm.account_events)
-        e_cap = evr["u64"].shape[0] - 1
+        e_cap = ev_cap(evr)
         assert n_e <= e_cap, "e_cap exceeded: raise capacities"
         for k, v in cols.items():
             evr[k][:n_e] = v
@@ -2545,7 +2550,7 @@ class DeviceLedger:
         execution, src/lsm/groove.zig:1339)."""
         t0 = self._xfer_rows_dev
         e0 = self._events_pushed
-        t_len = int(self.state["transfers"]["u64"].shape[0])
+        t_len = int(self.state["transfers"]["u32"].shape[0])
         e_len = ev_cap(self.state["events"]) + 1
         # Buckets: point batches, one prepare, a full commit window.
         for size in (256, N_PAD, 8 * N_PAD):
@@ -2965,7 +2970,7 @@ class DeviceLedger:
         import jax
 
         a0 = len(self._acct_row)
-        a_len = int(self.state["accounts"]["u64"].shape[0])
+        a_len = int(self.state["accounts"]["u32"].shape[0])
         size = min(256 if n_new <= 256 else N_PAD, a_len)
         assert n_new <= size
         a_start = max(0, min(a0, a_len - size))
@@ -3205,7 +3210,7 @@ class DeviceLedger:
                            dtype=np.int32), self.a_cap)
             objs = [sm.accounts[a] for a in dirty_accounts]
             u64m, bal = _pack_account_rows(objs)
-            cols = {"bal": bal, "u64": u64m}
+            cols = {"bal": narrow(bal), "u32": narrow(u64m)}
             count = jnp.int32(next_row)
             acc = st["accounts"] = scatter_cols(
                 {k: v for k, v in acc.items() if k != "count"},
@@ -3253,7 +3258,7 @@ class DeviceLedger:
                 lambda o: int(sm.pending_status.get(o.timestamp, 0)),
                 lambda aid, dump: self._acct_row.get(aid, dump),
                 self.a_cap)
-            cols = {"u64": u64m}
+            cols = {"u32": narrow(u64m)}
             count = jnp.int32(next_row)
             xfr = st["transfers"] = scatter_cols(
                 {k: v for k, v in xfr.items() if k != "count"},
@@ -3279,11 +3284,8 @@ class DeviceLedger:
             rows = pad(np.array([r for r, _ in flip], dtype=np.int32),
                        self.t_cap)
             vals = pad(np.array([v for _, v in flip], dtype=np.int32), 0)
-            # pstat lives ALONE in its packed column (ev_layout.XF_P32),
-            # so the flip write cannot clobber a partner field.
-            xfr["u64"] = xfr["u64"].at[
-                rows, XF_P32_POS["pstat"][0]].set(
-                jnp.asarray(pack32(vals)))
+            xfr["u32"] = xfr["u32"].at[rows, XF_PSTAT_COL32].set(
+                jnp.asarray(vals.astype(np.uint32)))
         dirty_expiry = sorted(sm.expiry.dirty_dev)
         sm.expiry.dirty_dev.clear()
         exp = [(self._xfer_row[sm.transfer_by_timestamp[ts]],
@@ -3294,8 +3296,10 @@ class DeviceLedger:
             rows = pad(np.array([r for r, _ in exp], dtype=np.int32),
                        self.t_cap)
             vals = pad(np.array([v for _, v in exp], dtype=np.uint64), 0)
-            xfr["u64"] = xfr["u64"].at[rows, XF_U64_IDX["expires"]].set(
-                jnp.asarray(vals))
+            c = 2 * XF_U64_IDX["expires"]
+            xfr["u32"] = xfr["u32"].at[
+                rows[:, None], np.array([c, c + 1])].set(
+                jnp.asarray(narrow(vals[:, None])))
 
         # ---- orphaned ids (inline in the transfer table, val sentinel)
         dirty_orphans = sorted(sm.orphaned.dirty_dev)

@@ -25,7 +25,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-from .hash_table import SLOTS, _buckets, match_bucket
+from .hash_table import (
+    LANES, SLOTS, _buckets, bucket_rows, ht_buckets, match_bucket)
 
 # VMEM working-set budget for the ungridded fused probe (v5e has ~16 MiB
 # per core): packed table + key/bucket inputs + the two gathered
@@ -65,8 +66,9 @@ def _probe_kernel(khi_ref, klo_ref, b1_ref, b2_ref, table_ref,
     querying = ~((k_hi == 0) & (k_lo == 0))
     found = jnp.zeros(k_hi.shape, dtype=jnp.bool_)
     val = jnp.full(k_hi.shape, -1, dtype=jnp.int32)
+    lanes = table_ref[:].reshape(-1, LANES)
     for rows_ref in (b1_ref, b2_ref):
-        g = jnp.take(table_ref[:], rows_ref[:], axis=0)
+        g = bucket_rows(lanes, rows_ref[:])
         hit, lane_val = match_bucket(g, k_hi, k_lo, querying)
         found = found | hit
         val = jnp.where(hit, lane_val, val)
@@ -83,7 +85,7 @@ def ht_lookup_fused(table: dict, k_hi, k_lo, *, interpret: bool = False):
     key prep) so the kernel body is pure probe."""
     from jax.experimental import pallas as pl
 
-    b = table["packed"].shape[0] - 1
+    b = ht_buckets(table)
     b1, b2 = _buckets(k_hi, k_lo, b)
     n = k_hi.shape[0]
     out_shape = (

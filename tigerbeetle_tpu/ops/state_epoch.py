@@ -24,8 +24,10 @@ robustness layer (tigerbeetle_tpu/serving.py is the recovery half):
 
 What is digested (and what deliberately is not):
 
-  covered   accounts u64 matrix (all columns), the balance-limb matrix,
-            transfers u64 matrix, and the scalar vector (row counts,
+  covered   accounts matrix (all columns), the balance-limb matrix,
+            transfers matrix (each widened to its u64 words: the fold
+            is over the u64 view of the u32 stores, ev_layout.widen),
+            and the scalar vector (row counts,
             key maxima, commit_ts) — exactly the fields the VOPR/fuzz
             differentials pin as path-canonical (identical whether a
             row was written by the fast kernel, a mirror push, or a
@@ -51,7 +53,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from .ev_layout import AC_NCOLS, XF_NCOLS, XF_P32_POS, XF_U64_IDX
+from .ev_layout import (AC_NCOLS, XF_NCOLS, XF_P32_POS, XF_U64_IDX, narrow,
+                        widen)
 
 _U64_MASK = (1 << 64) - 1
 _PHI = 0x9E3779B97F4A7C15  # odd golden-ratio constant (also the Horner base)
@@ -131,12 +134,13 @@ def _digest_components(state: dict, xp) -> dict:
     xfr = state["transfers"]
     comps = {
         "accounts_u64": _matrix_digest(
-            acc["u64"], acc["count"], AC_COL_MASKS,
+            widen(acc["u32"]), acc["count"], AC_COL_MASKS,
             _SALT["accounts_u64"], xp),
         "accounts_bal": _matrix_digest(
-            acc["bal"], acc["count"], None, _SALT["accounts_bal"], xp),
+            widen(acc["bal"]), acc["count"], None,
+            _SALT["accounts_bal"], xp),
         "transfers_u64": _matrix_digest(
-            xfr["u64"], xfr["count"], XF_COL_MASKS,
+            widen(xfr["u32"]), xfr["count"], XF_COL_MASKS,
             _SALT["transfers_u64"], xp),
     }
     scalars = xp.stack([
@@ -199,9 +203,9 @@ def pack_oracle_state(sm, a_cap: int) -> dict:
     else:
         x_u64 = np.zeros((0, XF_NCOLS), dtype=np.uint64)
     return dict(
-        accounts=dict(u64=a_u64, bal=a_bal,
+        accounts=dict(u32=narrow(a_u64), bal=narrow(a_bal),
                       count=np.int32(len(accounts))),
-        transfers=dict(u64=x_u64, count=np.int32(len(transfers))),
+        transfers=dict(u32=narrow(x_u64), count=np.int32(len(transfers))),
         acct_key_max=np.uint64(sm.accounts_key_max or 0),
         xfer_key_max=np.uint64(sm.transfers_key_max or 0),
         commit_ts=np.uint64(sm.commit_timestamp),
@@ -389,17 +393,19 @@ def _range_digest_components(state: dict, lo, hi, src, n_shards: int,
                 & ((h & u(n_shards - 1))
                    == xp.asarray(src).astype(xp.uint64)))
 
-    a_h = mix_id(acc["u64"][:, 0], acc["u64"][:, 1])
-    x_h = mix_id(xfr["u64"][:, 0], xfr["u64"][:, 1])
+    a_u64 = widen(acc["u32"])
+    a_h = mix_id(a_u64[:, 0], a_u64[:, 1])
+    x_u64 = widen(xfr["u32"])
+    x_h = mix_id(x_u64[:, 0], x_u64[:, 1])
     a_m, x_m = member(a_h), member(x_h)
     a_dig, a_n = _range_matrix_digest(
-        acc["u64"], acc["count"], AC_COL_MASKS,
+        a_u64, acc["count"], AC_COL_MASKS,
         _RSALT["accounts_u64"], a_h, a_m, xp)
     b_dig, _ = _range_matrix_digest(
-        acc["bal"], acc["count"], None, _RSALT["accounts_bal"],
+        widen(acc["bal"]), acc["count"], None, _RSALT["accounts_bal"],
         a_h, a_m, xp)
     x_dig, x_n = _range_matrix_digest(
-        xfr["u64"], xfr["count"], XF_COL_MASKS,
+        x_u64, xfr["count"], XF_COL_MASKS,
         _RSALT["transfers_u64"], x_h, x_m, xp)
     return {"accounts_u64": a_dig, "accounts_bal": b_dig,
             "transfers_u64": x_dig, "accounts_rows": a_n,

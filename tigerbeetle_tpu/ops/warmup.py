@@ -3,8 +3,8 @@ prints `listening`, so no client request pays a compile out of its
 timeout budget (vsr/client.py: 10 s by default).
 
 At production caps (a_cap 2^17, t_cap 2^21) one create_transfers tier
-takes the TPU compiler 100-150 s of ONE core (PERF.md "Bring-up on the
-local v5e"), and the dispatch surface is tiers x four batch buckets x
+takes the TPU compiler one to two minutes of ONE core (the plain tier
+52-73 s, the limit fixpoint 119 s: sandbox compile, PR 32), and the dispatch surface is tiers x four batch buckets x
 window depths 2..8 — warming all of it cold would take the better part
 of an hour. So the warm set is chosen, not exhaustive:
 

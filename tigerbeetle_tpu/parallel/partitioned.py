@@ -83,8 +83,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.ev_layout import (
-    AC_NCOLS, EV_NCOLS, EV_P32_POS, XF_NCOLS, XF_U64_IDX, XF_P32_POS,
-    pack32,
+    AC_NCOLS, EV_NCOLS, EV_P32_POS, XF_NCOLS, XF_PSTAT_COL32, XF_U64_IDX,
+    XF_P32_POS, ev_cap, narrow, pack32, widen, with_col32,
 )
 from ..ops.fast_kernels import (
     _CREATED,
@@ -115,9 +115,6 @@ __all__ = ["make_partitioned_create_transfers",
 
 _U64_MAX = np.uint64(0xFFFFFFFFFFFFFFFF)
 _XF_DRROW_COL = XF_P32_POS["dr_row"][0]   # ("dr_row","cr_row") word
-_XF_PSTAT_COL = XF_P32_POS["pstat"][0]    # pstat lives ALONE (flips)
-_EV_PROW_COL = EV_P32_POS["p_row"][0]     # ("pstat","p_row") word
-_EV_TFLAGS_COL = EV_P32_POS["tflags"][0]  # ("tflags","dr_flags") word
 
 
 def _psum_u64(x, axis):
@@ -268,9 +265,9 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
                + idxs.astype(jnp.uint64) + jnp.uint64(1))
     acc, xfr, evr = (sub["accounts"], sub["transfers"],
                      sub["events"])
-    a_dump_l = acc["u64"].shape[0] - 1
-    t_dump_l = xfr["u64"].shape[0] - 1
-    e_cap_l = evr["u64"].shape[0] - 1
+    a_dump_l = acc["u32"].shape[0] - 1
+    t_dump_l = xfr["u32"].shape[0] - 1
+    e_cap_l = ev_cap(evr)
 
     # ---- phase 1: transfer-key probe + exchange (2N lanes:
     # [ev.id | ev.pid]). Encoding in lane 0 of the exchanged
@@ -290,9 +287,13 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
     x_live_l = xf_l & (xv_l >= 0)
     enc_l = jnp.where(
         xf_l, (xv_l + 2).astype(jnp.uint64), jnp.uint64(0))
-    xrow_l = jnp.where(x_live_l, xv_l, t_dump_l)
-    xdata_l = jnp.where(x_live_l[:, None],
-                        xfr["u64"][xrow_l], jnp.uint64(0))
+    # The local copy's rows (u32, as stored): the exchange widens
+    # them; the pstat write-back below rewrites them whole. Gathered
+    # wherever this shard HOLDS the key, read owner or not.
+    xraw_l = xfr["u32"][
+        jnp.where(xf_raw & (xv_l >= 0), xv_l, t_dump_l)]
+    xdata_l = jnp.where(x_live_l[:, None], widen(xraw_l),
+                        jnp.uint64(0))
     g = _psum_u64(
         jnp.concatenate([enc_l[:, None], xdata_l], axis=1), axis)
     g_enc, g_rows = g[:, 0], g[:, 1:]
@@ -323,9 +324,9 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
         af_l, (ar_l + 1).astype(jnp.uint64), jnp.uint64(0))
     arow_g_l = jnp.where(af_l, ar_l, a_dump_l)
     au_l = jnp.where(af_l[:, None],
-                     acc["u64"][arow_g_l], jnp.uint64(0))
+                     widen(acc["u32"][arow_g_l]), jnp.uint64(0))
     ab_l = jnp.where(af_l[:, None],
-                     acc["bal"][arow_g_l], jnp.uint64(0))
+                     widen(acc["bal"][arow_g_l]), jnp.uint64(0))
     ga = _psum_u64(
         jnp.concatenate([aenc_l[:, None], au_l, ab_l], axis=1),
         axis)
@@ -375,16 +376,17 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
 
     # Ring prefill (p_row=-1 / tflags=0xFFFFFFFF) built ON
     # DEVICE by column sets — never as a host closure constant.
-    mini_ev = jnp.zeros((ME + 1, EV_NCOLS), jnp.uint64)
-    mini_ev = mini_ev.at[:, _EV_PROW_COL].set(
-        jnp.uint64(0xFFFFFFFF) << jnp.uint64(32))
-    mini_ev = mini_ev.at[:, _EV_TFLAGS_COL].set(
-        jnp.uint64(0xFFFFFFFF))
+    mini_ev = jnp.zeros((ME + 1, 2 * EV_NCOLS), jnp.uint32)
+    for name in ("p_row", "tflags"):
+        col, half = EV_P32_POS[name]
+        mini_ev = mini_ev.at[:, 2 * col + half].set(
+            jnp.uint32(0xFFFFFFFF))
 
     mini = dict(
-        accounts=dict(u64=mini_au, bal=mini_ab, count=n_a),
-        transfers=dict(u64=mini_xu, count=n_live),
-        events=dict(u64=mini_ev, count=jnp.int32(0)),
+        accounts=dict(u32=narrow(mini_au), bal=narrow(mini_ab),
+                      count=n_a),
+        transfers=dict(u32=narrow(mini_xu), count=n_live),
+        events=dict(u32=mini_ev, count=jnp.int32(0)),
         acct_ht=ht_a,
         xfer_ht=ht_x,
         # Scalars are stored per shard but hold GLOBAL values.
@@ -448,7 +450,7 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
     mini_trow = jnp.clip(mini_t0 + row_off, 0, MT)
     dest_t = jnp.where(mine & g_ok,
                        xfr["count"] + local_rank, t_dump_l)
-    new_rows = new_mini["transfers"]["u64"][mini_trow]
+    new_rows = widen(new_mini["transfers"]["u32"][mini_trow])
     # Stored row pointers become SHARD-LOCAL: resolve the new
     # row's dr/cr against the local table (remote -> dump).
     fdr2, rdr2 = ht_lookup(sub["acct_ht"],
@@ -458,10 +460,13 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
     new_rows = new_rows.at[:, _XF_DRROW_COL].set(
         pack32(jnp.where(fdr2, rdr2, a_dump_l),
                jnp.where(fcr2, rcr2, a_dump_l)))
-    xu_new = xfr["u64"].at[dest_t].set(new_rows)
-    # Pending-status flips on existing owned rows: the pstat
-    # word is alone in its column, so the flip cannot clobber a
-    # neighbor. Unchanged rows rewrite their own value.
+    xu_new = xfr["u32"].at[dest_t].set(narrow(new_rows))
+    # Pending-status flips on existing owned rows: a ROW scatter of
+    # the local copy's rows as gathered in phase 1 (an owned key's
+    # dest_p is the row they were gathered from), pstat replaced — an
+    # element scatter into the 2-D store would relayout the whole
+    # store on TPU (ops/ev_layout.py). Unchanged rows rewrite
+    # themselves.
     if overlay:
         # Copy-catchup owners flip their OWN copy's row: the read
         # owner's row index is the exchanged encoding, the other
@@ -480,9 +485,11 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
         dest_p = jnp.where(flip & g_ok,
                            (g_enc - jnp.uint64(2)).astype(jnp.int32),
                            t_dump_l)
-    pword = new_mini["transfers"]["u64"][
-        jnp.where(x_live, lrow, MT), _XF_PSTAT_COL]
-    xu_new = xu_new.at[dest_p, _XF_PSTAT_COL].set(pword)
+    pword = new_mini["transfers"]["u32"][
+        jnp.where(x_live, lrow, MT), XF_PSTAT_COL32]
+    xu_new = xu_new.at[dest_p].set(jnp.where(
+        (dest_p != t_dump_l)[:, None],
+        with_col32(xraw_l, XF_PSTAT_COL32, pword), jnp.uint32(0)))
 
     if overlay:
         wr_ak = writes_here(ak_hi, ak_lo, n_dev, me, overlay)
@@ -499,16 +506,16 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
                            (g_aenc - jnp.uint64(1)).astype(jnp.int32),
                            a_dump_l)
     amrow_c = jnp.where(afirst, amrow, MA)
-    au_new = acc["u64"].at[dest_a].set(
-        new_mini["accounts"]["u64"][amrow_c])
+    au_new = acc["u32"].at[dest_a].set(
+        new_mini["accounts"]["u32"][amrow_c])
     ab_new = acc["bal"].at[dest_a].set(
         new_mini["accounts"]["bal"][amrow_c])
 
     dest_e = jnp.where(mine & g_ok,
                        evr["count"] + local_rank, e_cap_l)
-    ring_rows = new_mini["events"]["u64"][
+    ring_rows = new_mini["events"]["u32"][
         jnp.clip(row_off, 0, ME)]
-    eu_new = evr["u64"].at[dest_e].set(ring_rows)
+    eu_new = evr["u32"].at[dest_e].set(ring_rows)
 
     vals = jnp.where(created, xfr["count"] + local_rank,
                      jnp.int32(ORPHAN_VAL))
@@ -523,11 +530,11 @@ def _partitioned_batch_body(sub, ev, timestamp, n, *, axis, n_dev,
         return jnp.where(g_ok, new_v, old_v)
 
     new_sub = dict(
-        accounts=dict(u64=au_new, bal=ab_new,
+        accounts=dict(u32=au_new, bal=ab_new,
                       count=acc["count"]),
-        transfers=dict(u64=xu_new,
+        transfers=dict(u32=xu_new,
                        count=xfr["count"] + n_mine_ok),
-        events=dict(u64=eu_new,
+        events=dict(u32=eu_new,
                     count=evr["count"] + n_mine_ok),
         acct_ht=sub["acct_ht"],
         xfer_ht=ht_new,
@@ -840,8 +847,8 @@ def partitioned_from_oracle(sm, mesh: Mesh, axis: str = "batch",
         acct_row = {a.id: r for r, a in enumerate(accounts)}
         xfer_row = {t.id: r for r, t in enumerate(transfers)}
         a_u64, a_bal = _pack_account_rows(accounts)
-        st["accounts"]["u64"][:len(accounts)] = a_u64
-        st["accounts"]["bal"][:len(accounts)] = a_bal
+        st["accounts"]["u32"][:len(accounts)] = narrow(a_u64)
+        st["accounts"]["bal"][:len(accounts)] = narrow(a_bal)
         st["accounts"]["count"] = np.int32(len(accounts))
         st["acct_ht"] = jax.tree.map(np.asarray, _chunk_insert(
             st["acct_ht"],
@@ -853,7 +860,7 @@ def partitioned_from_oracle(sm, mesh: Mesh, axis: str = "batch",
                 o.timestamp, TransferPendingStatus.none)),
             lambda aid, dump: acct_row.get(aid, dump),
             a_cap_s)
-        st["transfers"]["u64"][:len(transfers)] = u64m
+        st["transfers"]["u32"][:len(transfers)] = narrow(u64m)
         st["transfers"]["count"] = np.int32(len(transfers))
         st["xfer_ht"] = jax.tree.map(np.asarray, _chunk_insert(
             st["xfer_ht"],
@@ -861,7 +868,7 @@ def partitioned_from_oracle(sm, mesh: Mesh, axis: str = "batch",
             + [(o, ORPHAN_VAL) for o in orphans], N_PAD))
 
         ecols = _pack_event_rows(records, acct_row, xfer_row, a_cap_s)
-        st["events"]["u64"][:len(records)] = ecols["u64"]
+        st["events"]["u32"][:len(records)] = ecols["u32"]
         st["events"]["count"] = np.int32(len(records))
 
         # Scalars hold GLOBAL values on every shard (the mini-state and

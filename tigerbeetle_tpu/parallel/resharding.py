@@ -93,9 +93,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.ev_layout import AC_NCOLS, AC_U64_IDX, EV_NCOLS, XF_NCOLS, \
-    XF_U64_IDX
-from ..ops.hash_table import ORPHAN_VAL, SLOTS, ht_lookup, ht_plan, \
-    ht_write
+    XF_U64_IDX, narrow, widen
+from ..ops.hash_table import ORPHAN_VAL, SLOTS, ht_lookup, ht_matrix, \
+    ht_pack, ht_plan, ht_unpack, ht_write
 from ..ops.state_epoch import _range_digest_components, \
     partitioned_range_digest
 from ..trace import Event, NullTracer
@@ -174,22 +174,23 @@ def _install_chunk(stacked, shard, a_u64, a_bal, a_n, x_u64, x_n,
         return u64.at[shard, idx].set(rows), cnt_vec.at[shard].add(n)
 
     acc, xfr, evr = out["accounts"], out["transfers"], out["events"]
-    au, a_cnt = append(acc["u64"], acc["count"], a_u64, a_n)
+    au, a_cnt = append(acc["u32"], acc["count"], narrow(a_u64), a_n)
     iota_a = jnp.arange(a_u64.shape[0], dtype=jnp.int32)
     idx_a = jnp.where(iota_a < a_n,
                       acc["count"][shard] + iota_a,
                       jnp.int32(acc["bal"].shape[1] - 1))
-    ab = acc["bal"].at[shard, idx_a].set(a_bal)
-    xu, x_cnt = append(xfr["u64"], xfr["count"], x_u64, x_n)
-    eu, e_cnt = append(evr["u64"], evr["count"], e_u64, e_n)
-    out["accounts"] = dict(u64=au, bal=ab, count=a_cnt)
-    out["transfers"] = dict(u64=xu, count=x_cnt)
-    out["events"] = dict(u64=eu, count=e_cnt)
+    ab = acc["bal"].at[shard, idx_a].set(narrow(a_bal))
+    xu, x_cnt = append(xfr["u32"], xfr["count"], narrow(x_u64), x_n)
+    eu, e_cnt = append(evr["u32"], evr["count"], narrow(e_u64), e_n)
+    out["accounts"] = dict(u32=au, bal=ab, count=a_cnt)
+    out["transfers"] = dict(u32=xu, count=x_cnt)
+    out["events"] = dict(u32=eu, count=e_cnt)
     return out
 
 
 def _remap_table_vals(packed, newpos):
-    """Remap every live row-index value in a packed table through the
+    """Remap every live row-index value in a table's (b+1, 3*SLOTS) u64
+    matrix (hash_table.ht_unpack) through the
     row permutation (bucket choice depends only on the key, so values
     move without touching the structure). Orphan markers (< 0) and
     empty slots pass through."""
@@ -248,22 +249,22 @@ def _finalize_shard(stacked, shard, o_hi, o_lo, o_n):
     out = jax.tree.map(lambda x: x, stacked)
     acc, xfr = out["accounts"], out["transfers"]
 
-    au = acc["u64"][shard]
-    ab = acc["bal"][shard]
+    au = widen(acc["u32"][shard])
+    ab = widen(acc["bal"][shard])
     a_cnt = acc["count"][shard]
     au_s, a_perm, a_newpos = _sort_store(au, a_cnt, _AC_TS)
     cap_a = au.shape[0]
     ab_s = jnp.where(jnp.arange(cap_a)[:, None] < a_cnt,
                      ab[a_perm], jnp.uint64(0))
-    aht = {"packed": _remap_table_vals(out["acct_ht"]["packed"][shard],
-                                       a_newpos)}
+    aht = {"packed": ht_pack(_remap_table_vals(
+        ht_unpack(out["acct_ht"]["packed"][shard]), a_newpos))}
     aht, ok_a = _insert_missing(aht, au_s, a_cnt)
 
-    xu = xfr["u64"][shard]
+    xu = widen(xfr["u32"][shard])
     x_cnt = xfr["count"][shard]
     xu_s, _x_perm, x_newpos = _sort_store(xu, x_cnt, _XF_TS)
-    xht = {"packed": _remap_table_vals(out["xfer_ht"]["packed"][shard],
-                                       x_newpos)}
+    xht = {"packed": ht_pack(_remap_table_vals(
+        ht_unpack(out["xfer_ht"]["packed"][shard]), x_newpos))}
     xht, ok_x = _insert_missing(xht, xu_s, x_cnt)
     # The range's orphan markers (transiently-failed ids with no row):
     # unique, absent from the target, valued ORPHAN_VAL forever.
@@ -274,10 +275,10 @@ def _finalize_shard(stacked, shard, o_hi, o_lo, o_n):
                    jnp.full(o_hi.shape[0], ORPHAN_VAL, jnp.int32),
                    o_ins & ok_o)
 
-    out["accounts"] = dict(u64=acc["u64"].at[shard].set(au_s),
-                           bal=acc["bal"].at[shard].set(ab_s),
+    out["accounts"] = dict(u32=acc["u32"].at[shard].set(narrow(au_s)),
+                           bal=acc["bal"].at[shard].set(narrow(ab_s)),
                            count=acc["count"])
-    out["transfers"] = dict(u64=xfr["u64"].at[shard].set(xu_s),
+    out["transfers"] = dict(u32=xfr["u32"].at[shard].set(narrow(xu_s)),
                             count=xfr["count"])
     out["acct_ht"] = {"packed": out["acct_ht"]["packed"].at[shard].set(
         aht["packed"])}
@@ -287,7 +288,8 @@ def _finalize_shard(stacked, shard, o_hi, o_lo, o_n):
 
 
 def _drop_range_keys(packed, lo, hi, base_shard, n_shards):
-    """Zero every table slot whose key's ownership hash is in [lo, hi]
+    """Over a table's u64 matrix (hash_table.ht_unpack): zero every
+    table slot whose key's ownership hash is in [lo, hi]
     with base owner `base_shard` (catches orphan markers — they have
     keys but no rows), then re-compact each bucket's slots to a leading
     non-empty prefix (the planner's occupancy invariant)."""
@@ -347,26 +349,26 @@ def _evict_range(stacked, shard, lo, hi, n_shards, base_shard):
         newpos = jnp.zeros(cap, jnp.int32).at[perm].set(iota)
         return new_u64, new_count, perm, newpos
 
-    au, a_cnt2, a_perm, a_newpos = evict_store(acc["u64"][shard],
+    au, a_cnt2, a_perm, a_newpos = evict_store(widen(acc["u32"][shard]),
                                                acc["count"][shard])
     ab = jnp.where(jnp.arange(au.shape[0])[:, None] < a_cnt2,
-                   acc["bal"][shard][a_perm], jnp.uint64(0))
-    xu, x_cnt2, _xp, x_newpos = evict_store(xfr["u64"][shard],
+                   acc["bal"][shard][a_perm], jnp.uint32(0))
+    xu, x_cnt2, _xp, x_newpos = evict_store(widen(xfr["u32"][shard]),
                                             xfr["count"][shard])
 
-    aht = _drop_range_keys(out["acct_ht"]["packed"][shard], lo, hi,
-                           base_shard, n_shards)
-    aht = _remap_table_vals(aht, a_newpos)
-    xht = _drop_range_keys(out["xfer_ht"]["packed"][shard], lo, hi,
-                           base_shard, n_shards)
-    xht = _remap_table_vals(xht, x_newpos)
+    aht = _drop_range_keys(ht_unpack(out["acct_ht"]["packed"][shard]),
+                           lo, hi, base_shard, n_shards)
+    aht = ht_pack(_remap_table_vals(aht, a_newpos))
+    xht = _drop_range_keys(ht_unpack(out["xfer_ht"]["packed"][shard]),
+                           lo, hi, base_shard, n_shards)
+    xht = ht_pack(_remap_table_vals(xht, x_newpos))
 
     out["accounts"] = dict(
-        u64=acc["u64"].at[shard].set(au),
+        u32=acc["u32"].at[shard].set(narrow(au)),
         bal=acc["bal"].at[shard].set(ab),
         count=acc["count"].at[shard].set(a_cnt2))
     out["transfers"] = dict(
-        u64=xfr["u64"].at[shard].set(xu),
+        u32=xfr["u32"].at[shard].set(narrow(xu)),
         count=xfr["count"].at[shard].set(x_cnt2))
     out["acct_ht"] = {"packed": out["acct_ht"]["packed"].at[shard].set(
         aht)}
@@ -543,14 +545,16 @@ class ReshardController:
                    & ((h & np.uint64(n - 1)) == np.uint64(p.src)))
             return live & inr
 
-        a_sel = sel(sub["accounts"]["u64"], sub["accounts"]["count"])
-        x_sel = sel(sub["transfers"]["u64"], sub["transfers"]["count"])
-        a_rows = np.asarray(sub["accounts"]["u64"])[a_sel]
-        a_bal = np.asarray(sub["accounts"]["bal"])[a_sel]
-        x_rows = np.asarray(sub["transfers"]["u64"])[x_sel]
+        a_u64 = widen(np.asarray(sub["accounts"]["u32"]))
+        a_sel = sel(a_u64, sub["accounts"]["count"])
+        x_u64 = widen(np.asarray(sub["transfers"]["u32"]))
+        x_sel = sel(x_u64, sub["transfers"]["count"])
+        a_rows = a_u64[a_sel]
+        a_bal = widen(np.asarray(sub["accounts"]["bal"]))[a_sel]
+        x_rows = x_u64[x_sel]
         # Orphan markers ride the transfer table only (no rows): pull
         # them straight out of the fetched packed matrix.
-        packed = np.asarray(sub["xfer_ht"]["packed"])[:-1]
+        packed = ht_matrix(sub["xfer_ht"])[:-1]
         kh = packed[:, :SLOTS].reshape(-1)
         kl = packed[:, SLOTS:2 * SLOTS].reshape(-1)
         v = packed[:, 2 * SLOTS:].reshape(-1).astype(
@@ -563,9 +567,9 @@ class ReshardController:
         if oracle is not None:
             e_rows = self._pack_range_events(oracle)
         digest = {k: int(v2) for k, v2 in _range_digest_components(
-            dict(accounts=dict(u64=a_rows, bal=a_bal,
+            dict(accounts=dict(u32=narrow(a_rows), bal=narrow(a_bal),
                                count=np.int32(len(a_rows))),
-                 transfers=dict(u64=x_rows,
+                 transfers=dict(u32=narrow(x_rows),
                                 count=np.int32(len(x_rows)))),
             np.uint64(p.lo), np.uint64(p.hi), np.uint64(p.src), n,
             np).items()}
@@ -584,7 +588,7 @@ class ReshardController:
         if not recs:
             return np.zeros((0, EV_NCOLS), dtype=np.uint64)
         a_cap_s = self.router.a_cap // self.router.n_shards
-        return _pack_event_rows(recs, {}, {}, a_cap_s)["u64"]
+        return widen(_pack_event_rows(recs, {}, {}, a_cap_s)["u32"])
 
     def _check_capacity(self, state, recv: int, snap: dict) -> None:
         """dynamic scatter starts clamp instead of trapping: the whole
@@ -593,9 +597,9 @@ class ReshardController:
         counts = jax.device_get(dict(
             a=state["accounts"]["count"], x=state["transfers"]["count"],
             e=state["events"]["count"]))
-        caps = dict(a=state["accounts"]["u64"].shape[1] - 1,
-                    x=state["transfers"]["u64"].shape[1] - 1,
-                    e=state["events"]["u64"].shape[1] - 1)
+        caps = dict(a=state["accounts"]["u32"].shape[1] - 1,
+                    x=state["transfers"]["u32"].shape[1] - 1,
+                    e=state["events"]["u32"].shape[1] - 1)
         need = dict(a=len(snap["a_u64"]), x=len(snap["x_u64"]),
                     e=len(snap["e_u64"]))
         for k in ("a", "x", "e"):

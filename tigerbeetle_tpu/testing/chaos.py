@@ -206,19 +206,23 @@ def inject_state_bitflip(led, f: dict) -> bool:
     target = f["target"]
     comp = "accounts" if target.startswith("accounts") else "transfers"
     store = st[comp]
-    mat = store["bal"] if target == "accounts_bal" else store["u64"]
     count = int(store["count"])
     if count == 0:
         return False
+    row = f["row_pick"] % count
+    key = "bal" if target == "accounts_bal" else "u32"
+    mat = store[key]
+    # A store holds logical u64 column c as the u32 columns 2c (low
+    # half) and 2c+1 (ev_layout).
     if target == "transfers_u64":
         cols = [j for j, m in enumerate(state_epoch.XF_COL_MASKS) if m]
     else:
-        cols = list(range(mat.shape[1]))
-    row = f["row_pick"] % count
+        cols = list(range(mat.shape[1] // 2))
     col = cols[f["col_pick"] % len(cols)]
-    bit = jnp.uint64(1 << (f["bit"] % 64))
-    key = "bal" if target == "accounts_bal" else "u64"
-    store[key] = mat.at[row, col].set(mat[row, col] ^ bit)
+    bit64 = f["bit"] % 64
+    c32 = 2 * col + bit64 // 32
+    store[key] = mat.at[row, c32].set(
+        mat[row, c32] ^ jnp.uint32(1 << (bit64 % 32)))
     f["where"] = f"{target}[{row},{col}] bit {f['bit'] % 64}"
     return True
 
