@@ -82,7 +82,7 @@ CHAIN_DEPTHS = (2, 8, 32)
 
 
 def _mk_prepares(n_prepares, n=N_SUPER, nid0=10 ** 6, seed=0):
-    from tigerbeetle_tpu.benchmark import _soa
+    from tigerbeetle_tpu.ops.batch import transfers_soa
 
     rng = np.random.default_rng(seed)
     evs, tss = [], []
@@ -90,8 +90,8 @@ def _mk_prepares(n_prepares, n=N_SUPER, nid0=10 ** 6, seed=0):
     for b in range(n_prepares):
         dr = rng.integers(1, 64, n, dtype=np.uint64)
         cr = (dr % 63) + 1
-        evs.append(_soa(np.arange(nid, nid + n), dr, cr,
-                        rng.integers(1, 100, n)))
+        evs.append(transfers_soa(np.arange(nid, nid + n), dr, cr,
+                                 rng.integers(1, 100, n)))
         nid += n
         tss.append(10 ** 12 + b * (n + 10))
     return evs, tss
@@ -142,10 +142,8 @@ def _partitioned_fixture(mesh, axis="batch"):
     return jax.device_put(stacked, NamedSharding(mesh, P(axis)))
 
 
-def census_tiers(include_sharded: bool = True,
-                 only: tuple | None = None) -> dict:
-    """tier name -> heavy_census dict for every kernel tier. `only`
-    restricts to a named subset (bench.py's light ##opbudget line)."""
+def census_tiers() -> dict:
+    """tier name -> heavy_census dict for every kernel tier."""
     from tigerbeetle_tpu.ops import fast_kernels as fk
 
     state, ev, ev_s, seg = _fixtures()
@@ -193,31 +191,19 @@ def census_tiers(include_sharded: bool = True,
     }
     out = {}
     for name, (fn, args) in tiers.items():
-        if only is not None and name not in only:
-            continue
         out[name] = jaxhound.heavy_census(jax.make_jaxpr(fn)(*args))
     # Chain route (the default whole-window scan dispatch): the
     # whole-program census at three depths — ~constant heavy totals
     # prove the scan body lowers once — plus the per-iteration BODY
     # census the gate pins against the per-batch plain tier.
-    chain_names = tuple(f"chain_w{w}" for w in CHAIN_DEPTHS) + (
-        "chain_body_w8",)
-    if only is None or any(n in only for n in chain_names):
-        for w in CHAIN_DEPTHS:
-            name = f"chain_w{w}"
-            if only is not None and name not in only and not (
-                    w == 8 and "chain_body_w8" in only):
-                continue
-            ev_c, seg_c = _chain_fixture(w)
-            cj = jax.make_jaxpr(fk._create_transfers_chain)(
-                state, ev_c, seg_c)
-            if only is None or name in only:
-                out[name] = jaxhound.heavy_census(cj)
-            if w == 8 and (only is None or "chain_body_w8" in only):
-                out["chain_body_w8"] = jaxhound.scan_body_census(cj)
-    if only is not None:
-        include_sharded = False
-    if include_sharded and len(jax.devices()) >= 8:
+    for w in CHAIN_DEPTHS:
+        ev_c, seg_c = _chain_fixture(w)
+        cj = jax.make_jaxpr(fk._create_transfers_chain)(
+            state, ev_c, seg_c)
+        out[f"chain_w{w}"] = jaxhound.heavy_census(cj)
+        if w == 8:
+            out["chain_body_w8"] = jaxhound.scan_body_census(cj)
+    if len(jax.devices()) >= 8:
         from jax.sharding import Mesh
         from tigerbeetle_tpu.parallel.full_sharded import (
             make_sharded_create_transfers)
@@ -560,29 +546,6 @@ def check_budgets(current: dict | None = None) -> list[str]:
                 f"{tier}: heavy operand bytes "
                 f"{cur['heavy_operand_bytes']} > budget {limit_b}")
     return fails
-
-
-# Light subset for bench.py's per-run ##opbudget line (the full table
-# incl. deep/sharded tiers is the gate's job; tracing them every bench
-# run would eat the bench budget). chain_body_w8 is the serving route's
-# per-iteration op mass — the number the whole-window dispatch bills W
-# times per window.
-BENCH_TIERS = ("per_event_plain", "plain", "fixpoint_8",
-               "super_plain_s4", "chain_body_w8")
-
-
-def summary_line(current: dict | None = None) -> dict:
-    """Compact per-tier summary for bench.py's ##opbudget line and the
-    devhub table."""
-    if current is None:
-        current = census_tiers(only=BENCH_TIERS)
-    return {
-        tier: {
-            "heavy_total": c["heavy_total"],
-            "heavy": c["heavy"],
-            "operand_mb": round(c["heavy_operand_bytes"] / 1e6, 2),
-        } for tier, c in current.items()
-    }
 
 
 def main() -> int:

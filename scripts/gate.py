@@ -61,17 +61,13 @@ METRICS leg (testing/trace_coverage.py metrics_main: perf/slo.json
 must load with every objective on-catalog — a dead SLO is a RED — and
 a live /metrics endpoint over a seeded serving run must serve
 Prometheus-parseable text with per-route window histograms and SLO
-series; skip with --no-metrics), the BENCH-REGRESSION leg
-(testing/latency_smoke.py: live serving-window p99 vs the committed
-perf/latency_baseline.json and the BENCH_r*.json pinned p99
-trajectory; skip with --no-bench-regression), the STATIC leg
+series; skip with --no-metrics), the STATIC leg
 (testing/static_smoke.py: jaxhound 2.0's four whole-stack passes over
 the full serving-entry registry on an 8-device virtual mesh — device
 determinism, host-determinism AST lint, retrace/recompile audit vs the
 committed perf/tracebudget_r*.json, sharding-spec verification of the
 partitioned lowerings — plus one negative injected-violation proof per
-pass, each of which must RED; writes perf/static_status.json for the
-devhub panel; skip with --no-static), the CAUSALITY leg
+pass, each of which must RED; skip with --no-static), the CAUSALITY leg
 (testing/causality_smoke.py: causal request tracing end to end on a
 REAL 3-replica vortex at sampling 1.0 — one complete orphan-free span
 tree per client request, the commit causally attributed inside it,
@@ -490,33 +486,6 @@ def run_causality(timeout: int = 900) -> int:
     return rc
 
 
-def run_bench_regression(timeout: int = 600) -> int:
-    """Bench-regression leg: live serving-window p99 (seeded supervisor
-    workload) vs the committed perf/latency_baseline.json, plus the
-    committed BENCH_r*.json pinned p99 trajectory
-    (testing/latency_smoke.py; regenerate the baseline on a healthy
-    tree with `python -m tigerbeetle_tpu.testing.latency_smoke
-    --write-baseline`). Skip with --no-bench-regression."""
-    cmd = [sys.executable, "-c",
-           "import sys; "
-           "from tigerbeetle_tpu.testing import latency_smoke; "
-           "sys.exit(latency_smoke.regression_main([]))"]
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    print("[gate] bench-reg: serving-window p99 vs committed baseline",
-          flush=True)
-    t0 = time.time()
-    try:
-        p = subprocess.run(cmd, cwd=REPO, env=env, timeout=timeout)
-        rc = p.returncode
-    except subprocess.TimeoutExpired:
-        print(f"[gate] RED: bench-reg timed out after {timeout}s",
-              flush=True)
-        return 124
-    print(f"[gate] bench-reg rc={rc} in {time.time() - t0:.0f}s",
-          flush=True)
-    return rc
-
-
 def run_profile(timeout: int = 900) -> int:
     """Profile leg: the performance observatory proven live WITH its
     negatives (testing/observatory_smoke.py) — sampled per-dispatch
@@ -640,9 +609,6 @@ def main() -> int:
     ap.add_argument("--no-static", action="store_true",
                     help="skip the static leg (jaxhound determinism/"
                          "retrace/sharding passes + negative proofs)")
-    ap.add_argument("--no-bench-regression", action="store_true",
-                    help="skip the bench-regression leg (serving p99 "
-                         "vs committed baseline)")
     ap.add_argument("--mesh-devices", type=int, default=8)
     ap.add_argument("--timeout", type=int, default=840,
                     help="test-tier wall clock budget (s)")
@@ -700,10 +666,6 @@ def main() -> int:
         rc = run_causality()
         if rc != 0:
             reds.append(f"causality rc={rc}")
-    if not args.no_bench_regression:
-        rc = run_bench_regression()
-        if rc != 0:
-            reds.append(f"bench-reg rc={rc}")
     if not args.no_profile:
         rc = run_profile()
         if rc != 0:

@@ -434,73 +434,6 @@ def test_cluster_clock_and_release_sampling():
         assert r.clock.realtime_synchronized() is not None
 
 
-class TestDevhub:
-    def test_record_and_render(self, tmp_path):
-        from tigerbeetle_tpu import devhub
-
-        history = str(tmp_path / "h.jsonl")
-        out = str(tmp_path / "devhub.html")
-        for v in (100.0, 200.0, 150.0):
-            devhub.record(history, {"value": v, "config2_10k_tps": v * 2})
-        assert devhub.render(history, out) == 3
-        html = open(out).read()
-        assert "polyline" in html and "300" in html
-
-    def test_torn_history_line_skipped(self, tmp_path):
-        from tigerbeetle_tpu import devhub
-
-        history = tmp_path / "h.jsonl"
-        history.write_text('{"value": 1.0}\n{"val')  # torn tail
-        assert devhub.load(str(history)) == [{"value": 1.0}]
-
-    def test_regression_flagged_against_trailing_median(self, tmp_path):
-        """reference: the devhub run is the nightly perf gate
-        (src/scripts/devhub.zig:174-237) — a drop beyond tolerance vs
-        the trailing median must surface."""
-        from tigerbeetle_tpu import devhub
-
-        entries = [{"value": 300_000 + i * 1000,
-                    "serving_batch_latency": {"sustained_tps": 70_000,
-                                              "p99_ms": 90.0}}
-                   for i in range(8)]
-        # Healthy latest: no flags.
-        assert devhub.regressions(entries + [
-            {"value": 301_000,
-             "serving_batch_latency": {"sustained_tps": 71_000,
-                                       "p99_ms": 91.0}}]) == {}
-        # Throughput drop + latency spike: both flagged.
-        got = devhub.regressions(entries + [
-            {"value": 150_000,
-             "serving_batch_latency": {"sustained_tps": 30_000,
-                                       "p99_ms": 200.0}}])
-        assert set(got) == {"value", "serving_sustained_tps",
-                            "serving_p99_ms"}
-        assert got["value"]["ratio"] < 0.9
-        assert got["serving_p99_ms"]["ratio"] > 1.1
-
-    def test_render_surfaces_cfo_failing_seeds(self, tmp_path):
-        from tigerbeetle_tpu import devhub
-
-        history = str(tmp_path / "h.jsonl")
-        devhub.record(history, {"value": 1.0,
-                                "config5_oracle_parity": True})
-        cfo_dir = tmp_path / "cfo"
-        cfo_dir.mkdir()
-        (cfo_dir / "CFO_r04.json").write_text(json.dumps({
-            "runs_clean": 10, "runs_failing": 1, "elapsed_s": 5.0,
-            "failing": [{"kind": "vopr", "name": "vopr", "seed": 777,
-                         "error": "AssertionError(...)",
-                         "reproduce": "python -m tigerbeetle_tpu cfo "
-                                      "--kind vopr --seed 777 "
-                                      "--max-runs 1"}]}))
-        out = str(tmp_path / "d.html")
-        devhub.render(history, out, cfo_dir=str(cfo_dir))
-        doc = open(out).read()
-        assert "continuous fuzzing" in doc and "777" in doc
-        assert "--kind vopr --seed 777" in doc
-        assert "oracle parity: 1/1" in doc
-
-
 class TestJaxhound:
     def test_report_accounts_kernel(self):
         import re

@@ -21,8 +21,8 @@ import pytest
 
 import jax
 
-from tigerbeetle_tpu.benchmark import _soa
 from tigerbeetle_tpu.ops import fast_kernels as fk
+from tigerbeetle_tpu.ops.batch import transfers_soa, transfers_to_arrays
 from tigerbeetle_tpu.ops.ledger import DeviceLedger, stack_superbatch
 from tigerbeetle_tpu.types import Account, Transfer, TransferFlags
 
@@ -52,8 +52,8 @@ def _mk_windows(seed=5, poison_window=None):
             if poison_window == w:
                 # balancing_credit (1<<5) is a hard E1 fallback.
                 flags[3] = np.uint32(int(TransferFlags.balancing_credit))
-            ev = _soa(np.arange(nid, nid + N), dr, cr,
-                      rng.integers(1, 1000, N), flags=flags)
+            ev = transfers_soa(np.arange(nid, nid + N), dr, cr,
+                               rng.integers(1, 1000, N), flags=flags)
             nid += N
             evs.append(ev)
             tss.append(ts)
@@ -61,6 +61,23 @@ def _mk_windows(seed=5, poison_window=None):
         ev_s, seg = stack_superbatch(evs, tss)
         windows.append((ev_s, seg))
     return windows
+
+
+def test_transfers_soa_equals_transfers_to_arrays():
+    """The column builder makes the dict the object path makes."""
+    ids, dr, cr = [7, 8, 1 << 40], [1, 2, 3], [2, 3, 1]
+    amount, flags = [5, 1 << 33, 9], [0, int(TransferFlags.pending), 0]
+    got = transfers_soa(ids, dr, cr, amount, flags=flags)
+    want = transfers_to_arrays([
+        Transfer(id=i, debit_account_id=d, credit_account_id=c, amount=a,
+                 ledger=1, code=1, flags=f)
+        for i, d, c, a, f in zip(ids, dr, cr, amount, flags)])
+    assert sorted(got) == sorted(want)
+    for key, column in want.items():
+        assert got[key].dtype == column.dtype, key
+        assert (got[key] == column).all(), key
+    plain = transfers_soa(ids, dr, cr, amount)
+    assert not plain["flags"].any() and plain["flags"].dtype == np.uint32
 
 
 def _fresh_state():

@@ -1,7 +1,6 @@
 """The SLO-grade latency plane: Prometheus exposition + endpoint, SLO
-engine + burn rates, critical-path attribution, the gate's
-bench-regression leg (including the injected-slowdown negative test),
-devhub panels, and the scraped-vs-offline p99 parity acceptance."""
+engine + burn rates, critical-path attribution, and the
+scraped-vs-offline p99 parity acceptance."""
 
 import dataclasses
 import json
@@ -14,9 +13,7 @@ from tigerbeetle_tpu.metrics import (MetricsServer, parse_prometheus,
 from tigerbeetle_tpu.trace import Event, Tracer
 from tigerbeetle_tpu.trace.histogram import REL_ERROR, Histogram
 from tigerbeetle_tpu.trace.merge import critical_path, span_quantile
-from tigerbeetle_tpu.trace.slo import (burn_rates, evaluate,
-                                       evaluate_bench_record,
-                                       load_objectives)
+from tigerbeetle_tpu.trace.slo import burn_rates, evaluate, load_objectives
 
 
 def _tracer_with_latency_series():
@@ -215,25 +212,6 @@ def test_burn_rates_and_badges():
     assert burn3["evaluated"] == 1 and burn3["badge"] is False
 
 
-def test_evaluate_bench_record():
-    cfg = load_objectives()
-    h = Histogram()
-    h.record_many([300.0] * 50)  # ms, over the 250ms chain threshold
-    record = {"serving_batch_latency": {"histogram": h.to_dict(),
-                                        "p99_ms": 300.0}}
-    rows = {r["name"]: r
-            for r in evaluate_bench_record(record, cfg["objectives"])}
-    assert rows["chain_window_p99_ms"]["ok"] is False
-    assert rows["window_p99_ms"]["ok"] is True  # 300 <= 400
-    # No histogram: the pinned p99 is the q=0.99 fallback.
-    rows2 = {r["name"]: r for r in evaluate_bench_record(
-        {"serving_batch_latency": {"p99_ms": 120.0}}, cfg["objectives"])}
-    assert rows2["chain_window_p99_ms"]["value"] == 120.0
-    # Records without the series evaluate unknown.
-    rows3 = evaluate_bench_record({}, cfg["objectives"])
-    assert all(r["ok"] is None for r in rows3)
-
-
 # ------------------------------------------------------- critical path
 
 def _span(name, ts, dur, pid=0, **args):
@@ -281,16 +259,47 @@ def test_critical_path_empty():
     assert critical_path({"traceEvents": []}) is None
 
 
-# --------------------------------------- live parity + regression leg
+# ---------------------------------------------------------- live parity
+
+def _serve_windows(tracer, windows=7):
+    """A seeded supervisor run of `windows` commit windows of two
+    batches each, traced."""
+    from tigerbeetle_tpu.serving import RetryPolicy, ServingSupervisor
+    from tigerbeetle_tpu.types import Account, Transfer
+
+    n_accounts, per_batch = 32, 64
+
+    # epoch_interval past the run length: an epoch verification costs
+    # an order of magnitude more than a window and would own p99.
+    sup = ServingSupervisor(
+        a_cap=1 << 9, t_cap=1 << 12, epoch_interval=2 * windows + 1,
+        retry=RetryPolicy(max_retries=2, base_delay_s=1e-3,
+                          max_delay_s=4e-3, deadline_s=30.0),
+        seed=1234, tracer=tracer)
+    ts = 1_000
+    sup.create_accounts([Account(id=i, ledger=1, code=1)
+                         for i in range(1, n_accounts + 1)], ts)
+    next_id = 1_000_000
+    for _ in range(windows):
+        batches, stamps = [], []
+        for _ in range(2):
+            batches.append([Transfer(
+                id=i, debit_account_id=i % n_accounts + 1,
+                credit_account_id=(i % n_accounts + 1) % n_accounts + 1,
+                amount=1 + i % 7, ledger=1, code=1)
+                for i in range(next_id, next_id + per_batch)])
+            next_id += per_batch
+            ts += per_batch + 10
+            stamps.append(ts)
+        sup.create_transfers_window(batches, stamps)
+
 
 def test_endpoint_p99_matches_offline_trace():
     """Acceptance: the endpoint's per-route window histogram p99 agrees
     with the offline (merged-trace) exact quantile within the histogram
     error bound."""
-    from tigerbeetle_tpu.testing.latency_smoke import measure
-
     t = Tracer(pid=0)
-    measure(windows=6, warmup=1, tracer=t)
+    _serve_windows(t)
     parsed = parse_prometheus(render_prometheus(t))
     # The supervisor tagged every window_commit span with its route.
     routes = {lab.get("route")
@@ -303,92 +312,6 @@ def test_endpoint_p99_matches_offline_trace():
             merged.merge(t.histograms[key])
     got_ms = merged.quantile(0.99) / 1000.0
     assert abs(got_ms - exact) / exact <= 2 * REL_ERROR
-
-
-def test_bench_regression_leg_pass_and_injected_fail(monkeypatch):
-    """The gate leg passes on the unmodified tree and REDs under an
-    injected 2x-baseline per-window slowdown."""
-    from tigerbeetle_tpu.testing import latency_smoke
-
-    monkeypatch.delenv("TB_TPU_LATENCY_INJECT_MS", raising=False)
-    assert latency_smoke.regression_main(["--windows", "6"]) == 0
-    with open(latency_smoke.BASELINE_PATH) as f:
-        base_p99 = json.load(f)["p99_ms"]
-    monkeypatch.setenv("TB_TPU_LATENCY_INJECT_MS",
-                       str(2.0 * base_p99 + 10.0))
-    assert latency_smoke.regression_main(["--windows", "6"]) >= 1
-
-
-def test_bench_trajectory_guard(tmp_path, monkeypatch):
-    from tigerbeetle_tpu.testing import latency_smoke
-
-    def rec(name, p99):
-        (tmp_path / name).write_text(json.dumps(
-            {"parsed": {"serving_batch_latency": {"p99_ms": p99}}}))
-
-    rec("BENCH_r01.json", 80.0)
-    rec("BENCH_r02.json", 90.0)
-    monkeypatch.setattr(latency_smoke, "BENCH_GLOB",
-                        str(tmp_path / "BENCH_r*.json"))
-    assert latency_smoke.check_trajectory() == 0
-    rec("BENCH_r03.json", 170.0)  # 2.1x the best prior (80)
-    assert latency_smoke.check_trajectory() == 1
-
-
-def test_bench_trajectory_backcompat_pre_observatory_records(tmp_path):
-    """Schema stability across record generations (ISSUE 20): a
-    pre-observatory BENCH record — no `profile` sub-dict anywhere —
-    must audit identically to a new record that carries the full
-    ##profile payload. The trajectory audit keys only on the pinned
-    serving p99, and the ratio check still bites across the
-    generation boundary."""
-    from tigerbeetle_tpu.testing import latency_smoke
-
-    old = {"config": {"quick": True},
-           "parsed": {"serving_batch_latency": {"p99_ms": 80.0}}}
-    assert "profile" not in old and "profile" not in old["parsed"]
-    new = {"config": {"quick": True},
-           "parsed": {"serving_batch_latency": {"p99_ms": 88.0}},
-           "profile": {"cost_model": {"tiers": {}},
-                       "dispatch_device_time": {}, "roofline": {},
-                       "memwatch": {"reds": []}}}
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(old))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(new))
-    bench_glob = str(tmp_path / "BENCH_r*.json")
-    assert latency_smoke.check_trajectory(bench_glob) == 0
-    # The guard still REDs across the boundary: a regressed NEW record
-    # against an old-format best prior.
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps(
-        dict(new, parsed={"serving_batch_latency": {"p99_ms": 170.0}})))
-    assert latency_smoke.check_trajectory(bench_glob) == 1
-
-
-# ------------------------------------------------------- devhub panels
-
-def test_devhub_slo_and_critical_path_panels(tmp_path):
-    from tigerbeetle_tpu import devhub
-
-    history = str(tmp_path / "history.jsonl")
-    out = str(tmp_path / "devhub.html")
-    h = Histogram()
-    h.record_many([300.0] * 40)  # breaches chain_window_p99_ms (250ms)
-    cp = {"window_event": "window_commit", "windows_total": 40,
-          "windows_analyzed": 4, "slow_quantile": 0.9,
-          "threshold_ms": 200.0, "p99_ms": 310.0,
-          "stage_share": {"serving_dispatch": 0.7, "other": 0.3},
-          "p99_owner": "serving_dispatch"}
-    devhub.record(history, {
-        "value": 1.0,
-        "serving_batch_latency": {"p99_ms": 300.0,
-                                  "histogram": h.to_dict()},
-        "trace": {"critical_path": cp},
-    })
-    assert devhub.render(history, out) == 1
-    html_text = open(out).read()
-    assert "SLOs (perf/slo.json" in html_text
-    assert "BREACHED" in html_text
-    assert "p99 critical path" in html_text
-    assert "serving_dispatch" in html_text
 
 
 # --------------------------------------------- vortex cluster scrape

@@ -301,6 +301,40 @@ def test_the_scenarios_exercise_what_they_claim():
         ((at == np.asarray(b1)) | (at == np.asarray(b2))).all()
 
 
+def test_ht_lookup_finds_what_ht_insert_put():
+    """`ht_insert` then `ht_lookup`, called by name: every inserted key
+    is found with its value, ten that share a first-choice bucket (two
+    of them sit in their second choice) among them; a key never
+    inserted and the zero sentinel are absent."""
+    import jax.numpy as jnp
+
+    from tigerbeetle_tpu.ops import hash_table as ht
+
+    table = ht.ht_init(1 << 9)
+    crowd = np.array(_same_bucket_ids(ht.ht_buckets(table), 10, 1),
+                     dtype=np.uint64)
+    b1, _ = ht._buckets(np.zeros_like(crowd), crowd, ht.ht_buckets(table))
+    assert len(set(np.asarray(b1).tolist())) == 1 and len(crowd) > ht.SLOTS
+    rng = np.random.default_rng(5)
+    k_lo = np.concatenate([crowd, np.setdiff1d(
+        rng.integers(1 << 20, 1 << 40, 90, dtype=np.uint64), crowd)])
+    k_hi = np.concatenate([np.zeros(len(crowd), dtype=np.uint64),
+                           rng.integers(0, 1 << 63, len(k_lo) - len(crowd),
+                                        dtype=np.uint64)])
+    vals = np.arange(len(k_lo), dtype=np.int32)
+    table, ok = ht.ht_insert(table, jnp.asarray(k_hi), jnp.asarray(k_lo),
+                             jnp.asarray(vals),
+                             jnp.ones(len(k_lo), dtype=bool))
+    assert bool(ok)
+    absent = np.array([1 << 50, 0], dtype=np.uint64)  # a miss, the sentinel
+    found, val = ht.ht_lookup(
+        table, jnp.asarray(np.concatenate([k_hi, np.zeros(2, np.uint64)])),
+        jnp.asarray(np.concatenate([k_lo, absent])))
+    found, val = np.asarray(found), np.asarray(val)
+    assert found[:-2].all() and (val[:-2] == vals).all()
+    assert not found[-2:].any() and (val[-2:] == -1).all()
+
+
 @pytest.mark.parametrize("n", [64, 1 << 17])
 def test_worst_case_loads_are_exact_in_u32_pieces(n):
     """The headroom proof's per-account sums accumulate in u32 pieces

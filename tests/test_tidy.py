@@ -5,6 +5,8 @@ import ast
 import pathlib
 import re
 
+import pytest
+
 REPO = pathlib.Path(__file__).resolve().parent.parent
 PACKAGE = REPO / "tigerbeetle_tpu"
 
@@ -117,3 +119,154 @@ def test_no_reference_code_imports():
     """Nothing may read from /root/reference at runtime."""
     for path in _python_files():
         assert "/root/reference" not in path.read_text(), path
+
+
+# ------------------------------------------------ the tree's shape (PR 33)
+
+def _module_name(path: pathlib.Path) -> str:
+    parts = path.relative_to(REPO).with_suffix("").parts
+    return ".".join(parts[:-1] if parts[-1] == "__init__" else parts)
+
+
+MODULES = {_module_name(p): p for p in _python_files()}
+
+
+def _imported_names(path: pathlib.Path, this: str) -> set[str]:
+    """Every absolute dotted name an import statement of `path` names,
+    function-level imports and the Python source held in its string
+    constants (the gate's `-c` programs) included."""
+    source = ast.parse(path.read_text(), filename=str(path))
+    trees = [source]
+    for node in ast.walk(source):
+        if isinstance(node, ast.Constant) and isinstance(node.value, str) \
+                and "import" in node.value:
+            try:
+                trees.append(ast.parse(node.value))
+            except SyntaxError:
+                pass
+    package = this.split(".")
+    if path.name != "__init__.py":
+        package = package[:-1]
+    names = set()
+    for tree in trees:
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                names.update(a.name for a in node.names)
+            elif isinstance(node, ast.ImportFrom):
+                base = package[:len(package) - node.level + 1] \
+                    if node.level else []
+                base = ".".join(base + ([node.module] if node.module else []))
+                names.add(base)
+                names.update(f"{base}.{a.name}" for a in node.names)
+    return names
+
+
+def _package_imports(path: pathlib.Path, this: str) -> set[str]:
+    """The package's modules that `path` imports, parents included
+    (importing a.b.c runs a and a.b)."""
+    out = set()
+    for name in _imported_names(path, this):
+        while name:
+            if name in MODULES:
+                out.add(name)
+            name = name.rpartition(".")[0]
+    return out
+
+
+# What `start` serves from, and what may not know what stands on it.
+SERVED = ("ops", "lsm", "vsr", "oracle", "state_machine", "types")
+ABOVE = {"serving", "admission", "testing", "jaxhound", "metrics", "repl",
+         "cdc", "amqp", "main"}
+SCRIPTS = {p.stem for p in REPO.glob("*.py")} | {
+    "scripts", "perf", "chipbench", "tests"}
+
+
+@pytest.mark.parametrize("pkg", SERVED)
+def test_served_path_imports_nothing_above_it(pkg):
+    """The fence for ROADMAP D12: the layers under `Replica` import no
+    second served path, no test harness, no tool and no script."""
+    checked = 0
+    for name, path in MODULES.items():
+        if name.split(".")[1:2] != [pkg]:
+            continue
+        checked += 1
+        for imported in _imported_names(path, name):
+            parts = imported.split(".")
+            assert parts[0] not in SCRIPTS, f"{name} imports {imported}"
+            assert not (parts[0] == "tigerbeetle_tpu"
+                        and parts[1:2] and parts[1] in ABOVE), \
+                f"{name} imports {imported}"
+    assert checked, f"no module under tigerbeetle_tpu/{pkg}"
+
+
+def _registered_subcommands() -> list[str]:
+    tree = ast.parse((PACKAGE / "main.py").read_text())
+    return [node.args[0].value for node in ast.walk(tree)
+            if isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == "add_parser"]
+
+
+def _documented_subcommands() -> tuple[set[str], set[str]]:
+    """(README's tooling line, main.py's usage docstring)."""
+    readme = re.search(r"python -m tigerbeetle_tpu \{([^}]*)\}",
+                       (REPO / "README.md").read_text())
+    usage = ast.get_docstring(ast.parse((PACKAGE / "main.py").read_text()))
+    return (set(re.sub(r"\s", "", readme.group(1)).split(",")),
+            set(re.findall(r"(?:^  |\|  )([a-z]+)\b", usage, re.M)))
+
+
+REGISTERED = _registered_subcommands()
+
+
+@pytest.mark.parametrize("name", REGISTERED)
+def test_subcommand_is_documented(name):
+    for where, documented in zip(("README.md's tooling line",
+                                  "main.py's usage docstring"),
+                                 _documented_subcommands()):
+        assert name in documented, f"{where} lacks `{name}`"
+        unknown = documented - set(REGISTERED)
+        assert not unknown, f"{where} names {sorted(unknown)}, not registered"
+
+
+def test_no_orphan_module():
+    """Every module of the package is an entry point (`__main__`) or is
+    imported, directly or through others, by an entry point, a gate leg
+    or a test."""
+    roots = [PACKAGE / "main.py", REPO / "chip_smoke.py",
+             REPO / "scripts" / "gate.py", REPO / "__graft_entry__.py",
+             REPO / "perf" / "opbudget.py",
+             *sorted((REPO / "tests").glob("*.py"))]
+    todo = [(path, _module_name(path)) for path in roots]
+    reached = {name for _, name in todo if name in MODULES}
+    while todo:
+        path, name = todo.pop()
+        for imported in _package_imports(path, name) - reached:
+            reached.add(imported)
+            todo.append((MODULES[imported], imported))
+    orphans = [name for name, path in sorted(MODULES.items())
+               if name not in reached
+               and path.name != "__main__.py"
+               and '__name__ == "__main__"' not in path.read_text()]
+    assert not orphans, f"modules nothing imports: {orphans}"
+
+
+# A path with a `*` in it is a pattern, not a file: the lookbehind
+# keeps the tail of one (`_r*.json`) from matching.
+DOC_PATH = re.compile(r"(?<![\w/*.-])[\w./-]*\w\.(?:py|json|cpp|md)\b")
+DOCS = {
+    "README.md": lambda: (REPO / "README.md").read_text(),
+    "scripts/gate.py": lambda: ast.get_docstring(
+        ast.parse((REPO / "scripts" / "gate.py").read_text())),
+}
+
+
+@pytest.mark.parametrize("doc", DOCS)
+def test_docs_name_only_files_that_exist(doc):
+    """A path a reader is sent to exists under the repo root or the
+    package."""
+    named = set(DOC_PATH.findall(DOCS[doc]()))
+    assert len(named) >= 8, f"{doc}: the pattern found only {sorted(named)}"
+    missing = [m for m in sorted(named)
+               if not (REPO / m).exists() and not (PACKAGE / m).exists()]
+    assert not missing, f"{doc} names files that do not exist: {missing}"

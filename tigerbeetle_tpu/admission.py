@@ -241,7 +241,7 @@ class AdmissionPlane:
         self._forced_level: int | None = None
         self._clean_ticks = 0
         self._tick = 0
-        # Cumulative per-class accounting (the ##admission record).
+        # Cumulative per-class accounting (`stats()`).
         self.submitted = {c.name: 0 for c in self.classes}
         self.admitted = {c.name: 0 for c in self.classes}
         self.shed_counts = {c.name: {} for c in self.classes}
@@ -660,7 +660,7 @@ class AdmissionPlane:
                 "ok": sub == adm + shed + self._queued_total + staged}
 
     def stats(self) -> dict:
-        """The ##admission record: per-class admitted/shed + wait
+        """The plane's record: per-class admitted/shed + wait
         distributions, the shed line, occupancy, and conservation."""
         per_class = {}
         for c in self.classes:

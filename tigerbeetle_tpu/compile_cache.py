@@ -1,6 +1,8 @@
-"""The one persistent XLA compile cache every entry point shares
-(`start`, `benchmark`, bench.py; chip_smoke.py reports what `start`
-prints).
+"""The one persistent XLA compile cache. Two places use it: `start`
+(`main.py` `cmd_start`) turns it on with `enable()`, and the warm-up
+(`ops/warmup.py` `warmup_kernels`) precompiles its warm set in parallel
+where JAX's cache directory is set. `chip_smoke.py` reports what
+`start` prints.
 
 Serving kernels at production caps take tens of seconds each to compile
 for a TPU, so a server that recompiled them at every boot would spend

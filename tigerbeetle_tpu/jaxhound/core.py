@@ -244,7 +244,7 @@ def newest_budget_path(perf_dir: str | None = None) -> str:
     """Path of the NEWEST committed perf/opbudget_r*.json (highest
     round number). The budget trail is append-oriented — every round
     that moves a pinned census commits a new file — so consumers
-    (devhub, smokes, the gate) resolve the head dynamically instead of
+    (smokes, the gate) resolve the head dynamically instead of
     hardcoding a round that silently goes stale."""
     return _newest_round_path(perf_dir, "opbudget")
 
@@ -405,7 +405,7 @@ def analyze_lowered(lowered) -> dict:
         "stats": stats,
     }
     if unavailable:
-        # Consumers (report(), devhub) render "n/a: <reason>" instead
+        # Consumers (report()) render "n/a: <reason>" instead
         # of mistaking a swallowed backend failure for zero cost.
         out["stats_unavailable"] = "; ".join(unavailable)
     return out

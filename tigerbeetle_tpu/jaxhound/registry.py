@@ -76,7 +76,7 @@ class Entry:
 def _mk_prepares(n_prepares, n=_N_SUPER, nid0=10 ** 6, seed=0):
     import numpy as np
 
-    from tigerbeetle_tpu.benchmark import _soa
+    from tigerbeetle_tpu.ops.batch import transfers_soa
 
     rng = np.random.default_rng(seed)
     evs, tss = [], []
@@ -84,8 +84,8 @@ def _mk_prepares(n_prepares, n=_N_SUPER, nid0=10 ** 6, seed=0):
     for b in range(n_prepares):
         dr = rng.integers(1, 64, n, dtype=np.uint64)
         cr = (dr % 63) + 1
-        evs.append(_soa(np.arange(nid, nid + n), dr, cr,
-                        rng.integers(1, 100, n)))
+        evs.append(transfers_soa(np.arange(nid, nid + n), dr, cr,
+                                 rng.integers(1, 100, n)))
         nid += n
         tss.append(10 ** 12 + b * (n + 10))
     return evs, tss

@@ -61,7 +61,7 @@ def unwrap(raw: bytes, kind: BlockKind) -> bytes:
 
 def classify(raw: bytes):
     """(BlockKind, tree_id, payload_len) of any block, or None if the
-    bytes carry no valid header (inspect/devhub tooling)."""
+    bytes carry no valid header (inspect tooling)."""
     if len(raw) < BLOCK_HEADER_SIZE:
         return None
     magic, kind, version, tree_id, payload_len, _ = _FMT.unpack_from(raw)

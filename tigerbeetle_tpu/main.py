@@ -7,9 +7,12 @@ reference: src/tigerbeetle/main.zig (commands :146-186) + cli.zig. Commands:
              [--account-capacity=N] [--transfer-capacity=N] <path>
   recover    <aof> <path>  |  --from-cluster --addresses=... <path>
   repl       --addresses=... [--cluster=N]
-  benchmark  [--transfer-count=N] [--account-count=N]
   inspect    [--integrity] [--digest] <path>
-  version
+  amqp       --addresses=... --amqp=host:port  (CDC pump to a broker)
+  multiversion <path>  |  fuzz <name> [seed]  |  cfo [--kind=...]
+  jaxhound   [--kernel=NAME]  |  clients [--out=DIR]  |  version
+
+Speed is measured by `python3 chipbench/run.py` (BENCHMARK.json), not here.
 """
 
 from __future__ import annotations
@@ -367,31 +370,6 @@ def cmd_repl(args) -> int:
         run_repl(client)
     finally:
         client.close()
-    return 0
-
-
-def cmd_benchmark(args) -> int:
-    import json
-
-    if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
-
-    from .benchmark import bench_config2, bench_config_zipfian
-
-    batches = max(1, args.transfer_count // 8190)
-    if args.zipfian:
-        accepted, elapsed = bench_config_zipfian(
-            batches, account_count=args.account_count, theta=args.theta)
-    else:
-        accepted, elapsed = bench_config2(
-            batches, account_count=args.account_count)
-    print(json.dumps({
-        "load_accepted_tx_per_s": round(accepted / elapsed, 1),
-        "transfers": accepted,
-        "seconds": round(elapsed, 3),
-    }))
     return 0
 
 
@@ -882,34 +860,13 @@ def cmd_jaxhound(args) -> int:
     return 0
 
 
-def cmd_devhub(args) -> int:
-    """Record bench results + render the metrics dashboard (reference:
-    src/scripts/devhub.zig + devhub.tigerbeetle.com)."""
-    from . import devhub
-
-    if args.record:
-        with open(args.record) as f:
-            devhub.record(args.history, json.load(f))
-    entries = devhub.load(args.history)
-    regress = devhub.regressions(entries)
-    n = devhub.render(args.history, args.out, cfo_dir=args.cfo_dir,
-                      entries=entries, regress=regress)
-    for key, r in regress.items():
-        print(f"devhub: REGRESSION {key}: {r['latest']:,.0f} is "
-              f"{r['ratio']:.2f}x of trailing median {r['baseline']:,.0f}")
-    print(f"devhub: {n} runs -> {args.out}")
-    # Nonzero on regression so CI can gate on it (reference: the devhub
-    # run IS the nightly perf gate, src/scripts/devhub.zig:174-237).
-    return 2 if regress and args.strict else 0
-
-
 def cmd_cfo(args) -> int:
     """Continuous fuzzing orchestrator: interleave random single-
     component fuzzer runs with WHOLE-CLUSTER VOPR swarm seeds (random
     topology + fault config + audited workload), recording failing
     seeds and a results artifact (reference: src/scripts/cfo.zig —
     fleet machines run fuzzers AND VOPR 24/7, failing seeds pushed to
-    devhubdb)."""
+    its dashboard's database)."""
     import random as _random
     import time as _time
 
@@ -1105,15 +1062,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--client-id", type=int, default=1)
     p.set_defaults(fn=cmd_repl)
 
-    p = sub.add_parser("benchmark")
-    p.add_argument("--transfer-count", type=int, default=100_000)
-    p.add_argument("--account-count", type=int, default=10_000)
-    p.add_argument("--zipfian", action="store_true",
-                   help="Zipfian hot-account workload (reference default)")
-    p.add_argument("--theta", type=float, default=0.99)
-    p.add_argument("--platform", default=None)
-    p.set_defaults(fn=cmd_benchmark)
-
     p = sub.add_parser("inspect")
     p.add_argument("--small", action="store_true")
     p.add_argument("--integrity", action="store_true",
@@ -1164,18 +1112,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--kernel", default=None)
     p.add_argument("--platform", default=None)
     p.set_defaults(fn=cmd_jaxhound)
-
-    p = sub.add_parser("devhub")
-    p.add_argument("--record", default=None,
-                   help="bench JSON file to append to the history")
-    p.add_argument("--history", default="devhub_history.jsonl")
-    p.add_argument("--out", default="devhub.html")
-    p.add_argument("--cfo-dir", default="cfo",
-                   help="directory of CFO sweep artifacts to surface")
-    p.add_argument("--strict", action="store_true",
-                   help="exit 2 when a metric regressed vs its trailing "
-                        "median (the nightly perf gate)")
-    p.set_defaults(fn=cmd_devhub)
 
     p = sub.add_parser("cfo")
     p.add_argument("--budget-s", type=float, default=0,

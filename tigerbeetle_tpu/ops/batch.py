@@ -107,7 +107,7 @@ def encode_create_results(st: np.ndarray, ts: np.ndarray) -> bytes:
 
 def transfers_to_arrays(transfers: list[Transfer]) -> dict:
     """Convert a list of Transfer objects to SoA numpy arrays (slow path;
-    benchmarks generate arrays directly)."""
+    `transfers_soa` builds the same dict from columns)."""
     ids = [t.id for t in transfers]
     drs = [t.debit_account_id for t in transfers]
     crs = [t.credit_account_id for t in transfers]
@@ -134,6 +134,33 @@ def transfers_to_arrays(transfers: list[Transfer]) -> dict:
         code=np.array([t.code for t in transfers], dtype=np.uint32),
         flags=np.array([t.flags for t in transfers], dtype=np.uint32),
         ts=np.array([t.timestamp for t in transfers], dtype=np.uint64),
+    )
+
+
+def transfers_soa(ids, dr, cr, amount, flags=None) -> dict:
+    """The SoA dict of `transfers_to_arrays` built from columns: ids,
+    accounts and amounts under 2^64, ledger 1, code 1, everything else
+    zero (generated workloads; no Transfer objects)."""
+    n = len(ids)
+
+    def z64():
+        return np.zeros(n, dtype=np.uint64)
+
+    def z32():
+        return np.zeros(n, dtype=np.uint32)
+
+    return dict(
+        id_hi=z64(), id_lo=np.asarray(ids, dtype=np.uint64),
+        dr_hi=z64(), dr_lo=np.asarray(dr, dtype=np.uint64),
+        cr_hi=z64(), cr_lo=np.asarray(cr, dtype=np.uint64),
+        amt_hi=z64(), amt_lo=np.asarray(amount, dtype=np.uint64),
+        pid_hi=z64(), pid_lo=z64(),
+        ud128_hi=z64(), ud128_lo=z64(), ud64=z64(), ud32=z32(),
+        timeout=z32(),
+        ledger=np.ones(n, dtype=np.uint32),
+        code=np.ones(n, dtype=np.uint32),
+        flags=z32() if flags is None else np.asarray(flags, dtype=np.uint32),
+        ts=z64(),
     )
 
 

@@ -683,9 +683,7 @@ def per_event_status(state, ev, ts_event, return_gathers=False,
     row gathers for the single-device caller to reuse (the SPMD path must
     NOT ship them — it re-gathers locally to keep the all-gather
     compact)."""
-    # TB_PALLAS=1 routes VMEM-admissible probes through the fused Pallas
-    # kernel (ops/pallas_kernels.py); default is the XLA path.
-    from .pallas_kernels import ht_lookup_auto as ht_lookup
+    from .hash_table import ht_lookup
 
     acc = state["accounts"]
     xfr = state["transfers"]
@@ -2238,7 +2236,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
     # Per-cause fallback observability (scalar bools, nonzero only when
     # the batch actually fell back): the host drivers accumulate these
     # into counters so "zero host fallbacks on a mixed window" is a
-    # MEASURED invariant (bench.py diagnostics / devhub.py), not an
+    # MEASURED invariant (`start`'s shutdown record), not an
     # assumption. `limit`/`closing`/`e5`/`e2` may be escalations the
     # caller resolves on a deeper tier — the drivers count those
     # separately from true host fallbacks.
@@ -2356,12 +2354,11 @@ create_transfers_super_deep_ring_jit = jax.jit(
 #
 # Window round budget: 24 (measured: the config4 window workload at
 # bench scale — 8 x 8190-event prepares, 64 limited accounts —
-# converges at 24 rounds with the same-round death fold, 6/6 windows;
-# perf/fixpoint_benchscale_probe.py). An unconverged window falls
-# back to the per-batch ladder whose own deep tier keeps the full 32
-# rounds (single batches cascade shallower than windows), so the cut
-# is pure throughput: 25% less round mass on the config4-dominant
-# kernel with an on-device escape hatch.
+# converges at 24 rounds with the same-round death fold, 6/6 windows).
+# An unconverged window falls back to the per-batch ladder whose own
+# deep tier keeps the full 32 rounds (single batches cascade shallower
+# than windows), so the cut is pure throughput: 25% less round mass on
+# the config4-dominant kernel with an on-device escape hatch.
 LIMIT_FIXPOINT_ROUNDS_WINDOW_DEEP = 24
 create_transfers_super_deep_jit = jax.jit(
     _create_transfers_super_deep, donate_argnums=0)

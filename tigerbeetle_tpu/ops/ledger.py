@@ -977,7 +977,7 @@ class DeviceLedger:
         # Per-cause host-fallback counters (kernel fb_causes flags,
         # accumulated at every final-fallback decision): the measured
         # "why did we leave the device" record surfaced through
-        # bench.py diagnostics and devhub.py.
+        # fallback_stats() and `start`'s shutdown record.
         self.fallback_causes: dict = {}
         # Dispatch-route observability: per-route window counts
         # ("chain" is the default scan-form whole-window route) and the
@@ -1310,8 +1310,7 @@ class DeviceLedger:
     def staging_summary(self) -> dict:
         """The fallback_stats()["staging"] record: windows through the
         pipelined submit path, how many consumed a staged pack, and the
-        measured host-stall split the overlap gate leg and bench ##diag
-        read."""
+        measured host-stall split the overlap gate leg reads."""
         st = self.staging_stats
         frac = (st["stall_ms"] / st["work_ms"]) if st["work_ms"] else None
         return {
@@ -3067,8 +3066,8 @@ class DeviceLedger:
                 "transfer_rows": int(self.state["transfers"]["count"])}
 
     def fallback_stats(self) -> dict:
-        """Host-visible routing/fallback counters (bench diagnostics +
-        devhub): 'zero host fallbacks' is a measured invariant.
+        """Host-visible routing/fallback counters (`start`'s shutdown
+        record): 'zero host fallbacks' is a measured invariant.
 
         Which counter counts what (they overlap, so their sum counts
         nothing): `fast_batches` is the count of create REQUESTS

@@ -1407,10 +1407,7 @@ class PartitionedRouter:
             },
             # Device telemetry plane: everything below decodes from the
             # fixed-layout u32 block harvested with the outputs —
-            # measured on device, never host-side guesswork. The
-            # exchange-occupancy histogram dict is what the SLO
-            # engine's exchange-headroom burn objective reads
-            # (trace/slo.py evaluate_bench_record).
+            # measured on device, never host-side guesswork.
             "telemetry": None if not self.telemetry else {
                 "device_poison_causes": dict(self.device_poison_causes),
                 "writeback_rows": int(self.writeback_rows),

@@ -32,8 +32,7 @@ the TPU serving path the same property, in three parts:
    the oracle's answers, rebuilds a fresh mirror + device state from
    the recovered oracle (`from_host`, the same path a restart takes),
    and resumes kernel serving. Per-cause recovery counters surface
-   through `DeviceLedger.fallback_stats()["recovery"]`, bench.py's
-   ``##bench`` line, and the devhub dashboard.
+   through `DeviceLedger.fallback_stats()["recovery"]`.
 
 Fault model, detection latency, and the reproduction workflow are
 documented in ARCHITECTURE.md ("Fault model & recovery"); the seeded
@@ -236,8 +235,8 @@ class ServingSupervisor:
 
     def _attach(self, led: DeviceLedger) -> None:
         self.led = led
-        # The ledger surfaces OUR counters through fallback_stats() so
-        # bench/devhub records carry them next to the fallback causes.
+        # The ledger surfaces OUR counters through fallback_stats(),
+        # next to the fallback causes.
         led.recovery_stats = self.counters
         # And OUR tracer flows down so window_stage spans + the
         # host-stall gauge land in the same catalog as everything else.

@@ -179,7 +179,7 @@ class StateMachine:
     def fallback_stats(self) -> dict:
         """Device-engine routing/fallback counters (per-cause host
         fallbacks + on-device escalations); empty for host engines.
-        Surfaced by bench.py per-config diagnostics and devhub.py."""
+        Surfaced by `start`'s shutdown record."""
         if self.led is None:
             return {}
         return self.led.fallback_stats()

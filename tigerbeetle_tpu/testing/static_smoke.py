@@ -28,9 +28,8 @@ and stay clean on its paired fixed form:
   sharding     a donated shard_map state arg lowered replicated
                (in_specs=P()) vs the P("batch") layout clean.
 
-Writes perf/static_status.json (per-pass ok flags, finding samples,
-negative-proof verdicts, the retrace table) for the devhub panel, then
-raises on any RED — a silently-passing verifier never gates anything.
+Prints each pass's verdict, then raises on any RED — a
+silently-passing verifier never gates anything.
 
 Run via ``scripts/gate.py`` (skip with --no-static) or directly:
 ``python -c "from tigerbeetle_tpu.testing import static_smoke;
@@ -39,12 +38,10 @@ static_smoke.static_smoke()"``.
 
 from __future__ import annotations
 
-import json
 import os
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-STATUS_PATH = os.path.join(REPO, "perf", "static_status.json")
 
 
 def _negative_proofs(entries) -> dict[str, bool]:
@@ -149,10 +146,8 @@ def static_smoke() -> None:
     retrace_fails.extend(audit_fails)
     try:
         retrace_fails.extend(retrace.check_budget(entries, table=table))
-        budget = os.path.basename(retrace.newest_tracebudget_path())
     except FileNotFoundError as e:
         retrace_fails.append(f"tracebudget: {e}")
-        budget = None
     for name, cj in traces.items():
         retrace_fails.extend(retrace.weak_carries(cj, name))
     # Live cache probe: re-driving a flat entry at an already-compiled
@@ -166,21 +161,6 @@ def static_smoke() -> None:
     passes["sharding"] = shardspec.run(entries)
 
     negatives = _negative_proofs(entries)
-
-    status = {
-        "n_entries": len(entries),
-        "tracebudget": budget,
-        "passes": {
-            name: {"ok": not fails, "n_findings": len(fails),
-                   "findings": fails[:20]}
-            for name, fails in passes.items()},
-        "negatives": negatives,
-        "retrace_table": table,
-    }
-    with open(STATUS_PATH, "w") as f:
-        json.dump(status, f, indent=1, sort_keys=True)
-        f.write("\n")
-    print(f"[static] wrote {STATUS_PATH}", flush=True)
 
     reds: list[str] = []
     for name, fails in passes.items():

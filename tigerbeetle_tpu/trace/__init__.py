@@ -65,8 +65,7 @@ from .merge import (CRITICAL_PATH_STAGES, assemble_traces, causal_edges,
 from .profiler import (DispatchProfiler, measured_dispatch_us,
                        profile_probe, roofline_fractions,
                        roofline_seconds, static_cost_model)
-from .slo import (Objective, burn_rates, evaluate, evaluate_bench_record,
-                  load_objectives)
+from .slo import Objective, burn_rates, evaluate, load_objectives
 from .statsd import StatsD, TimingAggregates
 from .tracer import NullTracer, Tracer, install_gc_spans
 
@@ -78,8 +77,8 @@ __all__ = [
     "Histogram", "CRITICAL_PATH_STAGES", "critical_path",
     "assemble_traces", "causal_edges", "estimate_clock_offsets",
     "merge_trace_files", "merge_traces", "span_quantile",
-    "Objective", "burn_rates", "evaluate", "evaluate_bench_record",
-    "load_objectives", "StatsD", "TimingAggregates",
+    "Objective", "burn_rates", "evaluate", "load_objectives",
+    "StatsD", "TimingAggregates",
     "NullTracer", "Tracer", "install_gc_spans",
     "Alert", "AlertEngine", "AlertRule", "load_alert_rules",
     "MemWatch", "check_budget", "device_memory_stats", "load_budget",

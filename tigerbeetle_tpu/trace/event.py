@@ -398,9 +398,8 @@ for _e in Event:
 # costs a DynamicClassAttribute descriptor hop, `ev.tags` a property
 # into the EventSpec, and any dict keyed by the member a Python-level
 # Enum.__hash__ call. The recording tracer's span-close path reads
-# several of these per span; the bench ##trace overhead ratios guard
-# the sum. Layout: (name, kind, frozenset(tags), slots, hist_tags,
-# TID_BASE[member]).
+# several of these per span. Layout: (name, kind, frozenset(tags),
+# slots, hist_tags, TID_BASE[member]).
 for _e in Event:
     _e._hot = (_e.name, _e.kind, frozenset(_e.tags), _e.slots,
                _e.hist_tags, TID_BASE[_e])

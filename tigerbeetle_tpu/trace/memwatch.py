@@ -24,7 +24,7 @@ checkable (ISSUE 20):
   profile leg enforces it with an injected-leak negative.
 - ``MemWatch`` emits the watermark as catalog gauges
   (``memory_watermark_bytes`` / ``memory_budget_headroom_bytes``) so
-  the footprint flows into StatsD/Prometheus/devhub like any metric,
+  the footprint flows into StatsD/Prometheus like any metric,
   and samples per-device allocator stats (``device.memory_stats()``)
   where the backend provides them (TPU does; CPU typically returns
   nothing — the shape-derived ledger is the deterministic source of
@@ -252,7 +252,7 @@ def load_budget(path: Optional[str] = None) -> dict:
 class MemWatch:
     """The watermark sampler the serving supervisor ticks: measures the
     static-allocation ledger, emits the catalog gauges, and keeps the
-    last observation (+ budget verdict) for ``stats()``/devhub."""
+    last observation (+ budget verdict) for ``stats()``."""
 
     def __init__(self, tracer=None, budget_path: Optional[str] = None,
                  budget: Optional[dict] = None):

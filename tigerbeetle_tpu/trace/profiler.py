@@ -11,9 +11,9 @@ how far each dispatch tier sits from what the hardware could do
   the dispatch result, so the timer covers real device execution, not
   just async enqueue — and lands in the ``dispatch_device_time``
   catalog histogram partitioned by route and shape tier. Unsampled
-  dispatches pay one integer increment (the ##profile bench record
-  proves the whole plane ≤ the 1.05 overhead ceiling in
-  perf/membudget_r*.json).
+  dispatches pay one integer increment (the gate's profile leg,
+  testing/observatory_smoke.py, holds the whole plane under the 1.05
+  overhead ceiling in perf/membudget_r*.json).
 - Where the backend supports programmatic capture, ``capture_once``
   wraps one sampled dispatch in a ``jax.profiler`` trace (a real XLA
   profile artifact under ``capture_dir``); elsewhere the deterministic
@@ -312,7 +312,7 @@ def roofline_fractions(cost_model: dict, measured: dict) -> dict:
 def profile_probe(tracer=None, profiler: Optional[DispatchProfiler] = None,
                   include_partitioned: Optional[bool] = None,
                   depth: int = 4) -> dict:
-    """The bench ``##profile`` record: static cost model + measured
+    """The observatory's profile record: static cost model + measured
     sampled-dispatch histograms + achieved-vs-roofline fractions per
     tier + profiler/sampling counters. Pure assembly over state the
     run already produced — the probe itself dispatches nothing."""

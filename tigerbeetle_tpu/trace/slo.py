@@ -1,7 +1,7 @@
 """SLO engine: declared latency objectives, evaluation, burn rates.
 
-`perf/slo.json` declares the service-level objectives (the ROADMAP's
-"per-class p50/p99 latency SLOs tracked in bench + devhub"). Schema:
+`perf/slo.json` declares the service-level objectives (per-class
+p50/p99 latency SLOs, evaluated live and scraped at `/metrics`). Schema:
 
     {
       "burn_window_runs": 8,          # sliding window for burn rates
@@ -23,9 +23,9 @@ ever feed — is RED in the gate's metrics leg). Evaluation reads the
 recording tracer's cumulative histograms: an objective with no samples
 is `ok: None` (unknown), a breached one emits the `slo_breach` counter.
 Burn-rate accounting is run-granular: over the trailing
-`burn_window_runs` bench/devhub records, the burn rate is the fraction
-of evaluated runs in breach; burn above `burn_budget` (or a breach in
-the latest run) raises the devhub panel's badge.
+`burn_window_runs` evaluations, the burn rate is the fraction of
+evaluated runs in breach; burn above `burn_budget` (or a breach in the
+latest run) raises the badge.
 """
 
 from __future__ import annotations
@@ -165,58 +165,10 @@ def evaluate(tracer, objectives, emit_to=None) -> list:
     return rows
 
 
-def evaluate_bench_record(record: dict, objectives) -> list:
-    """Evaluate objectives against one bench/devhub record (offline —
-    the devhub panel's per-run data point). Serving-window objectives
-    read the record's per-window latency histogram
-    (serving_batch_latency.histogram, milliseconds); anything the
-    record does not carry evaluates to ok=None. Device-telemetry
-    objectives (device_exchange_occupancy — the exchange-headroom burn
-    early warning) read the shard probe's harvested distribution
-    (shard_balance.telemetry.exchange_occupancy, already in the
-    event's declared unit)."""
-    lat = record.get("serving_batch_latency") or {}
-    hist = None
-    if isinstance(lat.get("histogram"), dict):
-        try:
-            hist = Histogram.from_dict(lat["histogram"])
-        except (AssertionError, ValueError, TypeError):
-            hist = None
-    tel = (record.get("shard_balance") or {}).get("telemetry") or {}
-    tel_hist = None
-    if isinstance(tel.get("exchange_occupancy"), dict):
-        try:
-            tel_hist = Histogram.from_dict(tel["exchange_occupancy"])
-        except (AssertionError, ValueError, TypeError):
-            tel_hist = None
-    rows = []
-    for o in objectives:
-        value = None
-        count = 0
-        if o.event == "window_commit":
-            if hist is not None:
-                value = hist.quantile(o.quantile)  # already ms
-                count = hist.count
-            elif o.quantile == 0.99 and lat.get("p99_ms") is not None:
-                value = float(lat["p99_ms"])
-        elif o.event == "device_exchange_occupancy" \
-                and tel_hist is not None:
-            value = tel_hist.quantile(o.quantile)  # already pct
-            count = tel_hist.count
-        ok = None if value is None else bool(value <= o.threshold)
-        rows.append({
-            "name": o.name, "event": o.event, "quantile": o.quantile,
-            "value": None if value is None else round(value, 3),
-            "threshold": o.threshold, "unit": o.unit,
-            "count": count, "ok": ok,
-        })
-    return rows
-
-
 def burn_rates(per_run_rows: list, window_runs: int,
                budget: float) -> dict:
     """Run-granular burn accounting: `per_run_rows` is a list (oldest
-    first) of evaluate()/evaluate_bench_record() outputs, one per run.
+    first) of evaluate() outputs, one per run.
     Returns {objective: {burn_rate, breaches, evaluated, budget,
     breached_now, badge}} over the trailing `window_runs` runs; runs
     where the objective was unknown don't consume error budget."""

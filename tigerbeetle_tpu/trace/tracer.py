@@ -4,8 +4,8 @@ reference: src/trace.zig — span start/stop compiled into the hot path,
 Chrome/Perfetto JSON via --trace, StatsD aggregation via trace/statsd.zig.
 The tracer is injected at construction (replica, journal, scrubber,
 message bus, serving supervisor, sharded router); the default NullTracer
-keeps every hot path free of overhead (bench.py's ##trace probe records
-that cost every run).
+keeps every hot path free of overhead (PERF.md §3 has what the
+recording tracer costs on the chip).
 
 The recording `Tracer` enforces the typed catalog (trace/event.py): a
 span/counter/gauge outside the catalog, or a tag key outside the event's
@@ -35,8 +35,6 @@ from .statsd import StatsD, TimingAggregates
 # The recording span path reads per-event constants through `ev._hot`
 # (trace/event.py): one plain attribute access instead of enum property
 # hops or member-keyed dict lookups (Enum.__hash__ is Python-level).
-# The traced-vs-NullTracer overhead ratios in the bench ##trace record
-# guard this path.
 
 
 class NullTracer:
