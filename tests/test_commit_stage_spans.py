@@ -71,13 +71,17 @@ def traced_run():
     replica = cluster.replicas[0]
     tree = replica.durable.forest.trees["transfers"]
     puts = [0]
-    put = tree.put
+    put, put_run = tree.put, tree.put_run
 
     def counting_put(key, value):
         puts[0] += 1
         put(key, value)
 
-    tree.put = counting_put
+    def counting_put_run(keys, values):
+        puts[0] += len(keys)
+        put_run(keys, values)
+
+    tree.put, tree.put_run = counting_put, counting_put_run
     created = _drive_past_checkpoint(cluster)
     events = tracers[0].chrome_dict()["traceEvents"]
     return {"replica": replica, "tracer": tracers[0], "events": events,
@@ -282,8 +286,12 @@ def test_durable_rows_count_what_the_paths_put(traced_run):
     # every row created goes into the object tree once.
     assert rows["object_at_checkpoint"] == 0
     assert traced_run["transfer_puts"] == traced_run["created"]
+    # The column rows entered the memtables as runs, and nothing read
+    # them back by key: none was folded into a dict.
+    assert rows["run"] == rows["column"] and rows["folded"] == 0
+    # The tracer's counter holds every path's rows (tag `path`).
     assert traced_run["tracer"].counters["durable_rows_put"] == \
-        traced_run["transfer_puts"]
+        rows["column"] + rows["run"]
 
 
 # ------------------------------------------------------- the jit tiers

@@ -73,18 +73,18 @@ class TestDurableRoundtrip:
         durable.checkpoint(sm.state)
         # After a checkpoint nothing is dirty: a second flush writes nothing.
         trees = durable.forest.trees
-        before = {name: len(t.memtable) for name, t in trees.items()}
+        before = {name: len(t.memtable_rows()) for name, t in trees.items()}
         durable.flush(sm.state)
-        after = {name: len(t.memtable) for name, t in trees.items()}
+        after = {name: len(t.memtable_rows()) for name, t in trees.items()}
         assert before == after == {name: 0 for name in trees}
         # One more transfer dirties exactly the touched objects.
         sm.create_transfers(
             [Transfer(id=20, debit_account_id=1, credit_account_id=2,
                       amount=1, ledger=1, code=1)], timestamp=10_000)
         durable.flush(sm.state)
-        assert len(trees["transfers"].memtable) == 1
-        assert len(trees["accounts"].memtable) == 2
-        assert len(trees["events"].memtable) == 1
+        assert len(trees["transfers"].memtable_rows()) == 1
+        assert len(trees["accounts"].memtable_rows()) == 2
+        assert len(trees["events"].memtable_rows()) == 1
 
     def test_failed_linked_chain_rollback_flush(self):
         """A rolled-back linked chain leaves dirty keys whose objects were
@@ -272,7 +272,8 @@ def test_vectorized_column_flush_matches_object_flush():
     for name in dev.forest.trees:
         t_dev = dev.forest.trees[name]
         t_ora = ora.forest.trees[name]
-        assert t_dev.memtable == t_ora.memtable, f"tree {name} diverged"
+        assert t_dev.memtable_rows() == t_ora.memtable_rows(), \
+            f"tree {name} diverged"
 
 
 def test_column_flush_hard_batch_interleave_matches_oracle():
@@ -336,8 +337,8 @@ def test_column_flush_hard_batch_interleave_matches_oracle():
     dev = build("device")
     ora = build("oracle")
     for name in dev.forest.trees:
-        assert dev.forest.trees[name].memtable == \
-            ora.forest.trees[name].memtable, f"tree {name} diverged"
+        assert dev.forest.trees[name].memtable_rows() == \
+            ora.forest.trees[name].memtable_rows(), f"tree {name} diverged"
 
 
 def test_cache_invalidated_after_column_flush():

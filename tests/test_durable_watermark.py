@@ -208,8 +208,10 @@ def test_a_drain_of_persisted_chunks_leaves_the_mirror_quiescent(drain):
     assert replica._mirror_quiescent()
 
     tree = replica.durable.forest.trees["transfers"]
-    puts, put = [], tree.put
+    puts, put, put_run = [], tree.put, tree.put_run
     tree.put = lambda key, value: (puts.append(key), put(key, value))
+    tree.put_run = lambda keys, values: (
+        puts.extend(k.tobytes() for k in keys), put_run(keys, values))
     _, flushed = replica.durable.flush(replica.state_machine.state)
     assert flushed == [] and puts == []
     run.transfers(1, _plain_body)
@@ -297,7 +299,8 @@ def test_chunks_over_the_watermark_are_dirty_and_flushed_as_objects(case):
     assert dev.durable.rows_put["object"] == created
     assert dev.durable.rows_put["column"] == 0
     for name, tree in dev.durable.forest.trees.items():
-        assert tree.memtable == ora.durable.forest.trees[name].memtable, \
+        assert tree.memtable_rows() == \
+            ora.durable.forest.trees[name].memtable_rows(), \
             f"tree {name} diverged"
 
 

@@ -229,7 +229,7 @@ class TestFullForestSchema:
                           ledger=1, code=1)], ts)
             ts += 100
             durable.flush(sm.state)
-        assert durable.forest.trees["acct_by_closed"].memtable == {}
+        assert durable.forest.trees["acct_by_closed"].memtable_rows() == {}
 
     def test_checkpoint_roundtrip_with_full_schema(self):
         sm, durable, storage = _mk()

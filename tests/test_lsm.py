@@ -284,7 +284,8 @@ class TestMemtableSplit:
             op += 1
             tree.compact_beat(op)
         assert saw_pending_flush, "freeze must defer the write-out"
-        assert tree._flush is None and not tree.immutable_map
+        assert tree._flush is None \
+            and not tree.memtable_rows(frozen=True)
         assert len(tree.levels[0]) >= 1
         # New puts during the flight went to the NEW mutable memtable.
         tree.put(k(1), v(9999))

@@ -7,6 +7,9 @@ A log-structured merge forest over a copy-on-write block grid:
                src/vsr/free_set.zig)
 - table.py   — immutable sorted runs serialized into grid blocks
                (reference: src/lsm/table.zig)
+- memtable.py — a tree's in-memory side: dicts and column runs in
+               arrival order, sorted once into a columnar run at the
+               freeze (reference: src/lsm/table_memory.zig)
 - tree.py    — memtable + leveled tables, growth factor 8, deterministic
                least-overlap compaction (reference: src/lsm/tree.zig,
                compaction.zig, manifest.zig)

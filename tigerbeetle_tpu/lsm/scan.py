@@ -58,15 +58,12 @@ class TreeScan:
     def _sources(self, start: bytes):
         sources = []
         if self.snapshot is None:
-            sources.append(sorted(
-                (k, v) for k, v in self.tree.memtable.items()
-                if start <= k <= self.key_max))
-        if self.tree._frozen_visible(self.snapshot):
+            sources.append(self.tree.memtable.between(start, self.key_max))
+        frozen = self.tree._frozen(self.snapshot)
+        if frozen is not None:
             # The frozen memtable is table-visible from its freeze op on,
             # even while its flush job is still streaming it out.
-            sources.append(sorted(
-                (k, v) for k, v in self.tree.immutable_map.items()
-                if start <= k <= self.key_max))
+            sources.append(frozen.between(start, self.key_max))
         # Levels newest-first; within L0, newest table first (L0 overlaps).
         for level_i, level in enumerate(self.tree.levels):
             entries = level.visible(self.snapshot)

@@ -13,6 +13,8 @@ import bisect
 import dataclasses
 import struct
 
+import numpy as np
+
 from .grid import ADDRESS_SIZE, BlockAddress, Grid
 from .schema import BLOCK_HEADER_SIZE, BlockKind, unwrap, wrap
 
@@ -155,17 +157,27 @@ def table_entry_max(grid: Grid, key_size: int, value_size: int) -> int:
     return per_block * index_entries_max
 
 
-def write_value_block(grid: Grid, chunk: list[tuple[bytes, bytes]],
+def entry_rows(entries: list[tuple[bytes, bytes]], key_size: int,
+               value_size: int) -> np.ndarray:
+    """A sorted (key, value) list as the rows the block encoder takes:
+    uint8[n, key_size + value_size], each row `key || value`."""
+    return np.frombuffer(b"".join(k + v for k, v in entries),
+                         dtype=np.uint8).reshape(
+                             len(entries), key_size + value_size)
+
+
+def write_value_block(grid: Grid, rows: np.ndarray, key_size: int,
                       reservation=None, tree_id: int = 0):
-    """One value block; returns (address, size, first_key) — the index
-    entry triple. The SINGLE encoder for the value-block layout (shared
-    by whole-table writes and the incremental memtable flush)."""
+    """One value block of `rows` (uint8[n, key_size + value_size], each
+    `key || value`, sorted); returns (address, size, first_key) — the
+    index entry triple. The SINGLE encoder for the value-block layout
+    (shared by whole-table writes and the incremental memtable flush):
+    the rows are the block's body as they stand, one `tobytes()`."""
     raw = wrap(BlockKind.value,
-               struct.pack("<I", len(chunk)) + b"".join(
-                   k + v for k, v in chunk),
+               struct.pack("<I", len(rows)) + rows.tobytes(),
                tree_id=tree_id)
     addr = grid.write_block(raw, reservation=reservation)
-    return addr, len(raw), chunk[0][0]
+    return addr, len(raw), rows[0, :key_size].tobytes()
 
 
 def write_index_block(grid: Grid, blocks: list,
@@ -214,9 +226,11 @@ def write_table(grid: Grid, entries: list[tuple[bytes, bytes]],
     """Serialize one sorted run (caller guarantees sort order + unique keys)."""
     assert entries
     per_block = value_block_entry_max(grid, key_size, value_size)
-    blocks = [write_value_block(grid, entries[base:base + per_block],
+    # The merge hands a list; the encoder takes columns.
+    rows = entry_rows(entries, key_size, value_size)
+    blocks = [write_value_block(grid, rows[base:base + per_block], key_size,
                                 reservation=reservation, tree_id=tree_id)
-              for base in range(0, len(entries), per_block)]
+              for base in range(0, len(rows), per_block)]
     index_addr, index_size = write_index_block(grid, blocks,
                                                reservation=reservation,
                                                tree_id=tree_id)

@@ -31,6 +31,7 @@ from typing import Optional
 
 from .grid import Grid
 from .manifest_level import SNAPSHOT_LATEST, ManifestLevel
+from .memtable import Memtable, SortedRun
 from .table import (
     Table,
     TableInfo,
@@ -57,7 +58,7 @@ class _FlushJob:
     table_memory.zig — the immutable side streams to disk across the
     bar's beats while staying readable)."""
 
-    entries: list  # sorted (key, value)
+    entries: SortedRun  # the frozen rows, sorted, as columns
     snapshot: int  # freeze op: installed tables carry this snapshot_min
     pos: int = 0
     # Current table's completed value blocks: (address, size, first_key).
@@ -111,10 +112,10 @@ class Tree:
         # Stamped into every block this tree writes (lsm/schema.py);
         # 0 = standalone. The forest assigns deterministic ids.
         self.tree_id = tree_id
-        self.memtable: dict[bytes, bytes] = {}
-        # Frozen previous memtable: readable while its flush job streams
-        # it into level-0 tables across the bar's beats.
-        self.immutable_map: dict[bytes, bytes] = {}
+        self.memtable = Memtable(key_size, value_size)
+        # The frozen previous memtable is the flush job's `entries`:
+        # readable while the job streams it into level-0 tables across
+        # the bar's beats.
         self._flush: Optional[_FlushJob] = None
         self._flush_per_beat = 0
         # Per-level manifest structures over (key range x snapshot range)
@@ -133,11 +134,32 @@ class Tree:
 
     def put(self, key: bytes, value: bytes) -> None:
         assert len(key) == self.key_size and len(value) == self.value_size
-        self.memtable[key] = value
+        self.memtable.put(key, value)
+
+    def put_run(self, keys, values) -> None:
+        """Put a column of rows whole: `keys` a uint8[n, key_size] matrix
+        (or its bytes), `values` a uint8[n, value_size] matrix or the one
+        value of every row. The same writes as n `put` calls in row
+        order, with no work per row here: the run waits in the memtable
+        until the freeze sorts it, or a read by key folds it."""
+        self.memtable.put_run(keys, values)
 
     def remove(self, key: bytes) -> None:
         assert len(key) == self.key_size
-        self.memtable[key] = TOMBSTONE * self.value_size
+        self.memtable.put(key, TOMBSTONE * self.value_size)
+
+    def memtable_rows(self, frozen: bool = False) -> dict:
+        """{key: value} of the mutable memtable (or of the frozen one,
+        while its flush is in flight), tombstones included: the logical
+        contents, whatever form they are held in."""
+        if not frozen:
+            run = self.memtable.freeze()
+        else:
+            run = self._flush.entries if self._flush is not None else None
+        if run is None:
+            return {}
+        return dict(run.between(bytes(self.key_size),
+                                b"\xff" * self.key_size))
 
     def get(self, key: bytes,
             snapshot: Optional[int] = None) -> Optional[bytes]:
@@ -148,13 +170,15 @@ class Tree:
         retention window; reference: manifest snapshot queries,
         src/lsm/manifest_level.zig)."""
         value = self.memtable.get(key) if snapshot is None else None
-        if value is None and self._frozen_visible(snapshot):
+        if value is None:
             # The frozen memtable became logically table-visible at its
             # freeze op: snapshots at or past it must read it even while
             # the flush job is still streaming it out (otherwise the same
             # (key, snapshot) would answer differently before and after
             # the install).
-            value = self.immutable_map.get(key)
+            frozen = self._frozen(snapshot)
+            if frozen is not None:
+                value = frozen.get(key)
         if value is None:
             # L0 tables may overlap: newest-first probe; deeper levels
             # yield at most one candidate per snapshot (binary-searched on
@@ -179,10 +203,11 @@ class Tree:
         found live (tombstoned/missing keys are absent)."""
         found: dict = {}
         remaining = []
+        frozen = self._frozen(snapshot)
         for key in keys:
             value = self.memtable.get(key) if snapshot is None else None
-            if value is None and self._frozen_visible(snapshot):
-                value = self.immutable_map.get(key)
+            if value is None and frozen is not None:
+                value = frozen.get(key)
             if value is not None:
                 found[key] = value
             else:
@@ -297,27 +322,30 @@ class Tree:
 
     # -------------------------------------------------- memtable flushing
 
-    def _frozen_visible(self, snapshot: Optional[int]) -> bool:
-        """Is the frozen memtable part of the view at `snapshot`?"""
-        if snapshot is None:
-            return True
-        return self._flush is not None and snapshot >= self._flush.snapshot
+    def _frozen(self, snapshot: Optional[int]) -> Optional[SortedRun]:
+        """The frozen memtable, if there is one and it is part of the
+        view at `snapshot`."""
+        job = self._flush
+        if job is None or (snapshot is not None
+                           and snapshot < job.snapshot):
+            return None
+        return job.entries
 
     def _freeze_memtable(self) -> None:
-        """Swap mutable -> immutable (reference tree.zig:543): the frozen
-        rows stay readable from `immutable_map` while a flush job streams
-        them into level-0 tables across the bar's beats."""
+        """Swap mutable -> immutable (reference tree.zig:543): one numpy
+        sort turns the memtable into a columnar sorted run, which stays
+        readable as the flush job's `entries` while the job streams it
+        into level-0 tables across the bar's beats."""
         if not self.memtable:
             return
         self._drain_flush()  # at most one frozen memtable at a time
         # Reserve BEFORE the swap: a "grid full" reserve failure must
         # leave the tree unchanged (a post-swap failure would strand the
         # frozen rows with no flush job and lose them at the next freeze).
-        entries = sorted(self.memtable.items())
+        entries = self.memtable.freeze()
         reservation = self.grid.reserve(table_block_bound(
             self.grid, len(entries), self.key_size, self.value_size))
-        self.immutable_map = self.memtable
-        self.memtable = {}
+        self.memtable.clear()
         self._flush = _FlushJob(
             entries=entries,
             snapshot=self.beat,
@@ -343,10 +371,11 @@ class Tree:
                 return
             table_end = min(len(job.entries),
                             (job.pos // cap + 1) * cap)
-            chunk = job.entries[job.pos:min(job.pos + per_block, table_end)]
+            chunk = job.entries.rows[
+                job.pos:min(job.pos + per_block, table_end)]
             job.blocks.append(write_value_block(
-                self.grid, chunk, reservation=job.reservation,
-                tree_id=self.tree_id))
+                self.grid, chunk, self.key_size,
+                reservation=job.reservation, tree_id=self.tree_id))
             job.pos += len(chunk)
             if budget is not None:
                 budget -= len(chunk)
@@ -359,7 +388,6 @@ class Tree:
                 snapshot=job.snapshot)
         if job.reservation is not None:
             self.grid.forfeit(job.reservation)
-        self.immutable_map = {}
         self._flush = None
 
     def _finish_flush_table(self, job: _FlushJob, cap: int) -> TableInfo:
@@ -371,7 +399,7 @@ class Tree:
         start = (job.pos - 1) // cap * cap
         info = TableInfo(
             index_address=index_addr, index_size=index_size,
-            key_min=first_key, key_max=job.entries[job.pos - 1][0],
+            key_min=first_key, key_max=job.entries.key(job.pos - 1),
             entry_count=job.pos - start)
         job.blocks = []
         return info
@@ -560,7 +588,6 @@ class Tree:
                         snapshot_max=snap_max, seq=seq))
             self.levels[level].next_seq = next_seq
         self.memtable.clear()
-        self.immutable_map = {}
         self._flush = None
         # Rebuild in-flight jobs against the RESTORED Table objects
         # (identity matters: finalize removes job tables from the level

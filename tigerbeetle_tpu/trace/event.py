@@ -342,7 +342,9 @@ class Event(enum.Enum):
         "transfer rows put into the trees by the durable flush, by path: "
         "column (device delta columns), object (mirror objects, per-op "
         "flush), object_at_checkpoint (mirror objects, during a "
-        "checkpoint's flush)", "path")
+        "checkpoint's flush); run (column rows that entered the "
+        "memtables as whole runs, once a transfer), folded (rows of runs, "
+        "a tree at a time, that a read by key made pay per key)", "path")
 
     # ------------------------------------------------ serving thread
     # Stamped with now_ns() in the same wall-anchored domain as every

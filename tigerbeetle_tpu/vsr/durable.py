@@ -286,8 +286,13 @@ class DurableState:
         # durable_rows_put), and the checkpoints taken: plain ints, kept
         # whether or not a tracer records. `object_at_checkpoint` rows
         # are those a checkpoint's flush puts through the object path.
-        self.rows_put = {"column": 0, "object": 0,
-                         "object_at_checkpoint": 0, "checkpoints": 0}
+        # `run` rows entered the memtables as part of a column run
+        # (Tree.put_run; once a transfer, not once a tree); `folded`
+        # counts the rows of runs, a tree at a time, that a read by key
+        # or range made pay per key after all (lsm/memtable.py).
+        self._rows_put = {"column": 0, "object": 0,
+                          "object_at_checkpoint": 0, "checkpoints": 0,
+                          "run": 0, "folded": 0}
         layout = storage.layout
         self.grid = Grid(
             _ZoneDevice(storage, "grid"),
@@ -321,9 +326,10 @@ class DurableState:
         flush_columns: drained device-delta transfer columns
         (DeviceLedger.take_flush_columns). Transfers covered by them are
         flushed through the VECTORIZED path — values and index keys built
-        in numpy passes instead of per-object int.to_bytes — and skipped
-        by the object loop. Same puts, same bytes; memtable freeze sorts,
-        so put order cannot affect the on-grid result."""
+        in numpy passes and handed to each tree as one run
+        (Tree.put_run) — and skipped by the object loop. Same puts, same
+        bytes; memtable freeze sorts, so put order cannot affect the
+        on-grid result."""
         vector_tids: list = []
         vector_aids: list = []
         if flush_columns:
@@ -331,19 +337,35 @@ class DurableState:
                 self._flush_columns(state, flush_columns,
                                     vector_tids, vector_aids)
             self._count_rows("column", len(vector_tids))
+            self._count_rows("run", len(vector_tids))
         with self.tracer.span(Event.flush_objects, op=op):
             flushed_accounts, flushed_transfers = self._flush_objects(
                 state, vector_tids)
         self._count_rows(
             "object_at_checkpoint" if at_checkpoint else "object",
             len(flushed_transfers))
+        self._count_folded()
         return (flushed_accounts + vector_aids,
                 flushed_transfers + vector_tids)
 
     def _count_rows(self, path: str, n: int) -> None:
         if n:
-            self.rows_put[path] += n
+            self._rows_put[path] += n
             self.tracer.count(Event.durable_rows_put, n, path=path)
+
+    def _count_folded(self) -> None:
+        """Bring `folded` up to what the trees' memtables have folded."""
+        folded = sum(tree.memtable.rows_folded
+                     for tree in self.forest.trees.values())
+        self._count_rows("folded", folded - self._rows_put["folded"])
+
+    @property
+    def rows_put(self) -> dict:
+        """The row counters. Reads fold between flushes too (lookups, the
+        scrubber), so `folded` is read off the trees here as well as at
+        the end of every flush."""
+        self._count_folded()
+        return self._rows_put
 
     def _flush_columns(self, state, flush_columns, vector_tids: list,
                        vector_aids: list) -> None:
@@ -512,25 +534,17 @@ class DurableState:
 
     def _flush_transfer_columns(self, trees, t, n: int) -> list:
         """Vectorized transfer flush from drained device columns: value
-        bytes and every index key built in whole-column numpy passes; the
-        per-row Python work is the memtable puts themselves. Returns the
-        flushed transfer ids. Bit-identical to the object path (the wire
-        codec IS the object pack format)."""
+        bytes and every index key are built in whole-column numpy passes
+        and each tree takes its rows as ONE run (Tree.put_run) — no
+        Python work per row. Returns the flushed transfer ids.
+        Bit-identical to the object path (the wire codec IS the object
+        pack format)."""
         import numpy as np
 
         from ..ops.batch import TRANSFER_WIRE
         from ..types import TransferFlags as TF
 
-        # Closing and imported transfers come through the fast path now
-        # (closing-native fixpoint tiers / the imported tiers), so the
-        # column flush maintains their flag indexes exactly like the
-        # object path does.
         flags = t["flags"][:n]
-        closing_l = ((flags & np.uint32(int(TF.closing_debit
-                                            | TF.closing_credit))) != 0
-                     ).tolist()
-        imported_l = ((flags & np.uint32(int(TF.imported))) != 0).tolist()
-
         rec = np.zeros(n, dtype=TRANSFER_WIRE)
         for f in ("id_lo", "id_hi", "dr_lo", "dr_hi", "cr_lo", "cr_hi",
                   "amt_lo", "amt_hi", "pid_lo", "pid_hi",
@@ -539,71 +553,60 @@ class DurableState:
         rec["ledger"] = t["ledger"][:n]
         rec["code"] = t["code"][:n].astype(np.uint16)
         rec["flags"] = flags.astype(np.uint16)
-        valb = rec.tobytes()
 
-        def be(*cols):
-            return np.ascontiguousarray(
-                np.stack([c[:n] for c in cols], axis=1).astype(">u8")
-            ).tobytes()
+        # Key matrices stay uint8 from here on: concatenating big-endian
+        # integer arrays would hand back native byte order.
+        def be(*cols, width=8):
+            return np.stack([c[:n] for c in cols], axis=1).astype(
+                f">u{width}").view(np.uint8)
+
+        def with_ts(prefix):
+            return np.concatenate([prefix, ts8], axis=1)
 
         ts = t["ts"]
-        idb = be(t["id_hi"], t["id_lo"])                      # 16B rows
         ts8 = be(ts)                                          # 8B rows
-        drk = be(t["dr_hi"], t["dr_lo"], ts)                  # 24B rows
-        crk = be(t["cr_hi"], t["cr_lo"], ts)
-        pidk = be(t["pid_hi"], t["pid_lo"], ts)
-        ud128k = be(t["ud128_hi"], t["ud128_lo"], ts)
-        amtk = be(t["amt_hi"], t["amt_lo"], ts)
-        ud64k = be(t["ud64"], ts)
-        ud32p = np.ascontiguousarray(t["ud32"][:n].astype(">u4")).tobytes()
-        ledp = np.ascontiguousarray(t["ledger"][:n].astype(">u4")).tobytes()
-        codep = np.ascontiguousarray(
-            t["code"][:n].astype(np.uint16).astype(">u2")).tobytes()
-        pid_live = ((t["pid_hi"][:n] != 0) | (t["pid_lo"][:n] != 0)).tolist()
-
-        put_obj = trees["transfers"].put
-        put_ts = trees["xfer_by_ts"].put
-        put_dr = trees["xfer_by_dr"].put
-        put_cr = trees["xfer_by_cr"].put
-        put_pid = trees["xfer_by_pid"].put
-        put_ud128 = trees["xfer_by_ud128"].put
-        put_ud64 = trees["xfer_by_ud64"].put
-        put_ud32 = trees["xfer_by_ud32"].put
-        put_led = trees["xfer_by_ledger"].put
-        put_code = trees["xfer_by_code"].put
-        put_amt = trees["xfer_by_amount"].put
-        put_closing = trees["xfer_by_closing"].put
-        put_imported = trees["xfer_by_imported"].put
+        idb = be(t["id_hi"], t["id_lo"])                      # 16B rows
         ONE = b"\x01"
-        tids = []
-        for i in range(n):
-            k16 = idb[16 * i:16 * i + 16]
-            t8 = ts8[8 * i:8 * i + 8]
-            tids.append(int.from_bytes(k16, "big"))
-            put_obj(k16, valb[128 * i:128 * i + 128])
-            put_ts(t8, k16)
-            put_dr(drk[24 * i:24 * i + 24], ONE)
-            put_cr(crk[24 * i:24 * i + 24], ONE)
-            if pid_live[i]:
-                put_pid(pidk[24 * i:24 * i + 24], ONE)
-            put_ud128(ud128k[24 * i:24 * i + 24], ONE)
-            put_ud64(ud64k[16 * i:16 * i + 16], ONE)
-            put_ud32(ud32p[4 * i:4 * i + 4] + t8, ONE)
-            put_led(ledp[4 * i:4 * i + 4] + t8, ONE)
-            put_code(codep[2 * i:2 * i + 2] + t8, ONE)
-            put_amt(amtk[24 * i:24 * i + 24], ONE)
-            # Flag indexes (composite_key(1, ts, 1) == b"\x01" + ts_be).
-            if closing_l[i]:
-                put_closing(ONE + t8, ONE)
-            if imported_l[i]:
-                put_imported(ONE + t8, ONE)
-        return tids
+        trees["transfers"].put_run(
+            idb, rec.view(np.uint8).reshape(n, TRANSFER_WIRE.itemsize))
+        trees["xfer_by_ts"].put_run(ts8, idb)
+        trees["xfer_by_dr"].put_run(be(t["dr_hi"], t["dr_lo"], ts), ONE)
+        trees["xfer_by_cr"].put_run(be(t["cr_hi"], t["cr_lo"], ts), ONE)
+        # Zero means 'not a post/void' — never indexed.
+        pid_live = (t["pid_hi"][:n] != 0) | (t["pid_lo"][:n] != 0)
+        trees["xfer_by_pid"].put_run(
+            be(t["pid_hi"], t["pid_lo"], ts)[pid_live], ONE)
+        trees["xfer_by_ud128"].put_run(
+            be(t["ud128_hi"], t["ud128_lo"], ts), ONE)
+        trees["xfer_by_ud64"].put_run(be(t["ud64"], ts), ONE)
+        trees["xfer_by_ud32"].put_run(with_ts(be(t["ud32"], width=4)), ONE)
+        trees["xfer_by_ledger"].put_run(
+            with_ts(be(t["ledger"], width=4)), ONE)
+        trees["xfer_by_code"].put_run(with_ts(be(t["code"], width=2)), ONE)
+        trees["xfer_by_amount"].put_run(
+            be(t["amt_hi"], t["amt_lo"], ts), ONE)
+        # Closing and imported transfers come through the fast path
+        # (closing-native fixpoint tiers / the imported tiers), so the
+        # column flush maintains their flag indexes exactly like the
+        # object path does (composite_key(1, ts, 1) == b"\x01" + ts_be).
+        flag_keys = with_ts(np.ones((n, 1), dtype=np.uint8))
+        closing = (flags & np.uint32(int(TF.closing_debit
+                                         | TF.closing_credit))) != 0
+        trees["xfer_by_closing"].put_run(flag_keys[closing], ONE)
+        imported = (flags & np.uint32(int(TF.imported))) != 0
+        trees["xfer_by_imported"].put_run(flag_keys[imported], ONE)
+        return ((t["id_hi"][:n].astype(object) << 64)
+                | t["id_lo"][:n].astype(object)).tolist()
 
-    def _flush_side_columns(self, trees, t, e, der, n: int) -> None:
+    def _flush_side_columns(self, trees, t, e, der, n: int) -> list:
         """Vectorized flush of one chunk's NON-transfer effects: the
         account_events rows (+ their index trees), the touched accounts'
         object rows, and the pending/expiry trees — all from device delta
         columns, so the flush does not require materializing the mirror.
+        The event rows are built as one uint8[n, 428] matrix and handed
+        to their trees as runs; Python loops only over the chunk's
+        DISTINCT accounts and over the rows that reference a pending
+        transfer or set a pending status (they read trees).
 
         Immutable account metadata (user_data/ledger/code/timestamp) is
         spliced from the account's PREVIOUS tree value (the fast path
@@ -612,133 +615,132 @@ class DurableState:
         closed-flag index transitions are maintained here exactly like
         the object path. Per-event balances come from the event columns.
         Byte-identical to the object path (oracle-exact snapshots either
-        way)."""
+        way). Returns the touched account ids."""
         import numpy as np
 
         from ..types import AccountFlags as AF
-        from ..types import TransferFlags as TF
 
-        hist = int(AF.history)
+        def le(*cols, width=8):
+            return np.stack([c[:n] for c in cols], axis=1).astype(
+                f"<u{width}").view(np.uint8)
 
-        def le(*cols):
-            return np.ascontiguousarray(
-                np.stack([c[:n] for c in cols], axis=1).astype("<u8")
-            ).tobytes()
+        pstat = e["pstat"][:n]
+        assert ((pstat >= 0) & (pstat <= 3)).all(), \
+            "expiry events never come from chunks"
+        has_p = e["p_row"][:n] >= 0
+        tflags = e["tflags"][:n]
+        ets8 = t["ts"][:n].astype(">u8").view(np.uint8).reshape(n, 8)
 
-        ets8 = np.ascontiguousarray(t["ts"][:n].astype(">u8")).tobytes()
-        amt16 = le(e["amt_lo"], e["amt_hi"])
-        areq16 = le(e["areq_lo"], e["areq_hi"])
-        # Per-side account front half (id + four balances, wire LE).
-        fronts = {}
-        for side, idh, idl in (("dr", "dr_id_hi", "dr_id_lo"),
-                               ("cr", "cr_id_hi", "cr_id_lo")):
-            fronts[side] = le(
-                der[idl], der[idh],
+        # The DISTINCT accounts of the chunk. Sides interleave (dr 0, cr
+        # 0, dr 1, ...) so that a higher position is a later image; the
+        # sort is stable, so each account's positions stay ascending.
+        id_hi = np.stack([der["dr_id_hi"][:n], der["cr_id_hi"][:n]],
+                         axis=1).reshape(2 * n)
+        id_lo = np.stack([der["dr_id_lo"][:n], der["cr_id_lo"][:n]],
+                         axis=1).reshape(2 * n)
+        order = np.lexsort((id_lo, id_hi))
+        id_hi, id_lo = id_hi[order], id_lo[order]
+        first = np.ones(2 * n, dtype=bool)
+        first[1:] = (id_hi[1:] != id_hi[:-1]) | (id_lo[1:] != id_lo[:-1])
+        inverse = np.empty(2 * n, dtype=np.intp)  # position -> account
+        inverse[order] = np.cumsum(first) - 1
+        # Each account's last position: the one before the next's first.
+        last = order[np.append(np.flatnonzero(first)[1:] - 1, 2 * n - 1)]
+        # The immutable bytes of each distinct account, read once from
+        # its previous tree value.
+        acct_tree = trees["accounts"]
+        raw = np.stack([id_hi[first], id_lo[first]], axis=1).astype(
+            ">u8").tobytes()
+        keys16 = [raw[p:p + 16] for p in range(0, len(raw), 16)]
+        olds = [acct_tree.get(k16) for k16 in keys16]
+        assert None not in olds, "account flushed before transfers"
+        olds = np.frombuffer(b"".join(olds), dtype=np.uint8).reshape(-1, 128)
+        meta = olds[:, 80:118][inverse].reshape(n, 2, 38)
+        acct_ts = olds[:, 120:128][inverse].reshape(n, 2, 8)
+
+        # events rows: <QHBB | dr account | cr account | amount_requested
+        # | amount | pending transfer (zeros without one).
+        ev = np.zeros((n, _EVENT_SIZE), dtype=np.uint8)
+        ev[:, 0:8] = le(t["ts"])
+        ev[:, 8:10] = le(np.where(tflags == 0xFFFFFFFF, _FLAGS_NONE, tflags),
+                         width=2)
+        ev[:, 10] = pstat
+        ev[:, 11] = has_p
+        for s, side in enumerate(("dr", "cr")):
+            off = 12 + 128 * s
+            # id + four balances (wire LE), then the immutable bytes
+            # around the flags word.
+            ev[:, off:off + 80] = le(
+                der[f"{side}_id_lo"], der[f"{side}_id_hi"],
                 e[f"{side}_dp_lo"], e[f"{side}_dp_hi"],
                 e[f"{side}_dpos_lo"], e[f"{side}_dpos_hi"],
                 e[f"{side}_cp_lo"], e[f"{side}_cp_hi"],
                 e[f"{side}_cpos_lo"], e[f"{side}_cpos_hi"])
-        flags2 = {
-            side: np.ascontiguousarray(
-                e[f"{side}_flags"][:n].astype("<u2")).tobytes()
-            for side in ("dr", "cr")}
-        idbe = {
-            side: np.ascontiguousarray(np.stack(
-                [der[f"{side}_id_hi"][:n], der[f"{side}_id_lo"][:n]],
-                axis=1).astype(">u8")).tobytes()
-            for side in ("dr", "cr")}
-        pstat_l = e["pstat"][:n].tolist()
-        p_row_l = e["p_row"][:n].tolist()
-        tflags_l = e["tflags"][:n].tolist()
-        side_flags_l = {side: e[f"{side}_flags"][:n].tolist()
-                        for side in ("dr", "cr")}
-        p_ts_l = der["p_ts"][:n].tolist()
-        timeout_l = t["timeout"][:n].tolist()
-        expires_l = t["expires"][:n].tolist()
-        ts_l = t["ts"][:n].tolist()
+            ev[:, off + 80:off + 118] = meta[:, s]
+            ev[:, off + 118:off + 120] = le(e[f"{side}_flags"], width=2)
+            ev[:, off + 120:off + 128] = acct_ts[:, s]
+        ev[:, 268:284] = le(e["areq_lo"], e["areq_hi"])
+        ev[:, 284:300] = le(e["amt_lo"], e["amt_hi"])
 
-        acct_tree = trees["accounts"]
+        # Rows that reference a pending transfer or set a pending status
+        # read trees and touch pending / expiry (oracle semantics): a
+        # loop over those rows only.
         xfer_tree = trees["transfers"]
         by_ts = trees["xfer_by_ts"]
-        put_ev = trees["events"].put
-        put_ev_acct = trees["ev_by_acct_ts"].put
-        put_ev_pstat = trees["ev_by_pstat"].put
-        put_ev_prun = trees["ev_by_prunable"].put
         put_pending = trees["pending"].put
         put_expiry = trees["expiry"].put
         rm_expiry = trees["expiry"].remove
         ONE = b"\x01"
-        meta_cache: dict = {}  # acct key16be -> (meta bytes, ts_be8)
         p_cache: dict = {}  # p_ts -> pending transfer value bytes
-        acct_last: dict = {}  # acct key16be -> final account value bytes
-
-        def acct_meta(k16):
-            got = meta_cache.get(k16)
-            if got is None:
-                old = acct_tree.get(k16)
-                assert old is not None, "account flushed before transfers"
-                got = (old[80:118], old[120:128])
-                meta_cache[k16] = got
-            return got
-
-        for i in range(n):
-            pstat = pstat_l[i]
-            assert 0 <= pstat <= 3, "expiry events never come from chunks"
-            has_p = 1 if p_row_l[i] >= 0 else 0
-            tflags = tflags_l[i]
-            tflags16 = _FLAGS_NONE if tflags == 0xFFFFFFFF else tflags
-            sides_bytes = {}
-            for side in ("dr", "cr"):
-                k16 = idbe[side][16 * i:16 * i + 16]
-                meta, ts_le = acct_meta(k16)
-                acct = (fronts[side][80 * i:80 * i + 80] + meta
-                        + flags2[side][2 * i:2 * i + 2] + ts_le)
-                sides_bytes[side] = acct
-                acct_last[k16] = acct
-            p_val = _NO_PENDING
-            if has_p:
-                pts = p_ts_l[i]
+        for i in np.flatnonzero(has_p | (pstat != 0)).tolist():
+            p_val = None
+            if has_p[i]:
+                pts = int(der["p_ts"][i])
                 p_val = p_cache.get(pts)
                 if p_val is None:
-                    ptid = by_ts.get(pts.to_bytes(8, "big"))
+                    ptid = by_ts.get(_k8(pts))
                     assert ptid is not None, "pending flushed before resolve"
-                    p_val = xfer_tree.get(ptid)
-                    p_cache[pts] = p_val
-            ets = ets8[8 * i:8 * i + 8]
-            put_ev(ets, struct.pack("<QHBB", ts_l[i], tflags16, pstat, has_p)
-                   + sides_bytes["dr"] + sides_bytes["cr"]
-                   + areq16[16 * i:16 * i + 16] + amt16[16 * i:16 * i + 16]
-                   + p_val)
-            dr_hist = side_flags_l["dr"][i] & hist
-            cr_hist = side_flags_l["cr"][i] & hist
-            if dr_hist:
-                put_ev_acct(sides_bytes["dr"][120:128][::-1] + ets, ONE)
-            if cr_hist:
-                put_ev_acct(sides_bytes["cr"][120:128][::-1] + ets, ONE)
-            if not (dr_hist or cr_hist):
-                put_ev_prun(ets, ONE)
-            put_ev_pstat(bytes([pstat]) + ets, ONE)
-            # Pending-status + expiry effects (oracle semantics).
-            if pstat == 1:
+                    p_val = p_cache[pts] = xfer_tree.get(ptid)
+                ev[i, 300:428] = np.frombuffer(p_val, dtype=np.uint8)
+            if pstat[i] == 1:
+                ets = ets8[i].tobytes()
                 put_pending(ets, ONE)
-                if timeout_l[i]:
-                    put_expiry(ets, struct.pack("<Q", expires_l[i]))
-            elif pstat in (2, 3):
-                pts = p_ts_l[i]
-                pk8 = pts.to_bytes(8, "big")
-                put_pending(pk8, bytes([pstat]))
-                p_timeout = int.from_bytes(p_val[108:112], "little")
-                if p_timeout:
+                if t["timeout"][i]:
+                    put_expiry(ets, struct.pack("<Q", int(t["expires"][i])))
+            elif pstat[i] in (2, 3):
+                pk8 = _k8(int(der["p_ts"][i]))
+                put_pending(pk8, bytes([int(pstat[i])]))
+                if int.from_bytes(p_val[108:112], "little"):  # its timeout
                     rm_expiry(pk8)
+
+        def with_ets(prefix, mask=slice(None)):
+            return np.concatenate([prefix[mask], ets8[mask]], axis=1)
+
+        trees["events"].put_run(ets8, ev)
+        trees["ev_by_pstat"].put_run(
+            with_ets(pstat.astype(np.uint8).reshape(n, 1)), ONE)
+        hist = (np.stack([e["dr_flags"][:n], e["cr_flags"][:n]], axis=1)
+                & np.uint32(int(AF.history))) != 0
+        # The accounts' timestamps big-endian: the image holds them LE.
+        acct_ts_be = acct_ts[:, :, ::-1]
+        trees["ev_by_acct_ts"].put_run(np.concatenate([
+            with_ets(acct_ts_be[:, s], hist[:, s]) for s in (0, 1)]), ONE)
+        trees["ev_by_prunable"].put_run(ets8[~hist.any(axis=1)], ONE)
+
+        # The LAST image of each distinct account is its object row.
+        images = ev[:, 12:268].reshape(2 * n, 128)[last].tobytes()
         put_acct = acct_tree.put
         closed_bit = int(AF.closed)
         by_closed = trees["acct_by_closed"]
-        for k16, val in acct_last.items():
+        aids = []
+        for u, k16 in enumerate(keys16):
+            val = images[128 * u:128 * u + 128]
             put_acct(k16, val)
             # `closed` transitions (closing-native tiers evolve it on
             # the fast path): same put/remove-on-transition contract as
             # the object flush, keyed by the account's timestamp.
             aid = int.from_bytes(k16, "big")
+            aids.append(aid)
             closed = bool(val[118] & closed_bit)  # flags u16 LE low byte
             if closed != (aid in self._closed_indexed):
                 a_ts = int.from_bytes(val[120:128], "little")
@@ -751,7 +753,7 @@ class DurableState:
                     self._closed_indexed.discard(aid)
         # The touched account ids: the caller invalidates their cache
         # entries (reads must never serve pre-chunk balances).
-        return [int.from_bytes(k16, "big") for k16 in acct_last]
+        return aids
 
     def prune_events(self, before_ts: int) -> int:
         """Delete prunable (no-history) event rows older than `before_ts`
@@ -797,7 +799,7 @@ class DurableState:
         count) ride in the root blob itself — they are only ever read at
         restore, so they don't belong in a tree (reference analog: the
         superblock's VSRState vs the checkpoint trailer)."""
-        self.rows_put["checkpoints"] += 1
+        self._rows_put["checkpoints"] += 1
         with self.tracer.span(Event.checkpoint_flush, op=op):
             self.flush(state, flush_columns=flush_columns, op=op,
                        at_checkpoint=True)
