@@ -1,15 +1,20 @@
 """The spans beneath commit_execute, commit_compact and commit_checkpoint,
-the serving thread's busy turns, the collector's pauses, the durable row
-counters, and the benchmark readers that turn them into per-layer
-metrics."""
+the two that a two-phase row opens inside flush_columns, the serving
+thread's busy turns, the collector's pauses, the durable row counters,
+and the benchmark readers that turn them into per-layer metrics."""
 
 import gc
 import importlib.util
+import json
 import pathlib
 
 import numpy as np
 import pytest
 
+from chipbench import check, wire
+from chipbench.reference.ledger import StateMachineOracle
+from chipbench.traffic import F_PENDING, F_POST, F_VOID, Deployment
+from chipbench.window import Sent
 from tigerbeetle_tpu import multi_batch
 from tigerbeetle_tpu.state_machine import StateMachine
 from tigerbeetle_tpu.testing.cluster import Cluster
@@ -88,12 +93,52 @@ def traced_run():
             "created": created, "transfer_puts": puts[0]}
 
 
+TWO_PHASE_PAIRS = 3   # requests of pendings, each followed by its posts
+TWO_PHASE_WIDTH = 120
+TWO_PHASE_STAGES = ("flush_columns", "flush_two_phase")
+
+
+@pytest.fixture(scope="module")
+def two_phase_run():
+    """The same replica under a recording tracer, fed the benchmark's
+    two-phase deployment (its own generator and configuration file, a
+    quarter of the resolutions made voids so that all three counters
+    move): pairs of a request of pendings and the request that posts or
+    voids each of them. No checkpoint falls inside the run."""
+    config = json.loads((REPO / "chipbench" / "configs"
+                         / "tb_bench_twophase_1r.json").read_text())
+    config["transfers"]["two_phase"].update(post_share=0.75, void_share=0.25)
+    dep = Deployment(config, seed=35, accounts_cut=64)
+    tracer = Tracer(pid=0)
+    cluster = Cluster(seed=5, replica_count=1,
+                      tracer_factory=lambda i: tracer,
+                      state_machine_factory=_device_machine)
+    client = cluster.client(9)
+    sent = []
+    requests = dep.account_requests(TWO_PHASE_WIDTH) + [
+        dep.transfer_request(0, k, TWO_PHASE_WIDTH)
+        for k in range(2 * TWO_PHASE_PAIRS)]
+    for k, request in enumerate(requests):
+        client.request(getattr(Operation, request.operation),
+                       wire.encode_one(request.payload, 128))
+        assert cluster.run(4000, until=lambda: client.idle), \
+            cluster.debug_status()
+        sent.append(Sent("window", 0, request, float(k), float(k) + 0.5,
+                         results=np.frombuffer(wire.decode_one(
+                             client.replies[-1].body, 16), wire.RESULT)))
+    replica = cluster.replicas[0]
+    assert replica.superblock.op_checkpoint == 0
+    return {"replica": replica, "tracer": tracer, "sent": sent,
+            "events": tracer.chrome_dict()["traceEvents"]}
+
+
 # ------------------------------------------------------------ (a) the spans
 
 @pytest.mark.parametrize("stage", sorted(STAGE_CHILDREN))
 def test_every_child_span_lies_inside_its_parent_and_carries_its_op(
-        traced_run, stage):
-    spans = [e for e in traced_run["events"] if e["ph"] == "X"]
+        traced_run, two_phase_run, stage):
+    run = two_phase_run if stage in TWO_PHASE_STAGES else traced_run
+    spans = [e for e in run["events"] if e["ph"] == "X"]
     parents = {e["args"]["op"]: e for e in spans if e["name"] == stage}
     assert parents
     for child in STAGE_CHILDREN[stage]:
@@ -108,6 +153,59 @@ def test_every_child_span_lies_inside_its_parent_and_carries_its_op(
                 # checkpoint's flush, for the same op as a commit_compact.
                 assert e["name"] in ("flush_columns", "flush_objects"), e
                 assert stage == "commit_compact"
+
+
+def test_two_phase_spans_open_only_in_an_op_that_holds_such_a_row(
+        traced_run, two_phase_run):
+    names = {e["name"] for e in traced_run["events"]}
+    assert "flush_columns" in names
+    assert not names & {"flush_two_phase", "memtable_fold"}
+
+    spans = [e for e in two_phase_run["events"] if e["ph"] == "X"]
+    by_op = {name: {e["args"]["op"]: e for e in spans if e["name"] == name}
+             for name in ("commit_execute", "flush_columns",
+                          "flush_two_phase", "memtable_fold")}
+    transfer_ops = sorted(
+        op for op, e in by_op["commit_execute"].items()
+        if e["args"]["operation"] == int(Operation.create_transfers))
+    assert len(transfer_ops) == 2 * TWO_PHASE_PAIRS
+    # Every request of the run holds two-phase rows; only a request of
+    # posts and voids reads its pendings by key, so only it folds.
+    assert sorted(by_op["flush_two_phase"]) == transfer_ops
+    assert sorted(by_op["memtable_fold"]) == transfer_ops[1::2]
+    for op, fold in by_op["memtable_fold"].items():
+        outer = by_op["flush_two_phase"][op]
+        assert outer["ts"] <= fold["ts"] and \
+            fold["ts"] + fold["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    for op, loop in by_op["flush_two_phase"].items():
+        outer = by_op["flush_columns"][op]
+        assert outer["ts"] <= loop["ts"] and \
+            loop["ts"] + loop["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    got = children_share(two_phase_run["events"])
+    assert got["flush_columns"]["count"] == 2 * TWO_PHASE_PAIRS
+    assert 0.0 < got["flush_columns"]["share_mean"] < 1.0
+    assert got["flush_two_phase"]["children_mean_ms"]["memtable_fold"] > 0.0
+
+
+def test_two_phase_counters_equal_the_references_rows(two_phase_run):
+    """`start` prints DurableState.two_phase_rows as the shutdown
+    record's `two_phase` block: the rows the plain reference holds by
+    flag after replaying the same request bytes."""
+    order, _ = check.ordered(two_phase_run["sent"])
+    reference = StateMachineOracle()
+    assert check.replay(reference, order) == 0
+    flags = np.array([t.flags for t in reference.transfers.values()])
+    want = {"pending": int((flags & F_PENDING != 0).sum()),
+            "posted": int((flags & F_POST != 0).sum()),
+            "voided": int((flags & F_VOID != 0).sum())}
+    durable = two_phase_run["replica"].durable
+    assert durable.two_phase_rows == want
+    assert want["voided"] > 0 and want["posted"] > want["voided"]
+    assert want["pending"] == want["posted"] + want["voided"]
+    assert sum(want.values()) == len(reference.transfers)
+    # The fold counted what it folded: each resolving request's own run
+    # and its pendings' run, in `transfers` and in `xfer_by_ts`.
+    assert durable.rows_put["folded"] == 2 * sum(want.values())
 
 
 def test_checkpoint_flush_holds_a_flush_pass_of_its_own(traced_run):
@@ -344,6 +442,9 @@ SYNTHETIC = {
     "commit_compact": [(102.0, 1.0), (106.0, 1.0)],
     # a third flush pass runs under the checkpoint, not under a compact
     "flush_columns": [(102.0, 0.5), (106.0, 0.3)],
+    # both ops hold two-phase rows; only the second reads by key
+    "flush_two_phase": [(102.1, 0.2), (106.05, 0.1)],
+    "memtable_fold": [(106.06, 0.04)],
     "flush_objects": [(102.5, 0.1), (106.3, 0.1), (107.3, 1.0)],
     "compact_beat": [(102.7, 0.2), (106.5, 0.4)],
     "commit_checkpoint": [(107.0, 2.0)],
@@ -365,7 +466,8 @@ SYNTHETIC = {
     ("checkpoint_drain_ms", 250.0), ("checkpoint_flush_ms", 1250.0),
     ("checkpoint_forest_ms", 250.0), ("checkpoint_superblock_ms", 250.0),
     ("replica_busy_share", 87.5), ("replica_protocol_ms", 1375.0),
-    ("host_gc_window_share", 5.0)])
+    ("host_gc_window_share", 5.0),
+    ("flush_two_phase_ms", 150.0), ("memtable_fold_ms", 20.0)])
 def test_span_readers_on_a_synthetic_trace(name, want):
     read = _reader(name)
     assert read(_context(SYNTHETIC)) == pytest.approx(want)
@@ -393,9 +495,23 @@ def test_checkpoint_object_rows_reads_the_shutdown_record():
         is None
 
 
-def test_every_new_per_layer_entry_has_its_reader_file():
-    import json
+@pytest.mark.parametrize("shutdown, want", [
+    ({"two_phase": {"pending": 500, "posted": 400, "voided": 80},
+      "stores": {"transfer_rows": 1000, "t_cap": 4096}}, 98.0),
+    # a single-phase cell: the control that its traffic is what its file says
+    ({"two_phase": {"pending": 0, "posted": 0, "voided": 0},
+      "stores": {"transfer_rows": 1000, "t_cap": 4096}}, 0.0),
+    # the parent's record has no such block; an empty store has no share
+    ({"stores": {"transfer_rows": 1000, "t_cap": 4096}}, None),
+    ({"fallback_stats": {}}, None),
+    ({"two_phase": {"pending": 0, "posted": 0, "voided": 0},
+      "stores": {"transfer_rows": 0, "t_cap": 4096}}, None)])
+def test_two_phase_event_share_reads_the_shutdown_record(shutdown, want):
+    got = _reader("two_phase_event_share")(_context({}, shutdown))
+    assert got == want
 
+
+def test_every_new_per_layer_entry_has_its_reader_file():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     for entry in bench["per_layer"]:
         assert (REPO / "chipbench" / "layer_metrics"

@@ -313,6 +313,27 @@ def test_only_a_tree_that_is_read_pays_per_key():
     assert tree.memtable.rows_folded == 70
 
 
+def test_a_caller_can_fold_ahead_of_its_reads():
+    """`fold()` is what the next read by key would do first, for the
+    flush that times it under a span of its own (`memtable_fold`):
+    `pending_runs` says whether there is anything to fold, the rows
+    count once, and the reads that follow find the same values."""
+    tree = _forest(8, 16).trees["t"]
+    assert tree.memtable.pending_runs == 0
+    tree.memtable.fold()  # nothing waits: nothing happens
+    assert tree.memtable.rows_folded == 0
+    tree.put(key(1), val(7))
+    _apply(tree, [run(range(40)), run(range(30, 60))], whole_runs=True)
+    assert tree.memtable.pending_runs == 2
+    tree.memtable.fold()
+    assert tree.memtable.pending_runs == 0
+    assert tree.memtable.rows_folded == 70
+    assert [tree.get(key(i)) for i in (1, 35, 59)] == \
+        [val(1), val(35), val(59)]
+    assert tree.memtable.rows_folded == 70  # the reads folded nothing more
+    assert len(tree.scan(key(0), key(10 ** 6))) == 60
+
+
 @pytest.mark.parametrize("key_size,value_size", [(8, 16), (9, 1), (24, 1)])
 def test_the_frozen_run_answers_by_binary_search(key_size, value_size):
     """A memtable that held runs freezes without a dict: point reads and

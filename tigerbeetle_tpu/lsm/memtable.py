@@ -164,6 +164,17 @@ class Memtable:
         self._top = out
         self._runs = 0
 
+    @property
+    def pending_runs(self) -> int:
+        """Run segments that the next read by key or range would fold."""
+        return self._runs
+
+    def fold(self) -> None:
+        """Fold the pending runs now: what the next `get` or `between`
+        would do first, for a caller that wants to time it apart."""
+        if self._runs:
+            self._fold()
+
     def get(self, key: bytes) -> Optional[bytes]:
         if self._runs:
             self._fold()

@@ -349,6 +349,10 @@ def cmd_start(args) -> int:
             # checkpoints taken: a checkpoint that put again what the
             # column path had already written would show here.
             "durable_rows": dict(replica.durable.rows_put),
+            # Rows created pending, posted and voided (the column
+            # flush counts them where it loops over them anyway): what
+            # share of the traffic was two-phase.
+            "two_phase": dict(replica.durable.two_phase_rows),
             # How full the deployment's sizes got, read here from state
             # that exists anyway: the stores' row counters, the free
             # set (and what each checkpoint counted of it), the

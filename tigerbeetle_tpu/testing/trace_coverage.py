@@ -657,7 +657,8 @@ def _scenario_causal_trace(col: _Collector) -> None:
 def _scenario_commit_stage_children(col: _Collector) -> None:
     """One device-engine replica driven past a checkpoint: every child
     span of commit_execute / commit_compact / commit_checkpoint and the
-    durable row counter; then the serving loop over a bus that wakes it
+    durable row counter, then a pending and its post (the two spans a
+    two-phase row opens); then the serving loop over a bus that wakes it
     for one long turn (loop_busy), under the collector hook (host_gc)."""
     import gc
     import time
@@ -666,7 +667,7 @@ def _scenario_commit_stage_children(col: _Collector) -> None:
     from ..main import serve
     from ..state_machine import StateMachine
     from ..trace import install_gc_spans
-    from ..types import Account, Operation, Transfer
+    from ..types import Account, Operation, Transfer, TransferFlags
     from .cluster import Cluster
 
     tracer = col.make(0)
@@ -690,6 +691,18 @@ def _scenario_commit_stage_children(col: _Collector) -> None:
             [Transfer(id=300 + k, debit_account_id=1, credit_account_id=2,
                       amount=1, ledger=1, code=1).pack()], 128))
     assert replica.durable.rows_put["checkpoints"] >= 1
+    # A pending and, in the next op, its post: flush_two_phase in both,
+    # memtable_fold where the post reads its pending by key.
+    drive(Operation.create_transfers, multi_batch.encode(
+        [Transfer(id=400, debit_account_id=1, credit_account_id=2,
+                  amount=1, ledger=1, code=1,
+                  flags=int(TransferFlags.pending)).pack()], 128))
+    drive(Operation.create_transfers, multi_batch.encode(
+        [Transfer(id=401, pending_id=400, amount=1, ledger=1, code=1,
+                  flags=int(TransferFlags.post_pending_transfer)).pack()],
+        128))
+    assert replica.durable.two_phase_rows == {
+        "pending": 1, "posted": 1, "voided": 0}
 
     class _Bus:
         woke_ns = 0

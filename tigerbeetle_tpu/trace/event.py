@@ -359,6 +359,22 @@ class Event(enum.Enum):
         "(gc.callbacks start -> stop)", "generation",
         hist_tags=("generation",))
 
+    # ------------------------------------------- two-phase rows in the flush
+    # Children of flush_columns, opened only where an op's delta holds a
+    # row that sets a pending status or reads its pending transfer: a
+    # single-phase op records neither and pays for neither.
+    flush_two_phase = _span(
+        "the column flush's loop over the rows that set a pending status "
+        "or reference a pending transfer: a put into the pending tree a "
+        "pending; a post or void reads its pending's row by timestamp "
+        "and id, copies it into the event row and puts its new status "
+        "(holds memtable_fold)", "op")
+    memtable_fold = _span(
+        "fold of the column runs of xfer_by_ts and transfers into their "
+        "memtables' dicts, a row at a time, before the flush reads them "
+        "by key (lsm/memtable.py; the rows count in durable_rows_put "
+        "path=folded); only when a run waits", "op")
+
     # ------------------------------------------------------ tracer internal
     trace_dropped_events = _counter(
         "span ring evictions (the trace is truncated at its start)")

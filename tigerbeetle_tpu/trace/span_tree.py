@@ -2,7 +2,9 @@
 
 The three commit stages that hold most of a request's time
 (`commit_execute`, `commit_compact`, `commit_checkpoint`) are split into
-child spans, one catalog event per phase (trace/event.py). A child
+child spans, one catalog event per phase (trace/event.py), and
+`flush_columns` once more where an op holds two-phase rows
+(`flush_two_phase`, and `memtable_fold` inside it). A child
 carries no pointer to its parent: it belongs to the parent occurrence
 that contains it in time, on the same pid. This module lays the children
 over their parents and says what share of each parent they cover — the
@@ -33,6 +35,11 @@ STAGE_CHILDREN: dict = {
     "commit_checkpoint": (
         "checkpoint_wal_barrier", "checkpoint_mirror_drain",
         "checkpoint_flush", "checkpoint_forest", "checkpoint_superblock"),
+    # What two-phase rows cost the column flush: both open only in an op
+    # whose delta holds such a row (a single-phase trace has no
+    # flush_two_phase occurrence, and its flush_columns reads 0 covered).
+    "flush_columns": ("flush_two_phase",),
+    "flush_two_phase": ("memtable_fold",),
 }
 
 

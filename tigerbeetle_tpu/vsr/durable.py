@@ -293,6 +293,11 @@ class DurableState:
         self._rows_put = {"column": 0, "object": 0,
                           "object_at_checkpoint": 0, "checkpoints": 0,
                           "run": 0, "folded": 0}
+        # Rows of the column path by the pending status they set, over
+        # the life of this object: counted from the delta's `pstat`
+        # column inside the two-phase loop, so an op with no such row
+        # counts nothing.
+        self.two_phase_rows = {"pending": 0, "posted": 0, "voided": 0}
         layout = storage.layout
         self.grid = Grid(
             _ZoneDevice(storage, "grid"),
@@ -319,9 +324,10 @@ class DurableState:
         can write its bounded object caches through (state_machine.py
         cache_upsert).
 
-        `op` tags the two spans (flush_columns where there are chunks,
-        flush_objects always); `at_checkpoint` says the caller is
-        checkpoint(), for the row counters.
+        `op` tags the spans (flush_columns where there are chunks, with
+        flush_two_phase and memtable_fold inside it where a chunk holds
+        two-phase rows; flush_objects always); `at_checkpoint` says the
+        caller is checkpoint(), for the row counters.
 
         flush_columns: drained device-delta transfer columns
         (DeviceLedger.take_flush_columns). Transfers covered by them are
@@ -335,7 +341,7 @@ class DurableState:
         if flush_columns:
             with self.tracer.span(Event.flush_columns, op=op):
                 self._flush_columns(state, flush_columns,
-                                    vector_tids, vector_aids)
+                                    vector_tids, vector_aids, op)
             self._count_rows("column", len(vector_tids))
             self._count_rows("run", len(vector_tids))
         with self.tracer.span(Event.flush_objects, op=op):
@@ -368,7 +374,7 @@ class DurableState:
         return self._rows_put
 
     def _flush_columns(self, state, flush_columns, vector_tids: list,
-                       vector_aids: list) -> None:
+                       vector_aids: list, op: int) -> None:
         """The vectorized path over an op's chunks; appends the flushed
         transfer and account ids to the two lists."""
         trees = self.forest.trees
@@ -397,7 +403,7 @@ class DurableState:
             vector_tids.extend(self._flush_transfer_columns(
                 trees, t_cols, n_new))
             vector_aids.extend(self._flush_side_columns(
-                trees, t_cols, e_cols, der_cols, n_new))
+                trees, t_cols, e_cols, der_cols, n_new, op))
             self.events_persisted = abs_start + n_new
 
     def _flush_objects(self, state, vector_tids: list):
@@ -598,7 +604,8 @@ class DurableState:
         return ((t["id_hi"][:n].astype(object) << 64)
                 | t["id_lo"][:n].astype(object)).tolist()
 
-    def _flush_side_columns(self, trees, t, e, der, n: int) -> list:
+    def _flush_side_columns(self, trees, t, e, der, n: int,
+                            op: int) -> list:
         """Vectorized flush of one chunk's NON-transfer effects: the
         account_events rows (+ their index trees), the touched accounts'
         object rows, and the pending/expiry trees — all from device delta
@@ -606,7 +613,8 @@ class DurableState:
         The event rows are built as one uint8[n, 428] matrix and handed
         to their trees as runs; Python loops only over the chunk's
         DISTINCT accounts and over the rows that reference a pending
-        transfer or set a pending status (they read trees).
+        transfer or set a pending status (_flush_two_phase_rows: they
+        read trees).
 
         Immutable account metadata (user_data/ledger/code/timestamp) is
         spliced from the account's PREVIOUS tree value (the fast path
@@ -682,40 +690,16 @@ class DurableState:
         ev[:, 268:284] = le(e["areq_lo"], e["areq_hi"])
         ev[:, 284:300] = le(e["amt_lo"], e["amt_hi"])
 
-        # Rows that reference a pending transfer or set a pending status
-        # read trees and touch pending / expiry (oracle semantics): a
-        # loop over those rows only.
-        xfer_tree = trees["transfers"]
-        by_ts = trees["xfer_by_ts"]
-        put_pending = trees["pending"].put
-        put_expiry = trees["expiry"].put
-        rm_expiry = trees["expiry"].remove
-        ONE = b"\x01"
-        p_cache: dict = {}  # p_ts -> pending transfer value bytes
-        for i in np.flatnonzero(has_p | (pstat != 0)).tolist():
-            p_val = None
-            if has_p[i]:
-                pts = int(der["p_ts"][i])
-                p_val = p_cache.get(pts)
-                if p_val is None:
-                    ptid = by_ts.get(_k8(pts))
-                    assert ptid is not None, "pending flushed before resolve"
-                    p_val = p_cache[pts] = xfer_tree.get(ptid)
-                ev[i, 300:428] = np.frombuffer(p_val, dtype=np.uint8)
-            if pstat[i] == 1:
-                ets = ets8[i].tobytes()
-                put_pending(ets, ONE)
-                if t["timeout"][i]:
-                    put_expiry(ets, struct.pack("<Q", int(t["expires"][i])))
-            elif pstat[i] in (2, 3):
-                pk8 = _k8(int(der["p_ts"][i]))
-                put_pending(pk8, bytes([int(pstat[i])]))
-                if int.from_bytes(p_val[108:112], "little"):  # its timeout
-                    rm_expiry(pk8)
+        two_phase = np.flatnonzero(has_p | (pstat != 0))
+        if two_phase.size:
+            with self.tracer.span(Event.flush_two_phase, op=op):
+                self._flush_two_phase_rows(
+                    trees, t, der, ev, ets8, pstat, has_p, two_phase, op)
 
         def with_ets(prefix, mask=slice(None)):
             return np.concatenate([prefix[mask], ets8[mask]], axis=1)
 
+        ONE = b"\x01"
         trees["events"].put_run(ets8, ev)
         trees["ev_by_pstat"].put_run(
             with_ets(pstat.astype(np.uint8).reshape(n, 1)), ONE)
@@ -754,6 +738,55 @@ class DurableState:
         # The touched account ids: the caller invalidates their cache
         # entries (reads must never serve pre-chunk balances).
         return aids
+
+    def _flush_two_phase_rows(self, trees, t, der, ev, ets8, pstat, has_p,
+                              rows, op: int) -> None:
+        """The chunk's rows that reference a pending transfer or set a
+        pending status (`rows`: their indices) read trees and touch
+        pending / expiry (oracle semantics): a loop over those rows
+        only. A post or void copies its pending transfer's row into
+        `ev` (the chunk's event rows, written in place)."""
+        import numpy as np
+
+        xfer_tree = trees["transfers"]
+        by_ts = trees["xfer_by_ts"]
+        counts = np.bincount(pstat[rows], minlength=4)
+        for status, name in ((1, "pending"), (2, "posted"), (3, "voided")):
+            self.two_phase_rows[name] += int(counts[status])
+        if has_p.any() and (by_ts.memtable.pending_runs
+                            or xfer_tree.memtable.pending_runs):
+            # The reads below go by key, and the first of them would fold
+            # what _flush_transfer_columns appended as runs since the
+            # last such read, a row at a time: done here, under a span of
+            # its own.
+            with self.tracer.span(Event.memtable_fold, op=op):
+                by_ts.memtable.fold()
+                xfer_tree.memtable.fold()
+        put_pending = trees["pending"].put
+        put_expiry = trees["expiry"].put
+        rm_expiry = trees["expiry"].remove
+        ONE = b"\x01"
+        p_cache: dict = {}  # p_ts -> pending transfer value bytes
+        for i in rows.tolist():
+            p_val = None
+            if has_p[i]:
+                pts = int(der["p_ts"][i])
+                p_val = p_cache.get(pts)
+                if p_val is None:
+                    ptid = by_ts.get(_k8(pts))
+                    assert ptid is not None, "pending flushed before resolve"
+                    p_val = p_cache[pts] = xfer_tree.get(ptid)
+                ev[i, 300:428] = np.frombuffer(p_val, dtype=np.uint8)
+            if pstat[i] == 1:
+                ets = ets8[i].tobytes()
+                put_pending(ets, ONE)
+                if t["timeout"][i]:
+                    put_expiry(ets, struct.pack("<Q", int(t["expires"][i])))
+            elif pstat[i] in (2, 3):
+                pk8 = _k8(int(der["p_ts"][i]))
+                put_pending(pk8, bytes([int(pstat[i])]))
+                if int.from_bytes(p_val[108:112], "little"):  # its timeout
+                    rm_expiry(pk8)
 
     def prune_events(self, before_ts: int) -> int:
         """Delete prunable (no-history) event rows older than `before_ts`
