@@ -61,12 +61,13 @@ def test_every_grid_block_is_classifiable():
 def test_misdirected_block_read_fails_loudly():
     """A valid VALUE block served where an INDEX block is expected (the
     misdirected-write shape) must raise, not misparse."""
-    from tigerbeetle_tpu.lsm.table import (Table, TableInfo, entry_rows,
-                                           write_value_block)
+    import numpy as np
+
+    from tigerbeetle_tpu.lsm.table import Table, TableInfo, write_value_block
 
     forest, grid = _forest()
-    addr, size, _first = write_value_block(
-        grid, entry_rows([(b"k" * 8, b"v" * 16)], 8, 16), 8, tree_id=1)
+    row = np.frombuffer(b"k" * 8 + b"v" * 16, dtype=np.uint8).reshape(1, 24)
+    addr, size, _first = write_value_block(grid, row, 8, tree_id=1)
     info = TableInfo(index_address=addr, index_size=size,
                      key_min=b"k" * 8, key_max=b"k" * 8, entry_count=1)
     with pytest.raises(ValueError, match="kind"):

@@ -511,6 +511,29 @@ def test_two_phase_event_share_reads_the_shutdown_record(shutdown, want):
     assert got == want
 
 
+@pytest.mark.parametrize("shutdown, want", [
+    ({"forest": {"deepest_level": 1, "tables": 40, "compaction": {
+        "jobs": 12, "rows_in": 25_000, "rows_out": 21_000,
+        "passed_sorted": 4_000}},
+      "stores": {"transfer_rows": 1000, "t_cap": 4096}}, 25.0),
+    # no tree left level 0: no job ran
+    ({"forest": {"deepest_level": 0, "tables": 9, "compaction": {
+        "jobs": 0, "rows_in": 0, "rows_out": 0, "passed_sorted": 0}},
+      "stores": {"transfer_rows": 1000, "t_cap": 4096}}, 0.0),
+    # the parent's record: a forest block without the counters, or none
+    ({"forest": {"deepest_level": 1, "tables": 40},
+      "stores": {"transfer_rows": 1000, "t_cap": 4096}}, None),
+    ({"stores": {"transfer_rows": 1000, "t_cap": 4096}}, None),
+    ({"fallback_stats": {}}, None),
+    ({"forest": {"deepest_level": 0, "tables": 9, "compaction": {
+        "jobs": 0, "rows_in": 0, "rows_out": 0, "passed_sorted": 0}},
+      "stores": {"transfer_rows": 0, "t_cap": 4096}}, None)])
+def test_compaction_rows_per_transfer_reads_the_shutdown_record(
+        shutdown, want):
+    got = _reader("compaction_rows_per_transfer")(_context({}, shutdown))
+    assert got == want
+
+
 def test_every_new_per_layer_entry_has_its_reader_file():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     for entry in bench["per_layer"]:

@@ -31,6 +31,7 @@ from tigerbeetle_tpu.lsm.forest import Forest, chain_next, chain_payload
 from tigerbeetle_tpu.lsm.grid import Grid, MemoryDevice
 from tigerbeetle_tpu.lsm.manifest_level import SNAPSHOT_LATEST
 from tigerbeetle_tpu.lsm.table import TableInfo
+from tigerbeetle_tpu.lsm.tree import BAR_LENGTH
 from tigerbeetle_tpu.ops.warmup import (WARM_ACCOUNTS, WARM_SIZES,
                                         WARM_TRANSFERS, capacity_error)
 from tigerbeetle_tpu.state_machine import StateMachine
@@ -406,6 +407,9 @@ def test_grid_held_counts_follow_the_free_set():
                                   "held_peak": 7}
 
 
+NO_COMPACTION = {"jobs": 0, "rows_in": 0, "rows_out": 0, "passed_sorted": 0}
+
+
 def _persisted_root(served) -> bytes:
     sb = served.replica.superblock
     return served.cluster.storages[0].read(
@@ -468,21 +472,68 @@ def test_forest_block_equals_the_persisted_manifests(served):
                     tables += 1
                     deepest = max(deepest, level)
     stats = served.replica.durable.forest.depth_stats()
+    compaction = stats.pop("compaction")
     assert stats == {"deepest_level": deepest, "tables": tables}
     assert tables > 0 and deepest == 0  # 23,000 rows leave level 0 to no tree
+    # No tree left level 0, so no job ran (the freezes and their
+    # flushes are not compaction's rows).
+    assert compaction == NO_COMPACTION
 
 
 def test_forest_depth_follows_a_table_down_the_levels():
     grid = Grid(MemoryDevice(256 * 4096), block_size=4096, block_count=256)
     forest = Forest(grid, {"a": (8, 8), "b": (8, 8)})
-    assert forest.depth_stats() == {"deepest_level": -1, "tables": 0}
+    assert forest.depth_stats() == {"deepest_level": -1, "tables": 0,
+                                    "compaction": NO_COMPACTION}
     for name, tree in forest.trees.items():
         for i in range(10):
             tree.put(i.to_bytes(8, "big"), name.encode() * 8)
     forest.checkpoint()
-    assert forest.depth_stats() == {"deepest_level": 0, "tables": 2}
+    assert forest.depth_stats() == {"deepest_level": 0, "tables": 2,
+                                    "compaction": NO_COMPACTION}
     tree = forest.trees["b"]
     table = tree.levels[0][0]
     tree.levels[0].remove(table, snapshot=tree.beat)
     tree.levels[3].insert(table, snapshot=tree.beat)
-    assert forest.depth_stats() == {"deepest_level": 3, "tables": 2}
+    assert forest.depth_stats() == {"deepest_level": 3, "tables": 2,
+                                    "compaction": NO_COMPACTION}
+
+
+def test_compaction_counters_move_once_a_tree_reaches_level_1(served):
+    """The last test of the file: it serves the replica on, a few
+    transfers a request, to the end of the bar in which the trees'
+    second jobs install (level 0 holds a table a freeze and a table a
+    checkpoint; a tree's first job finds level 1 empty). Before any
+    job the counters are all zero (the test above); from then on they
+    say what the jobs read and wrote."""
+    forest = served.replica.durable.forest
+    assert forest.depth_stats()["compaction"] == NO_COMPACTION
+    rng = np.random.default_rng(36)
+    while served.replica.commit_min < 5 * BAR_LENGTH - 1:
+        served.request(Operation.create_transfers,
+                       _stream(rng, 1_000_000 + served.created, 8))
+        served.created += 8
+    assert served.mismatches == []
+    stats = forest.depth_stats()
+    compaction = stats["compaction"]
+    assert stats["deepest_level"] == 1 and compaction["jobs"] >= 2
+    assert 0 < compaction["rows_out"] < compaction["rows_in"]
+    assert 0 < compaction["passed_sorted"] < compaction["rows_in"]
+    assert compaction == {
+        key: sum(tree.compaction[key] for tree in forest.trees.values())
+        for key in NO_COMPACTION}
+    # A tree keyed by timestamp compacts into an empty range of level 1:
+    # its rows pass as they stand. One keyed by account interleaves
+    # with what level 1 holds, and the accounts' own rows meet their
+    # older selves there.
+    by_ts = forest.trees["xfer_by_ts"].compaction
+    assert by_ts["jobs"] == 2
+    assert by_ts["passed_sorted"] == by_ts["rows_in"] == by_ts["rows_out"]
+    by_dr = forest.trees["xfer_by_dr"].compaction
+    assert by_dr["jobs"] == 2
+    assert by_dr["passed_sorted"] < by_dr["rows_in"] == by_dr["rows_out"]
+    accounts = forest.trees["accounts"].compaction
+    assert accounts["jobs"] == 2
+    # (the first job moved 96 rows down, the second met them with 96)
+    assert (accounts["rows_in"], accounts["rows_out"]) == \
+        (3 * ACCOUNTS, 2 * ACCOUNTS)

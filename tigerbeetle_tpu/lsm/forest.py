@@ -15,7 +15,7 @@ import struct
 from typing import Optional
 
 from .grid import ADDRESS_SIZE, BlockAddress, Grid
-from .tree import Tree
+from .tree import COMPACTION_COUNTERS, Tree
 
 from .schema import BLOCK_HEADER_SIZE, BlockKind, unwrap, wrap
 
@@ -62,14 +62,20 @@ class Forest:
         """How deep the trees stand (`start`'s shutdown record), read
         off the manifests: the deepest level (0-based, as
         `Tree.levels`; -1 with no table anywhere) that holds a live
-        table in any tree, and the live tables of all trees."""
+        table in any tree, and the live tables of all trees; and under
+        `compaction` what it cost to get them there, the trees'
+        counters (`Tree.compaction`) summed."""
         deepest, tables = -1, 0
+        compaction = dict.fromkeys(COMPACTION_COUNTERS, 0)
         for tree in self.trees.values():
             for li, level in enumerate(tree.levels):
                 if len(level):
                     deepest = max(deepest, li)
                     tables += len(level)
-        return {"deepest_level": deepest, "tables": tables}
+            for key, count in tree.compaction.items():
+                compaction[key] += count
+        return {"deepest_level": deepest, "tables": tables,
+                "compaction": compaction}
 
     def checkpoint(self) -> bytes:
         """Flush + serialize everything; returns the root blob (manifest

@@ -77,6 +77,25 @@ class Table:
             self.block_sizes.append(size)
             self.block_first_keys.append(first)
 
+    def block_rows(self, i: int) -> np.ndarray:
+        """Value block i as the rows it was written from: a read-only
+        uint8[n, key_size + value_size] view of the block's bytes, each
+        row `key || value`, sorted. Block i+1's device read is
+        submitted first, so it runs while the caller works on block i
+        (reference: compaction reads are pipelined through io_uring,
+        src/storage.zig:177 + docs/internals/lsm.md pipelined
+        compaction); a no-op on synchronous devices (the deterministic
+        simulator)."""
+        if i + 1 < len(self.block_addresses):
+            self.grid.prefetch_async(
+                [(self.block_addresses[i + 1], self.block_sizes[i + 1])])
+        raw = unwrap(memoryview(self.grid.read_block(
+            self.block_addresses[i], self.block_sizes[i])), BlockKind.value)
+        (n,) = struct.unpack_from("<I", raw)
+        entry = self.key_size + self.value_size
+        return np.frombuffer(raw, dtype=np.uint8, count=n * entry,
+                             offset=4).reshape(n, entry)
+
     def _block_entries(self, i: int) -> tuple[list[bytes], list[bytes]]:
         raw = unwrap(self.grid.read_block(self.block_addresses[i],
                                           self.block_sizes[i]),
@@ -125,12 +144,8 @@ class Table:
         return None
 
     def iter_entries(self):
-        # Read-ahead: block i+1's device read runs while block i's
-        # entries are merged (compaction input no longer stalls the
-        # replica loop per block — reference: compaction reads are
-        # pipelined through io_uring, src/storage.zig:177 +
-        # docs/internals/lsm.md pipelined compaction). No-op on
-        # synchronous devices (the deterministic simulator).
+        # (key, value) pairs of the whole table (scans and tests; the
+        # compaction job reads `block_rows`), with the same read-ahead.
         n = len(self.block_addresses)
         for i in range(n):
             if i + 1 < n:
@@ -155,15 +170,6 @@ def table_entry_max(grid: Grid, key_size: int, value_size: int) -> int:
     index_entries_max = ((grid.block_size - BLOCK_HEADER_SIZE - 4)
                          // (ADDRESS_SIZE + 4 + key_size))
     return per_block * index_entries_max
-
-
-def entry_rows(entries: list[tuple[bytes, bytes]], key_size: int,
-               value_size: int) -> np.ndarray:
-    """A sorted (key, value) list as the rows the block encoder takes:
-    uint8[n, key_size + value_size], each row `key || value`."""
-    return np.frombuffer(b"".join(k + v for k, v in entries),
-                         dtype=np.uint8).reshape(
-                             len(entries), key_size + value_size)
 
 
 def write_value_block(grid: Grid, rows: np.ndarray, key_size: int,
@@ -208,26 +214,26 @@ def table_block_bound(grid: Grid, n_entries: int, key_size: int,
     return -(-n // per_block) + 2 * tables
 
 
-def write_tables(grid: Grid, entries: list[tuple[bytes, bytes]],
+def write_tables(grid: Grid, rows: np.ndarray,
                  key_size: int, value_size: int,
                  reservation=None, tree_id: int = 0) -> list["TableInfo"]:
-    """Serialize a sorted run as one or more bounded tables (a single merge
-    output may exceed one table's index capacity — split, like the
-    reference's compaction emitting multiple output tables)."""
+    """Serialize a sorted run (uint8[n, key_size + value_size] rows, each
+    `key || value`) as one or more bounded tables (a single merge output
+    may exceed one table's index capacity — split, like the reference's
+    compaction emitting multiple output tables)."""
     cap = table_entry_max(grid, key_size, value_size)
-    return [write_table(grid, entries[i:i + cap], key_size, value_size,
+    return [write_table(grid, rows[i:i + cap], key_size, value_size,
                         reservation=reservation, tree_id=tree_id)
-            for i in range(0, len(entries), cap)]
+            for i in range(0, len(rows), cap)]
 
 
-def write_table(grid: Grid, entries: list[tuple[bytes, bytes]],
+def write_table(grid: Grid, rows: np.ndarray,
                 key_size: int, value_size: int,
                 reservation=None, tree_id: int = 0) -> TableInfo:
-    """Serialize one sorted run (caller guarantees sort order + unique keys)."""
-    assert entries
+    """Serialize one sorted run of rows (caller guarantees sort order +
+    unique keys)."""
+    assert len(rows) and rows.shape[1] == key_size + value_size
     per_block = value_block_entry_max(grid, key_size, value_size)
-    # The merge hands a list; the encoder takes columns.
-    rows = entry_rows(entries, key_size, value_size)
     blocks = [write_value_block(grid, rows[base:base + per_block], key_size,
                                 reservation=reservation, tree_id=tree_id)
               for base in range(0, len(rows), per_block)]
@@ -236,8 +242,9 @@ def write_table(grid: Grid, entries: list[tuple[bytes, bytes]],
                                                tree_id=tree_id)
     return TableInfo(
         index_address=index_addr, index_size=index_size,
-        key_min=entries[0][0], key_max=entries[-1][0],
-        entry_count=len(entries))
+        key_min=rows[0, :key_size].tobytes(),
+        key_max=rows[-1, :key_size].tobytes(),
+        entry_count=len(rows))
 
 
 def release_table(grid: Grid, table: Table) -> None:

@@ -29,6 +29,8 @@ import dataclasses
 import struct
 from typing import Optional
 
+import numpy as np
+
 from .grid import Grid
 from .manifest_level import SNAPSHOT_LATEST, ManifestLevel
 from .memtable import Memtable, SortedRun
@@ -49,6 +51,12 @@ LSM_LEVELS = 7
 GROWTH_FACTOR = 8
 BAR_LENGTH = 32  # ops per bar (reference: lsm_compaction_ops)
 L0_TABLES_MAX = 4
+# What a tree counts of its compaction (Tree.compaction), cumulative:
+# jobs installed, input rows consumed (as a beat consumes them), rows
+# written (as a job installs), and the input rows that went through a
+# beat with no merge because the beat's two key ranges did not
+# interleave.
+COMPACTION_COUNTERS = ("jobs", "rows_in", "rows_out", "passed_sorted")
 
 
 @dataclasses.dataclass
@@ -68,38 +76,159 @@ class _FlushJob:
     reservation: object = None
 
 
+def _keys(rows: np.ndarray, key_size: int) -> np.ndarray:
+    """The keys of `rows` as one fixed-width byte string a row (dtype
+    `S<key_size>`). numpy searches and compares such strings by their
+    unsigned bytes over the whole width, so their order is the `bytes`
+    order of the keys at every key size of the forest, a trailing zero
+    byte included (every key of a tree has the same width, so padding
+    never decides)."""
+    return np.ascontiguousarray(rows[:, :key_size]).view(
+        f"S{key_size}").ravel()
+
+
+class _SortedInput:
+    """One side of a merge as a sorted run: the value blocks of tables
+    that are disjoint and in key order, read a block at a time as row
+    matrices (`Table.block_rows`) and only as far as a beat asks."""
+
+    def __init__(self, tables: list[Table], entry: int):
+        self._blocks = [(t, i) for t in tables
+                        for i in range(len(t.block_addresses))]
+        self._next = 0  # the first block not read yet
+        self._rows = np.empty((0, entry), dtype=np.uint8)  # read, not taken
+
+    def peek(self, n: Optional[int]) -> np.ndarray:
+        """The next `n` rows (None: all that are left), fewer only
+        where the input ends; they stay until `skip` takes them."""
+        parts, have = [self._rows], len(self._rows)
+        while (n is None or have < n) and self._next < len(self._blocks):
+            table, i = self._blocks[self._next]
+            self._next += 1
+            parts.append(table.block_rows(i))
+            have += len(parts[-1])
+        if len(parts) > 1:
+            self._rows = np.concatenate(parts) if len(parts[0]) \
+                or len(parts) > 2 else parts[1]
+        return self._rows if n is None else self._rows[:n]
+
+    def skip(self, n: int) -> None:
+        self._rows = self._rows[n:]
+
+
 @dataclasses.dataclass
 class _CompactionJob:
-    """One level's in-flight incremental merge: input tables captured at
-    schedule time, merge advanced a bounded number of entries per beat,
-    output written + installed only at completion."""
+    """One level's in-flight incremental merge, on rows from the input
+    block to the output block. The inputs are captured at schedule
+    time as two sorted runs: `old`, the overlapping level-(L+1) tables
+    (disjoint, in key order), and `new`, the picked level-L table. A
+    beat merges a bounded number of input rows, in key order, onto
+    `out`; the output is written + installed only at completion."""
 
     level: int
     table: Table
     overlapping: list[Table]
-    total: int  # input entries (pacing estimate)
-    merged: dict = dataclasses.field(default_factory=dict)
-    streams: list = dataclasses.field(default_factory=list)
-    stream_i: int = 0
+    total: int  # input entries (pacing estimate, and the output's bound)
     # Worst-case grid reservation claimed at schedule (free_set.zig:28-35).
     reservation: object = None
 
+    def __post_init__(self):
+        self.key_size = self.table.key_size
+        entry = self.key_size + self.table.value_size
+        self.old = _SortedInput(self.overlapping, entry)
+        self.new = _SortedInput([self.table], entry)
+        # The merged rows so far: out[:rows_out], sorted, unique keys.
+        self.out = np.empty((self.total, entry), dtype=np.uint8)
+        self.rows_out = 0
+
     def advance(self, budget: Optional[int]):
-        """Merge up to `budget` INPUT entries (None = drain). Returns
-        (done, used): done when the inputs are exhausted (caller
-        finalizes); used = entries consumed, which the caller charges
-        against the beat budget (NOT merged-dict growth — duplicate-key
-        merges consume entries without growing the dict)."""
-        used = 0
-        while self.stream_i < len(self.streams):
-            stream = self.streams[self.stream_i]
-            for k, v in stream:
-                self.merged[k] = v
-                used += 1
-                if budget is not None and used >= budget:
-                    return False, used
-            self.stream_i += 1
-        return True, used
+        """Merge the next `budget` INPUT rows in key order (None =
+        drain). Returns (done, used, passed): done when the inputs ran
+        out before the budget did (caller finalizes); used = rows
+        consumed, which the caller charges against the beat budget (NOT
+        output growth — a key both inputs hold consumes two rows and
+        leaves one); passed = `used` where the rows went through as
+        they stood, 0 where they were merged."""
+        ks = self.key_size
+        a, b = self.old.peek(budget), self.new.peek(budget)
+        # Where the two ranges do not interleave the rows pass through
+        # as they stand, the lower range first (one input exhausted or
+        # empty is the common case: a tree keyed by timestamp compacts
+        # into an empty range of the next level).
+        if not len(a) or not len(b) \
+                or a[-1, :ks].tobytes() < b[0, :ks].tobytes():
+            b = b if budget is None else b[:budget - len(a)]
+            self._emit(a)
+            self._emit(b)
+            merged = False
+        elif b[-1, :ks].tobytes() < a[0, :ks].tobytes():
+            a = a if budget is None else a[:budget - len(b)]
+            self._emit(b)
+            self._emit(a)
+            merged = False
+        else:
+            a, b = self._interleave(a, b, budget)
+            merged = True
+        self.old.skip(len(a))
+        self.new.skip(len(b))
+        used = len(a) + len(b)
+        return budget is None or used < budget, used, 0 if merged else used
+
+    def _room(self, first: np.ndarray, n: int) -> np.ndarray:
+        """The output's next `n` rows, to be filled by the caller with
+        rows of which `first` is the lowest. Of equal keys the old row
+        goes first and the new one is kept (the rule Memtable.freeze
+        applies); where a beat's cut fell between the two, the new row
+        takes the old one's place here."""
+        ks = self.key_size
+        if self.rows_out and self.out[self.rows_out - 1, :ks].tobytes() \
+                == first[:ks].tobytes():
+            self.rows_out -= 1
+        self.rows_out += n
+        return self.out[self.rows_out - n:self.rows_out]
+
+    def _emit(self, rows: np.ndarray) -> None:
+        if len(rows):
+            self._room(rows[0], len(rows))[:] = rows
+
+    def _interleave(self, a: np.ndarray, b: np.ndarray,
+                    budget: Optional[int]):
+        """Emit the merge of the first `budget` rows, in key order, of
+        sorted `a` (old) and `b` (new); returns the rows of each it
+        took. No sort: each new row's place among the old ones is one
+        binary search, and the old rows between two places move as
+        they stand."""
+        ks = self.key_size
+        keys_a, keys_b = _keys(a, ks), _keys(b, ks)
+        if budget is not None and len(a) == budget:
+            # No new row at or past the last old one is among the
+            # first `budget`.
+            b = b[:int(np.searchsorted(keys_b, keys_a[-1:])[0])]
+            keys_b = keys_b[:len(b)]
+        # Old rows at or below each new row: its merged index is that
+        # many plus the new rows before it.
+        below = np.searchsorted(keys_a, keys_b, side="right")
+        total = len(a) + len(b) if budget is None \
+            else min(budget, len(a) + len(b))
+        n_b = int(np.searchsorted(below + np.arange(len(b)), total))
+        a, b = a[:total - n_b], b[:n_b]
+        below, keys_b = below[:n_b], keys_b[:n_b]
+        # An old row whose key a new row repeats is superseded.
+        same = (keys_a[np.maximum(below, 1) - 1] == keys_b) & (below > 0)
+        old = a
+        if same.any():
+            keep = np.ones(len(a), dtype=bool)
+            keep[below[same] - 1] = False
+            old = a[keep]
+            below = below - np.cumsum(same)
+        merged = self._room(b[0] if n_b and not below[0] else old[0],
+                            len(old) + n_b)
+        at = below + np.arange(n_b)
+        from_b = np.zeros(len(merged), dtype=bool)
+        from_b[at] = True
+        merged[at] = b
+        merged[~from_b] = old
+        return a, b
 
 
 class Tree:
@@ -129,6 +258,9 @@ class Tree:
         # advanced per beat, drained by bar end).
         self._jobs: list[_CompactionJob] = []
         self._per_beat = 0
+        # Forest.depth_stats sums the trees'. Not persisted: a restart
+        # counts from zero.
+        self.compaction = dict.fromkeys(COMPACTION_COUNTERS, 0)
 
     # ------------------------------------------------------------- updates
 
@@ -438,43 +570,45 @@ class Tree:
                 if any(id(t) in claimed for t in touched):
                     continue
                 claimed.update(id(t) for t in touched)
-                total = (table.info.entry_count
-                         + sum(t.info.entry_count for t in overlapping))
-                job = _CompactionJob(
-                    level=level, table=table,
-                    overlapping=overlapping, total=total,
-                    reservation=self.grid.reserve(table_block_bound(
-                        self.grid, total, self.key_size, self.value_size)))
-                # Older tables first so the newer input wins the merge.
-                job.streams = [t.iter_entries() for t in overlapping]
-                job.streams.append(table.iter_entries())
-                # Warm the first input block of every stream now: the
+                # Warm the first input block of every table now: the
                 # device reads run during the beats before the job's
-                # first advance (iter_entries read-ahead covers the
+                # first advance (block_rows' read-ahead covers the
                 # rest of each table).
                 self.grid.prefetch_async(
                     [(t.block_addresses[0], t.block_sizes[0])
                      for t in touched if t.block_addresses])
-                jobs.append(job)
+                jobs.append(self._new_job(level, table, overlapping))
+        self._set_jobs(jobs)
+
+    def _new_job(self, level: int, table: Table,
+                 overlapping: list[Table]) -> _CompactionJob:
+        total = (table.info.entry_count
+                 + sum(t.info.entry_count for t in overlapping))
+        return _CompactionJob(
+            level=level, table=table, overlapping=overlapping, total=total,
+            reservation=self.grid.reserve(table_block_bound(
+                self.grid, total, self.key_size, self.value_size)))
+
+    def _set_jobs(self, jobs: list[_CompactionJob]) -> None:
         self._jobs = jobs
         total = sum(j.total for j in jobs)
         self._per_beat = max(1, -(-total // (BAR_LENGTH - 1)))
 
-    def _advance_jobs(self, budget: int) -> None:
-        while budget > 0 and self._jobs:
-            job = self._jobs[0]
-            done, used = job.advance(budget)
+    def _advance_jobs(self, budget: Optional[int]) -> None:
+        """Advance the jobs in order by `budget` input rows in all
+        (None: drain them), installing each that runs out of input."""
+        while self._jobs and (budget is None or budget > 0):
+            done, used, passed = self._jobs[0].advance(budget)
+            self.compaction["rows_in"] += used
+            self.compaction["passed_sorted"] += passed
             if done:
-                self._finalize_job(job)
-                self._jobs.pop(0)
-            budget -= max(1, used)
+                self._finalize_job(self._jobs.pop(0))
+            if budget is not None:
+                budget -= max(1, used)
 
     def _drain_jobs(self) -> None:
-        for job in self._jobs:
-            done, _ = job.advance(None)
-            assert done
-            self._finalize_job(job)
-        self._jobs = []
+        self._advance_jobs(None)
+        assert not self._jobs
 
     def _finalize_job(self, job: _CompactionJob) -> None:
         """Write output tables, install, logically remove inputs — the
@@ -488,23 +622,23 @@ class Tree:
         next_level = self.levels[level + 1]
         for t in job.overlapping:
             next_level.remove(t, snapshot=self.beat)
-        last_level = level + 1 == LSM_LEVELS - 1
-        dead = TOMBSTONE * self.value_size
-        entries = sorted(
-            (k, v) for k, v in job.merged.items()
-            if not (last_level and v == dead))  # tombstones die at the bottom
-        if entries:
-            # A merge output exceeding one table's capacity splits into
-            # several disjoint tables (all still inside next_level's range).
-            for info in write_tables(self.grid, entries, self.key_size,
-                                     self.value_size,
-                                     reservation=job.reservation,
-                                     tree_id=self.tree_id):
-                next_level.insert(Table(
-                    self.grid, info, self.key_size, self.value_size),
-                    snapshot=self.beat)
+        rows = job.out[:job.rows_out]
+        if level + 1 == LSM_LEVELS - 1:  # tombstones die at the bottom
+            dead = np.frombuffer(TOMBSTONE * self.value_size, dtype=np.uint8)
+            rows = rows[(rows[:, self.key_size:] != dead).any(axis=1)]
+        # A merge output exceeding one table's capacity splits into
+        # several disjoint tables (all still inside next_level's range).
+        for info in write_tables(self.grid, rows, self.key_size,
+                                 self.value_size,
+                                 reservation=job.reservation,
+                                 tree_id=self.tree_id):
+            next_level.insert(Table(
+                self.grid, info, self.key_size, self.value_size),
+                snapshot=self.beat)
         if job.reservation is not None:
             self.grid.forfeit(job.reservation)
+        self.compaction["jobs"] += 1
+        self.compaction["rows_out"] += len(rows)
 
     def _pick_table(self, level: int) -> Table:
         """Selection policy: L0 tables overlap each other, so only the
@@ -593,7 +727,7 @@ class Tree:
         # (identity matters: finalize removes job tables from the level
         # lists by identity). Merge progress restarts from zero — the
         # output is input-deterministic, so only pacing differs.
-        self._jobs = []
+        jobs = []
         if pos < len(raw):
             (n_jobs,) = struct.unpack_from("<I", raw, pos)
             pos += 4
@@ -614,19 +748,9 @@ class Tree:
                     raise AssertionError(
                         f"job table missing from restored level {lvl}")
 
-                table = resident(level, t_info)
-                overlapping = [resident(level + 1, i) for i in over_infos]
-                total = (table.info.entry_count
-                         + sum(t.info.entry_count for t in overlapping))
-                job = _CompactionJob(
-                    level=level, table=table,
-                    overlapping=overlapping, total=total,
-                    reservation=self.grid.reserve(table_block_bound(
-                        self.grid, total, self.key_size, self.value_size)))
-                job.streams = [t.iter_entries() for t in overlapping]
-                job.streams.append(table.iter_entries())
-                self._jobs.append(job)
-            total = sum(j.total for j in self._jobs)
-            self._per_beat = max(1, -(-total // (BAR_LENGTH - 1)))
+                jobs.append(self._new_job(
+                    level, resident(level, t_info),
+                    [resident(level + 1, i) for i in over_infos]))
+        self._set_jobs(jobs)
 
 
