@@ -8,6 +8,10 @@ compared row for row. All comparisons are exact: each number's limit
 is 0.
 
     result_mismatches     events whose (status, timestamp) differs
+    read_mismatches       rows of a lookup sent among the writes (a mix's
+                          `reads`, and set-up's un-timed one) that differ
+                          from the reference's at the read's place in
+                          the commit order
     account_mismatches    accounts read back that differ, or are missing
     transfer_mismatches   sampled transfers read back that differ
     order_violations      prepares whose order contradicts real time,
@@ -17,7 +21,9 @@ is 0.
 
 The commit order is the server's own claim (each reply's timestamps
 name its prepare); the claim is checked against the client's clock, and
-the reference then has to reproduce every answer in that order.
+the reference then has to reproduce every answer in that order. A
+read's reply names no prepare: its place is the one its caller's clock
+gives it (`place_reads`).
 """
 
 from __future__ import annotations
@@ -35,13 +41,14 @@ GUARANTEES = {
     "replicas": ("unanswered",),
     "acknowledged_means": ("unanswered", "account_mismatches",
                            "transfer_mismatches"),
-    "consistency": ("order_violations",),
+    "consistency": ("order_violations", "read_mismatches"),
     "limits": ("result_mismatches", "account_mismatches"),
-    "results": ("result_mismatches", "account_mismatches",
+    "results": ("result_mismatches", "read_mismatches", "account_mismatches",
                 "transfer_mismatches"),
 }
-LIMITS = {"result_mismatches": 0, "account_mismatches": 0,
-          "transfer_mismatches": 0, "order_violations": 0, "unanswered": 0}
+LIMITS = {"result_mismatches": 0, "read_mismatches": 0,
+          "account_mismatches": 0, "transfer_mismatches": 0,
+          "order_violations": 0, "unanswered": 0}
 
 
 def prepare_timestamp(results: np.ndarray) -> int:
@@ -69,31 +76,77 @@ def commit_order(answered: list) -> tuple[list, int]:
     return order, violations
 
 
+def place_reads(order: list, reads: list) -> list:
+    """The writes in commit order with each read where its caller's
+    clock puts it: after the last write of that order that had been
+    answered when the read was sent, reads of one place in send order.
+    That is the read's one possible place where a single caller sends
+    one request at a time (which `run.servable` requires of a mix with
+    reads): the next write the caller sent must then be ordered after
+    it, which `commit_order` holds the writes' own timestamps to."""
+    after: dict[int, list] = {}
+    for r in sorted(reads, key=lambda r: r.t_send):
+        at = max((i for i, w in enumerate(order) if w.t_reply <= r.t_send),
+                 default=-1)
+        after.setdefault(at, []).append(r)
+    placed = list(after.get(-1, []))
+    for i, w in enumerate(order):
+        placed.append(w)
+        placed += after.get(i, [])
+    return placed
+
+
 def ordered(sent: list) -> tuple[list, int]:
-    """The answered requests in commit order, each with its prepare's
-    timestamp as `.ts`, and the count of order violations."""
+    """The answered requests in commit order, each write with its
+    prepare's timestamp as `.ts`, each read at its place among them, and
+    the count of order violations."""
     answered = [s for s in sent if s.error is None]
-    for s in answered:
+    writes = [s for s in answered if not s.request.is_read]
+    for s in writes:
         s.ts = prepare_timestamp(s.results)
-    return commit_order(answered)
+    order, violations = commit_order(writes)
+    return place_reads(order, [s for s in answered if s.request.is_read]), \
+        violations
 
 
 def apply(reference: StateMachineOracle, s) -> list:
     """One request through the reference at its prepare's timestamp."""
-    payload = s.request.payload
+    payload, size = s.request.payload, s.request.event_size
     accounts = s.request.operation == "create_accounts"
     cls = Account if accounts else Transfer
-    events = [cls.unpack(payload[i:i + 128])
-              for i in range(0, len(payload), 128)]
+    events = [cls.unpack(payload[i:i + size])
+              for i in range(0, len(payload), size)]
     return (reference.create_accounts(events, s.ts) if accounts
             else reference.create_transfers(events, s.ts))
+
+
+def int_ids(pairs: np.ndarray) -> list[int]:
+    """(n, 2) u64 (id_lo, id_hi) pairs as the 128-bit ids they spell."""
+    return [(int(h) << 64) | int(l) for l, h in pairs]
+
+
+def read_rows(reference: StateMachineOracle, s) -> list[bytes]:
+    """The rows the reference answers a read with, asked now."""
+    return [a.pack()
+            for a in reference.lookup_accounts(int_ids(s.request.ids))]
 
 
 def replay(reference: StateMachineOracle, order: list) -> int:
     """Feed the reference in commit order; the number of events whose
     (status, timestamp) differs from what the client received."""
-    mismatches = 0
+    return replay_with_reads(reference, order)[0]
+
+
+def replay_with_reads(reference: StateMachineOracle,
+                      order: list) -> tuple[int, int]:
+    """`replay`, and beside its count the number of rows of the reads
+    in `order` that differ from what the reference holds when the
+    replay reaches each."""
+    mismatches = reads = 0
     for s in order:
+        if s.request.is_read:
+            reads += rows_differ(s.results.tobytes(), read_rows(reference, s))
+            continue
         want = apply(reference, s)
         if len(s.results) != len(want):
             mismatches += max(len(want), len(s.results))
@@ -104,7 +157,7 @@ def replay(reference: StateMachineOracle, order: list) -> int:
                               len(want))
         mismatches += int(((want_ts != s.results["timestamp"])
                            | (want_st != s.results["status"])).sum())
-    return mismatches
+    return mismatches, reads
 
 
 def rows_differ(got: bytes | None, want_rows: list[bytes]) -> int:
@@ -124,8 +177,10 @@ def judge(sent: list, readback: dict) -> dict:
     [(ids, reply)]})."""
     order, violations = ordered(sent)
     reference = StateMachineOracle()
+    result_mismatches, read_mismatches = replay_with_reads(reference, order)
     return {
-        "result_mismatches": replay(reference, order),
+        "result_mismatches": result_mismatches,
+        "read_mismatches": read_mismatches,
         "account_mismatches": sum(
             rows_differ(reply, [a.pack() for a in
                                 reference.lookup_accounts(ids)])

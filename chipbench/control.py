@@ -12,6 +12,10 @@ guarantee of the configuration file:
   lost_write   "an acknowledged write is read back": the control
                acknowledges each session's last window request and then
                forgets it; its lookups answer from the state without it.
+  stale_read   (traffic with reads) "strict serializability: a read
+               sees every write acknowledged before it was sent": the
+               control answers each read of the run from the state before
+               the last write ahead of it in the commit order.
   no_limits    (configurations with balance limits) "the event that
                would pass the limit answers exceeds_credits": the control
                judges every event with the limit flags ignored.
@@ -41,13 +45,22 @@ from chipbench.reference.ledger import StateMachineOracle  # noqa: E402
 from chipbench.reference.ledger_types import Account  # noqa: E402
 
 
+def _rows(ref, s) -> np.ndarray:
+    return np.frombuffer(b"".join(check.read_rows(ref, s)),
+                         dtype=s.request.result)
+
+
 def _control_answers(order: list, *, skip=()):
     """The broken reference's answers to the requests in commit order:
-    {id(request): RESULT records}, and its final state."""
+    {id(request): a write's RESULT records, a read's rows}, and its
+    final state."""
     ref = StateMachineOracle()
     answers = {}
     for s in order:
         if id(s) in skip:
+            continue
+        if s.request.is_read:
+            answers[id(s)] = _rows(ref, s)
             continue
         want = check.apply(ref, s)
         rec = np.zeros(len(want), dtype=wire.RESULT)
@@ -67,7 +80,8 @@ def _lookups(ref, readback: dict) -> dict:
 
 def lost_write(sent: list, readback: dict) -> None:
     order, _ = check.ordered(sent)
-    last = {s.session: s for s in order if s.phase == "window"}
+    last = {s.session: s for s in order
+            if s.phase == "window" and not s.request.is_read}
     _, ref = _control_answers(order, skip={id(s) for s in last.values()})
     readback.update(_lookups(ref, readback))
 
@@ -85,7 +99,22 @@ def no_limits(sent: list, readback: dict) -> None:
     readback.update(_lookups(ref, readback))
 
 
-CONTROLS = {"lost_write": lost_write, "no_limits": no_limits}
+def stale_read(sent: list, readback: dict) -> None:
+    order, _ = check.ordered(sent)
+    ref = StateMachineOracle()
+    for i, s in enumerate(order):
+        if s.request.is_read:
+            continue
+        # the reads that follow this write see the state before it
+        for r in order[i + 1:]:
+            if not r.request.is_read:
+                break
+            r.results = _rows(ref, r)
+        check.apply(ref, s)
+
+
+CONTROLS = {"lost_write": lost_write, "no_limits": no_limits,
+            "stale_read": stale_read}
 
 
 def main(argv=None) -> int:
@@ -125,8 +154,10 @@ def main(argv=None) -> int:
         limited = any(
             np.frombuffer(s.request.payload, dtype=wire.ACCOUNT)["flags"].any()
             for s in kept["sent"] if s.request.operation == "create_accounts")
+        reads = any(s.request.is_read for s in kept["sent"])
         for name, control in CONTROLS.items():
-            if name == "no_limits" and not limited:
+            if (name == "no_limits" and not limited) or \
+                    (name == "stale_read" and not reads):
                 continue
             sent, readback = copy.deepcopy((kept["sent"], kept["readback"]))
             control(sent, readback)

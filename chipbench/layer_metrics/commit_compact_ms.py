@@ -1,5 +1,7 @@
 """Milliseconds of the program's `commit_compact` span inside the window:
-durable flush of the committed op + one compaction beat, mean per prepare."""
+durable flush of the committed op + one compaction beat, mean per prepare
+that is no read (`trace_reduce.stage_spans`: a read has nothing to flush;
+the beat it owes all the same is in `compact_beat_ms`)."""
 
 from chipbench.trace_reduce import window_durations
 

@@ -7,8 +7,9 @@ Formats a data file, boots `start --engine=<the configuration's>`
 (production layout, one replica) as the only process on the chip, loads
 the configuration's
 accounts over TCP, sends a few un-timed requests of the cell's own
-traffic, measures a closed-loop window of about `--seconds` (a fixed
-number of requests: `--seconds` times the mix's stated rate), reads the
+traffic (and one un-timed lookup where the mix states reads), measures
+a closed-loop window of about `--seconds` (a fixed number of requests:
+`--seconds` times the mix's stated rate), reads the
 state back, stops the server in order, replays every answer through the
 plain reference, and prints the contract's one JSON line last on
 stdout. This process never starts a JAX backend.
@@ -22,10 +23,17 @@ entries, editing none. A configuration states the sizes its server is
 formatted and started with (`server.format_args`, `server.start_args`:
 appended to the two command lines as they stand) and the state that
 exists before the window (`transfers.preloaded_count`, sent through
-the served path in set-up). What a file asks for and the harness cannot
-serve (an open loop, three replicas, a guarantee no comparison holds
-the program to, an argument the harness itself puts on the command
-line) fails the run; no key is read by nothing.
+the served path in set-up). A traffic mix may state reads (`reads`:
+the last of every `every` requests of a session is a `lookup_accounts`
+as wide as the wire admits, its ids drawn by the configuration's key
+skew); each is held against the plain reference at its place in the
+commit order (`read_mismatches`). The request percentiles read every
+answered request of the window, of any operation; the lookups' own
+median stands beside them (`lookup_p50_ms`).
+What a file asks for and the harness cannot serve (an open loop, three
+replicas, a guarantee no comparison holds the program to, an argument
+the harness itself puts on the command line, a read the comparison
+cannot hold or cannot place) fails the run; no key is read by nothing.
 """
 
 from __future__ import annotations
@@ -35,6 +43,7 @@ import time
 T_PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -131,6 +140,28 @@ def servable(config: dict, mix: dict) -> None:
         raise BenchFailure(f"traffic {mix['name']!r} asks for a "
                            f"{mix['loop']!r} loop; the harness drives closed "
                            "loops only")
+    reads = mix.get("reads")
+    if reads is not None:
+        if set(reads) != {"every"} or not isinstance(reads["every"], int) \
+                or reads["every"] < 2:
+            raise BenchFailure(
+                f"traffic {mix['name']!r}: `reads` is {reads!r}; it states "
+                "`every` alone, n >= 2: the last of every n requests is a "
+                "`lookup_accounts` as wide as the wire admits, ids by the "
+                "configuration's key skew (what the generator makes and "
+                "the comparison holds)")
+        if mix["sessions"] != 1:
+            raise BenchFailure(
+                f"traffic {mix['name']!r} asks for reads from "
+                f"{mix['sessions']} sessions; a read's reply names no "
+                "prepare, so its place among another session's concurrent "
+                "writes cannot be known: reads need one session")
+        if config["transfers"].get("two_phase"):
+            raise BenchFailure(
+                f"traffic {mix['name']!r} asks for reads on the two-phase "
+                f"configuration {config['name']!r}, whose request k + 1 "
+                "resolves request k: a read in a write's place would leave "
+                "pendings unresolved")
     server = config["server"]
     if server["replica_count"] != 1:
         raise BenchFailure(f"configuration {config['name']!r} asks for "
@@ -230,7 +261,7 @@ def read_back(client, Operation, dep: Deployment, sent: list,
         parts.append(pool[rng.choice(len(pool), size=min(take, len(pool)),
                                      replace=False)])
         chosen = np.unique(np.concatenate(parts), axis=0)
-        tids = [(int(h) << 64) | int(l) for l, h in chosen][:n_lookup]
+        tids = check.int_ids(chosen)[:n_lookup]
         out["transfers"].append(
             (tids, lookup(client, Operation.lookup_transfers, tids)))
     return out
@@ -262,6 +293,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     n_lookup = events_max(Operation.lookup_accounts, body_max)
     n_req = mix["events_per_request"]
     n_req = n_max if n_req == "wire_max" else min(int(n_req), n_max)
+    reads = mix.get("reads")
     quota = max(1, round(seconds * mix["requests_per_second_per_session"]))
     capacity = (REHEARSAL["transfers"] if rehearse
                 else config["transfers"]["transfer_count"])
@@ -278,7 +310,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     preload = sum(preload_widths)
     say(f"cell {workload}: config {config['name']}, traffic {mix['name']} "
         f"({mix['sessions']} sessions x {quota} requests x {n_req} events, "
-        f"closed loop), "
+        f"closed loop"
+        + (f", the last of every {reads['every']} a lookup_accounts of "
+           f"{n_lookup} ids" if reads else "") + "), "
         f"seed {seed}, {seconds}s, trace {int(trace)}; messages of "
         f"{layout.message_size_max} B; format_args {format_args}, "
         f"start_args {start_args}; {preload} transfers preloaded in "
@@ -370,6 +404,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         # window uses is compiled (or loaded) before it.
         for k in range(mix["warm_requests"]):
             setup(dep.transfer_request(STREAM_WARM, k, n_req))
+        if reads:  # so that the first timed read is not the path's first use
+            setup(dep.lookup_request(STREAM_WARM, mix["warm_requests"],
+                                     n_lookup))
         say(f"set-up: {dep.n} accounts, {len(sent)} requests, "
             f"{budget.created} transfers created")
 
@@ -385,8 +422,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         wall_t0 = time.time()
         window, t0, t1, cut = run_window(
             clients, Operation,
-            lambda s, k: dep.transfer_request(s, k, n_req), quota, seconds,
-            budget)
+            lambda s, k: dep.session_request(mix, s, k, n_req, n_lookup),
+            quota, seconds, budget)
         wall_t1 = wall_t0 + (t1 - t0)
         open(os.path.join(workdir, "mark.window_end"), "w").close()
         if trace:
@@ -438,7 +475,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     answered = [s for s in window if s.error is None]
     if not answered:
         raise BenchFailure("no request of the window was answered")
+    # The request percentiles read every answered request, of any
+    # operation; the lookups' seconds stand beside them as well.
     secs = [s.seconds for s in answered]
+    lookup_secs = [s.seconds for s in answered if s.request.is_read]
+    by_operation = dict(collections.Counter(
+        s.request.operation for s in answered))
     window_s = t1 - t0
     created = sum(s.created for s in answered)
     marks = device_record.get("marks", {})
@@ -454,7 +496,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             for n in range(mix["sessions"])}))
     say(f"latency sample: {len(secs)} requests (p95 has "
         f"{int(len(secs) * 0.05)} beyond it, p98 {int(len(secs) * 0.02)}; "
-        f"the longest {round(max(secs), 4)}s)")
+        f"the longest {round(max(secs), 4)}s)"
+        + (f"; {len(lookup_secs)} of them lookups, the longest "
+           f"{round(max(lookup_secs), 4)}s" if lookup_secs else ""))
     say(f"compiles inside the window: {compiles_in_window} "
         f"(after listening, whole run: {shutdown['compiles_after_listening']})")
     say(f"server shutdown record: {json.dumps(shutdown, sort_keys=True)}")
@@ -470,11 +514,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "compiles_in_window": compiles_in_window, "setup_s": setup_s,
         "window": {"wall_t0": wall_t0, "wall_t1": wall_t1,
                    "seconds": window_s, "requests": len(answered),
-                   "request_seconds": secs, "created": created,
+                   "request_seconds": secs, "lookup_seconds": lookup_secs,
+                   "created": created,
                    "events_per_request": n_req,
                    "create_requests_answered":
                        sum(1 for s in sent if s.error is None and
                            s.request.operation.startswith("create_"))},
+        "read_operations": {int(o) for o in Operation
+                            if o.name.startswith("lookup_")},
         "device_kind": device["kind"],
         "memory_peak_bytes": device_record["memory_peak_bytes"],
         "spans": None, "device": None, "profile": None,
@@ -499,6 +546,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             + json.dumps(metrics))
     result["window"] = {
         "seconds": window_s, "requests_per_session": quota,
+        "answered_by_operation": by_operation,
         "cut_at_hard_stop": cut,
         "ended_early_at_store_capacity": budget.exhausted,
         "transfers_created_whole_run": budget.created,
@@ -530,7 +578,7 @@ def read_traces(workdir: str, span_path: str, context: dict,
     if xplane is None:
         raise BenchFailure("the traced run left no .xplane.pb")
     xp = trace_reduce.reduce_xplane(xplane)
-    spans = trace_reduce.load_spans(span_path)
+    spans = trace_reduce.load_spans(span_path, context["read_operations"])
     context["spans"] = spans
     if not xp["devices"] and not need_device:
         say("rehearsal: the profiler's trace has no TPU device plane; "

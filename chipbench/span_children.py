@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from chipbench.trace_reduce import stage_spans
+
 
 def _sound(context: dict, *names: str):
     spans = context["spans"]
@@ -27,16 +29,20 @@ def _sound(context: dict, *names: str):
     return by_name
 
 
-def children_in_window(context: dict, child: str, parent: str):
+def children_in_window(context: dict, child: str, parent: str,
+                       ops: str = "writes"):
     """Durations (s) of the `child` spans that start inside a `parent`
     that starts inside the measured window, and the number of those
     parents; None where there is nothing sound to read or no such
-    child."""
+    child. Of a parent that runs once per committed op, `ops` says
+    which ops' (`trace_reduce.stage_spans`): the writes' for what a
+    write alone does (a read executes and flushes nothing of theirs),
+    "all" for what every op owes, as its compaction beat."""
     by_name = _sound(context, child, parent)
     if by_name is None:
         return None
     w = context["window"]
-    p_start, p_dur = by_name[parent]
+    p_start, p_dur = stage_spans(context["spans"], parent, ops)
     keep = (p_start >= w["wall_t0"]) & (p_start < w["wall_t1"])
     if not keep.any():
         return None
@@ -51,12 +57,14 @@ def children_in_window(context: dict, child: str, parent: str):
     return c_dur[inside], len(p_lo)
 
 
-def child_ms_per_parent(context: dict, child: str, parent: str):
+def child_ms_per_parent(context: dict, child: str, parent: str,
+                        ops: str = "writes"):
     """Milliseconds of `child` spans per occurrence of `parent`: the
     summed duration of the children that start inside a parent that
     starts inside the measured window, over the number of those parents
-    (a parent with no such child counts as zero)."""
-    found = children_in_window(context, child, parent)
+    (a parent with no such child counts as zero); `ops` as in
+    `children_in_window`."""
+    found = children_in_window(context, child, parent, ops)
     if found is None:
         return None
     return 1e3 * float(found[0].sum()) / found[1]

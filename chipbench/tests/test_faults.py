@@ -3,7 +3,9 @@ server on the CPU) and sees `correct` come out false: once for the
 control of each guarantee, once for each fault planted under the timed
 path; each in a cell of BENCHMARK.json and in the fixture cell that
 states `start_args` and a preload (the rehearsal leaves the arguments
-out and sends eight preload requests). Run by hand (each case boots a
+out and sends eight preload requests); and in the cell whose mix states
+reads, where the `stale_read` control and one row altered where a lookup
+is answered come out not correct too. Run by hand (each case boots a
 server, about 35 s):
 
     JAX_PLATFORMS=cpu python3 -m pytest chipbench/tests/test_faults.py -q -p no:cacheprovider
@@ -94,6 +96,54 @@ def test_fault_under_the_timed_path_is_not_correct(fault, number, cell,
                       launcher=FAULTY, cells=CELLS)
     assert not result["correct"]
     assert result["compared"][number]["value"] > 0
+
+
+READ_CELL = "default.read_mix_1s"
+
+
+def test_sound_read_mix_is_correct_and_a_stale_read_is_not():
+    kept = {}
+
+    def keep(sent, readback):
+        kept["sent"], kept["readback"] = sent, readback
+
+    result = run_cell(READ_CELL, 2026100401, 5.0, False, rehearse=True,
+                      tamper=keep)
+    assert result["correct"], result["compared"]
+    assert all(v["value"] == 0 for v in result["compared"].values())
+    assert result["window"]["answered_by_operation"] == {
+        "create_transfers": 4, "lookup_accounts": 4}
+    assert set(result["metrics"]) == {
+        "accepted_tps", "request_p50_ms", "request_p98_ms", "lookup_p50_ms",
+        "setup_s"}
+    reads = [s for s in kept["sent"] if s.request.is_read]
+    assert [s.phase for s in reads] == ["setup"] + ["window"] * 4
+    # rows came back, the unknown ids' left out, and balances had moved
+    assert all(0 < len(s.results) <= s.request.n_events for s in reads)
+    assert any(len(s.results) < s.request.n_events for s in reads)
+    assert all(s.results["debits_posted_lo"].any() for s in reads)
+    sent, readback = copy.deepcopy((kept["sent"], kept["readback"]))
+    control.stale_read(sent, readback)
+    numbers = check.judge(sent, readback)
+    assert not check.verdict(numbers)
+    assert numbers["read_mismatches"] > 0
+    assert all(v == 0 for k, v in numbers.items() if k != "read_mismatches")
+    sent, readback = copy.deepcopy((kept["sent"], kept["readback"]))
+    control.lost_write(sent, readback)
+    numbers = check.judge(sent, readback)
+    assert numbers["account_mismatches"] > 0
+    assert numbers["transfer_mismatches"] > 0
+    assert numbers["read_mismatches"] == 0
+
+
+def test_a_row_altered_where_a_lookup_is_answered_is_not_correct(monkeypatch):
+    monkeypatch.setenv("CHIPBENCH_FAULT", "row_altered")
+    result = run_cell(READ_CELL, 2026100402, 5.0, False, rehearse=True,
+                      launcher=FAULTY)
+    assert not result["correct"]
+    assert result["compared"]["read_mismatches"]["value"] == 1
+    assert all(v["value"] == 0 for k, v in result["compared"].items()
+               if k != "read_mismatches")
 
 
 def test_an_argument_the_program_refuses_fails_in_its_own_words(tmp_path):

@@ -5,6 +5,7 @@ in tier 1):
 """
 
 import copy
+import functools
 import json
 import os
 import sys
@@ -302,6 +303,198 @@ def test_every_metric_of_the_benchmark_has_its_reader():
         1e3 * (6.0 + 0.06 * 2.0))
     assert "request_p95_ms" in run.read_metrics(
         "e2e_metrics", bench["end_to_end"], "default.b1024_4s", context)
+    # the read mix's cell: the request percentiles read every request's
+    # seconds as in any cell, the lookups' own median stands beside them
+    context["window"]["lookup_seconds"] = [0.05, 0.07, 0.04, 0.30]
+    got = run.read_metrics("e2e_metrics", bench["end_to_end"],
+                           "default.read_mix_1s", context)
+    assert set(got) == {"accepted_tps", "request_p50_ms", "request_p98_ms",
+                        "lookup_p50_ms", "setup_s"}
+    assert got["lookup_p50_ms"]["value"] == pytest.approx(60.0)
+    assert got["request_p50_ms"]["value"] == pytest.approx(200.0)
+    context["window"]["lookup_seconds"] = []
+    assert "lookup_p50_ms" not in run.read_metrics(
+        "e2e_metrics", bench["end_to_end"], "default.read_mix_1s", context)
+
+
+# ------------------------------------------------- a mix that states reads
+
+def mix(name):
+    with open(os.path.join(ROOT, "chipbench", "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_read_mix_same_seed_same_bytes_and_its_writes_are_cell_1s():
+    """Request k of a session under read_mix_1s is a lookup where
+    k % 2 == 1 and full_batch_1s's own write at the same k otherwise;
+    the same seed gives the same bytes, another seed others."""
+    cfg, reads, plain = (config("tb_bench_default_1r"), mix("read_mix_1s"),
+                         mix("full_batch_1s"))
+    assert {k: v for k, v in reads.items()
+            if k not in ("name", "why", "reads", "requests_per_second_why")} \
+        == {k: v for k, v in plain.items()
+            if k not in ("name", "why", "requests_per_second_why")}
+    a, b, c = (Deployment(cfg, 4000000123), Deployment(cfg, 4000000123),
+               Deployment(cfg, 4000000124))
+    kinds = []
+    for k in range(8):
+        ra = a.session_request(reads, 0, k, 8189, 7278)
+        assert ra.payload == b.session_request(reads, 0, k, 8189, 7278).payload
+        assert ra.payload != c.session_request(reads, 0, k, 8189, 7278).payload
+        kinds.append(ra.operation)
+        if ra.is_read:
+            assert (ra.event_size, ra.result.itemsize) == (16, 128)
+            assert len(ra.payload) == 7278 * 16 and ra.n_events == 7278
+            assert ra.payload == wire.ids_payload(check.int_ids(ra.ids))
+        else:
+            assert (ra.event_size, ra.result.itemsize) == (128, 16)
+            assert ra.payload == a.session_request(
+                plain, 0, k, 8189, 0).payload
+            assert ra.payload == a.transfer_request(0, k, 8189).payload
+    assert reads["reads"] == {"every": 2}  # YCSB A's share, by requests
+    assert kinds == ["create_transfers", "lookup_accounts"] * 4
+    every4 = dict(reads, reads={"every": 4})
+    assert [a.session_request(every4, 0, k, 8189, 7278).is_read
+            for k in range(8)] == [False, False, False, True] * 2
+    # the ids are the deployment's accounts by its key skew, repeats
+    # kept, its unknown_account share of them ids that no account has
+    r = a.lookup_request(0, 3, 7278)
+    known = set(a.account_ids())
+    asked = check.int_ids(r.ids)
+    unknown = sum(1 for i in asked if i not in known)
+    assert 5 <= unknown <= 45  # 0.3% of 7278 is 22
+    assert len(set(asked)) < len(asked)
+    assert asked.count(a.account_ids()[0]) > 400  # the hot account
+
+
+def sent_of(request, t_send, t_reply, results):
+    return Sent("window", 0, request, t_send, t_reply, results=results)
+
+
+def test_a_read_is_held_at_its_place_in_the_commit_order():
+    """Write, read, write from one caller: the read has to see the
+    first write and not the second. One answered from any other place
+    is counted, under `read_mismatches` and no other number."""
+    from chipbench.reference.ledger import StateMachineOracle
+
+    dep = Deployment(config("tb_bench_default_1r"), 17, accounts_cut=500)
+    ref, sent, seen = StateMachineOracle(), [], []
+    read = dep.lookup_request(0, 3, 300)
+
+    def rows_now():
+        return control._rows(ref, sent_of(read, 0, 0, None))
+
+    for i, request in enumerate(dep.account_requests(8189)
+                                + [dep.transfer_request(0, 0, 400), read,
+                                   dep.transfer_request(0, 1, 400)]):
+        seen.append(rows_now())  # what the read would see before this one
+        s = sent_of(request, float(i), i + 0.5, seen[-1])
+        if not request.is_read:
+            s.ts = 10 ** 18 + 1000 * (i + 1)
+            want = check.apply(ref, s)
+            s.results = np.zeros(len(want), dtype=wire.RESULT)
+            s.results["timestamp"] = [w.timestamp for w in want]
+            s.results["status"] = [int(w.status) for w in want]
+        sent.append(s)
+    before_first, before_read, before_second = seen[-3:]
+    after_second = rows_now()
+    asked = {"accounts": [(dep.account_ids(), None)], "transfers": []}
+    readback = control._lookups(ref, asked)
+    order, _ = check.ordered(sent)
+    assert [s.request.operation for s in order[-3:]] == [
+        "create_transfers", "lookup_accounts", "create_transfers"]
+    numbers = check.judge(sent, readback)
+    assert check.verdict(numbers), numbers
+    assert before_read.tobytes() == before_second.tobytes()
+    for stale in (before_first, after_second):
+        assert stale.tobytes() != before_read.tobytes()
+        sent[-2].results = stale
+        numbers = check.judge(sent, readback)
+        assert numbers["read_mismatches"] > 0
+        assert not check.verdict(numbers)
+        assert all(v == 0 for k, v in numbers.items()
+                   if k != "read_mismatches")
+    # a row missing from the reply counts, and so does a surplus one
+    sent[-2].results = before_read[:-1]
+    assert check.judge(sent, readback)["read_mismatches"] == 1
+    # the stale_read control answers from before the first write
+    sent[-2].results = before_read
+    control.stale_read(sent, readback)
+    assert sent[-2].results.tobytes() == before_first.tobytes()
+    assert check.judge(sent, readback)["read_mismatches"] > 0
+    # a read sent while a write was still unanswered takes its place
+    # after the last write answered before it
+    sent[-2].results = before_first
+    sent[-2].t_send, sent[-2].t_reply = sent[-3].t_send + 0.1, sent[-3].t_reply + 0.1
+    order, _ = check.ordered(sent)
+    assert [s.request.operation for s in order[-3:]] == [
+        "lookup_accounts", "create_transfers", "create_transfers"]
+    assert check.judge(sent, readback)["read_mismatches"] == 0
+
+
+@pytest.mark.parametrize("change,words", [
+    (lambda c, m: m.update(sessions=2), "reads need one session"),
+    (lambda c, m: m["reads"].update(operation="lookup_transfers"),
+     "`every` alone"),
+    (lambda c, m: m["reads"].update(every=1), "n >= 2"),
+    (lambda c, m: c["transfers"].update(
+        two_phase={"post_share": 1.0, "void_share": 0.0}),
+     "would leave pendings unresolved"),
+])
+def test_reads_the_comparison_cannot_hold_or_place_are_refused(change, words):
+    cfg, m = config("tb_bench_default_1r"), mix("read_mix_1s")
+    run.servable(cfg, m)
+    change(cfg, m)
+    with pytest.raises(BenchFailure, match=words):
+        run.servable(cfg, m)
+
+
+def test_per_op_means_read_the_writes_and_a_reads_execute_stands_apart(
+        tmp_path):
+    """Three ops in the window, the second a lookup (its `commit_execute`
+    names operation 140): the per-op means read ops 7 and 9, a child span
+    under the lookup belongs to no kept parent, `lookup_execute_ms` reads
+    op 8, and what every op owes (its compaction beat, the protocol's
+    work) is read over all three."""
+    def x(name, ts, dur, **args):
+        return {"name": name, "ph": "X", "ts": ts * 1e6, "dur": dur * 1e6,
+                "args": args}
+
+    events = [x("loop_busy", 9.9, 0.9), x("loop_busy", 11.0, 1.0)]
+    for op, t, operation, execute in [(6, 5.0, 147, 0.5), (7, 10.0, 147, 0.02),
+                                      (8, 10.3, 140, 0.04),
+                                      (9, 11.0, 147, 0.03)]:
+        events += [x("journal_write", t - 0.01, 0.001 * op, op=op),
+                   x("commit_execute", t, execute, op=op, operation=operation),
+                   x("execute_encode", t + 0.001, 0.004, op=op),
+                   # the bar's long beat falls to the lookup's op
+                   x("commit_compact", t + 0.1, 0.05 if operation == 147
+                     else 0.12, op=op),
+                   x("compact_beat", t + 0.101, 0.002 if operation == 147
+                     else 0.1, op=op)]
+    events.append(x("commit_checkpoint", 10.35, 0.2, op=8))
+    path = tmp_path / "spans.json"
+    path.write_text(json.dumps({"traceEvents": events,
+                                "metadata": {"dropped_events": 0}}))
+    context = {"window": {"wall_t0": 9.5, "wall_t1": 30.0}}
+    reader = functools.partial(run.load_reader, "layer_metrics")
+    for read_operations, want in [
+            ({140}, {"commit_execute_ms": 25.0, "lookup_execute_ms": 40.0,
+                     "journal_write_ms": 8.0, "commit_compact_ms": 50.0,
+                     "execute_encode_ms": 4.0, "checkpoint_ms": 200.0,
+                     "compact_beat_ms": 104 / 3, "compact_beat_max_ms": 100.0}),
+            # with no operation named a read, every op is a write's
+            ((), {"commit_execute_ms": 30.0, "lookup_execute_ms": None,
+                  "journal_write_ms": 8.0, "commit_compact_ms": 220 / 3,
+                  "execute_encode_ms": 4.0, "checkpoint_ms": 200.0,
+                  "compact_beat_ms": 104 / 3, "compact_beat_max_ms": 100.0})]:
+        context["spans"] = trace_reduce.load_spans(str(path), read_operations)
+        for name, value in want.items():
+            got = reader(name)(context)
+            assert got == (pytest.approx(value) if value else None), name
+        # 1.9 s busy, less 0.09 + 0.22 + 0.2 staged, over three ops
+        assert reader("replica_protocol_ms")(context) == pytest.approx(
+            1e3 * (1.9 - 0.51) / 3)
 
 
 def test_least_bytes_hand_worked():

@@ -36,6 +36,9 @@ KERNEL_MODULES = ("jit_create_transfers",)
 SHORT_GAP_NS = 100_000.0
 BETWEEN_OPS = "between ops of a dispatch"
 # Host spans a device gap is attributed to, most specific first.
+# The program's spans that occur once per committed op, each tagged
+# with its `op`: a mean over them is a mean per op.
+PER_OP_STAGES = ("journal_write", "commit_execute", "commit_compact")
 GAP_SPANS = ("commit_checkpoint", "commit_compact", "journal_write",
              "commit_execute", "commit_prefetch", "bus_recv", "bus_send")
 
@@ -161,33 +164,61 @@ def device_summary(xp: dict, module_patterns: tuple) -> dict:
             "op_seconds": {k: v / n for k, v in op_totals.items()}}
 
 
-def load_spans(chrome_path: str) -> dict:
+def load_spans(chrome_path: str, read_operations=()) -> dict:
     """The program's completed spans: name -> (start_s, dur_s) arrays on
-    the wall clock, and the trace's own count of dropped events."""
+    the wall clock, and the trace's own count of dropped events. Beside
+    them, for the stages that run once per committed op, each span's
+    `op` tag, and the ops that are reads: those whose `commit_execute`
+    names one of `read_operations` (the program's operation numbers)."""
     with open(chrome_path) as f:
         doc = json.load(f)
     by_name: dict[str, list] = {}
+    read_ops = set()
     for e in doc["traceEvents"]:
         if e.get("ph") == "X":
+            args = e.get("args", {})
             by_name.setdefault(e["name"], []).append(
-                (e["ts"] / 1e6, e["dur"] / 1e6))
-    spans = {k: (np.array([s for s, _ in v]), np.array([d for _, d in v]))
+                (e["ts"] / 1e6, e["dur"] / 1e6, args.get("op", -1)))
+            if e["name"] == "commit_execute" and \
+                    args.get("operation") in read_operations:
+                read_ops.add(args["op"])
+    spans = {k: (np.array([s for s, _, _ in v]), np.array([d for _, d, _ in v]))
              for k, v in by_name.items()}
     return {"spans": spans,
+            "op": {k: np.array([op for _, _, op in by_name[k]])
+                   for k in PER_OP_STAGES if k in by_name},
+            "read_ops": np.array(sorted(read_ops), dtype=np.int64),
             "dropped_events": doc["metadata"]["dropped_events"]}
 
 
-def window_durations(context: dict, name: str):
+def stage_spans(spans: dict, name: str, ops: str = "writes"):
+    """(start_s, dur_s) of the spans of `name`. Of a stage that runs
+    once per committed op (PER_OP_STAGES), `ops` says which ops' spans:
+    "writes", every op that is no read, so that a mean per op means in
+    a cell whose mix states reads what it means in one that does not;
+    "reads"; or "all"."""
+    start, dur = spans["spans"][name]
+    if ops == "all" or name not in PER_OP_STAGES:
+        return start, dur
+    # a context made by hand carries no tags: no op of it is a read
+    is_read = (np.isin(spans["op"][name], spans["read_ops"])
+               if "read_ops" in spans else np.zeros(len(start), bool))
+    keep = is_read if ops == "reads" else ~is_read
+    return start[keep], dur[keep]
+
+
+def window_durations(context: dict, name: str, ops: str = "writes"):
     """Durations (s) of the program's spans of `name` that start inside
-    the measured window, or None where there is nothing sound to read:
-    no span trace, no such span, or a ring that dropped events."""
+    the measured window (`ops` as in `stage_spans`), or None where there
+    is nothing sound to read: no span trace, no such span, or a ring
+    that dropped events."""
     spans = context["spans"]
     if spans is None or spans["dropped_events"] != 0:
         return None
     if name not in spans["spans"]:
         return None
     w = context["window"]
-    start, dur = spans["spans"][name]
+    start, dur = stage_spans(spans, name, ops)
     inside = dur[(start >= w["wall_t0"]) & (start < w["wall_t1"])]
     return inside if len(inside) else None
 

@@ -3,10 +3,12 @@
 A deployment (chipbench/configs/<name>.json) says what data exists and
 what one transfer event looks like; a traffic mix
 (chipbench/traffic/<name>.json) says how many sessions send how wide a
-request. Everything is a pure function of (seed, stream, request
-index): a session's k-th request has the same bytes in every run of
-one seed, however fast the server answers, and every seed sends the
-same sizes. Bodies are built as numpy records (wire.py), never through
+request, and which requests of a session are reads (its `reads` block:
+every n-th request a `lookup_accounts` as wide as the wire admits, its
+ids drawn by the configuration's own key skew). Everything is a pure function of (seed,
+stream, request index): a session's k-th request has the same bytes in
+every run of one seed, however fast the server answers, and every seed
+sends the same sizes. Bodies are built as numpy records (wire.py), never through
 the program's packers.
 """
 
@@ -33,9 +35,16 @@ SEED_MASK = (1 << 63) - 1
 @dataclasses.dataclass
 class Request:
     operation: str      # the program's Operation member name
-    payload: bytes      # n events of 128 bytes, no trailer
+    payload: bytes      # n events of `event_size` bytes, no trailer
     n_events: int
     ids: np.ndarray     # (n, 2) u64: id_lo, id_hi of each event
+    event_size: int = wire.TRANSFER.itemsize
+    result: np.dtype = wire.RESULT  # one record of the reply; its itemsize
+    #                                 is the result size on the wire
+
+    @property
+    def is_read(self) -> bool:
+        return self.operation.startswith("lookup_")
 
 
 def _rng(seed: int, *path: int) -> np.random.Generator:
@@ -69,6 +78,7 @@ class Deployment:
         self.fail_edges = np.cumsum([fs["same_account"],
                                      fs["unknown_account"],
                                      fs["wrong_ledger"]])
+        self.unknown_share = fs["unknown_account"]
         self.two_phase = tr.get("two_phase")
         self.id_tag = int(rng.integers(1, 1 << 31)) << 16
 
@@ -160,6 +170,31 @@ class Deployment:
         rec["debit_lo"][unknown] = rng.integers(1, 1 << 40, int(unknown.sum()))
         rec["ledger"][ledger] = self.ledger + 1
         return self._request("create_transfers", rec)
+
+    def lookup_request(self, stream: int, k: int, n: int) -> Request:
+        """Request k of a stream as a read: `lookup_accounts` of n ids
+        drawn by the deployment's key skew over the account index,
+        repeats kept (a row is answered per id found, in request order),
+        the deployment's `unknown_account` share of them ids that no
+        account has."""
+        rng = _rng(self.seed, stream, k)
+        at = draw(self.cdf, rng, n)
+        ids = np.stack([self.id_lo[at], self.id_hi[at]], axis=1)
+        unknown = rng.random(n) < self.unknown_share
+        ids[unknown, 1] = 9
+        ids[unknown, 0] = rng.integers(1, 1 << 40, int(unknown.sum()))
+        return Request("lookup_accounts", ids.astype("<u8").tobytes(), n, ids,
+                       event_size=wire.ID_SIZE, result=wire.ACCOUNT)
+
+    def session_request(self, mix: dict, stream: int, k: int, n_events: int,
+                        n_ids: int) -> Request:
+        """Request k of a session under a traffic mix: the read where
+        the mix's `reads` block puts one (the last of every `every`
+        requests), the write it would have been otherwise."""
+        reads = mix.get("reads")
+        if reads and k % reads["every"] == reads["every"] - 1:
+            return self.lookup_request(stream, k, n_ids)
+        return self.transfer_request(stream, k, n_events)
 
     def _resolve(self, stream: int, k: int, n: int) -> Request:
         prev = np.frombuffer(

@@ -37,7 +37,8 @@ class Sent:
     t_send: float               # time.monotonic()
     t_reply: float | None = None
     wall_send: float = 0.0      # time.time()
-    results: np.ndarray | None = None   # wire.RESULT records
+    results: np.ndarray | None = None   # records of `request.result`: a
+    #                           write's wire.RESULTs, a read's rows
     error: str | None = None
 
     @property
@@ -46,6 +47,8 @@ class Sent:
 
     @property
     def created(self) -> int:
+        if self.request.is_read:
+            return 0
         return int((self.results["status"] == wire.CREATED).sum())
 
 
@@ -84,13 +87,15 @@ def percentile_ms(seconds: list[float], q: float) -> float:
 def send(client, operation, sent: Sent,
          timeout_s: float = REPLY_TIMEOUT_S) -> Sent:
     """One request through the program's client; fills in the reply."""
-    body = wire.encode_one(sent.request.payload, 128)
+    request = sent.request
+    body = wire.encode_one(request.payload, request.event_size)
     sent.wall_send = time.time()
     sent.t_send = time.monotonic()
     try:
         reply = client.request(operation, body, timeout_s=timeout_s)
-        sent.results = np.frombuffer(wire.decode_one(reply, 16),
-                                     dtype=wire.RESULT)
+        sent.results = np.frombuffer(
+            wire.decode_one(reply, request.result.itemsize),
+            dtype=request.result)
     except (TimeoutError, ValueError, OSError) as e:
         sent.error = f"{type(e).__name__}: {e}"
     sent.t_reply = time.monotonic()
