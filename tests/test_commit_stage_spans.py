@@ -88,6 +88,17 @@ def traced_run():
 
     tree.put, tree.put_run = counting_put, counting_put_run
     created = _drive_past_checkpoint(cluster)
+    # One served lookup, an id among them that no account has: the four
+    # spans under a read's commit_execute (the cache holds none of the
+    # accounts yet, so the tree's part opens).
+    client = cluster.client(10)
+    client.request(Operation.lookup_accounts, multi_batch.encode(
+        [b"".join(i.to_bytes(16, "little")
+                  for i in (*range(1, ACCOUNTS + 1), 999))], 16))
+    assert cluster.run(4000, until=lambda: client.idle), \
+        cluster.debug_status()
+    assert len(multi_batch.decode(client.replies[-1].body, 128)[0]) \
+        == 128 * ACCOUNTS
     events = tracers[0].chrome_dict()["traceEvents"]
     return {"replica": replica, "tracer": tracers[0], "events": events,
             "created": created, "transfer_puts": puts[0]}

@@ -261,6 +261,12 @@ class Tree:
         # Forest.depth_stats sums the trees'. Not persisted: a restart
         # counts from zero.
         self.compaction = dict.fromkeys(COMPACTION_COUNTERS, 0)
+        # Point reads (get / get_many) that no memtable answered, and
+        # the tables probed for them: an add a call, nothing a row. Not
+        # persisted either; a caller reads the difference around its own
+        # reads (DurableState.account_reads).
+        self.keys_from_tables = 0
+        self.table_probes = 0
 
     # ------------------------------------------------------------- updates
 
@@ -315,13 +321,17 @@ class Tree:
             # L0 tables may overlap: newest-first probe; deeper levels
             # yield at most one candidate per snapshot (binary-searched on
             # the live set for the latest snapshot).
+            probes = 0
             for level in self.levels:
                 for table in level.lookup(key, snapshot):
+                    probes += 1
                     value = table.get(key)
                     if value is not None:
                         break
                 if value is not None:
                     break
+            self.keys_from_tables += 1
+            self.table_probes += probes
         if value is None or value == TOMBSTONE * self.value_size:
             return None
         return value
@@ -344,6 +354,7 @@ class Tree:
                 found[key] = value
             else:
                 remaining.append(key)
+        self.keys_from_tables += len(remaining)
         plans: dict = {}  # key -> [(table, blk)] planned by the lookahead
         for li, level in enumerate(self.levels):
             if not remaining:
@@ -388,6 +399,7 @@ class Tree:
                     slots.append((key, table, cand))
                 if not reqs:
                     break
+                self.table_probes += len(reqs)
                 for (key, table, cand), raw in zip(
                         slots, self.grid.read_blocks(reqs)):
                     value = table.get_in_block(key, raw)

@@ -353,6 +353,12 @@ def cmd_start(args) -> int:
             # flush counts them where it loops over them anyway): what
             # share of the traffic was two-phase.
             "two_phase": dict(replica.durable.two_phase_rows),
+            # What account reads by key met: the served lookups' cache
+            # (the ObjectCache's own counters) and the column flush's
+            # previous-row reads of the accounts tree (keys asked, the
+            # ones no memtable answered, the tables probed for them).
+            "accounts": {**replica.state_machine.account_cache_stats(),
+                         **replica.durable.account_reads},
             # How full the deployment's sizes got, read here from state
             # that exists anyway: the stores' row counters, the free
             # set (and what each checkpoint counted of it), the

@@ -308,41 +308,47 @@ class StateMachine:
     # ------------------------------------------------------------- lookups
 
     def lookup_accounts(self, ids: list[int]) -> list[Account]:
-        if self._fq is not None:
-            return self._lookup_batched(
-                ids, self._acct_cache, "accounts", Account)
-        return [self.state.accounts[i] for i in ids if i in self.state.accounts]
+        found = self._lookup_found(ids, "accounts")
+        return [found[i] for i in ids if i in found]
 
     def lookup_transfers(self, ids: list[int]) -> list[Transfer]:
-        if self._fq is not None:
-            return self._lookup_batched(
-                ids, self._xfer_cache, "transfers", Transfer)
-        return [self.state.transfers[i] for i in ids if i in self.state.transfers]
+        found = self._lookup_found(ids, "transfers")
+        return [found[i] for i in ids if i in found]
 
-    def _lookup_batched(self, ids, cache, tree_name, cls) -> list:
-        """Cache hits first; ALL misses go to the object tree as one
-        batched fan-out (Tree.get_many), then refill the cache — a cold
-        batch costs one concurrent read round per LSM level, not one
-        synchronous read per id (VERDICT r2 weak #5; reference:
-        src/lsm/groove.zig:996,1339)."""
+    def _lookup_found(self, ids, tree_name: str):
+        """{id: object} holding every id of `ids` that exists. Served
+        from the forest: cache hits first; ALL misses go to the object
+        tree as one batched fan-out (Tree.get_many), then refill the
+        cache — a cold batch costs one concurrent read round per LSM
+        level, not one synchronous read per id (VERDICT r2 weak #5;
+        reference: src/lsm/groove.zig:996,1339). The loop over the cache
+        and the tree's part are the spans lookup_cache and lookup_tree
+        (the second only where an id missed)."""
+        if self._fq is None:
+            return getattr(self.state, tree_name)
+        cache, cls = ((self._acct_cache, Account) if tree_name == "accounts"
+                      else (self._xfer_cache, Transfer))
+        span, at = self._tracer.span, self.trace_op
         hit: dict = {}
         misses = []
-        for i in ids:
-            obj = cache.get(i)
-            if obj is not None:
-                hit[i] = obj
-            elif i not in hit:
-                misses.append(i)
-        if misses:
-            tree = self._fq.forest.trees[tree_name]
-            unique = list(dict.fromkeys(misses))
-            got = tree.get_many([i.to_bytes(16, "big") for i in unique])
-            for i in unique:
-                raw = got.get(i.to_bytes(16, "big"))
-                if raw is not None:
-                    obj = cls.unpack(raw)
-                    cache.put(i, obj)
+        with span(Event.lookup_cache, op=at):
+            for i in ids:
+                obj = cache.get(i)
+                if obj is not None:
                     hit[i] = obj
+                elif i not in hit:
+                    misses.append(i)
+        if misses:
+            with span(Event.lookup_tree, op=at):
+                tree = self._fq.forest.trees[tree_name]
+                unique = list(dict.fromkeys(misses))
+                got = tree.get_many([i.to_bytes(16, "big") for i in unique])
+                for i in unique:
+                    raw = got.get(i.to_bytes(16, "big"))
+                    if raw is not None:
+                        obj = cls.unpack(raw)
+                        cache.put(i, obj)
+                        hit[i] = obj
         from . import constants
 
         if constants.VERIFY and hit:
@@ -354,7 +360,15 @@ class StateMachine:
                 raw = tree.get(i.to_bytes(16, "big"))
                 assert raw is not None and cls.unpack(raw) == obj, \
                     f"verify: cache/tree divergence on {tree_name} {i}"
-        return [hit[i] for i in ids if i in hit]
+        return hit
+
+    def account_cache_stats(self) -> dict:
+        """The account cache's own counters (`start`'s shutdown record,
+        block `accounts`): ids a served lookup found in the cache, ids
+        it did not (each appearance of an id counts), and entries a
+        refill pushed out. Zeros where no forest is attached."""
+        return {"cache_" + k: getattr(self._acct_cache, k, 0)
+                for k in ("hits", "misses", "evictions")}
 
     # ------------------------------------------------------------- indexes
 
@@ -829,18 +843,28 @@ class StateMachine:
                     timestamp: int) -> bytes:
         O = Operation
         base = _base_operation(op)
+        span, at = self._tracer.span, self.trace_op
         if base == O.create_transfers and self.engine == "device":
             # Vectorized serving path: wire -> SoA -> kernel -> wire with
             # no per-event Python objects (reference: commit is the cheap
             # part, src/state_machine.zig:2564-2669).
             from .ops.batch import transfers_soa_from_bytes
 
-            span, at = self._tracer.span, self.trace_op
             with span(Event.execute_decode, op=at):
                 ev = transfers_soa_from_bytes(body)
             st, ts = self.led.create_transfers_soa(ev, timestamp)
             with span(Event.execute_encode, op=at):
                 return _encode_results_soa(st, ts, spec)
+        if base in (O.lookup_accounts, O.lookup_transfers):
+            # ids from bytes, the cache and the tree (_lookup_found),
+            # the rows packed: a span each.
+            with span(Event.lookup_ids, op=at):
+                ids = [int.from_bytes(body[i:i + spec.event_size], "little")
+                       for i in range(0, len(body), spec.event_size)]
+            found = self._lookup_found(
+                ids, "accounts" if base == O.lookup_accounts else "transfers")
+            with span(Event.lookup_pack, op=at):
+                return b"".join(found[i].pack() for i in ids if i in found)
         events = [body[i:i + spec.event_size]
                   for i in range(0, len(body), spec.event_size)]
         if base == O.create_accounts:
@@ -851,12 +875,6 @@ class StateMachine:
             transfers = [Transfer.unpack(e) for e in events]
             results = self.create_transfers(transfers, timestamp)
             return _encode_create_results(results, spec)
-        if base == O.lookup_accounts:
-            ids = [int.from_bytes(e, "little") for e in events]
-            return b"".join(a.pack() for a in self.lookup_accounts(ids))
-        if base == O.lookup_transfers:
-            ids = [int.from_bytes(e, "little") for e in events]
-            return b"".join(t.pack() for t in self.lookup_transfers(ids))
         if base == O.get_account_transfers:
             assert len(events) == 1
             return b"".join(t.pack() for t in

@@ -657,8 +657,11 @@ def _scenario_causal_trace(col: _Collector) -> None:
 def _scenario_commit_stage_children(col: _Collector) -> None:
     """One device-engine replica driven past a checkpoint: every child
     span of commit_execute / commit_compact / commit_checkpoint and the
-    durable row counter, then a pending and its post (the two spans a
-    two-phase row opens); then the serving loop over a bus that wakes it
+    durable row counter (flush_account_reads opens in every op whose
+    delta holds a transfer), then a pending and its post (the two spans
+    a two-phase row opens) and a served lookup whose ids the cache does
+    not hold (lookup_ids, lookup_cache, lookup_tree, lookup_pack); then
+    the serving loop over a bus that wakes it
     for one long turn (loop_busy), under the collector hook (host_gc)."""
     import gc
     import time
@@ -703,6 +706,10 @@ def _scenario_commit_stage_children(col: _Collector) -> None:
         128))
     assert replica.durable.two_phase_rows == {
         "pending": 1, "posted": 1, "voided": 0}
+    drive(Operation.lookup_accounts, multi_batch.encode(
+        [b"".join(i.to_bytes(16, "little") for i in (1, 2))], 16))
+    assert replica.state_machine.account_cache_stats()["cache_misses"] == 2
+    assert replica.durable.account_reads["flush_reads"] > 0
 
     class _Bus:
         woke_ns = 0

@@ -375,6 +375,31 @@ class Event(enum.Enum):
         "by key (lsm/memtable.py; the rows count in durable_rows_put "
         "path=folded); only when a run waits", "op")
 
+    # ------------------------------------------------- account reads
+    # What an account row costs where it is read by key: the column
+    # flush's previous-row reads (a child of flush_columns) and the four
+    # parts of a served lookup (children of its commit_execute). One
+    # span an op or a lookup, nothing per key or id; the shutdown
+    # record's `accounts` block holds the counts beside them.
+    flush_account_reads = _span(
+        "the column flush's reads of the previous row of each distinct "
+        "account of a chunk, one Tree.get a key: the memtable's dict, "
+        "or the level tables where the row was last written before the "
+        "last freeze (a block read and a binary search a table probed)",
+        "op")
+    lookup_ids = _span(
+        "a served lookup: the ids from the request's bytes", "op")
+    lookup_cache = _span(
+        "a served lookup: the loop over the ids against the object "
+        "cache, hits kept and misses listed", "op")
+    lookup_tree = _span(
+        "a served lookup: Tree.get_many for the ids the cache missed, "
+        "the rows unpacked and the cache refilled; only when an id "
+        "missed", "op")
+    lookup_pack = _span(
+        "a served lookup: the rows found, in request order, packed into "
+        "the reply", "op")
+
     # ------------------------------------------------------ tracer internal
     trace_dropped_events = _counter(
         "span ring evictions (the trace is truncated at its start)")

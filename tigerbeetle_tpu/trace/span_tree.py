@@ -3,8 +3,11 @@
 The three commit stages that hold most of a request's time
 (`commit_execute`, `commit_compact`, `commit_checkpoint`) are split into
 child spans, one catalog event per phase (trace/event.py), and
-`flush_columns` once more where an op holds two-phase rows
-(`flush_two_phase`, and `memtable_fold` inside it). A child
+`flush_columns` once more (`flush_account_reads`, its reads of the
+accounts' previous rows, and, where an op holds two-phase rows,
+`flush_two_phase` with `memtable_fold` inside it); a served lookup's
+`commit_execute` holds `lookup_ids`, `lookup_cache`, `lookup_tree` and
+`lookup_pack`. A child
 carries no pointer to its parent: it belongs to the parent occurrence
 that contains it in time, on the same pid. This module lays the children
 over their parents and says what share of each parent they cover — the
@@ -28,17 +31,21 @@ import sys
 STAGE_CHILDREN: dict = {
     "commit_execute": (
         "execute_decode", "execute_stage", "execute_dispatch",
-        "execute_delta_fetch", "execute_encode"),
+        "execute_delta_fetch", "execute_encode",
+        # A lookup's: ids from bytes, the cache loop, Tree.get_many for
+        # the misses (only where an id missed), the rows packed.
+        "lookup_ids", "lookup_cache", "lookup_tree", "lookup_pack"),
     "commit_compact": (
         "flush_columns", "flush_objects", "flush_cache_upsert",
         "compact_beat"),
     "commit_checkpoint": (
         "checkpoint_wal_barrier", "checkpoint_mirror_drain",
         "checkpoint_flush", "checkpoint_forest", "checkpoint_superblock"),
-    # What two-phase rows cost the column flush: both open only in an op
-    # whose delta holds such a row (a single-phase trace has no
-    # flush_two_phase occurrence, and its flush_columns reads 0 covered).
-    "flush_columns": ("flush_two_phase",),
+    # The previous-row reads of the chunk's distinct accounts, in every
+    # op, and what two-phase rows cost the column flush: those two open
+    # only in an op whose delta holds such a row (a single-phase trace
+    # has no flush_two_phase occurrence).
+    "flush_columns": ("flush_account_reads", "flush_two_phase"),
     "flush_two_phase": ("memtable_fold",),
 }
 

@@ -298,6 +298,12 @@ class DurableState:
         # column inside the two-phase loop, so an op with no such row
         # counts nothing.
         self.two_phase_rows = {"pending": 0, "posted": 0, "voided": 0}
+        # The column flush's previous-row reads of the accounts tree,
+        # over the life of this object: keys asked, of them the ones no
+        # memtable answered, and the tables probed for those (the
+        # tree's own counters, read around the reads).
+        self.account_reads = {"flush_reads": 0, "flush_reads_from_tables": 0,
+                              "table_probes": 0}
         layout = storage.layout
         self.grid = Grid(
             _ZoneDevice(storage, "grid"),
@@ -325,8 +331,9 @@ class DurableState:
         cache_upsert).
 
         `op` tags the spans (flush_columns where there are chunks, with
-        flush_two_phase and memtable_fold inside it where a chunk holds
-        two-phase rows; flush_objects always); `at_checkpoint` says the
+        flush_account_reads inside it, and flush_two_phase and
+        memtable_fold where a chunk holds two-phase rows; flush_objects
+        always); `at_checkpoint` says the
         caller is checkpoint(), for the row counters.
 
         flush_columns: drained device-delta transfer columns
@@ -660,7 +667,15 @@ class DurableState:
         raw = np.stack([id_hi[first], id_lo[first]], axis=1).astype(
             ">u8").tobytes()
         keys16 = [raw[p:p + 16] for p in range(0, len(raw), 16)]
-        olds = [acct_tree.get(k16) for k16 in keys16]
+        with self.tracer.span(Event.flush_account_reads, op=op):
+            from_tables = acct_tree.keys_from_tables
+            probes = acct_tree.table_probes
+            olds = [acct_tree.get(k16) for k16 in keys16]
+            reads = self.account_reads
+            reads["flush_reads"] += len(keys16)
+            reads["flush_reads_from_tables"] += (
+                acct_tree.keys_from_tables - from_tables)
+            reads["table_probes"] += acct_tree.table_probes - probes
         assert None not in olds, "account flushed before transfers"
         olds = np.frombuffer(b"".join(olds), dtype=np.uint8).reshape(-1, 128)
         meta = olds[:, 80:118][inverse].reshape(n, 2, 38)
