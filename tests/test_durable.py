@@ -371,3 +371,88 @@ def test_cache_invalidated_after_column_flush():
     got = sm.lookup_accounts([1])
     assert got and got[0].debits_posted == 77, \
         "stale cached balance after column flush"
+
+
+# ------------------------- the column flush over accounts held in tables
+
+def _accounts_in_tables(engine: str, per_key_reads: bool = False):
+    """1,200 accounts and nine writes of 300 transfers, a bar of beats
+    after each, so that the accounts tree's rows move into level-0 and
+    then level-1 tables and the column flush reads most of its accounts'
+    previous rows from tables. `per_key_reads` gives the accounts tree
+    PR 38's read, a `Tree.get` a key, in place of `Tree.get_many`.
+    Returns the durable state and, before each write, the accounts
+    tree's tables at levels 0 and 1."""
+    import numpy as np
+
+    from tigerbeetle_tpu.lsm.tree import BAR_LENGTH
+
+    durable = DurableState(MemoryStorage(TEST_LAYOUT))
+    sm = StateMachine(engine=engine, a_cap=1 << 12, t_cap=1 << 14)
+    sm.attach_durable(durable)
+    accounts = durable.forest.trees["accounts"]
+    if per_key_reads:
+        def get_many(keys, snapshot=None):
+            got = {k: accounts.get(k, snapshot) for k in keys}
+            return {k: v for k, v in got.items() if v is not None}
+        accounts.get_many = get_many
+    n, ts, op = 1200, 1000, 0
+    ts += n + 10
+    sm.create_accounts([Account(id=i, ledger=1, code=1 + i % 3)
+                        for i in range(1, n + 1)], ts)
+    _policy_flush(sm, durable)
+    rng = np.random.default_rng(39)
+    levels = []
+    for b in range(9):
+        dr = rng.integers(1, n + 1, 300)
+        evs = [Transfer(id=10**7 + 300 * b + i, debit_account_id=int(d),
+                        credit_account_id=int(d) % n + 1,
+                        amount=int(rng.integers(1, 1000)), ledger=1,
+                        code=1) for i, d in enumerate(dr)]
+        levels.append((len(accounts.levels[0]), len(accounts.levels[1])))
+        ts += len(evs) + 10
+        sm.commit(Operation.create_transfers,
+                  multi_batch.encode([b"".join(e.pack() for e in evs)], 128),
+                  ts)
+        _policy_flush(sm, durable)
+        for _ in range(BAR_LENGTH):
+            op += 1
+            durable.compact_beat(op)
+    return durable, levels
+
+
+@pytest.fixture(scope="module")
+def accounts_in_tables():
+    return {"batched": _accounts_in_tables("device"),
+            "per_key": _accounts_in_tables("device", per_key_reads=True),
+            "oracle": _accounts_in_tables("oracle")}
+
+
+def _contents(durable) -> dict:
+    return {name: tree.scan(bytes(tree.key_size), b"\xff" * tree.key_size)
+            for name, tree in durable.forest.trees.items()}
+
+
+@pytest.mark.parametrize("other", ["per_key", "oracle"])
+def test_column_flush_over_accounts_in_tables_writes_the_same_rows(
+        accounts_in_tables, other):
+    """The column flush that reads its accounts' previous rows by the
+    value block writes every tree's rows as the per-key read wrote them
+    (PR 38's flush), and as the object path writes them (oracle)."""
+    durable, levels = accounts_in_tables["batched"]
+    assert any(l0 and l1 for l0, l1 in levels), levels
+    assert durable.account_reads["flush_reads_from_tables"] > 0
+    assert _contents(durable) == _contents(accounts_in_tables[other][0])
+
+
+def test_column_flush_counts_what_the_per_key_read_counted(
+        accounts_in_tables):
+    """The shutdown record's account reads: the keys, the keys from
+    tables and the tables probed read as PR 38's per-key loop read them
+    on the same state; the blocks searched are fewer than the probes."""
+    batched = accounts_in_tables["batched"][0].account_reads
+    per_key = accounts_in_tables["per_key"][0].account_reads
+    for name in ("flush_reads", "flush_reads_from_tables", "table_probes"):
+        assert batched[name] == per_key[name], name
+    assert per_key["blocks_searched"] == 0
+    assert 0 < batched["blocks_searched"] < batched["table_probes"]

@@ -8,8 +8,11 @@ construction even when the data set far exceeds the cache.
 """
 
 import numpy as np
+import pytest
 
 from tigerbeetle_tpu.lsm.cache_map import ObjectCache
+from tigerbeetle_tpu.lsm.grid import Grid, MemoryDevice
+from tigerbeetle_tpu.lsm.tree import BAR_LENGTH, Tree
 from tigerbeetle_tpu.state_machine import StateMachine
 from tigerbeetle_tpu.types import (
     Account,
@@ -222,3 +225,147 @@ class TestBatchedPrefetch:
         for k in keys:
             single = tree.get(k)
             assert batched.get(k) == single, k.hex()
+
+
+# ------------------------------------------- reads by the value block
+
+def _key(i: int) -> bytes:
+    return i.to_bytes(16, "big")
+
+
+class _History:
+    """A tree whose keys lie in every place a point read looks: level-1
+    tables, overlapping level-0 tables that hold some keys at several
+    ages, tombstones over older live rows, the frozen memtable while its
+    flush is in flight, and the mutable memtable. 8 KiB blocks hold 255
+    rows of 16 + 16 bytes."""
+
+    BASE = range(10_000, 18_000, 2)  # age 0: 4,000 rows, 16 blocks
+    HOT = range(10_000, 10_100, 2)   # rewritten at every age
+    FAR = range(50_000, 50_400, 4)   # a second range
+
+    def __init__(self):
+        grid = Grid(MemoryDevice(1024 * 8192), block_size=8192,
+                    block_count=1024)
+        self.tree = tree = Tree(grid, key_size=16, value_size=16)
+        rng = np.random.default_rng(39)
+        self.op = 0
+        base = np.array(self.BASE)
+        # Age 1 holds the second range alone: it moves down to level 1
+        # beside age 0's table, not into it.
+        for age in range(10):
+            keys = {0: self.BASE, 1: self.FAR}.get(age) or [
+                *self.HOT, *rng.choice(base, 600, replace=False).tolist()]
+            if age >= 4:
+                keys = [*keys, *self.FAR]
+            for i in keys:
+                tree.put(_key(i), age.to_bytes(2, "big") * 8)
+            if age > 1:
+                for i in rng.choice(base, 40, replace=False).tolist():
+                    tree.remove(_key(i))
+            self.snapshot_a_bar_ago = self.op
+            for _ in range(BAR_LENGTH):  # the last beat freezes
+                self.op += 1
+                tree.compact_beat(self.op)
+        for i in rng.choice(base, 50, replace=False).tolist():
+            tree.put(_key(i), b"m" * 16)
+        tree.remove(_key(self.BASE[1]))
+        assert len(tree.levels[0]) >= 2 and len(tree.levels[1]) >= 2
+        assert tree._flush is not None and tree._flush.snapshot == self.op
+        assert tree.memtable
+
+    def snapshot(self, name: str):
+        return {"latest": None, "before_freeze": self.op - 1,
+                "at_freeze": self.op,
+                "a_bar_ago": self.snapshot_a_bar_ago}[name]
+
+    def keys(self, name: str) -> list:
+        tree = self.tree
+        if name == "every_row":  # hundreds of keys a block
+            return [_key(i) for i in range(9_990, 18_010)] + [
+                _key(i) for i in self.FAR]
+        if name == "one_a_block":
+            return [k for level in tree.levels[:2] for t in level
+                    for k in t.block_first_keys]
+        if name == "outside":  # below, between and above every range
+            return [_key(i) for i in (
+                *range(0, 20), 10_001, 12_345, 17_999, 18_000, 30_000,
+                49_999, 50_001, 50_397, 50_401, 10**12)] + [
+                b"\x00" * 16, b"\xff" * 16]
+        assert name == "unsorted_repeated"
+        rng = np.random.default_rng(7)
+        return [_key(int(i)) for i in rng.integers(9_990, 50_500, 3_000)]
+
+
+@pytest.fixture(scope="module")
+def history():
+    return _History()
+
+
+def _per_key(tree, keys, snapshot) -> dict:
+    out = {}
+    for k in dict.fromkeys(keys):
+        v = tree.get(k, snapshot)
+        if v is not None:
+            out[k] = v
+    return out
+
+
+@pytest.mark.parametrize("keys", ["every_row", "one_a_block", "outside",
+                                  "unsorted_repeated"])
+@pytest.mark.parametrize("snapshot", ["latest", "before_freeze",
+                                      "at_freeze", "a_bar_ago"])
+def test_get_many_by_block_matches_get(history, keys, snapshot):
+    """A read by the value block answers what a `get` a key answers, at
+    every snapshot, wherever the keys lie and however many share a
+    block."""
+    name, keys, snap = keys, history.keys(keys), history.snapshot(snapshot)
+    want = _per_key(history.tree, keys, snap)
+    assert history.tree.get_many(keys, snap) == want
+    # No table holds a key outside the ranges; the others find rows.
+    assert (want == {}) == (name == "outside")
+
+
+def _pairs(tree, keys, snapshot) -> set:
+    """The (table, block) pairs `get` probes for the keys no memtable
+    holds, newest table first until a table holds the key."""
+    pairs = set()
+    frozen = tree._frozen(snapshot)
+    for k in dict.fromkeys(keys):
+        if (snapshot is None and tree.memtable.get(k) is not None) or (
+                frozen is not None and frozen.get(k) is not None):
+            continue
+        for level in tree.levels:
+            tables = level.lookup(k, snapshot)
+            for t in tables:
+                pairs.add((id(t), t.block_for(k)[0].index))
+                if t.get(k) is not None:
+                    break
+            else:
+                continue
+            break
+    return pairs
+
+
+@pytest.mark.parametrize("snapshot", ["latest", "at_freeze"])
+@pytest.mark.parametrize("keys", ["every_row", "unsorted_repeated"])
+def test_get_many_counts_what_a_get_a_key_counts(history, keys, snapshot):
+    """`keys_from_tables` and `table_probes` read what PR 38's loop of
+    `get` read over the same keys; `blocks_searched` counts each
+    distinct (table, block) pair those probes touched, once: at least
+    the pairs, at most the probes."""
+    tree = history.tree
+    keys, snap = history.keys(keys), history.snapshot(snapshot)
+    before = (tree.keys_from_tables, tree.table_probes, tree.blocks_searched)
+    tree.get_many(keys, snap)
+    from_tables, probes, searched = (
+        tree.keys_from_tables - before[0], tree.table_probes - before[1],
+        tree.blocks_searched - before[2])
+    before = (tree.keys_from_tables, tree.table_probes)
+    _per_key(tree, keys, snap)
+    assert (from_tables, probes) == (tree.keys_from_tables - before[0],
+                                     tree.table_probes - before[1])
+    assert probes > 0
+    pairs = _pairs(tree, keys, snap)
+    assert len(pairs) <= searched <= probes
+    assert searched == len(pairs)

@@ -269,8 +269,12 @@ def test_the_mechanism_works_rows_leave_the_memtable_and_the_cache(served):
     # account; afterwards a write's tail accounts lie in tables.
     assert early["flush_reads"] > 0
     assert early["flush_reads_from_tables"] == 0 == early["table_probes"]
+    assert early["blocks_searched"] == 0
     assert 0 < reads["flush_reads_from_tables"] < reads["flush_reads"]
     assert reads["table_probes"] >= reads["flush_reads_from_tables"]
+    # The reads from tables go by the value block: a block searched
+    # once for every key of the write it holds.
+    assert 0 < reads["blocks_searched"] <= reads["table_probes"]
     cache = served.window_cache
     ids = sum(s.request.n_events for s in served.lookups)
     assert cache["cache_hits"] + cache["cache_misses"] == ids
@@ -315,7 +319,8 @@ def _reader(name: str):
 
 ACCOUNTS_BLOCK = {"cache_hits": 600, "cache_misses": 200,
                   "cache_evictions": 50, "flush_reads": 8000,
-                  "flush_reads_from_tables": 5000, "table_probes": 12000}
+                  "flush_reads_from_tables": 5000, "table_probes": 12000,
+                  "blocks_searched": 2400}
 
 
 @pytest.mark.parametrize("name, shutdown, want", [

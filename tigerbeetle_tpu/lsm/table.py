@@ -5,7 +5,8 @@ src/lsm/table_memory.zig. A table is one sorted run of fixed-size
 (key, value) entries: value blocks hold the entries, the index block holds
 each value block's first key + address. Lookups binary-search the index
 then the block (reference: src/lsm/binary_search.zig — here Python's
-bisect over in-memory key arrays)."""
+bisect over in-memory key arrays, or for a batch numpy's searchsorted
+over the keys as fixed-width byte strings)."""
 
 from __future__ import annotations
 
@@ -76,6 +77,13 @@ class Table:
             self.block_addresses.append(addr)
             self.block_sizes.append(size)
             self.block_first_keys.append(first)
+        # The same first keys as one fixed-width byte string a block
+        # (`S<key_size>`), and the table's key range likewise: a batched
+        # read finds every key's block with one search (Tree.get_many).
+        self.first_keys = np.frombuffer(b"".join(self.block_first_keys),
+                                        dtype=f"S{key_size}")
+        self.key_range = np.frombuffer(info.key_min + info.key_max,
+                                       dtype=f"S{key_size}")
 
     def block_rows(self, i: int) -> np.ndarray:
         """Value block i as the rows it was written from: a read-only
@@ -118,8 +126,8 @@ class Table:
 
     def block_for(self, key: bytes):
         """(address, size) of the one value block that could hold `key`,
-        or None — the read-free planning half of a point lookup (the
-        batched prefetch fan-out plans ALL of a batch's reads first)."""
+        or None — the read-free planning half of a point lookup (a
+        batched read places all of its keys at once: Tree.get_many)."""
         if not (self.info.key_min <= key <= self.info.key_max):
             return None
         i = bisect.bisect_right(self.block_first_keys, key) - 1
@@ -142,6 +150,23 @@ class Table:
         if lo < n and raw[4 + lo * entry: 4 + lo * entry + self.key_size] == key:
             return raw[4 + lo * entry + self.key_size: 4 + (lo + 1) * entry]
         return None
+
+    def find_in_block(self, raw: bytes, keys: np.ndarray):
+        """Search a fetched value block for every key of `keys` (sorted,
+        `S<key_size>`, each of them one the block could hold): the block
+        is unwrapped once, with no copy of its payload, and searched by
+        one `searchsorted` over its row keys. Returns the indices into
+        `keys` of the keys found, and their values, in the same order."""
+        raw = unwrap(memoryview(raw), BlockKind.value)
+        (n,) = struct.unpack_from("<I", raw)
+        entry = self.key_size + self.value_size
+        rows = np.ndarray((n,), dtype=keys.dtype, buffer=raw, offset=4,
+                          strides=(entry,))
+        at = np.minimum(rows.searchsorted(keys), n - 1)
+        hit = np.flatnonzero(rows[at] == keys)
+        start = 4 + self.key_size
+        return hit, [bytes(raw[start + i * entry:(i + 1) * entry + 4])
+                     for i in at[hit].tolist()]
 
     def iter_entries(self):
         # (key, value) pairs of the whole table (scans and tests; the

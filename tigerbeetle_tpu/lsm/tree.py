@@ -267,6 +267,9 @@ class Tree:
         # reads (DurableState.account_reads).
         self.keys_from_tables = 0
         self.table_probes = 0
+        # Value blocks a batched read (get_many) searched, each once a
+        # call however many of its keys it held.
+        self.blocks_searched = 0
 
     # ------------------------------------------------------------- updates
 
@@ -337,16 +340,24 @@ class Tree:
         return value
 
     def get_many(self, keys, snapshot: Optional[int] = None) -> dict:
-        """Batched point lookups: per level, every unresolved key's value
-        block is issued in ONE concurrent fan-out (Grid.read_blocks),
-        then resolved in place — a cold cache costs one round trip per
-        level touched, not one per key (reference: the prefetch fan-out,
-        src/lsm/groove.zig:996,1339). Returns {key: value} for keys
-        found live (tombstoned/missing keys are absent)."""
+        """Batched point lookups, {key: value} for the keys of `keys`
+        found live (tombstoned or missing keys are absent; `keys` may be
+        in any order and repeat a key). The memtables answer a key at a
+        time; the rest are read by the value block: table by table,
+        newest first (a deeper level's tables, disjoint at the latest
+        snapshot, as one), each key is cut to the tables whose range
+        holds it and placed in its block by one search over the table's
+        first keys, the distinct blocks are read in ONE concurrent
+        fan-out (Grid.read_blocks) while the next table's or level's
+        are read ahead, and each block is unwrapped and searched once
+        for all of its keys (Table.find_in_block). A key found, a
+        tombstone included, leaves the search: it shadows what older
+        tables hold, as in `get` (reference: the prefetch fan-out,
+        src/lsm/groove.zig:996,1339)."""
         found: dict = {}
         remaining = []
         frozen = self._frozen(snapshot)
-        for key in keys:
+        for key in dict.fromkeys(keys):
             value = self.memtable.get(key) if snapshot is None else None
             if value is None and frozen is not None:
                 value = frozen.get(key)
@@ -355,62 +366,99 @@ class Tree:
             else:
                 remaining.append(key)
         self.keys_from_tables += len(remaining)
-        plans: dict = {}  # key -> [(table, blk)] planned by the lookahead
-        for li, level in enumerate(self.levels):
-            if not remaining:
-                break
-            # Per-key candidate queues (L0 may yield several overlapping
-            # tables, newest first; deeper levels at most one). The
-            # previous level's lookahead already planned (table, block)
-            # pairs for this level — reuse them instead of re-probing.
-            active = []
-            for key in remaining:
-                cand = plans.get(key)
-                if cand is None:
-                    cand = [(t, t.block_for(key))
-                            for t in level.lookup(key, snapshot)]
-                if cand:
-                    active.append((key, cand))
-            # Overlap: submit the NEXT level's candidate blocks (planned
-            # read-free) while THIS level's fan-out resolves — a superset
-            # read-ahead (keys resolved here waste their submit) bounded
-            # by the grid's in-flight cap; no-op on synchronous devices.
-            plans = {}
-            if li + 1 < len(self.levels) and active:
-                lookahead = []
-                for key, _ in active:
-                    cand2 = [(t, t.block_for(key)) for t in
-                             self.levels[li + 1].lookup(key, snapshot)]
-                    if cand2:
-                        plans[key] = cand2
-                        lookahead.extend(
-                            b for _, b in cand2 if b is not None)
-                if lookahead:
-                    self.grid.prefetch_async(lookahead)
-            while active:
-                reqs, slots, nxt = [], [], []
-                for key, cand in active:
-                    blk = None
-                    while cand and blk is None:
-                        table, blk = cand.pop(0)
-                    if blk is None:
-                        continue
-                    reqs.append(blk)
-                    slots.append((key, table, cand))
-                if not reqs:
-                    break
-                self.table_probes += len(reqs)
-                for (key, table, cand), raw in zip(
-                        slots, self.grid.read_blocks(reqs)):
-                    value = table.get_in_block(key, raw)
-                    if value is not None:
-                        found[key] = value  # tombstones shadow deeper levels
-                    elif cand:
-                        nxt.append((key, cand))
-                active = nxt
-            remaining = [k for k in remaining if k not in found]
+        if remaining:
+            remaining.sort()
+            self._get_from_tables(remaining, snapshot, found)
         dead = TOMBSTONE * self.value_size
         return {k: v for k, v in found.items() if v != dead}
+
+    def _table_batches(self, snapshot: Optional[int]) -> list:
+        """The tables a point read searches, in the order `get` probes
+        them, as the groups whose blocks are read in one fan-out: each
+        level-0 table alone, newest first (they overlap: a key found in
+        one is not looked for in the next), and each deeper level whole
+        at the latest snapshot, where its tables are disjoint; at an
+        older snapshot a deeper level's visible tables one at a time,
+        newest first, as `ManifestLevel.lookup` orders them."""
+        batches = []
+        for i, level in enumerate(self.levels):
+            entries = level.visible(snapshot)
+            if not entries:
+                continue
+            if i and snapshot is None:
+                batches.append([e.table for e in entries])
+            else:
+                batches.extend([e.table] for e in sorted(
+                    entries, key=lambda e: -e.seq))
+        return batches
+
+    @staticmethod
+    def _plan(tables: list, keys: np.ndarray) -> list:
+        """(table, lo, starts, blocks) for each table of `tables` whose
+        range holds some of `keys` (sorted `S<key_size>`): those keys
+        are keys[lo:lo + starts[-1]], and the keys of block blocks[j]
+        run from lo + starts[j] to lo + starts[j + 1]."""
+        plan = []
+        for table in tables:
+            # (Slices, not scalars: a numpy bytes scalar drops its
+            # trailing zero bytes.)
+            lo = int(keys.searchsorted(table.key_range[:1])[0])
+            hi = int(keys.searchsorted(table.key_range[1:], side="right")[0])
+            if lo == hi:
+                continue
+            # A table's first block starts at its key_min: no key in
+            # range falls before it.
+            block = table.first_keys.searchsorted(keys[lo:hi],
+                                                  side="right") - 1
+            starts = np.flatnonzero(block[1:] != block[:-1]) + 1
+            starts = np.concatenate(([0], starts, [hi - lo]))
+            plan.append((table, lo, starts, block[starts[:-1]]))
+        return plan
+
+    @staticmethod
+    def _requests(plan: list) -> list:
+        return [(table.block_addresses[b], table.block_sizes[b])
+                for table, _, _, blocks in plan for b in blocks.tolist()]
+
+    def _get_from_tables(self, keys: list, snapshot: Optional[int],
+                         found: dict) -> None:
+        """Resolve `keys` (sorted, unique, none of them in a memtable)
+        from the tables into `found`, tombstones included."""
+        raw = b"".join(keys)
+        assert len(raw) == self.key_size * len(keys)
+        keys_s = np.frombuffer(raw, dtype=f"S{self.key_size}")
+        at = np.arange(len(keys))  # the keys not found yet
+        batches = self._table_batches(snapshot)
+        for b, tables in enumerate(batches):
+            if not len(at):
+                break
+            todo = keys_s[at]
+            plan = self._plan(tables, todo)
+            if not plan:
+                continue
+            datas = self.grid.read_blocks(self._requests(plan))
+            # Read the next group's blocks ahead, for the keys not
+            # found yet (a superset: keys found here waste their read),
+            # while this group's are searched.
+            if b + 1 < len(batches):
+                ahead = self._requests(self._plan(batches[b + 1], todo))
+                if ahead:
+                    self.grid.prefetch_async(ahead)
+            done = np.zeros(len(at), dtype=bool)
+            d = 0
+            for table, lo, starts, blocks in plan:
+                self.table_probes += int(starts[-1])
+                self.blocks_searched += len(blocks)
+                for j in range(len(blocks)):
+                    s, e = lo + starts[j], lo + starts[j + 1]
+                    hit, values = table.find_in_block(datas[d], todo[s:e])
+                    d += 1
+                    if len(hit):
+                        hit += s
+                        done[hit] = True
+                        for i, value in zip(at[hit].tolist(), values):
+                            found[keys[i]] = value
+            at = at[~done]
 
     def scan(self, key_min: bytes, key_max: bytes,
              snapshot: Optional[int] = None) -> list[tuple[bytes, bytes]]:

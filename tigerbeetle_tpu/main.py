@@ -356,7 +356,8 @@ def cmd_start(args) -> int:
             # What account reads by key met: the served lookups' cache
             # (the ObjectCache's own counters) and the column flush's
             # previous-row reads of the accounts tree (keys asked, the
-            # ones no memtable answered, the tables probed for them).
+            # ones no memtable answered, the tables probed for them, the
+            # value blocks searched for them).
             "accounts": {**replica.state_machine.account_cache_stats(),
                          **replica.durable.account_reads},
             # How full the deployment's sizes got, read here from state

@@ -383,9 +383,10 @@ class Event(enum.Enum):
     # record's `accounts` block holds the counts beside them.
     flush_account_reads = _span(
         "the column flush's reads of the previous row of each distinct "
-        "account of a chunk, one Tree.get a key: the memtable's dict, "
+        "account of a chunk, one Tree.get_many: the memtable's dict, "
         "or the level tables where the row was last written before the "
-        "last freeze (a block read and a binary search a table probed)",
+        "last freeze (a block read and one search a block, for all of "
+        "the keys it holds)",
         "op")
     lookup_ids = _span(
         "a served lookup: the ids from the request's bytes", "op")

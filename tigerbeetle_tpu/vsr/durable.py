@@ -300,10 +300,11 @@ class DurableState:
         self.two_phase_rows = {"pending": 0, "posted": 0, "voided": 0}
         # The column flush's previous-row reads of the accounts tree,
         # over the life of this object: keys asked, of them the ones no
-        # memtable answered, and the tables probed for those (the
-        # tree's own counters, read around the reads).
+        # memtable answered, the tables probed for those and the value
+        # blocks searched for them (the tree's own counters, read around
+        # the reads).
         self.account_reads = {"flush_reads": 0, "flush_reads_from_tables": 0,
-                              "table_probes": 0}
+                              "table_probes": 0, "blocks_searched": 0}
         layout = storage.layout
         self.grid = Grid(
             _ZoneDevice(storage, "grid"),
@@ -670,14 +671,17 @@ class DurableState:
         with self.tracer.span(Event.flush_account_reads, op=op):
             from_tables = acct_tree.keys_from_tables
             probes = acct_tree.table_probes
-            olds = [acct_tree.get(k16) for k16 in keys16]
+            blocks = acct_tree.blocks_searched
+            got = acct_tree.get_many(keys16)
             reads = self.account_reads
             reads["flush_reads"] += len(keys16)
             reads["flush_reads_from_tables"] += (
                 acct_tree.keys_from_tables - from_tables)
             reads["table_probes"] += acct_tree.table_probes - probes
-        assert None not in olds, "account flushed before transfers"
-        olds = np.frombuffer(b"".join(olds), dtype=np.uint8).reshape(-1, 128)
+            reads["blocks_searched"] += acct_tree.blocks_searched - blocks
+        assert len(got) == len(keys16), "account flushed before transfers"
+        olds = np.frombuffer(b"".join([got[k16] for k16 in keys16]),
+                             dtype=np.uint8).reshape(-1, 128)
         meta = olds[:, 80:118][inverse].reshape(n, 2, 38)
         acct_ts = olds[:, 120:128][inverse].reshape(n, 2, 8)
 
