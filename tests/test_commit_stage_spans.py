@@ -223,6 +223,9 @@ def test_grid_counters_add_up_in_a_served_run(traced_run):
     assert stats["reads"] == (stats["cache_hits"] + stats["readahead_hits"]
                               + stats["device_reads"])
     assert stats["device_reads"] > 0 and stats["blocks_written"] > 0
+    # The record says the cache's size (one block here) and that it
+    # evicted: the misses above are the cache's, not the grid's.
+    assert stats["cache_blocks"] == 1 and stats["cache_evictions"] > 0
     # Every value block the flush's reads searched was asked of the grid.
     assert 0 < replica.durable.account_reads["blocks_searched"] \
         <= stats["reads"]

@@ -449,6 +449,21 @@ def test_grid_block_equals_the_persisted_free_set(served):
     assert stats["held_peak"] > TEST_LAYOUT.grid_block_count // 8
 
 
+def test_grid_block_says_the_cache_size_and_what_it_evicted(served):
+    """The block cache's capacity and evictions beside the block
+    counters: a grid of 6,144 blocks of 8 KiB is under the 1 GiB budget,
+    so the cache has a slot for each of them."""
+    grid = served.replica.durable.grid
+    stats = grid.held_stats()
+    assert stats["cache_blocks"] == GRID_BLOCKS == grid.cache.capacity
+    assert stats["cache_evictions"] == grid.cache.evictions
+    assert stats["blocks_written"] < GRID_BLOCKS
+    assert stats["reads"] == (stats["cache_hits"] + stats["readahead_hits"]
+                              + stats["device_reads"])
+    assert stats["reads"] > 0
+    json.dumps(stats)  # integers, as the shutdown record prints them
+
+
 def test_forest_block_equals_the_persisted_manifests(served):
     """Live tables and the deepest level holding one, decoded from the
     manifest chain the last checkpoint wrote (lsm.tree.manifest_pack's

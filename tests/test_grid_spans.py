@@ -7,8 +7,10 @@ import struct
 import numpy as np
 import pytest
 
+import tigerbeetle_tpu.lsm.grid as grid_mod
 import tigerbeetle_tpu.lsm.tree as tree_mod
 import tigerbeetle_tpu.trace.tracer as tracer_mod
+from tigerbeetle_tpu.lsm.forest import Forest
 from tigerbeetle_tpu.lsm.grid import Grid, MemoryDevice
 from tigerbeetle_tpu.lsm.tree import BAR_LENGTH, Tree
 from tigerbeetle_tpu.trace import NullTracer, Tracer
@@ -225,6 +227,71 @@ def test_batched_tree_reads_open_a_pair_a_batch_on_the_tracers_clock():
     _identity(grid)
 
 
+@pytest.mark.parametrize("block_size,block_count,cache_blocks", [
+    (64 * 1024, 8192, 8192),    # under the budget: a slot a block
+    (64 * 1024, 16384, 16384),  # at it
+    (64 * 1024, 65536, 16384),  # over it: 1 GiB of blocks
+    (4096, 1024, 1024),
+    (512, 5, 5),                # fewer blocks than a set has ways
+])
+def test_the_block_cache_holds_the_grid_up_to_a_gib(block_size, block_count,
+                                                    cache_blocks):
+    assert cache_blocks == min(block_count,
+                               grid_mod.GRID_CACHE_BYTES // block_size)
+    grid = Grid(None, block_size=block_size, block_count=block_count)
+    assert grid.cache.capacity == cache_blocks
+    assert grid.held_stats()["cache_blocks"] == cache_blocks
+    # A caller that sizes the cache keeps its size.
+    sized = Grid(None, block_size=block_size, block_count=block_count,
+                 cache_sets=2, cache_ways=3)
+    assert (sized.cache.n_sets, sized.cache.ways) == (2, 3)
+    assert sized.held_stats()["cache_blocks"] == 6
+
+
+@pytest.mark.parametrize("cache_sets", [None, 64])
+def test_a_grid_cached_whole_reads_and_checks_a_block_once(cache_sets):
+    """A tree of about 1,900 blocks, flushed and checkpointed, read twice
+    by key: under the default cache (a slot for each of the grid's
+    16,384 blocks) the second read is all cache hits and opens no span;
+    under the 512 blocks the cache used to hold, it reads and checks its
+    blocks again."""
+    tracer = Tracer()
+    grid = Grid(_AsyncDevice(16384 * 512), block_size=512,
+                block_count=16384, cache_sets=cache_sets, tracer=tracer)
+    forest = Forest(grid, {"t": (8, 16)})
+    tree = forest.trees["t"]
+    keys = np.arange(0, 60_000, 3, dtype=">u8")
+    tree.put_run(keys.view(np.uint8).reshape(-1, 8),
+                 np.repeat(keys, 2).view(np.uint8).reshape(-1, 16))
+    for op in range(1, BAR_LENGTH + 1):
+        tree.compact_beat(op)  # the bar's flush
+    tree.put_run(keys[::2].copy().view(np.uint8).reshape(-1, 8),
+                 b"\x01" * 16)
+    forest.checkpoint()
+    wanted = [struct.pack(">Q", k) for k in keys[::5].tolist()]
+    first = tree.get_many(wanted)
+    assert len(first) == len(wanted)
+    tracer.events.clear()
+    before = grid.held_stats()
+    assert tree.get_many(wanted) == first
+    after = grid.held_stats()
+    asked = after["reads"] - before["reads"]
+    assert asked > 512
+    _identity(grid)
+    if cache_sets is None:
+        assert grid.cache.capacity == grid.block_count
+        assert not _spans(tracer)
+        assert after["cache_hits"] - before["cache_hits"] == asked
+        for counter in ("device_reads", "readahead_submitted",
+                        "cache_evictions"):
+            assert after[counter] == before[counter], counter
+    else:
+        assert _spans(tracer, "grid_read") and _spans(tracer, "grid_verify")
+        assert after["cache_evictions"] > before["cache_evictions"]
+        assert after["device_reads"] + after["readahead_hits"] > \
+            before["device_reads"] + before["readahead_hits"]
+
+
 class _NoClock:
     def __getattr__(self, name):
         raise AssertionError(f"a grid path read time.{name} with "
@@ -232,8 +299,6 @@ class _NoClock:
 
 
 def test_under_the_null_tracer_no_grid_path_reads_a_clock(monkeypatch):
-    import tigerbeetle_tpu.lsm.grid as grid_mod
-
     for mod in (grid_mod, tree_mod):
         assert not any(getattr(mod, n, None) is __import__("time")
                        for n in dir(mod)), mod.__name__

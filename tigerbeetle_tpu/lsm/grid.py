@@ -22,6 +22,10 @@ from ..trace import Event, NullTracer
 from ..vsr.checksum import checksum
 
 BLOCK_SIZE_DEFAULT = 64 * 1024
+# The block cache's byte budget: the reference's default grid cache
+# (`tigerbeetle start --cache-grid`, 1 GiB), counted in the grid's own
+# blocks and never more blocks than the grid has.
+GRID_CACHE_BYTES = 1 << 30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +76,7 @@ class Grid:
     crash recovery never sees a block overwritten mid-interval."""
 
     def __init__(self, device, block_size: int = BLOCK_SIZE_DEFAULT,
-                 block_count: int = 4096, cache_sets: int = 64,
+                 block_count: int = 4096, cache_sets: Optional[int] = None,
                  cache_ways: int = 8, tracer=None):
         from .cache_map import ObjectCache
 
@@ -88,6 +92,15 @@ class Grid:
         # cache, src/vsr/grid.zig:30). Keys are (checksum, index), so a
         # freed-and-reused index can never serve stale bytes — blocks
         # are immutable under copy-on-write, making entries forever valid.
+        # Unless the caller sizes it, it holds min(block_count,
+        # GRID_CACHE_BYTES // block_size) blocks (down to whole sets): a
+        # grid under the budget has a slot for each of its blocks, so a
+        # block written or checked once is read and checked again only
+        # where more blocks than a set's ways fell into its set.
+        if cache_sets is None:
+            cache_blocks = min(block_count, GRID_CACHE_BYTES // block_size)
+            cache_ways = min(cache_ways, cache_blocks)
+            cache_sets = cache_blocks // cache_ways
         self.cache = ObjectCache(sets=cache_sets, ways=cache_ways)
         # Standing missing-block hook (reference: grid_blocks_missing,
         # src/vsr/grid_blocks_missing.zig:24): the replica wires this to
@@ -225,10 +238,13 @@ class Grid:
         reservation that finds fewer free blocks than it asks for kills
         the server, so `held_peak` against `blocks` says how far a
         `format --grid-blocks` was from that. Beside it, what the
-        blocks cost: the read, read-ahead and write counters."""
+        blocks cost: the read, read-ahead and write counters, and the
+        block cache's capacity and the blocks it evicted."""
         return {"blocks": self.block_count,
                 "held_at_checkpoint": self.held_at_checkpoint,
                 "held_peak": self.held_peak,
+                "cache_blocks": self.cache.capacity,
+                "cache_evictions": self.cache.evictions,
                 "reads": self.reads,
                 "cache_hits": self.cache_hits,
                 "readahead_hits": self.readahead_hits,

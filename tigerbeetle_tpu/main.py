@@ -362,8 +362,8 @@ def cmd_start(args) -> int:
                          **replica.durable.account_reads},
             # How full the deployment's sizes got, read here from state
             # that exists anyway: the stores' row counters, the free
-            # set (and what each checkpoint counted of it), the
-            # manifests.
+            # set (and what each checkpoint counted of it), the block
+            # cache's size and evictions, the manifests.
             "stores": led.store_stats(),
             "grid": replica.durable.grid.held_stats(),
             "forest": replica.durable.forest.depth_stats(),

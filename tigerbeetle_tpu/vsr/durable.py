@@ -243,10 +243,12 @@ def validate_staged_checkpoint(blocks: dict, layout,
     complete and uncorrupted. Raises on any fault — the caller must not
     have written anything to the live grid yet."""
     staged = DurableState.__new__(DurableState)
+    # The staged blocks are in memory already: a small cache, not the
+    # served grid's, so validation does not hold a second copy of them.
     staged.grid = Grid(
         _DictDevice(blocks, layout.grid_block_size),
         block_size=layout.grid_block_size,
-        block_count=layout.grid_block_count)
+        block_count=layout.grid_block_count, cache_sets=64)
     staged.forest = Forest(staged.grid, SCHEMA)
     staged.events_persisted = 0
     staged._indexed_accounts = set()
