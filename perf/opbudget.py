@@ -35,7 +35,8 @@ un-regressable:
     table is re-materialized per program instead of passed as an
     operand), no while/fori loop in any serving lowering beyond an
     entry's declared allowance (the chain entries' ONE deliberate scan
-    lowers to one stablehlo.while; everything else allows zero), and
+    and the converging limit tier's round loop lower to one
+    stablehlo.while each; everything else allows zero), and
     every state-carrying entry donates its ledger buffers
     (donated-input count == state leaf count in the lowered artifact —
     the chain entries are audited too, incl. the unrolled form).
@@ -174,11 +175,9 @@ def census_tiers() -> dict:
         "imported": (functools.partial(
             fk.create_transfers_fast, imported_mode=True),
             (state, ev, ts, n)),
-        "fixpoint_8": (functools.partial(
-            fk.create_transfers_fast, limit_rounds=8), (state, ev, ts, n)),
-        "fixpoint_deep_32": (functools.partial(
-            fk.create_transfers_fast, limit_rounds=32),
-            (state, ev, ts, n)),
+        "fixpoint_converge": (functools.partial(
+            fk.create_transfers_fast,
+            limit_rounds=fk.LIMIT_FIXPOINT_CONVERGE), (state, ev, ts, n)),
         "balancing_8": (functools.partial(
             fk.create_transfers_fast, limit_rounds=8,
             balancing_mode=True), (state, ev, ts, n)),
@@ -256,7 +255,8 @@ def serving_entries() -> dict:
     """name -> (lowered artifact, expected donated-input count, allowed
     while count) for the state-carrying jit entries on the serving/scan
     paths. The chain entries allow exactly ONE stablehlo.while (their
-    deliberate lax.scan); everything else allows zero."""
+    deliberate lax.scan), the converging limit tier its one round loop;
+    everything else allows zero."""
     from tigerbeetle_tpu.ops import fast_kernels as fk
 
     state, ev, ev_s, seg = _fixtures()
@@ -271,9 +271,7 @@ def serving_entries() -> dict:
     add("create_transfers_fast_jit", fk.create_transfers_fast_jit,
         state, ev, ts, n)
     add("create_transfers_fixpoint_jit", fk.create_transfers_fixpoint_jit,
-        state, ev, ts, n)
-    add("create_transfers_fixpoint_deep_jit",
-        fk.create_transfers_fixpoint_deep_jit, state, ev, ts, n)
+        state, ev, ts, n, max_while=1)
     add("create_transfers_balancing_jit",
         fk.create_transfers_balancing_jit, state, ev, ts, n)
     add("create_transfers_imported_jit",
@@ -347,18 +345,20 @@ def run_lints() -> list[str]:
     loops, donation. Returns human-readable failure strings."""
     fails = []
     for name, (lowered, n_donate, max_while) in serving_entries().items():
-        # The serving path must stay straight-line: lax.scan/while both
-        # lower to stablehlo.while. The chain entries declare their ONE
-        # deliberate scan (max_while=1); anything beyond an entry's
-        # allowance — e.g. a searchsorted left on the default scan
-        # method — is a red.
+        # The serving path is straight-line but for declared loops:
+        # lax.scan/while both lower to stablehlo.while. The chain
+        # entries declare their ONE deliberate scan and the converging
+        # limit tier its round loop (max_while=1); anything beyond an
+        # entry's allowance — e.g. a searchsorted left on the default
+        # scan method — is a red.
         text = lowered.as_text()
         n_while = text.count("stablehlo.while")
         if n_while > max_while:
             fails.append(
                 f"{name}: {n_while} while loop(s) in the lowering "
-                f"(> allowed {max_while}; one executed while degrades "
-                "every later dispatch to 5-8 ms — PERF.md)")
+                f"(> allowed {max_while}; a loop body runs its ops as "
+                "written every iteration — declare each loop an entry "
+                "means to have)")
         donated = jaxhound.donated_inputs(lowered)
         if donated < n_donate:
             fails.append(

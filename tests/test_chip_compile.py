@@ -104,8 +104,6 @@ def _cases():
             fk.create_transfers_fixpoint_jit, batch(1024)),
         "create_transfers_fixpoint@8192": (
             fk.create_transfers_fixpoint_jit, batch(N_PAD)),
-        "create_transfers_fixpoint_deep@8192": (
-            fk.create_transfers_fixpoint_deep_jit, batch(N_PAD)),
         "create_transfers_super@K2x8192": (
             fk.create_transfers_super_jit,
             lambda s: warmup.window_args(A_CAP, T_CAP, 2, N_PAD, s)),
@@ -206,7 +204,6 @@ def test_served_entry_compiles_for_v5e(name, one_chip):
 
 SLOW = ("create_transfers_fast@1024", "create_transfers_fixpoint@1024",
         "create_transfers_fixpoint@8192",
-        "create_transfers_fixpoint_deep@8192",
         "create_transfers_super@K2x8192", "create_transfers_super@K8x8192",
         "xfer_delta_gather@8192", "xfer_delta_gather@65536",
         "create_transfers_fast@8192,t_cap=2^22")

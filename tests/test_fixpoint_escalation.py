@@ -1,5 +1,6 @@
-"""Deep limit cascades: the 8-round fixpoint escalates to the 32-round
-variant on device instead of falling back to the host.
+"""Deep limit cascades: the plain tier escalates to the converging
+fixpoint tier, which runs as many rounds as the cascade needs in one
+dispatch instead of falling back to the host.
 
 A K-wave cascade is constructed from K linked chains: chain k's first
 member debits limited account L_k (which only has headroom if chain
@@ -91,12 +92,15 @@ def test_shallow_cascade_stays_in_first_tier():
 
 
 def test_deep_cascade_escalates_on_device():
-    """12 waves > the 8-round budget: must resolve via the 32-round
-    variant, never the host."""
+    """12 waves > the 8 rounds an unrolled tier starts with: one
+    converging dispatch resolves them, never a deeper tier or the
+    host."""
     led, sm = _setup(16)
     _diff(led, sm, _cascade_events(12), TS + 5000)
     assert led.fallbacks == 0, "escalation must not touch the host path"
-    assert led.deep_fixpoint_batches == 1
+    assert led.deep_fixpoint_batches == 0
+    assert led.fixpoint_rounds["dispatches"] == 1
+    assert led.fixpoint_rounds["rounds"] >= 12
 
 
 def test_warm_kernels_is_inert():

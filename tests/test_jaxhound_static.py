@@ -356,7 +356,7 @@ def test_committed_tracebudget_schema():
     assert doc["round"] == 1
     assert doc["matrix"]["depths"] == [1, 2, 8, 32]
     entries = doc["entries"]
-    assert len(entries) >= 19
+    assert len(entries) >= 18
     routes = set()
     for name, e in entries.items():
         assert set(e) == {"route", "depths", "n_signatures",

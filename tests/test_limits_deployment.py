@@ -11,8 +11,10 @@ checkpoint's op. The comparison is the benchmark's own
 (`chipbench.check.judge`): every reply, every account and a sample of
 transfers against the plain reference's replay of the same request
 bytes. Beside it, the spans and counters that say who judged each
-request: the device's tiers and the host mirror, by route. Then the
-readers of the cell's five per-layer metrics, on synthetic contexts.
+request: the device's tiers and the host mirror, by route (one request
+the device cannot judge, a payment sent twice, hands the ledger to the
+mirror for a while). Then the readers of the cell's
+per-layer metrics, on synthetic contexts.
 """
 
 import collections
@@ -29,6 +31,7 @@ from chipbench.reference.ledger_types import CreateTransferStatus
 from chipbench.traffic import Deployment
 from chipbench.window import Sent
 from tigerbeetle_tpu import main as tb_main
+from tigerbeetle_tpu.ops.ledger import DeviceLedger
 from tigerbeetle_tpu.state_machine import StateMachine
 from tigerbeetle_tpu.testing.cluster import Cluster
 from tigerbeetle_tpu.trace import Tracer
@@ -41,9 +44,9 @@ CONFIG = os.path.join(CONFIGS, "tb_bench_limits_1r.json")
 CLUSTER_ID = 0xC1A57E12  # testing.cluster.Cluster's own
 # 32 of the configuration's 10,000 accounts, 480 events a request: each
 # limited account takes enough of a request's debits that, once its
-# funding runs low, some cascades outgrow the deep tier's 32 rounds
-# (the cell's 8,189-event requests over 10,000 accounts outgrow them in
-# nearly every request).
+# funding runs low, some cascades outgrow the 32 rounds an unrolled tier
+# stops at (the cell's 8,189-event requests over 10,000 accounts outgrow
+# them in nearly every request); the converging tier judges them all.
 ACCOUNTS = 32
 A_CAP, T_CAP = 1 << 9, 1 << 14  # `start --small` gives its stores 2^14 rows
 WIDTH = 480
@@ -51,6 +54,19 @@ RESTART_AT_OP = 16       # the first checkpoint
 LAST_OP = 32             # the second
 SESSION = 0
 HOST = ("host_mirror", "host_fallback")
+DUPLICATE_AFTER = 3      # transfer requests before the one the host judges
+DUPLICATE_STREAM = 0x7F00
+
+
+def duplicate_request(dep: Deployment):
+    """A payment sent twice in one request, the same id and fields: the
+    reference answers the second `exists`. The device's tiers cannot
+    judge an id that repeats inside a batch, so the host mirror judges
+    the request and holds the hard regime for the requests after it."""
+    rec = np.frombuffer(dep.transfer_request(DUPLICATE_STREAM, 0, 2).payload,
+                        dtype=wire.TRANSFER).copy()
+    rec[1] = rec[0]
+    return Deployment._request("create_transfers", rec)
 
 
 class _Served:
@@ -126,6 +142,8 @@ class _Served:
         while self.replica.commit_min < LAST_OP:
             self.request(self.dep.transfer_request(SESSION, k, WIDTH))
             k += 1
+            if k == DUPLICATE_AFTER:
+                self.request(duplicate_request(self.dep))
             if self.replica.commit_min == RESTART_AT_OP:
                 assert self.replica.superblock.op_checkpoint == RESTART_AT_OP
                 self._keep_counters()
@@ -272,22 +290,31 @@ def test_some_payments_are_declined_for_exceeding_credits(served):
 
 def test_both_the_host_mirror_and_a_device_tier_judge_requests(served):
     """The comparison above covers both paths: requests the device's
-    fixpoint tiers judged and requests the host mirror judged, by both
-    of its routes over the two seeds' runs."""
-    device = sum(served.total(k) for k in (
-        "create_transfers_fixpoint", "create_transfers_fixpoint_deep"))
+    converging limit tier judged and requests the host mirror judged, by
+    both of its routes: the duplicate's request after its dispatch fell
+    back, and the hard regime's requests after it."""
+    device = served.total("create_transfers_fixpoint")
     host = sum(served.total(k) for k in HOST)
     assert device > 0 and host > 0
-    assert served.total("host_fallback") > 0
+    assert served.total("host_fallback") == 1
+    assert 0 < served.total("host_mirror") \
+        <= DeviceLedger.MIRROR_PROBE_INTERVAL
     # One count a create request, whoever judged it.
     assert sum(sum(j.values()) for j in served.judged) == len(served.sent)
     assert sum(s["host_fallbacks"] for s in served.stats) == host
-    # every fallback was a limit cascade past the deep tier's rounds
+    # No limit cascade left the device: the one fallback is the
+    # duplicate's collision, and every other request the host judged
+    # was the hard regime's.
     causes = collections.Counter()
     for s in served.stats:
         causes.update(s["causes"])
-    assert set(causes) == {"e3_limit"}
-    assert causes["e3_limit"] == served.total("host_fallback")
+    assert causes == {"e2_collision": 1}
+    assert "e3_limit" not in causes
+    # The device judged every request outside that stretch, by the
+    # plain tier or the converging one.
+    assert {k for j in served.judged for k in j} <= {
+        *HOST, "create_accounts_fast", "create_transfers_fast",
+        "create_transfers_fixpoint"}
 
 
 def test_the_host_spans_count_what_the_route_counters_count(served):
@@ -364,7 +391,7 @@ SPANS = {
                             (120.8, 0.1, 5)],
 }
 JUDGED = {"shutdown": {"judged": {"host_mirror": 2,
-                                  "create_transfers_fixpoint_deep": 3}}}
+                                  "create_transfers_fixpoint": 3}}}
 
 
 def _span_context(spans: dict, dropped: int = 0, shutdown=JUDGED) -> dict:
@@ -438,6 +465,16 @@ def test_fixpoint_roofline_is_the_least_bytes_over_the_peak_over_the_time():
     assert read(_device({}, {})) is None
 
 
+def test_fixpoint_rounds_reads_the_records_mean_a_dispatch():
+    read = _reader("fixpoint_rounds")
+    record = {"dispatches": 4, "rounds": 250, "rounds_max": 137}
+    assert read({"shutdown": {"fixpoint": record}}) == pytest.approx(62.5)
+    # No converging dispatch in the run, or the parent's program: no block.
+    assert read({"shutdown": {"fixpoint": {
+        "dispatches": 0, "rounds": 0, "rounds_max": 0}}}) is None
+    assert read({"shutdown": {}}) is None
+
+
 RECORDED = os.path.join(ROOT, "chipbench", "tests", "recorded",
                         "trace.xplane.pb.gz")
 
@@ -479,7 +516,8 @@ def test_the_cells_metrics_are_appended_with_the_cell_that_reports_them():
         ("kernel_ms.create_transfers_fixpoint", "kernels", "accepted_tps",
          cell),
         ("create_transfers_fixpoint_roofline", "kernels", "accepted_tps",
-         cell)]
+         cell),
+        ("fixpoint_rounds", "kernels", "accepted_tps", cell)]
     by_name = {e["name"]: e for e in bench["end_to_end"]}
     for e in tail:
         assert "workloads" not in by_name[e["moves"]], e["name"]

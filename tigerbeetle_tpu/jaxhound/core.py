@@ -316,10 +316,14 @@ def closure_constants(closed_jaxpr) -> list[tuple[str, int]]:
 
 
 def while_ops(closed_jaxpr) -> int:
-    """Count of while/fori loops anywhere in the program. One executed
-    lax.while_loop degrades every later dispatch in the process to
-    5-8 ms (PERF.md round-2 finding) — serving-path lowerings must stay
-    straight-line."""
+    """Count of while/fori loops anywhere in the program. On a TPU v5e
+    an executed lax.while_loop costs later dispatches nothing: with the
+    limit tier's round loop executed in every `start`'s warm-up, the
+    plain tier's dispatches in upstream's benchmark load took 18.8 ms
+    against 18.9 without it, 13.7 ms on the device against 13.5
+    (PERF.md 6). A while is still a deliberate choice: its body runs
+    its ops as written every iteration, so each serving entry declares
+    the loops it has (perf/opbudget.py)."""
     n = [0]
 
     def visit(eqn):

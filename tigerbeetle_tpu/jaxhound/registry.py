@@ -152,8 +152,6 @@ def entries(include_partitioned: bool | None = None) -> dict[str, Entry]:
              fk.create_transfers_fast_jit, (state, ev, ts, n))
     add_flat("create_transfers_fixpoint_jit",
              fk.create_transfers_fixpoint_jit, (state, ev, ts, n))
-    add_flat("create_transfers_fixpoint_deep_jit",
-             fk.create_transfers_fixpoint_deep_jit, (state, ev, ts, n))
     add_flat("create_transfers_balancing_jit",
              fk.create_transfers_balancing_jit, (state, ev, ts, n))
     add_flat("create_transfers_imported_jit",

@@ -349,6 +349,10 @@ def cmd_start(args) -> int:
             # device tier by its jitted entry's name, or the host
             # mirror by its route (host_mirror, host_fallback).
             "judged": dict(led.judged),
+            # The converging limit tier's dispatches, the rounds they
+            # ran summed, and the most in one (its `fix_rounds`,
+            # fetched with its flags).
+            "fixpoint": dict(led.fixpoint_rounds),
             # Transfer rows the durable flush put, by path, and the
             # checkpoints taken: a checkpoint that put again what the
             # column path had already written would show here.

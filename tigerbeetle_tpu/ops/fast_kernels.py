@@ -124,6 +124,26 @@ def _cummax(x, axis=-1):
     return jax.lax.associative_scan(jnp.maximum, x, axis=axis % x.ndim)
 
 
+def _shift_scan(op, x, axis=-1):
+    """Inclusive prefix `op` (jnp.add or jnp.maximum) along `axis`, as
+    log2(n) shift-and-combine passes (Hillis-Steele). Each pass is one
+    fused elementwise op on a TPU, where associative_scan's strided
+    slices, pads and interleaves come to about 7 ops a level: in a
+    while loop's body, which runs its ops as written every iteration,
+    that is the difference between ~30 ops a scan and ~100."""
+    axis = axis % x.ndim
+    n = x.shape[axis]
+    fill = 0 if op is jnp.add else jnp.iinfo(x.dtype).min
+    d = 1
+    while d < n:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (d, 0)
+        x = op(x, jnp.pad(jax.lax.slice_in_dim(x, 0, n - d, axis=axis),
+                          pad, constant_values=fill))
+        d *= 2
+    return x
+
+
 # ------------------------------------------------------------ limb helpers
 
 def _to_limbs(hi, lo):
@@ -315,8 +335,22 @@ def _packed_perm(rows2, order2, row_cap):
     return jnp.argsort(combined).astype(jnp.int32)
 
 
+def _linked_prev(linked, seg_start=None):
+    """Whether the event before each one is linked to it (never across
+    a superbatch's sub-batch boundary)."""
+    l_prev = jnp.concatenate([jnp.zeros(1, dtype=jnp.bool_), linked[:-1]])
+    if seg_start is not None:
+        l_prev = l_prev & ~seg_start
+    return l_prev
+
+
+def _chain_ids(linked, l_prev):
+    """Each event's chain number: the count of chain starts up to it."""
+    return _cumsum((linked & ~l_prev).astype(jnp.int32))
+
+
 def _chain_pass(status, linked, valid, idxs, n, N, seg_start=None,
-                chain_term=None):
+                chain_term=None, chain_id=None):
     """Linked-chain first-failure broadcast (reference execute_create
     :3033-3150): returns (status, not_the_failure, my_first, in_chain)
     where not_the_failure marks members overridden to linked_event_failed.
@@ -327,13 +361,12 @@ def _chain_pass(status, linked, valid, idxs, n, N, seg_start=None,
     never span prepares — a trailing open chain must NOT merge with the
     next sub-batch's head) and chain_term marks each sub-batch's last
     VALID event (the open-chain terminator position). Defaults reproduce
-    the single-batch semantics."""
-    l_prev = jnp.concatenate([jnp.zeros(1, dtype=jnp.bool_), linked[:-1]])
-    if seg_start is not None:
-        l_prev = l_prev & ~seg_start
+    the single-batch semantics. chain_id: the chains' numbering
+    (_chain_ids), where the caller has it already."""
+    l_prev = _linked_prev(linked, seg_start)
     in_chain = linked | l_prev
-    start = linked & ~l_prev
-    chain_id = _cumsum(start.astype(jnp.int32))
+    if chain_id is None:
+        chain_id = _chain_ids(linked, l_prev)
     is_last = (idxs == (n - 1)) if chain_term is None else chain_term
     chain_open_evt = linked & is_last
     status = jnp.where(chain_open_evt, _TS["linked_event_chain_open"],
@@ -639,9 +672,8 @@ def imported_batch_ctx(state, ev, ts_event, valid, idxs, seg_start=None):
     # live ascending prefix + MAX padding a sorted operand (user
     # timestamps are <= U63, so the padding can never collide).
     # method='sort': the default 'scan' method lowers to a while loop,
-    # which degrades every later dispatch in the process to 5-8 ms
-    # (PERF.md round-2 finding; jaxhound's serving-path lint enforces
-    # while-free lowerings).
+    # whose body runs op by op every iteration (jaxhound's serving-path
+    # lint holds each entry to the loops it declares).
     acct_ts_sorted = jnp.where(
         jnp.arange(acc["u32"].shape[0], dtype=jnp.int32) < acc["count"],
         ac_col(acc, "ts"), jnp.uint64(0xFFFFFFFFFFFFFFFF))
@@ -939,7 +971,9 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
     the worst-case headroom proof (fallback on a potential breach);
     K > 1 = resolve breaches natively with a K-round status fixpoint
     against exact per-event prefix balances (falls back only if the
-    limit-decision cascade is deeper than K rounds).
+    limit-decision cascade is deeper than K rounds);
+    LIMIT_FIXPOINT_CONVERGE = the same rounds in one while loop until
+    they stop changing (at most N + 1, which every cascade fits).
     seg: superbatch descriptor for K stacked prepares executed in ONE
     dispatch (one program launch and one set of state passes for the
     whole window; what that buys on a local chip is not measured):
@@ -1381,7 +1415,9 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
         # disagrees with the sequential truth (its own prefix is already
         # correct and stays correct), so K rounds resolve any batch whose
         # limit-decision cascade is shallower than K; deeper cascades
-        # fall back to the exact host path. Dependency deaths fold into
+        # fall back to the exact host path, and the converging form
+        # (LIMIT_FIXPOINT_CONVERGE) runs rounds until they stop
+        # changing, N + 1 at most. Dependency deaths fold into
         # the SAME round's apply set (a second cheap chain pass), so one
         # round advances a full over->death->relief wave — without the
         # fold the wave costs two rounds (measured: the config4 window
@@ -1547,10 +1583,23 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
             # exact running max and stays fixed.
             imp_lane = _flag(flags, _F_IMPORTED)
             actual_vec = jnp.where(imp_lane, ev["ts"], ts_event)
-        for _round in range(limit_rounds):
-            fix_rounds = fix_rounds + (
-                jnp.int32(1) if _round == 0
-                else (~fix_converged).astype(jnp.int32))
+
+        converging = limit_rounds == LIMIT_FIXPOINT_CONVERGE
+        if converging:
+            # A while body runs its ops as written every round: its two
+            # scans take the fused shift form, and the chains' numbering,
+            # which no round changes, is made once out here.
+            round_cumsum = functools.partial(_shift_scan, jnp.add)
+            round_cummax = functools.partial(_shift_scan, jnp.maximum)
+            chain_id = _chain_ids(linked, _linked_prev(linked, seg_start))
+        else:
+            round_cumsum, round_cummax, chain_id = _cumsum, _cummax, None
+
+        def _round(carry):
+            # One round: the previous round's decisions in, this
+            # round's out, and whether they are the same.
+            (over_dr, over_cr, dead, cdr_ln, ccr_ln, reg_low, ovf_code,
+             amt_fx_hi, amt_fx_lo) = carry
             st_r = jnp.where(ovf_code != 0, ovf_code, status)
             st_r = jnp.where(over_dr, _TS["exceeds_credits"], st_r)
             st_r = jnp.where(over_cr & ~over_dr, _TS["exceeds_debits"],
@@ -1594,7 +1643,8 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
             # reads pending_transfer_not_found (sequential truth).
             st_r = jnp.where(dead, status_dead, st_r)
             st_c, _, my_first_r, in_chain_r = _chain_pass(
-                st_r, linked, valid, idxs, n, N, seg_start, chain_term)
+                st_r, linked, valid, idxs, n, N, seg_start, chain_term,
+                chain_id)
             # Definition liveness AS OF THE USE's execution point: the
             # def is absent iff it failed on its own (pre-chain status)
             # or its chain broke STRICTLY BEFORE the use — a chain whose
@@ -1616,7 +1666,8 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
             # so the converged statuses are unchanged by it.
             st_f = jnp.where(new_dead & ~dead, status_dead, st_r)
             st_c, _, _, _ = _chain_pass(
-                st_f, linked, valid, idxs, n, N, seg_start, chain_term)
+                st_f, linked, valid, idxs, n, N, seg_start, chain_term,
+                chain_id)
             ap_r = valid & (st_c == _CREATED)
             # Delta lanes directly in sorted entry space: one u8 mask
             # gather + fused elementwise selects against the hoisted
@@ -1651,7 +1702,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
                 clr_s = jnp.where(cr_side_s, (m_s & 128) != 0,
                                   (m_s & 64) != 0)
                 op_pos = jnp.where(set_s | clr_s, idx2, jnp.int32(-1))
-                incl_op = _cummax(op_pos)
+                incl_op = round_cummax(op_pos)
                 excl_op = jnp.concatenate(
                     [jnp.full((1,), -1, jnp.int32), incl_op[:-1]])
                 # In-segment iff the latest op position is at/after my
@@ -1689,7 +1740,7 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
                 jnp.stack([jnp.where(cr_side_s, posted[j], z64_)
                            for j in range(4)]),       # cpos
             ])
-            fcs = _cumsum(fls, axis=2)
+            fcs = round_cumsum(fls, axis=2)
             foff = jnp.where(
                 fseg_start > 0,
                 jnp.take(fcs, jnp.maximum(fseg_start - 1, 0), axis=2),
@@ -1763,10 +1814,42 @@ def create_transfers_fast(state, ev, timestamp, n, force_fallback=None,
                                     & (new_cdr == cdr_ln)
                                     & (new_ccr == ccr_ln)
                                     & (new_reg_low == reg_low)) & amt_stable
-            over_dr, over_cr, dead = new_over_dr, new_over_cr, new_dead
-            cdr_ln, ccr_ln = new_cdr, new_ccr
-            reg_low = new_reg_low
-            ovf_code = new_ovf
+            return (new_over_dr, new_over_cr, new_dead, new_cdr, new_ccr,
+                    new_reg_low, new_ovf, amt_fx_hi, amt_fx_lo), \
+                fix_converged
+
+        carry = (over_dr, over_cr, dead, cdr_ln, ccr_ln, reg_low, ovf_code,
+                 amt_fx_hi, amt_fx_lo)
+        if converging:
+            # Rounds until one leaves every decision as it found it, in
+            # one while loop. By the induction above N + 1 rounds always
+            # converge; the cap only bounds the loop. A round that
+            # brings back the decisions of the round before last is a
+            # 2-cycle (the closing chain rollback above), which never
+            # converges: the loop stops there, unconverged, and the
+            # batch falls back as it would at the cap.
+            def _go_on(loop):
+                rounds, converged, cycled, _, _ = loop
+                return ~converged & ~cycled & (rounds <= N)
+
+            def _next(loop):
+                rounds, _, _, before, now = loop
+                after, converged = _round(now)
+                cycled = functools.reduce(jnp.logical_and, [
+                    jnp.all(a == b) for a, b in zip(after, before)])
+                return rounds + 1, converged, cycled, now, after
+
+            fix_rounds, fix_converged, _, _, carry = jax.lax.while_loop(
+                _go_on, _next, (fix_rounds, jnp.bool_(False),
+                                jnp.bool_(False), carry, carry))
+        else:
+            for _r in range(limit_rounds):
+                fix_rounds = fix_rounds + (
+                    jnp.int32(1) if _r == 0
+                    else (~fix_converged).astype(jnp.int32))
+                carry, fix_converged = _round(carry)
+        (over_dr, over_cr, dead, cdr_ln, ccr_ln, reg_low, ovf_code,
+         amt_fx_hi, amt_fx_lo) = carry
         status = jnp.where(ovf_code != 0, ovf_code, status)
         status = jnp.where(over_dr, _TS["exceeds_credits"], status)
         status = jnp.where(over_cr & ~over_dr, _TS["exceeds_debits"],
@@ -2392,24 +2475,25 @@ create_transfers_super_balancing_jit = jax.jit(
 create_transfers_super_balancing_ring_jit = jax.jit(
     _create_transfers_super_balancing_ring, donate_argnums=0)
 
-# The order-dependent-limits variant: resolves headroom-proof breaches
-# natively with a K-round status fixpoint (cascades deeper than K
-# limit-decision waves fall back to the exact host path; each wave needs
-# a limit failure whose rollback flips a LATER event's limit outcome —
-# K=8 empirically covers even the adversarial config4 workload with ~16
-# breach-boundary events per limited account per batch).
+# Round budgets of the fixpoint tiers that unroll their rounds: the
+# imported and balancing tiers and their deep escalations (each wave
+# needs a limit failure whose rollback flips a LATER event's limit
+# outcome; full protocol-max batches over few limited accounts cascade
+# 9-32 waves).
 LIMIT_FIXPOINT_ROUNDS = 8
-create_transfers_fixpoint_jit = _tier_jit(
-    "create_transfers_fixpoint", limit_rounds=LIMIT_FIXPOINT_ROUNDS)
-
-# Escalation tier: full protocol-max batches over few limited accounts
-# can cascade deeper than 8 waves (config4 at 8190 events / 64 accounts
-# measured 9-32); the deep variant costs ~4x the rounds but still beats
-# the host path by an order of magnitude on chip.
 LIMIT_FIXPOINT_ROUNDS_DEEP = 32
-create_transfers_fixpoint_deep_jit = _tier_jit(
-    "create_transfers_fixpoint_deep",
-    limit_rounds=LIMIT_FIXPOINT_ROUNDS_DEEP)
+
+# The order-dependent-limits tier: resolves headroom-proof breaches
+# natively, iterating its rounds in one while loop until they stop
+# changing (limit_rounds=LIMIT_FIXPOINT_CONVERGE). Wire-max requests of
+# upstream's benchmark load over accounts that cannot overdraw cascade
+# far past any budget worth unrolling (27-128 rounds a request on a TPU
+# v5e, 66 on the median, over 50 such requests); the
+# loop runs what the batch needs, in one dispatch. The value only has to
+# differ from every unrolled budget: the loop stops at N + 1 rounds.
+LIMIT_FIXPOINT_CONVERGE = 1 << 31
+create_transfers_fixpoint_jit = _tier_jit(
+    "create_transfers_fixpoint", limit_rounds=LIMIT_FIXPOINT_CONVERGE)
 
 # Imported fixpoint tier: the plain imported tier's escalation target
 # (closing flags, voids of closing pendings, potential limit breaches).

@@ -948,8 +948,9 @@ class DeviceLedger:
     # After this many consecutive batches in the host-mirror regime, drop
     # the mirror and probe the device fast path again (hysteresis).
     MIRROR_PROBE_INTERVAL = 8
-    # After an 8->32-round escalation, dispatch the deep tier directly
-    # for this many breach batches before re-probing the shallow one.
+    # After an 8->32-round balancing escalation, dispatch the deep
+    # balancing tier directly for this many batches before re-probing
+    # the shallow one.
     DEEP_PROBE_INTERVAL = 8
 
     def __init__(self, a_cap: int = 1 << 17, t_cap: int = 1 << 21,
@@ -981,7 +982,7 @@ class DeviceLedger:
         self.fallback_causes: dict = {}
         # Per-batch create requests by who answered them: the jitted
         # entry of the dispatch that judged it (`create_transfers_fast`,
-        # `create_transfers_fixpoint`, `create_transfers_fixpoint_deep`,
+        # `create_transfers_fixpoint`, `create_transfers_balancing`,
         # `create_accounts_fast`, ...: the `tier` of its last
         # execute_dispatch span), or the host mirror by its route
         # (`host_mirror`, `host_fallback`: execute_host_judge's
@@ -1008,8 +1009,12 @@ class DeviceLedger:
         # here so fallback_stats() always carries the recovery record —
         # "no recoveries" is a measured number in every bench run.
         self.recovery_stats: dict = default_recovery_stats()
-        self._deep_first = 0
         self._bal_deep_first = 0
+        # The converging limit tier's rounds (its `fix_rounds`, fetched
+        # with its flags): dispatches, rounds summed, the most in one
+        # (`start`'s shutdown record, `fixpoint`).
+        self.fixpoint_rounds = {"dispatches": 0, "rounds": 0,
+                                "rounds_max": 0}
         # Adaptive kernel routing: after a batch resolves breaches via the
         # limit fixpoint, later batches dispatch the fixpoint kernel first
         # (skipping the headroom-proof attempt that would fail anyway)
@@ -1915,34 +1920,29 @@ class DeviceLedger:
         return [self.create_transfers_soa(ev, ts)
                 for ev, ts in zip(evs, timestamps)]
 
-    def _escalate_fixpoint(self, evp, timestamp, n, balancing=False,
-                           imported=False):
-        """The 8-round fixpoint reported a limit cascade deeper than its
-        budget (and no other obstacle): resolve it on device with the
-        32-round variant before considering the host path. Returns
-        (fallback, out) from the deep run and enters the matching
-        deep-first regime (the shallow dispatch is a known waste while
-        cascades stay deep). balancing/imported select that tier's deep
-        variant (balancing keeps its own regime counter; imported has
-        none — imported windows are rare enough that re-probing costs
-        nothing)."""
+    def _escalate_fixpoint(self, evp, timestamp, n, balancing=False):
+        """The 8-round balancing or imported fixpoint reported a cascade
+        deeper than its budget (and no other obstacle): resolve it on
+        device with that tier's 32-round variant before considering the
+        host path. Returns (fallback, out) from the deep run; balancing
+        enters its deep-first regime (the shallow dispatch is a known
+        waste while cascades stay deep), imported has none (imported
+        windows are rare enough that re-probing costs nothing). The
+        plain limit tier converges in one dispatch and never comes
+        here."""
         from .fast_kernels import (
             create_transfers_balancing_deep_jit,
-            create_transfers_fixpoint_deep_jit,
             create_transfers_imported_fixpoint_deep_jit,
         )
 
         deep = (create_transfers_balancing_deep_jit if balancing
-                else create_transfers_imported_fixpoint_deep_jit
-                if imported else create_transfers_fixpoint_deep_jit)
+                else create_transfers_imported_fixpoint_deep_jit)
         deep_out, fallback = self._dispatch(deep, evp, timestamp, n,
                                             "fallback")
         self.deep_fixpoint_batches += 1
         self.escalations += 1
         if balancing:
             self._bal_deep_first = self.DEEP_PROBE_INTERVAL
-        elif not imported:
-            self._deep_first = self.DEEP_PROBE_INTERVAL
         return fallback, deep_out
 
     def _dispatch(self, tier_jit, evp, timestamp, n, *flags):
@@ -1950,21 +1950,35 @@ class DeviceLedger:
         the state it returns (the old one was donated), and block on the
         scalar `flags` of its output. Returns (out, *flag bools). One
         execute_dispatch span per call — launch, device time and the
-        sync — so an escalation shows as a second span."""
+        sync — so an escalation shows as a second span. The converging
+        limit tier's `fix_rounds` comes back in the same fetch: the
+        span's `rounds` tag and `fixpoint_rounds`."""
         import jax
 
+        from .fast_kernels import create_transfers_fixpoint_jit
+
         self._last_tier = tier_jit.__name__
+        converging = tier_jit is create_transfers_fixpoint_jit
+        fetch = flags + (("fix_rounds",) if converging else ())
         with self.tracer.span(Event.execute_dispatch, op=self.trace_op,
-                              tier=self._last_tier):
+                              tier=self._last_tier) as dispatched:
             self.state, out = tier_jit(
                 self.state, evp, np.uint64(timestamp), np.int32(n))
-            got = jax.device_get(tuple(out[f] for f in flags))
+            got = jax.device_get(tuple(out[f] for f in fetch))
+            if converging:
+                *got, rounds = got
+                rounds = int(rounds)
+                dispatched.tags["rounds"] = rounds
+                fr = self.fixpoint_rounds
+                fr["dispatches"] += 1
+                fr["rounds"] += rounds
+                fr["rounds_max"] = max(fr["rounds_max"], rounds)
         return (out, *(bool(x) for x in got))
 
     def warm_kernels(self, n_pad: int = N_PAD,
                      balancing: bool = True) -> None:
         """Compile every transfer-kernel variant (fast / fixpoint /
-        deep fixpoint, plus the balancing tiers unless balancing=False)
+        imported, plus the balancing tiers unless balancing=False)
         at the given padded shape with an all-invalid batch — no state
         change, no events created. Drivers call this once so a mid-run
         escalation never pays a compile inside a timed region; the
@@ -1977,17 +1991,14 @@ class DeviceLedger:
             create_transfers_balancing_deep_jit,
             create_transfers_balancing_jit,
             create_transfers_fast_jit,
-            create_transfers_fixpoint_deep_jit,
             create_transfers_fixpoint_jit,
+            create_transfers_imported_jit,
         )
-
-        from .fast_kernels import create_transfers_imported_jit
 
         evp = jax.device_put(
             pad_transfer_events(transfers_to_arrays([]), n_pad))
         variants = [create_transfers_fast_jit,
                     create_transfers_fixpoint_jit,
-                    create_transfers_fixpoint_deep_jit,
                     create_transfers_imported_jit]
         if balancing:
             variants += [create_transfers_balancing_jit,
@@ -2042,7 +2053,7 @@ class DeviceLedger:
                     timestamp, n, "fallback")
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
-                        evp, timestamp, n, imported=True)
+                        evp, timestamp, n)
                 if not fallback:
                     self.fixpoint_batches += 1
         elif _has_balancing([ev]):
@@ -2074,26 +2085,11 @@ class DeviceLedger:
         elif self._fixpoint_first:
             # The workload has been breaching balance limits: skip the
             # doomed headroom-proof dispatch and go straight to the
-            # fixpoint kernel; drop back once a batch reports no breach.
-            # While cascades have been exceeding the shallow budget, go
-            # straight to the DEEP tier too, re-probing the shallow one
-            # every DEEP_PROBE_INTERVAL batches (same hysteresis shape
-            # as the mirror probe).
-            from .fast_kernels import create_transfers_fixpoint_deep_jit
-
-            if self._deep_first > 0:
-                self._deep_first -= 1
-                out, fallback, limit_hit = self._dispatch(
-                    create_transfers_fixpoint_deep_jit, evp, timestamp,
-                    n, "fallback", "limit_hit")
-                self.deep_fixpoint_batches += 1
-            else:
-                out, fallback, limit_hit = self._dispatch(
-                    create_transfers_fixpoint_jit, evp, timestamp, n,
-                    "fallback", "limit_hit")
-                if fallback and bool(out["fix_unconverged"]):
-                    fallback, out = self._escalate_fixpoint(
-                        evp, timestamp, n)
+            # converging fixpoint kernel; drop back once a batch reports
+            # no breach.
+            out, fallback, limit_hit = self._dispatch(
+                create_transfers_fixpoint_jit, evp, timestamp, n,
+                "fallback", "limit_hit")
             if not fallback:
                 self.fixpoint_batches += 1
                 if not limit_hit:
@@ -2111,9 +2107,6 @@ class DeviceLedger:
                 out, fallback = self._dispatch(
                     create_transfers_fixpoint_jit, evp, timestamp, n,
                     "fallback")
-                if fallback and bool(out["fix_unconverged"]):
-                    fallback, out = self._escalate_fixpoint(
-                        evp, timestamp, n)
                 if not fallback:
                     self.fixpoint_batches += 1
                     self._fixpoint_first = True
@@ -3084,13 +3077,16 @@ class DeviceLedger:
         (accounts and transfers) the device judged, whatever tier
         answered — one per batch that did not fall back, one per prepare
         of a window; `fixpoint_batches` is how many of the per-batch
-        ones a fixpoint tier answered, shallow or deep;
+        ones a fixpoint tier answered, the converging limit tier or a
+        balancing / imported tier, shallow or deep;
         `deep_fixpoint_batches` counts dispatches of a deep tier (per
         prepare for a deep window) and `escalations` the extra
         dispatches a batch cost beyond its first. A batch that climbs
-        plain -> fixpoint -> deep reads fast 1, fixpoint 1, deep 1,
-        escalations 2: one request, three dispatches. `host_fallbacks`
-        is the count of requests the host answered instead."""
+        imported -> imported fixpoint -> its deep tier reads fast 1,
+        fixpoint 1, deep 1, escalations 2: one request, three
+        dispatches; one that climbs plain -> converging reads fast 1,
+        fixpoint 1, escalations 1. `host_fallbacks` is the count of
+        requests the host answered instead."""
         return {
             "host_fallbacks": self.fallbacks,
             "window_fallbacks": self.window_fallbacks,

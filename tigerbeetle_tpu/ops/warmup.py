@@ -4,19 +4,21 @@ timeout budget (vsr/client.py: 10 s by default).
 
 At production caps (a_cap 2^17, t_cap 2^21) one create_transfers tier
 takes the TPU compiler one to two minutes of ONE core (the plain tier
-52-73 s, the limit fixpoint 119 s: sandbox compile, PR 32), and the dispatch surface is tiers x four batch buckets x
+52-73 s, the limit fixpoint 81 s at 8192 and 19 s at 1024, compiled for
+a described v5e with no chip: PERF.md), and the dispatch surface is tiers x four batch buckets x
 window depths 2..8 — warming all of it cold would take the better part
 of an hour. So the warm set is chosen, not exhaustive:
 
 - the smallest and the widest batch bucket (1024: the everyday request;
-  8192: the wire maximum), each on the plain tier and the limit
-  fixpoint tier the plain tier escalates to;
+  8192: the wire maximum), each on the plain tier and the converging
+  limit fixpoint tier the plain tier escalates to, which judges a limit
+  cascade of any depth, so no deeper limit tier exists to warm;
 - create_accounts (always padded to the widest bucket);
 - the write-through delta gathers those dispatch.
 
 Still compiled on demand, inside some request's budget: the 2048 and
-4096 buckets, the deep fixpoint tiers, and the balancing / imported
-tiers. Commit windows (depths 2..8 x bucket, one program each) are not
+4096 buckets, and the balancing / imported tiers and their 32-round
+escalations. Commit windows (depths 2..8 x bucket, one program each) are not
 warmed either: a primary commits prepare by prepare, so only a backup
 catching up or a WAL replay at `open` dispatches them — before
 `listening`, or off the client's clock. The read path is not warmed

@@ -292,8 +292,10 @@ class Event(enum.Enum):
         "create_accounts): jit call until the "
         "device_get of its fallback flags returns (launch + device + "
         "sync); an escalation shows as a second span. `tier` is the "
-        "jitted entry's name, the device trace's module less `jit_`",
-        "op", "tier", hist_tags=("tier",))
+        "jitted entry's name, the device trace's module less `jit_`; "
+        "`rounds`, on the converging limit tier alone, the rounds its "
+        "while loop ran (fetched with the flags)",
+        "op", "tier", "rounds", hist_tags=("tier",))
     execute_delta_fetch = _span(
         "write-through capture of one batch's device delta: the gather "
         "dispatch and the device -> host copy it starts (the wait for "
