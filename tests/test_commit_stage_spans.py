@@ -24,7 +24,7 @@ from tigerbeetle_tpu.trace.span_tree import (STAGE_CHILDREN,
                                              children_share,
                                              keep_operation,
                                              stage_occurrences)
-from tigerbeetle_tpu.types import Account, Operation, Transfer
+from tigerbeetle_tpu.types import Account, AccountFlags, Operation, Transfer
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 PER_OP = 200  # transfers a request: the children dwarf the glue code
@@ -163,6 +163,34 @@ def host_run():
             "judged": dict(led.judged), "stats": led.fallback_stats()}
 
 
+@pytest.fixture(scope="module")
+def escalate_run():
+    """The same replica under a recording tracer, with one request that
+    debits an account which must not be overdrawn past its credits: the
+    headroom proof fails, the fast tier falls back `limit_only`, and the
+    request is dispatched again to the converging tier."""
+    tracer = Tracer(pid=0)
+    cluster = Cluster(seed=11, replica_count=1,
+                      tracer_factory=lambda i: tracer,
+                      state_machine_factory=_device_machine)
+    client = cluster.client(9)
+    for op, body in (
+            (Operation.create_accounts, [
+                Account(id=1, ledger=1, code=1, flags=int(
+                    AccountFlags.debits_must_not_exceed_credits)),
+                Account(id=2, ledger=1, code=1)]),
+            (Operation.create_transfers, [
+                Transfer(id=10 + k, debit_account_id=1, credit_account_id=2,
+                         amount=5, ledger=1, code=1) for k in range(3)])):
+        client.request(op, multi_batch.encode(
+            [b"".join(e.pack() for e in body)], 128))
+        assert cluster.run(4000, until=lambda: client.idle), \
+            cluster.debug_status()
+    led = cluster.replicas[0].state_machine.led
+    assert led.judged_kinds["single"]["escalated"] == 1
+    return {"events": tracer.chrome_dict()["traceEvents"]}
+
+
 TWO_PHASE_PAIRS = 3   # requests of pendings, each followed by its posts
 TWO_PHASE_WIDTH = 120
 TWO_PHASE_STAGES = ("flush_columns", "flush_two_phase")
@@ -214,10 +242,12 @@ GRID_PARENTS = {"grid_read": ("flush_account_reads", "lookup_tree"),
 
 @pytest.mark.parametrize("stage", OP_STAGES)
 def test_every_child_span_lies_inside_its_parent_and_carries_its_op(
-        traced_run, two_phase_run, host_run, stage):
+        traced_run, two_phase_run, host_run, escalate_run, stage):
     for child in STAGE_CHILDREN[stage]:
-        # The host mirror's spans open only where it judges a request.
-        run = (host_run if child in HOST_CHILDREN else two_phase_run
+        # The host mirror's spans open only where it judges a request,
+        # an escalation only where a limit breaches the headroom proof.
+        run = (host_run if child in HOST_CHILDREN else escalate_run
+               if stage == "execute_escalate" else two_phase_run
                if stage in TWO_PHASE_STAGES else traced_run)
         spans = [e for e in run["events"] if e["ph"] == "X"]
         parents = {e["args"]["op"]: e for e in spans if e["name"] == stage}
@@ -229,6 +259,11 @@ def test_every_child_span_lies_inside_its_parent_and_carries_its_op(
             p = parents[e["args"]["op"]]
             if not (p["ts"] <= e["ts"]
                     and e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3):
+                if stage == "execute_escalate":
+                    # The op's proof-gated dispatch, which fell back,
+                    # ends before its escalation starts.
+                    assert e["ts"] + e["dur"] <= p["ts"], e
+                    continue
                 # flush_columns / flush_objects also run under a
                 # checkpoint's flush, for the same op as a commit_compact.
                 assert e["name"] in ("flush_columns", "flush_objects"), e

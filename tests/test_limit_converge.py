@@ -202,3 +202,105 @@ def test_the_shift_scan_equals_the_associative_scan(op, scan, shape, axis):
         got = fk._shift_scan(getattr(jnp, op), x, axis=axis)
         assert got.dtype == want.dtype
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ------------------------------------------------- limits on pending rows
+
+CUSTOMER, OPERATOR = 1, 2
+
+
+def _limited_pair(funding: int):
+    """A DeviceLedger and the oracle, each holding a customer account
+    that must not be overdrawn, funded with `funding` from an operator
+    account that may."""
+    led, sm = DeviceLedger(a_cap=1 << 9, t_cap=1 << 14), StateMachineOracle()
+    accounts = [Account(id=CUSTOMER, ledger=1, code=1, flags=DR_LIMIT),
+                Account(id=OPERATOR, ledger=1, code=1)]
+    fund = [Transfer(id=1, debit_account_id=OPERATOR,
+                     credit_account_id=CUSTOMER, amount=funding, ledger=1,
+                     code=1)]
+    for eng in (led, sm):
+        eng.create_accounts(accounts, TS0)
+        eng.create_transfers(fund, TS0 + 10)
+    return led, sm
+
+
+def _both(led, sm, events: list, ts: int) -> list:
+    """One batch through both; the converging tier judged it on the
+    device; the answers and the state afterwards equal the oracle's."""
+    got = led.create_transfers(events, ts)
+    want = sm.create_transfers(events, ts)
+    assert got == want
+    assert led._last_tier == "create_transfers_fixpoint"
+    assert led.fallbacks == 0
+    host = led.to_host()
+    assert host.accounts == sm.accounts
+    assert host.transfers == sm.transfers
+    return [r.status for r in want]
+
+
+def _pending(tid, dr, cr, amount):
+    return Transfer(id=tid, debit_account_id=dr, credit_account_id=cr,
+                    amount=amount, ledger=1, code=1, flags=int(TF.pending))
+
+
+def _resolve(tid, pending_id, flag, amount=0):
+    return Transfer(id=tid, pending_id=pending_id, amount=amount, ledger=1,
+                    code=1, flags=int(flag))
+
+
+def test_a_pending_credit_gives_a_limited_account_no_headroom():
+    """A pending credit of 500 to the customer, then in the same batch a
+    pending debit of 300 from it: the limit counts debits_pending and
+    debits_posted against credits_posted alone, so the debit is declined
+    on 100 of funding; a debit of 50 after it fits. The debits breach
+    the headroom proof, and the converging tier judges the batch."""
+    led, sm = _limited_pair(funding=100)
+    got = _both(led, sm, [_pending(10, OPERATOR, CUSTOMER, 500),
+                          _pending(11, CUSTOMER, OPERATOR, 300),
+                          _pending(12, CUSTOMER, OPERATOR, 50)], TS0 + 100)
+    assert got == [CreateTransferStatus.created,
+                   CreateTransferStatus.exceeds_credits,
+                   CreateTransferStatus.created]
+    assert led.judged_kinds["pending"]["escalated"] == 1
+
+
+def test_a_post_of_a_declined_pending_finds_no_pending():
+    """The batch after: the post of the declined pending answers
+    pending_transfer_not_found, the post of the one reserved is created
+    and moves its 50 from debits_pending to debits_posted. The router's
+    hysteresis sends the posts to the converging tier."""
+    led, sm = _limited_pair(funding=100)
+    _both(led, sm, [_pending(10, CUSTOMER, OPERATOR, 80),
+                    _pending(11, CUSTOMER, OPERATOR, 50)], TS0 + 100)
+    got = _both(led, sm, [_resolve(20, 11, TF.post_pending_transfer, 50),
+                          _resolve(21, 10, TF.post_pending_transfer, 80)],
+                TS0 + 200)
+    assert got == [CreateTransferStatus.pending_transfer_not_found,
+                   CreateTransferStatus.created]
+    assert sm.accounts[CUSTOMER].debits_posted == 80
+    assert sm.accounts[CUSTOMER].debits_pending == 0
+    kinds = led.judged_kinds
+    assert kinds["post_void"]["by_tier"] == {"create_transfers_fixpoint": 1}
+    assert kinds["post_void"]["fixpoint_rounds"] == 1
+
+
+def test_a_void_frees_the_headroom_for_a_later_request():
+    """80 of the customer's 100 reserved (a second reservation of 50
+    declined), then voided; a later request reserves 90, which fits only
+    because the void released the 80, and a further 20 in the same
+    request is declined. A CPU test only: the benchmark's cell sends no
+    voids."""
+    led, sm = _limited_pair(funding=100)
+    _both(led, sm, [_pending(10, CUSTOMER, OPERATOR, 80),
+                    _pending(11, CUSTOMER, OPERATOR, 50)], TS0 + 100)
+    got = _both(led, sm, [_resolve(20, 10, TF.void_pending_transfer)],
+                TS0 + 200)
+    assert got == [CreateTransferStatus.created]
+    assert sm.accounts[CUSTOMER].debits_pending == 0
+    got = _both(led, sm, [_pending(30, CUSTOMER, OPERATOR, 90),
+                          _pending(31, CUSTOMER, OPERATOR, 20)], TS0 + 300)
+    assert got == [CreateTransferStatus.created,
+                   CreateTransferStatus.exceeds_credits]
+    assert sm.accounts[CUSTOMER].debits_pending == 90
+    assert led.judged_kinds["pending"]["escalated"] == 2

@@ -506,18 +506,22 @@ def test_ops_without_while_sum_to_the_union_on_a_chip_trace(
 def test_the_cells_metrics_are_appended_with_the_cell_that_reports_them():
     bench = _bench()
     names = [e["name"] for e in bench["per_layer"]]
-    tail = bench["per_layer"][names.index("host_judge_ms"):]
+    at = names.index("host_judge_ms")
+    tail = bench["per_layer"][at:at + 6]
     cell = ["limits.full_batch_1s"]
+    # The converging tier's metrics name every cell it judges in: the
+    # two-phase limits cell's pendings and posts too.
+    converging = cell + ["twophase_limits.full_batch_1s"]
     assert [(e["name"], e["layer"], e["moves"], e.get("workloads"))
             for e in tail] == [
         ("host_judge_ms", "device ledger", "request_p50_ms", cell),
         ("mirror_push_ms", "device ledger", "request_p50_ms", cell),
         ("host_judged_window_share", "device ledger", "accepted_tps", cell),
         ("kernel_ms.create_transfers_fixpoint", "kernels", "accepted_tps",
-         cell),
+         converging),
         ("create_transfers_fixpoint_roofline", "kernels", "accepted_tps",
-         cell),
-        ("fixpoint_rounds", "kernels", "accepted_tps", cell)]
+         converging),
+        ("fixpoint_rounds", "kernels", "accepted_tps", converging)]
     by_name = {e["name"]: e for e in bench["end_to_end"]}
     for e in tail:
         assert "workloads" not in by_name[e["moves"]], e["name"]
@@ -528,5 +532,10 @@ def test_the_cells_metrics_are_appended_with_the_cell_that_reports_them():
                 if w["name"] != "limits.full_batch_1s"]
     lists = {e["name"]: e.get("workloads") for e in bench["per_layer"]}
     for name in ("execute_delta_fetch_ms", "flush_columns_ms",
-                 "flush_account_reads_ms", "grid_readahead_used_share"):
+                 "flush_account_reads_ms"):
         assert lists[name] == accepted, name
+    # The grid's read-ahead is read in the cells that were accepted when
+    # the limits cell came, and in no cell added since.
+    cells = [w["name"] for w in bench["workloads"]]
+    assert lists["grid_readahead_used_share"] == \
+        cells[:cells.index("limits.full_batch_1s")]

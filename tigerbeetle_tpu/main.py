@@ -348,11 +348,16 @@ def cmd_start(args) -> int:
             # The per-batch create requests by who answered them: a
             # device tier by its jitted entry's name, or the host
             # mirror by its route (host_mirror, host_fallback).
-            "judged": dict(led.judged),
+            "judged": led.judged,
+            # The same requests by kind (accounts, single, pending,
+            # post_void, mixed): by tier, escalated, and the converging
+            # tier's dispatches and rounds; `judged` and `fixpoint` are
+            # its sums over kinds.
+            "judged_kinds": led.judged_kinds,
             # The converging limit tier's dispatches, the rounds they
             # ran summed, and the most in one (its `fix_rounds`,
             # fetched with its flags).
-            "fixpoint": dict(led.fixpoint_rounds),
+            "fixpoint": led.fixpoint_rounds,
             # Transfer rows the durable flush put, by path, and the
             # checkpoints taken: a checkpoint that put again what the
             # column path had already written would show here.

@@ -934,6 +934,18 @@ def _has_closing(evs) -> bool:
     return any((np.asarray(e["flags"]) & bit).any() for e in evs)
 
 
+def _request_kind(ev: dict) -> str:
+    """A create_transfers request's kind, from its flags column: `mixed`
+    where it holds pendings and posts or voids, else `pending`,
+    `post_void` or `single` (neither)."""
+    flags = np.asarray(ev["flags"])
+    pending = bool((flags & np.uint32(_pending_flag())).any())
+    post_void = bool((flags & np.uint32(_F_POST_VOID_HOST())).any())
+    if pending and post_void:
+        return "mixed"
+    return "pending" if pending else "post_void" if post_void else "single"
+
+
 def default_recovery_stats() -> dict:
     """The zero-valued recovery-counter record every ledger carries (the
     serving supervisor swaps in its live dict; see fallback_stats)."""
@@ -980,16 +992,23 @@ class DeviceLedger:
         # "why did we leave the device" record surfaced through
         # fallback_stats() and `start`'s shutdown record.
         self.fallback_causes: dict = {}
-        # Per-batch create requests by who answered them: the jitted
-        # entry of the dispatch that judged it (`create_transfers_fast`,
+        # Per-batch create requests by kind (`_request_kind`: accounts,
+        # single, pending, post_void, mixed), each with who answered
+        # them under `by_tier`: the jitted entry of the dispatch that
+        # judged it (`create_transfers_fast`,
         # `create_transfers_fixpoint`, `create_transfers_balancing`,
         # `create_accounts_fast`, ...: the `tier` of its last
         # execute_dispatch span), or the host mirror by its route
         # (`host_mirror`, `host_fallback`: execute_host_judge's
-        # `route`). Unlike the tier counters they do not overlap; they
-        # sum to the per-batch requests (`start`'s shutdown record).
-        self.judged: dict = {}
+        # `route`); the requests whose proof-gated dispatch fell back
+        # `limit_only` and were re-dispatched to a fixpoint tier
+        # (`escalated`); and the converging limit tier's dispatches and
+        # rounds (its `fix_rounds`, fetched with its flags). `judged`
+        # and `fixpoint_rounds` sum it over kinds (`start`'s shutdown
+        # record prints all three).
+        self.judged_kinds: dict = {}
         self._last_tier: str | None = None
+        self._fixpoint_rounds_max = 0
         # Dispatch-route observability: per-route window counts
         # ("chain" is the default scan-form whole-window route) and the
         # per-cause counts of prepares that fell OUT of the chain route
@@ -1010,11 +1029,6 @@ class DeviceLedger:
         # "no recoveries" is a measured number in every bench run.
         self.recovery_stats: dict = default_recovery_stats()
         self._bal_deep_first = 0
-        # The converging limit tier's rounds (its `fix_rounds`, fetched
-        # with its flags): dispatches, rounds summed, the most in one
-        # (`start`'s shutdown record, `fixpoint`).
-        self.fixpoint_rounds = {"dispatches": 0, "rounds": 0,
-                                "rounds_max": 0}
         # Adaptive kernel routing: after a batch resolves breaches via the
         # limit fixpoint, later batches dispatch the fixpoint kernel first
         # (skipping the headroom-proof attempt that would fail anyway)
@@ -1112,9 +1126,11 @@ class DeviceLedger:
         from .fast_kernels import create_accounts_fast_jit
 
         self.resolve_windows()  # pipeline ordering
+        kind = "accounts"
         if self._mirror_route():
             return self._host_judge(
-                "mirror", lambda sm: sm.create_accounts(accounts, timestamp))
+                "mirror", lambda sm: sm.create_accounts(accounts, timestamp),
+                kind)
         with self.tracer.span(Event.execute_stage, op=self.trace_op):
             ev = pad_account_events(accounts_to_arrays(accounts))
         n = len(accounts)
@@ -1126,13 +1142,14 @@ class DeviceLedger:
             tier = create_accounts_imported_jit
         # On fallback the adopted state is the old one (all selects
         # masked); it was donated, so adopt it before syncing down.
-        out, fallback = self._dispatch(tier, ev, timestamp, n, "fallback")
+        out, fallback = self._dispatch(tier, ev, timestamp, n, "fallback",
+                                       kind=kind)
         if fallback:
             return self._host_judge(
                 "fallback",
-                lambda sm: sm.create_accounts(accounts, timestamp))
+                lambda sm: sm.create_accounts(accounts, timestamp), kind)
         self.fast_batches += 1
-        self._count_judged(self._last_tier)
+        self._count_judged(self._last_tier, kind)
         self._probe_succeeded()
         st = np.asarray(out["r_status"][:n])
         ts = np.asarray(out["r_ts"][:n])
@@ -1920,7 +1937,7 @@ class DeviceLedger:
         return [self.create_transfers_soa(ev, ts)
                 for ev, ts in zip(evs, timestamps)]
 
-    def _escalate_fixpoint(self, evp, timestamp, n, balancing=False):
+    def _escalate_fixpoint(self, evp, timestamp, n, kind, balancing=False):
         """The 8-round balancing or imported fixpoint reported a cascade
         deeper than its budget (and no other obstacle): resolve it on
         device with that tier's 32-round variant before considering the
@@ -1938,21 +1955,22 @@ class DeviceLedger:
         deep = (create_transfers_balancing_deep_jit if balancing
                 else create_transfers_imported_fixpoint_deep_jit)
         deep_out, fallback = self._dispatch(deep, evp, timestamp, n,
-                                            "fallback")
+                                            "fallback", kind=kind)
         self.deep_fixpoint_batches += 1
         self.escalations += 1
         if balancing:
             self._bal_deep_first = self.DEEP_PROBE_INTERVAL
         return fallback, deep_out
 
-    def _dispatch(self, tier_jit, evp, timestamp, n, *flags):
-        """One create kernel dispatch of `tier_jit`: launch it, adopt
-        the state it returns (the old one was donated), and block on the
-        scalar `flags` of its output. Returns (out, *flag bools). One
-        execute_dispatch span per call — launch, device time and the
-        sync — so an escalation shows as a second span. The converging
-        limit tier's `fix_rounds` comes back in the same fetch: the
-        span's `rounds` tag and `fixpoint_rounds`."""
+    def _dispatch(self, tier_jit, evp, timestamp, n, *flags, kind):
+        """One create kernel dispatch of `tier_jit` for a request of
+        `kind`: launch it, adopt the state it returns (the old one was
+        donated), and block on the scalar `flags` of its output.
+        Returns (out, *flag bools). One execute_dispatch span per call
+        — launch, device time and the sync — so an escalation shows as
+        a second span. The converging limit tier's `fix_rounds` comes
+        back in the same fetch: the span's `rounds` tag and the kind's
+        `fixpoint_rounds`."""
         import jax
 
         from .fast_kernels import create_transfers_fixpoint_jit
@@ -1969,11 +1987,22 @@ class DeviceLedger:
                 *got, rounds = got
                 rounds = int(rounds)
                 dispatched.tags["rounds"] = rounds
-                fr = self.fixpoint_rounds
-                fr["dispatches"] += 1
-                fr["rounds"] += rounds
-                fr["rounds_max"] = max(fr["rounds_max"], rounds)
+                counts = self._kind_counts(kind)
+                counts["fixpoint_dispatches"] += 1
+                counts["fixpoint_rounds"] += rounds
+                self._fixpoint_rounds_max = max(self._fixpoint_rounds_max,
+                                                rounds)
         return (out, *(bool(x) for x in got))
+
+    def _escalate_limit_only(self, tier_jit, evp, timestamp, n, kind):
+        """The proof-gated dispatch of a request of `kind` fell back
+        `limit_only`: dispatch it again to the fixpoint tier `tier_jit`,
+        under one execute_escalate span. Returns (out, fallback)."""
+        self.escalations += 1
+        self._kind_counts(kind)["escalated"] += 1
+        with self.tracer.span(Event.execute_escalate, op=self.trace_op):
+            return self._dispatch(tier_jit, evp, timestamp, n, "fallback",
+                                  kind=kind)
 
     def warm_kernels(self, n_pad: int = N_PAD,
                      balancing: bool = True) -> None:
@@ -2021,8 +2050,9 @@ class DeviceLedger:
                 else transfers
             return sm.create_transfers(objs, timestamp)
 
+        kind = _request_kind(ev)
         if self._mirror_route():
-            return self._host_judge("mirror", judge)
+            return self._host_judge("mirror", judge, kind)
         n = len(ev["id_lo"])
         span, at = self.tracer.span, self.trace_op
         # Small batches compile + run at the smallest padded shape that
@@ -2043,17 +2073,16 @@ class DeviceLedger:
 
             out, fallback, limit_only = self._dispatch(
                 create_transfers_imported_jit, evp, timestamp, n,
-                "fallback", "limit_only")
+                "fallback", "limit_only", kind=kind)
             if fallback and limit_only:
                 # Resolvable on device (state was donated but unchanged
                 # on fallback — evp is intact).
-                self.escalations += 1
-                out, fallback = self._dispatch(
+                out, fallback = self._escalate_limit_only(
                     create_transfers_imported_fixpoint_jit, evp,
-                    timestamp, n, "fallback")
+                    timestamp, n, kind)
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
-                        evp, timestamp, n)
+                        evp, timestamp, n, kind)
                 if not fallback:
                     self.fixpoint_batches += 1
         elif _has_balancing([ev]):
@@ -2071,15 +2100,15 @@ class DeviceLedger:
                 self._bal_deep_first -= 1
                 out, fallback = self._dispatch(
                     create_transfers_balancing_deep_jit, evp, timestamp,
-                    n, "fallback")
+                    n, "fallback", kind=kind)
                 self.deep_fixpoint_batches += 1
             else:
                 out, fallback = self._dispatch(
                     create_transfers_balancing_jit, evp, timestamp, n,
-                    "fallback")
+                    "fallback", kind=kind)
                 if fallback and bool(out["fix_unconverged"]):
                     fallback, out = self._escalate_fixpoint(
-                        evp, timestamp, n, balancing=True)
+                        evp, timestamp, n, kind, balancing=True)
             if not fallback:
                 self.fixpoint_batches += 1
         elif self._fixpoint_first:
@@ -2089,7 +2118,7 @@ class DeviceLedger:
             # no breach.
             out, fallback, limit_hit = self._dispatch(
                 create_transfers_fixpoint_jit, evp, timestamp, n,
-                "fallback", "limit_hit")
+                "fallback", "limit_hit", kind=kind)
             if not fallback:
                 self.fixpoint_batches += 1
                 if not limit_hit:
@@ -2097,24 +2126,22 @@ class DeviceLedger:
         else:
             out, fallback, limit_only = self._dispatch(
                 create_transfers_fast_jit, evp, timestamp, n,
-                "fallback", "limit_only")
+                "fallback", "limit_only", kind=kind)
             if fallback and limit_only:
                 # The only obstacle was the balance-limit headroom proof,
                 # a collision, a closing flag or a void of a closing
                 # pending: all resolve natively on the fixpoint variant
                 # (only the state was donated — evp is intact).
-                self.escalations += 1
-                out, fallback = self._dispatch(
-                    create_transfers_fixpoint_jit, evp, timestamp, n,
-                    "fallback")
+                out, fallback = self._escalate_limit_only(
+                    create_transfers_fixpoint_jit, evp, timestamp, n, kind)
                 if not fallback:
                     self.fixpoint_batches += 1
                     self._fixpoint_first = True
         if fallback:
             self._note_fb(out)
-            return self._host_judge("fallback", judge)
+            return self._host_judge("fallback", judge, kind)
         self.fast_batches += 1
-        self._count_judged(self._last_tier)
+        self._count_judged(self._last_tier, kind)
         self._probe_succeeded()
         with span(Event.execute_encode, op=at):
             st = np.asarray(out["r_status"][:n])
@@ -3127,20 +3154,52 @@ class DeviceLedger:
                 for k, v in self.recovery_stats.items()},
         }
 
-    def _count_judged(self, who: str) -> None:
-        self.judged[who] = self.judged.get(who, 0) + 1
+    def _count_judged(self, who: str, kind: str) -> None:
+        counts = self._kind_counts(kind)
+        counts["requests"] += 1
+        counts["by_tier"][who] = counts["by_tier"].get(who, 0) + 1
 
-    def _host_judge(self, route: str, judge):
-        """One create request answered by the host mirror: `route` is
-        "mirror" (the hard regime's route, nothing dispatched) or
-        "fallback" (the device's tiers fell back, which enters or holds
-        the regime). `judge(sm)` runs the request on the mirror. The
+    def _kind_counts(self, kind: str) -> dict:
+        """`kind`'s entry of `judged_kinds`."""
+        counts = self.judged_kinds.get(kind)
+        if counts is None:
+            counts = self.judged_kinds[kind] = {
+                "requests": 0, "by_tier": {}, "escalated": 0,
+                "fixpoint_dispatches": 0, "fixpoint_rounds": 0}
+        return counts
+
+    @property
+    def judged(self) -> dict:
+        """The per-batch create requests by who answered them, summed
+        over `judged_kinds`. Unlike the tier counters they do not
+        overlap; they sum to the per-batch requests."""
+        out: dict = {}
+        for counts in self.judged_kinds.values():
+            for who, n in counts["by_tier"].items():
+                out[who] = out.get(who, 0) + n
+        return out
+
+    @property
+    def fixpoint_rounds(self) -> dict:
+        """The converging limit tier's dispatches and their rounds,
+        summed over `judged_kinds`, and the most in one."""
+        kinds = self.judged_kinds.values()
+        return {"dispatches": sum(c["fixpoint_dispatches"] for c in kinds),
+                "rounds": sum(c["fixpoint_rounds"] for c in kinds),
+                "rounds_max": self._fixpoint_rounds_max}
+
+    def _host_judge(self, route: str, judge, kind: str):
+        """One create request of `kind` answered by the host mirror:
+        `route` is "mirror" (the hard regime's route, nothing
+        dispatched) or "fallback" (the device's tiers fell back, which
+        enters or holds the regime). `judge(sm)` runs the request on
+        the mirror. The
         pure-Python oracle IS the exact sequential semantics — in the
         mirror regime it beats the device sequential kernel because the
         per-batch prefetch/compile cost disappears."""
         span, at = self.tracer.span, self.trace_op
         self.fallbacks += 1
-        self._count_judged("host_" + route)
+        self._count_judged("host_" + route, kind)
         with span(Event.execute_mirror_drain, op=at):
             self.drain_mirror()
         if route == "fallback":

@@ -660,8 +660,10 @@ def _scenario_commit_stage_children(col: _Collector) -> None:
     grid's block cache shrinks to one block after the checkpoint, so the
     next flush's batched read of its accounts goes to the device:
     grid_read, grid_verify; the checkpoint's table writes opened
-    grid_write), and two requests the host mirror judges (the three
-    execute_* spans of that path); then
+    grid_write), a debit of an account that cannot overdraw, whose
+    proof-gated dispatch falls back `limit_only` (execute_escalate), and
+    two requests the host mirror judges (the three execute_* spans of
+    that path); then
     the serving loop over a bus that wakes it
     for one long turn (loop_busy), under the collector hook (host_gc)."""
     import gc
@@ -672,7 +674,8 @@ def _scenario_commit_stage_children(col: _Collector) -> None:
     from ..main import serve
     from ..state_machine import StateMachine
     from ..trace import install_gc_spans
-    from ..types import Account, Operation, Transfer, TransferFlags
+    from ..types import (Account, AccountFlags, Operation, Transfer,
+                         TransferFlags)
     from .cluster import Cluster
 
     tracer = col.make(0)
@@ -719,6 +722,17 @@ def _scenario_commit_stage_children(col: _Collector) -> None:
     assert replica.state_machine.account_cache_stats()["cache_misses"] == 2
     assert replica.durable.account_reads["flush_reads"] > 0
     assert replica.durable.grid.device_reads > 0
+    # A debit of an account that must not be overdrawn and holds no
+    # credit: the headroom proof fails, the fast tier falls back
+    # `limit_only` and the request is dispatched again to the
+    # converging tier (execute_escalate), which declines it.
+    drive(Operation.create_accounts, multi_batch.encode(
+        [Account(id=5, ledger=1, code=1, flags=int(
+            AccountFlags.debits_must_not_exceed_credits)).pack()], 128))
+    drive(Operation.create_transfers, multi_batch.encode(
+        [Transfer(id=450, debit_account_id=5, credit_account_id=1,
+                  amount=1, ledger=1, code=1).pack()], 128))
+    assert replica.state_machine.led.judged_kinds["single"]["escalated"] == 1
     # A request whose balances would overflow falls back to the host
     # mirror (execute_mirror_drain, execute_host_judge route=fallback,
     # execute_mirror_push); the next is the hard regime's (route=mirror).

@@ -296,6 +296,11 @@ class Event(enum.Enum):
         "`rounds`, on the converging limit tier alone, the rounds its "
         "while loop ran (fetched with the flags)",
         "op", "tier", "rounds", hist_tags=("tier",))
+    execute_escalate = _span(
+        "the re-dispatch of a create_transfers request to a fixpoint "
+        "tier after its proof-gated dispatch fell back `limit_only`: "
+        "the second execute_dispatch lies inside it, so the one that "
+        "judged is told from the one that was wasted", "op")
     execute_delta_fetch = _span(
         "write-through capture of one batch's device delta: the gather "
         "dispatch and the device -> host copy it starts (the wait for "

@@ -9,6 +9,8 @@ accounts' previous rows, and, where an op holds two-phase rows,
 `commit_execute` holds `lookup_ids`, `lookup_cache`, `lookup_tree` and
 `lookup_pack`; a request the device ledger hands to its host mirror
 `execute_mirror_drain`, `execute_host_judge` and `execute_mirror_push`;
+an escalated request's `execute_escalate` holds its second
+`execute_dispatch` (which `commit_execute` counts once, as a dispatch);
 the account reads (`flush_account_reads`,
 `lookup_tree`) hold the grid's `grid_read` and `grid_verify`, and
 `compact_beat` and `checkpoint_forest` its `grid_write`. A child
@@ -54,6 +56,11 @@ STAGE_CHILDREN: dict = {
     # has no flush_two_phase occurrence).
     "flush_columns": ("flush_account_reads", "flush_two_phase"),
     "flush_two_phase": ("memtable_fold",),
+    # The re-dispatch to a fixpoint tier after a `limit_only` fallback:
+    # the dispatch that judged the request. It is counted under
+    # commit_execute as any dispatch, so the escalation is not listed
+    # there beside it.
+    "execute_escalate": ("execute_dispatch",),
     # The grid's block work under its callers (lsm/grid.py, lsm/tree.py):
     # a batched read's device fan-out and checksums where a block missed
     # the cache, and the trees' block writes. These carry no `op`.
